@@ -39,7 +39,7 @@ metrics-check:
 # node + kernel benches, merged into observatory.json with provenance,
 # one trajectory line appended to PROGRESS.jsonl.  Gate the artifact
 # against a baseline with:
-#   $(PYTHON) -m upow_tpu.loadgen.gate --against BENCH_r05.json
+#   $(PYTHON) -m upow_tpu.loadgen.gate --against <an earlier observatory.json>
 perf-observatory:
 	JAX_PLATFORMS=cpu $(PYTHON) -m upow_tpu.loadgen \
 		--out observatory.json --progress PROGRESS.jsonl

@@ -35,7 +35,7 @@ class BadResidentIndex:
         return boxed_call(probe_staged, self.table, fps)   # DR002
 
     def rebuild(self, fps):
-        # staging at call time hides the kernel from arm-time AOT warm
+        # staging at call time builds a new program per call
         fresh = jax.jit(probe_kernel)                 # DR003
         return fresh(self.table, fps)
 

@@ -312,16 +312,16 @@ def test_gate_trend_skips_driver_lines_and_tracks_direction(tmp_path):
 def test_rotate_keep_tail_preserves_complete_lines(tmp_path):
     """Satellite 2: the size cap keeps the newest half, aligned to a
     line boundary, and is a no-op under the cap."""
-    import tpu_watch
+    import bench  # repo root: the bench event log's rotation
 
     p = tmp_path / "grow.log"
     p.write_text("".join(f"line {i:06d} {'x' * 40}\n"
                          for i in range(4000)))
     before = p.stat().st_size
-    tpu_watch._rotate_keep_tail(str(p), max_bytes=before + 1)
+    bench._rotate_keep_tail(str(p), max_bytes=before + 1)
     assert p.stat().st_size == before   # under cap: untouched
 
-    tpu_watch._rotate_keep_tail(str(p), max_bytes=10_000)
+    bench._rotate_keep_tail(str(p), max_bytes=10_000)
     assert p.stat().st_size <= 5_000
     kept = p.read_text().splitlines()
     assert kept[0].startswith("line ")      # no partial first line

@@ -89,7 +89,7 @@ def test_fetch_mining_info_unwraps_node_errors(monkeypatch):
 
 
 def test_hang_watchdog_trips_on_stale_heartbeat():
-    """A dead-tunnel dispatch hangs forever; the watchdog must fire once
+    """A dispatch on a lost device hangs forever; the watchdog must fire once
     the heartbeat goes stale, and not before while it is refreshed."""
     import threading
     import time as _time

@@ -1,0 +1,181 @@
+"""The kernels of the served path, compiled for a described TPU v5e.
+
+No chip is attached here; the TPU compiler is.  Each case lowers the
+jitted program at the shape the node or the miner really dispatches and
+compiles it for one device of a ``v5e:2x2`` topology (the mesh search:
+for all four), so what Mosaic or XLA:TPU would refuse on the chip —
+tiling, fast-memory budget, an unpartitionable kernel — fails here, at
+no chip time.  A compile that passes says nothing about results or
+speed; ``chip_smoke.py`` is the run on the chip.
+
+Only one process may hold the TPU library, and it keeps it until it
+exits, so the topology is described inside this file's module fixture
+(never at import), every compile runs in the test's own process, and all
+cases live in this one file so one xdist worker gets them.
+
+The whole fused verify program (scalar prep + ladder,
+``p256._prep_and_verify_pallas_jac``) costs minutes of trace, lower and
+compile per padded shape; it is the ``slow`` case at the bottom, outside
+the tier-1 run.
+"""
+
+import os
+from decimal import Decimal
+
+import numpy as np
+import pytest
+
+# the compiler otherwise logs under /tmp
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding  # noqa: E402
+from jax.sharding import PartitionSpec as P  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without the chip (the next run warns
+    and recompiles): keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# ------------------------------------------------------------ sha256 ----
+
+@pytest.mark.parametrize("difficulty", ["6.0", "6.3"])
+def test_sha256_pallas_search_compiles(one_chip, no_compile_cache,
+                                       difficulty):
+    """The miner's default round: search_batch 2^24, tile_rows 64, at
+    the protocol's start difficulty and at a fractional one (the
+    charset branch of the kernel)."""
+    from upow_tpu.config import DeviceConfig
+    from upow_tpu.crypto import sha256 as sk
+
+    template = sk.make_template(bytes(104))
+    spec = sk.target_spec("ab" * 32, Decimal(difficulty))
+    compiled = sk._pow_search_pallas.lower(
+        _shape((8,), jnp.uint32, one_chip),
+        _shape((16,), jnp.uint32, one_chip),
+        _shape((), jnp.uint32, one_chip),
+        batch=DeviceConfig().search_batch, tile_rows=64,
+        nonce_spec=template.nonce_spec, spec=spec,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -------------------------------------------------------------- p256 ----
+
+def _ladder_args(n: int, sharding):
+    """Operand shapes of the Jacobian ladder kernel for ``n`` lanes:
+    what the device scalar prep hands it (traced, not run)."""
+    from upow_tpu.crypto import p256
+
+    outs = jax.eval_shape(
+        lambda packed: p256._scalar_prep(*p256._unpack_fused(packed),
+                                         w=p256.PALLAS_JAC_WINDOW),
+        jax.ShapeDtypeStruct((42, n), jnp.uint32))
+    return [_shape(o.shape, o.dtype, sharding) for o in outs]
+
+
+@pytest.mark.parametrize("lanes,tile", [
+    (128, 128),     # a small push_tx batch: one sublane row, grid 1
+    (2048, 1024),   # a default node's first dispatch: 1024 + 2 canaries
+])
+def test_p256_jacobian_ladder_kernel_compiles(one_chip, no_compile_cache,
+                                              lanes, tile):
+    from upow_tpu.crypto import p256
+
+    assert p256._pad_to_block(min(lanes, 1026)) == lanes
+    assert p256._pick_tile(lanes) == tile
+    compiled = p256._verify_device_pallas_jac.lower(
+        *_ladder_args(lanes, one_chip), tile=tile, interpret=False,
+        w=p256.PALLAS_JAC_WINDOW).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow
+def test_p256_fused_verify_program_compiles(one_chip, no_compile_cache):
+    """Scalar prep + ladder as the node dispatches them (~3 min): the
+    2,048-lane shape of a default node's first micro-batch."""
+    from upow_tpu.crypto import p256
+
+    compiled = p256._prep_and_verify_pallas_jac.lower(
+        _shape((42, 2048), jnp.uint32, one_chip), tile=1024,
+        w=p256.PALLAS_JAC_WINDOW).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# -------------------------------------------------------- utxo probe ----
+
+def test_utxo_probe_kernel_compiles(one_chip, no_compile_cache):
+    """The resident-index probe (plain XLA, no Pallas) at the size of
+    one full block: 8,160 outpoints queried against the 16,384-slot
+    table the fan-out leaves, default window."""
+    from upow_tpu.state import device_index as di
+
+    cap, queries = di._pow2(8160 + 8160), di._pow2(8160)
+    lane = _shape((cap,), jnp.int32, one_chip)
+    query = _shape((queries,), jnp.int32, one_chip)
+    compiled = di._probe_kernel.lower(
+        lane, lane, lane, lane, lane, lane,
+        _shape((), jnp.int32, one_chip),
+        query, query, query, query,
+        window=di.PROBE_WINDOW).compile()
+    assert compiled.memory_analysis() is not None
+
+
+# ------------------------------------------------------- mesh search ----
+
+def test_mesh_resident_search_compiles_on_four_devices(topo,
+                                                       no_compile_cache):
+    """`miner --device mesh` over the four chips of one host: one SPMD
+    program, a quarter of the default round on each device, the hit
+    reduced by a collective."""
+    from upow_tpu.config import DeviceConfig
+    from upow_tpu.crypto import sha256 as sk
+    from upow_tpu.parallel import mesh as pm
+
+    devices = topo.devices[:4]
+    assert len(devices) == 4
+    mesh = Mesh(np.array(devices), axis_names=("dp",))
+    rep = NamedSharding(mesh, P())
+    dp = NamedSharding(mesh, P("dp"))
+    compiled = pm._pow_search_mesh_resident.lower(
+        _shape((8,), jnp.uint32, rep), _shape((16,), jnp.uint32, rep),
+        _shape((4,), jnp.uint32, dp), _shape((4,), jnp.uint32, dp),
+        _shape((7,), jnp.uint32, rep),
+        batch_per_device=DeviceConfig().search_batch // 4,
+        nonce_spec=sk.make_template(bytes(104)).nonce_spec,
+        mesh=mesh).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text or "all_reduce" in text, \
+        "the pmin over dp must survive as a collective"

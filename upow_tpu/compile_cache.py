@@ -86,38 +86,82 @@ def host_fingerprint() -> str:
     return _FP_CACHE
 
 
-def evict_host_dir(cache_root: str) -> None:
-    """Delete this host's cache subdir (the layout twin of
-    :func:`enable`) — for recovery when a cached AOT entry miscomputes
-    or hangs (e.g. CPU features changed under the same fingerprint
-    after a VM migration)."""
-    import shutil
-
-    shutil.rmtree(os.path.join(cache_root, host_fingerprint()),
-                  ignore_errors=True)
-
+#: the one default root: ``.jax_cache`` at the top of the checkout — a
+#: fixed function of where the code lives, never of the working
+#: directory, a pid, a temporary name or the time (the path is part of
+#: what a cache hit depends on: a directory that moves never hits)
+DEFAULT_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 
 _enabled_dir = ""  # set by enable(); read by entry_count() for /metrics
 
 
-def enable(cache_root: str) -> str:
-    """Point JAX's persistent compile cache at a per-host subdir of
-    ``cache_root``.  Never raises; returns the directory used ('' on
-    failure)."""
+def evict_host_dir() -> None:
+    """Delete this host's subdir of the default root (the layout twin
+    of :func:`enable`) — recovery when a cached XLA:CPU AOT entry
+    miscomputes or hangs (CPU features changed under the same
+    fingerprint after a VM migration).  A directory placed from outside
+    through ``JAX_COMPILATION_CACHE_DIR`` is never touched."""
+    import shutil
+
+    shutil.rmtree(os.path.join(DEFAULT_ROOT, host_fingerprint()),
+                  ignore_errors=True)
+
+
+def enable() -> str:
+    """Turn on JAX's persistent compile cache; returns the directory.
+
+    One rule: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and no directory is set in code; otherwise the cache lives in
+    ``<checkout>/.jax_cache/<host fingerprint>``.  Called by the device
+    runtime at arm (every process: node, miner, benches) and by
+    tests/conftest.py.  Never raises ('' on failure)."""
     import jax
 
     global _enabled_dir
-    path = os.path.join(cache_root, host_fingerprint())
+    placed = os.environ.get(ENV_DIR)
+    path = placed or os.path.join(DEFAULT_ROOT, host_fingerprint())
     try:
-        jax.config.update("jax_compilation_cache_dir", path)
+        if not placed:
+            jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+        _count_persistent_lookups()
         _enabled_dir = path
         return path
     except Exception as e:
         logging.getLogger("upow_tpu.compile_cache").warning(
             "could not enable persistent compile cache at %s: %s", path, e)
         return ""
+
+
+#: JAX's own monitoring events -> /metrics counters
+#: (``compile_cache.persistent_hits`` / ``..._misses``): whether a
+#: process found its programs in the persistent cache or compiled them
+PERSISTENT_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile_cache.persistent_hits",
+    "/jax/compilation_cache/cache_misses": "compile_cache.persistent_misses",
+}
+_listening = False
+
+
+def _count_persistent_lookups() -> None:
+    global _listening
+    if _listening:
+        return
+    import jax.monitoring
+
+    from .telemetry import metrics
+
+    def on_event(event: str, **_kw) -> None:
+        name = PERSISTENT_EVENTS.get(event)
+        if name is not None:
+            metrics.inc(name)
+
+    jax.monitoring.register_event_listener(on_event)
+    _listening = True
 
 
 def entry_count() -> int:

@@ -8,8 +8,9 @@ Here one dataclass tree feeds the node, miner, wallet and bench; every
 field can come from (in order of precedence) explicit kwargs, a JSON
 config file, or ``UPOW_``-prefixed environment variables.
 
-Device selection (the ``device: cpu|tpu`` switch from BASELINE.json) maps
-to the mining/verify backend choices; mesh shape covers multi-chip.
+Device selection is ``device.device`` (``auto|tpu|cpu``, the switch from
+BASELINE.json), read once where the node and the miner start
+(device/runtime.py ``start``); mesh shape covers multi-chip.
 """
 
 from __future__ import annotations
@@ -27,13 +28,21 @@ DEFAULT_SEED_URL = "https://api.upow.ai/"
 class DeviceConfig:
     """Compute-backend selection (BASELINE.json `device` flag)."""
 
-    device: str = "auto"            # auto | tpu | cpu
+    device: str = "auto"            # auto | tpu | cpu — tpu: arm at
+                                    # start-up, exit non-zero without
+                                    # the chip, never fall back to the
+                                    # host or the jnp program; cpu: no
+                                    # TPU backend is ever initialised;
+                                    # auto: probe lazily and degrade
     search_backend: str = "auto"    # auto | pallas | jnp | native | python
     sig_backend: str = "auto"       # auto | tpu | host
     search_batch: int = 1 << 24     # nonces per device dispatch
     verify_pad_block: int = 128     # lane padding for the P-256 kernel
     verify_device_timeout: float = 240.0  # seconds before a hung device
                                     # dispatch falls back to the host path
+                                    # (the first dispatch of a shape, which
+                                    # compiles, gets COMPILE_ALLOWANCE x
+                                    # this: verify/txverify.py)
     mesh_devices: int = 0           # 0 = all visible devices
     utxo_index: bool = False        # device-resident UTXO membership
                                     # prefilter on block accept (worth it
@@ -51,11 +60,6 @@ class DeviceConfig:
                                     # digest prep of batch N overlaps the
                                     # in-flight sig verify of batch N-1
                                     # (verify/block.py); 0 = whole block
-
-    def resolve_search_backend(self, platform: str) -> str:
-        if self.search_backend != "auto":
-            return self.search_backend
-        return "pallas" if platform == "tpu" else "jnp"
 
     def apply_kernel_overrides(self) -> None:
         """Push the A/B-able kernel knobs into crypto.p256 (module-level
@@ -92,16 +96,10 @@ class DeviceRuntimeConfig:
     overridable as ``UPOW_DEVICE_RUNTIME_<FIELD>``."""
 
     arm_timeout: float = 90.0       # backend probe/arm deadline; a hung
-                                    # tunnel costs the process ONE such
-                                    # timeout, then every source runs on
-                                    # the host paths
-    aot_warm: bool = True           # compile the kernel set at arm time
-                                    # (real accelerators only; the CPU
-                                    # XLA fallbacks are never warmed)
-    compile_cache_dir: str = ""     # persistent compile cache root fed
-                                    # to compile_cache.enable() at arm
-                                    # ('' = caller manages it, as
-                                    # bench.py does)
+                                    # backend init costs the process ONE
+                                    # such timeout, then (device=auto)
+                                    # every source runs on the host
+                                    # paths
     weights: str = ("block=4,index=3,mempool=2,verify=2,"
                     "mine=1,bench=1,other=1")
                                     # fair-share weights per source; a

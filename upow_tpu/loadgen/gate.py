@@ -113,8 +113,6 @@ def flatten(doc: dict, prefix: str = "",
     kernels = doc.get("kernels")
     if isinstance(kernels, dict):
         for name, entry in kernels.items():
-            if name == "last_good_tpu":
-                continue  # stale snapshots must not gate a live run
             v = _num(entry.get("value")) if isinstance(entry, dict) \
                 else _num(entry)
             if v is not None:
@@ -195,8 +193,6 @@ def _flatten_progress_line(line: dict) -> Dict[str, float]:
             if v is not None:
                 out[f"slo.{ep}.{field}"] = v
     for name, value in (line.get("kernels") or {}).items():
-        if name == "last_good_tpu":
-            continue
         v = _num(value)
         if v is not None:
             out[f"kernel.{name}"] = v

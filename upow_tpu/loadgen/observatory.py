@@ -29,12 +29,8 @@ structured JSON artifact:
   trips on a broken hot/archive seam, same idiom as
   ``fleet_core_ok``).
 * ``provenance`` — what actually ran: ``backend``, ``platform``,
-  ``attempted_backend``, ``arm_failure_reason``, ``arm_attempt``
-  (which arm attempt produced this process — ``runtime`` /
-  ``cpu-child`` / ... — via bench.py's env contract in benchutil).
-  BENCH_r02–r05 all silently degraded to a scrubbed-env CPU child;
-  this block is the machine-readable record that it happened (or
-  didn't).
+  ``attempted_backend``, ``arm_failure_reason``: the machine-readable
+  record of whether the kernels ran on a device or on the host.
 * optionally appended (``--progress``) to PROGRESS.jsonl so the
   trajectory file carries SLO metrics alongside kernel throughput.
 
@@ -150,8 +146,7 @@ def kernel_bench(seconds: float = 0.4) -> dict:
 
 def _arm_device(probe_timeout: float) -> dict:
     """Try to arm a real accelerator; provenance either way, plus the
-    structured ``bench_arm_failed`` event on failure (satellite 1's
-    contract, shared with bench.py)."""
+    structured ``bench_arm_failed`` event on failure."""
     from .. import telemetry
     from ..benchutil import probed_platform_cached
 
@@ -210,17 +205,9 @@ def run_observatory(spec: Optional[PopulationSpec] = None,
 
     spec = spec or PopulationSpec()
     provenance = {"backend": "node-inprocess", "platform": "host",
-                  "attempted_backend": None, "arm_failure_reason": None,
-                  "arm_attempt": None}
+                  "attempted_backend": None, "arm_failure_reason": None}
     if device:
         provenance.update(_arm_device(probe_timeout))
-    # overlay the arm story bench.py's env contract carries (scrubbed
-    # CPU child, runtime re-arm, ...) — only the keys actually set, so
-    # a plain observatory run keeps its own probe-derived provenance
-    from ..benchutil import arm_provenance_from_env
-
-    provenance.update({k: v for k, v in
-                       arm_provenance_from_env().items() if v is not None})
 
     load = asyncio.run(run_against_node(spec))
     kernels = kernel_bench(bench_seconds)
@@ -316,20 +303,6 @@ def run_observatory(spec: Optional[PopulationSpec] = None,
                     k: analysis[k] for k in sorted(analysis)[:8]}
         except Exception as e:
             log.warning("cost analysis skipped: %s", e)
-
-    try:
-        from bench import _load_last_good_tpu  # repo-root bench.py
-
-        last_good = _load_last_good_tpu()
-    except Exception as e:  # installed-package runs have no bench.py
-        log.debug("last_good_tpu snapshot unavailable: %s", e)
-        last_good = None
-    if last_good:
-        kernels["last_good_tpu"] = {
-            metric: {"value": entry.get("value"),
-                     "unit": entry.get("unit"),
-                     "measured_at": entry.get("measured_at")}
-            for metric, entry in last_good.items()}
 
     artifact = {
         "kind": "perf_observatory",

@@ -5,8 +5,8 @@ round but holds no mesh: on a v5e-8 seven chips idle while one scans.
 This module owns the multi-device path:
 
 * **One compiled program** — ``parallel.mesh._pow_search_mesh_resident``
-  is jitted once per (batch_per_device, nonce_spec, mesh) at arm time
-  (AOT-warmed by the device runtime alongside the probe kernels).  Every
+  is jitted once per (batch_per_device, nonce_spec, mesh) at arm time.
+  Every
   job-specific field — midstate, tail words, per-shard ranges, packed
   target — rides as runtime data, so a new job or chain-tip change is a
   pure dispatch: zero recompilation, asserted by the ``mine_mesh``
@@ -20,11 +20,11 @@ This module owns the multi-device path:
   ``device/runtime.py`` ``submit_call`` under the weighted-fair source
   "mine", so mining rounds co-reside with block verify and mempool
   coalescing instead of racing them for the chip.
-* **Structured arm ladder** — :meth:`MeshEngine.arm` walks runtime →
-  scrubbed-env re-arm → child probe, capturing each attempt's actual
-  exception text and traceback fingerprint (no more opaque
-  "hung/failed"); the ladder lands in ``stats()`` and, via bench.py /
-  tpu_watch.py, in ``.bench_events.jsonl``.
+* **One arm, in the runtime** — :meth:`MeshEngine.arm` arms through
+  ``device/runtime.py`` and nothing else: no second attempt under a
+  changed environment, no probing child (a child would want the chip
+  this process holds).  The attempt's actual exception text and
+  traceback fingerprint land in ``stats()["arm_ladder"]``.
 
 Multi-host runs split the nonce space first via
 ``parallel.multihost.plan_nonce_ranges`` (each process mines its own
@@ -49,14 +49,10 @@ log = logging.getLogger("upow.mine.mesh")
 #: totals keep counting past the window
 ACCOUNTING_WINDOW = 4096
 
-#: wall-clock budget for one child-probe arm attempt
-_CHILD_PROBE_TIMEOUT = 60.0
-
-
 def _arm_attempt(name: str, ok: bool, seconds: float,
                  error: Optional[BaseException] = None,
                  detail: Optional[str] = None) -> dict:
-    """One rung of the arm ladder, with the real failure text captured."""
+    """The arm attempt's record, with the real failure text captured."""
     from ..benchutil import traceback_fingerprint
 
     rec = {"attempt": name, "ok": bool(ok), "seconds": round(seconds, 3)}
@@ -67,47 +63,6 @@ def _arm_attempt(name: str, ok: bool, seconds: float,
         rec["error"] = detail
     elif detail is not None:
         rec["detail"] = detail
-    return rec
-
-
-def _child_probe(timeout: float = _CHILD_PROBE_TIMEOUT) -> dict:
-    """Out-of-process backend probe for the last arm-ladder rung.
-
-    Runs ``jax.devices()`` in a child with the parent's env and captures
-    the child's stderr — when the in-process attempts died without a
-    Python exception (native hang, SIGKILL by the backend), the child's
-    stderr text is the only diagnostic there is.
-    """
-    import subprocess
-    import sys
-
-    from ..benchutil import text_fingerprint
-
-    code = ("import jax; d = jax.devices(); "
-            "print('PLATFORM=' + d[0].platform + ' COUNT=' + str(len(d)))")
-    t0 = time.perf_counter()
-    try:
-        proc = subprocess.run(
-            [sys.executable, "-c", code], capture_output=True, text=True,
-            timeout=timeout)
-    except subprocess.TimeoutExpired:
-        return _arm_attempt(
-            "child-probe", False, time.perf_counter() - t0,
-            detail=f"child probe hung past {timeout:.0f}s (backend init "
-                   "never returned in a fresh process either)")
-    dt = time.perf_counter() - t0
-    for line in proc.stdout.splitlines():
-        if line.startswith("PLATFORM="):
-            return _arm_attempt(
-                "child-probe", True, dt,
-                detail=line.strip() + " (child sees the backend; parent "
-                "process state is the blocker)")
-    tail = (proc.stderr or "").strip().splitlines()[-6:]
-    detail = (f"child probe rc={proc.returncode}; stderr tail: "
-              + (" | ".join(tail) if tail else "<empty>"))
-    rec = _arm_attempt("child-probe", False, dt, detail=detail)
-    if tail:
-        rec["traceback_fingerprint"] = text_fingerprint("\n".join(tail))
     return rec
 
 
@@ -153,70 +108,56 @@ class MeshEngine:
     def batch_per_device(self) -> int:
         return int(self._batch_per_device or 0)
 
+    def mesh_devices(self) -> list:
+        """The devices of the dp mesh, in shard order ([] before arm)."""
+        return [] if self._mesh is None else list(self._mesh.devices.flat)
+
     @property
     def capacity(self) -> int:
         """Max nonces a single dispatch can cover (n_dev * batch)."""
         return self._n_dev * self.batch_per_device
 
     def arm(self, timeout: Optional[float] = None) -> dict:
-        """Arm the runtime and compile the resident program, walking the
-        structured retry ladder: runtime → scrubbed-env re-arm → child
-        probe.  Each rung records its actual exception text; the ladder
-        is kept on the engine (and returned) so callers can log or emit
-        it verbatim instead of a generic "hung/failed"."""
+        """Arm the runtime (once per process — idempotent there) and
+        compile the resident program.  The attempt's actual exception
+        text is kept on the engine (and returned) so callers can log it
+        verbatim instead of a generic "hung/failed"."""
         if self._armed:
             return {"armed": True, "ladder": self.arm_ladder,
                     "devices": self._n_dev}
-        from ..config import DeviceRuntimeConfig
         from ..device.runtime import get_runtime
 
-        runtime = get_runtime()
-        timeout = timeout if timeout is not None else \
-            DeviceRuntimeConfig.from_env().arm_timeout
-        ladder: List[dict] = []
-        for name, kwargs in (
-                ("runtime", {}),
-                ("runtime-scrubbed-env", {"scrub_env": True, "force": True})):
-            t0 = time.perf_counter()
-            try:
-                runtime.arm(deadline=timeout, attempt=name, **kwargs)
-                if runtime.platform() is None:
-                    info = runtime.stats().get("arm", {})
-                    ladder.append(_arm_attempt(
-                        name, False, time.perf_counter() - t0,
-                        detail=info.get("arm_failure_reason")
-                        or "backend probe returned no platform"))
-                    continue
-                self._build_mesh_and_warm(via_runtime=True)
-                ladder.append(_arm_attempt(
-                    name, True, time.perf_counter() - t0,
-                    detail=f"{runtime.platform()} x{self._n_dev}"))
+        t0 = time.perf_counter()
+        try:
+            runtime = get_runtime()
+            runtime.arm(deadline=timeout)
+            if runtime.platform() is None:
+                info = runtime.stats().get("arm", {})
+                rung = _arm_attempt(
+                    "runtime", False, time.perf_counter() - t0,
+                    detail=info.get("arm_failure_reason")
+                    or "backend probe returned no platform")
+            else:
+                self._build_mesh_and_warm()
+                rung = _arm_attempt(
+                    "runtime", True, time.perf_counter() - t0,
+                    detail=f"{runtime.platform()} x{self._n_dev}")
                 self._armed = True
-                break
-            except Exception as e:
-                log.debug("mesh arm attempt %s failed", name, exc_info=True)
-                ladder.append(_arm_attempt(
-                    name, False, time.perf_counter() - t0, error=e))
-        if not self._armed:
-            ladder.append(_child_probe())
-        self.arm_ladder = ladder
-        if not self._armed:
-            self.arm_failure_reason = "; ".join(
-                f"{r['attempt']}: {r.get('error') or r.get('detail', '?')}"
-                for r in ladder)
-        else:
-            self.arm_failure_reason = None
-        return {"armed": self._armed, "ladder": ladder,
+        except Exception as e:
+            log.debug("mesh arm failed", exc_info=True)
+            rung = _arm_attempt("runtime", False,
+                                time.perf_counter() - t0, error=e)
+        self.arm_ladder = [rung]
+        self.arm_failure_reason = None if self._armed else (
+            f"runtime: {rung.get('error') or rung.get('detail', '?')}")
+        return {"armed": self._armed, "ladder": self.arm_ladder,
                 "devices": self._n_dev,
                 "arm_failure_reason": self.arm_failure_reason}
 
-    def _build_mesh_and_warm(self, via_runtime: bool) -> None:
+    def _build_mesh_and_warm(self) -> None:
         """Build the dp mesh from the armed runtime's device view and
-        compile the resident program with an all-invalid dummy dispatch.
-
-        ``via_runtime=False`` is for the runtime's own AOT-warm hook,
-        which runs adjacent to the drainer — a nested submit_call there
-        would deadlock the single drainer thread."""
+        compile the resident program with an all-invalid dummy dispatch
+        (submitted through the runtime like every later round)."""
         from ..config import DeviceConfig, _apply_env_fields
         from ..device.runtime import get_runtime
         from ..parallel.mesh import make_mesh, pow_search_resident
@@ -256,11 +197,8 @@ class MeshEngine:
                 zeros8, zeros16, zn, zn, zt,
                 self._batch_per_device, spec, self._mesh))
 
-        if via_runtime:
-            runtime.submit_call(
-                warm, kernel="sha256_search_mesh", source="mine").result()
-        else:
-            warm()
+        runtime.submit_call(
+            warm, kernel="sha256_search_mesh", source="mine").result()
 
     # ------------------------------------------------------------- job ---
 
@@ -416,24 +354,6 @@ def engine_stats() -> Optional[dict]:
     """Stats of the resident engine, or None before first use — the
     node's /metrics gauges read this without forcing an arm."""
     return _ENGINE.stats() if _ENGINE is not None else None
-
-
-def warm_resident_search() -> None:
-    """Arm-time AOT hook (device runtime): compile the resident mesh
-    program for the default engine when more than one device is visible.
-    Called adjacent to the runtime drainer — must NOT submit_call."""
-    from ..device.runtime import get_runtime
-
-    if len(get_runtime().devices()) < 2:
-        return  # single device: engine.mine's per-device path owns it
-    eng = get_mesh_engine()
-    if eng._armed:
-        return
-    eng._build_mesh_and_warm(via_runtime=False)
-    eng._armed = True
-    eng.arm_ladder = [
-        {"attempt": "runtime-aot-warm", "ok": True, "seconds": 0.0,
-         "detail": f"warmed at arm x{eng._n_dev}"}]
 
 
 def planned_range(lo: int = 0, hi: Optional[int] = None) -> Tuple[int, int]:

@@ -4,7 +4,7 @@ The signature-verify dispatch (verify/txverify.py) already survives a
 sick accelerator — errors fall back to the host batch, hangs are
 time-boxed — but before this module the policy was a one-way door: a few
 consecutive device errors *poisoned* the device path for the life of the
-process, so one transient XLA blip (tunnel flap, OOM during an unrelated
+process, so one transient XLA blip (a runtime error, OOM during an unrelated
 compile) cost the node its accelerator forever.
 
 :class:`DegradeManager` replaces the globals with a three-state machine:

@@ -64,6 +64,14 @@ def configure(cfg=None) -> None:
     # all-zero from scrape #1 so metrics-check can pin the name
     metrics.ensure_counter(events.ROTATED_UNSEEN)
     device.preregister("p256_verify")
+    metrics.ensure_counter("kernel.p256_verify.pallas_fallbacks")
+    # who really served: exported at zero from scrape #1 so a client
+    # (chip_smoke.py) reads "0 fallbacks", never "no such metric"
+    for name in ("compile_cache.persistent_hits",
+                 "compile_cache.persistent_misses",
+                 "verify.canary_pass", "verify.canary_fail",
+                 "resilience.device_fallback"):
+        metrics.ensure_counter(name)
     device.preregister("sha256_txid")
     device.preregister_runtime()
     device.preregister_index()

@@ -2,7 +2,7 @@
 
 When a bench-driven node fires an alert, the incident belongs next to
 the bench's own event stream (``bench_arm_failed``, ``bench_step_killed``
-— tpu_watch.py / bench.py format) so the trajectory tooling sees the
+— bench.py's format) so the trajectory tooling sees the
 regression and its exemplar trace in one place.  Same record shape and
 the same size-capped keep-newest-half rotation as the harnesses.
 
@@ -22,7 +22,7 @@ from ..logger import get_logger
 
 log = get_logger("watchtower")
 
-MAX_BYTES = 1 << 20   # matches tpu_watch.py / bench.py _EVENTS_MAX
+MAX_BYTES = 1 << 20   # matches bench.py _BENCH_EVENTS_MAX
 
 
 def _rotate_keep_tail(path: str, max_bytes: int) -> None:
