@@ -1,6 +1,6 @@
 """Perf-observatory tests: schedule determinism, SLO histogram
-exposition, the regression gate's exit codes, debug-endpoint limit
-hardening, and XLA cost-analysis recording.
+exposition, the regression gate's exit codes, and debug-endpoint limit
+hardening.
 
 The in-process-node integration lives in ``test_loadgen_node`` — the
 pure pieces here run without booting anything, so the determinism
@@ -311,39 +311,6 @@ def test_debug_limit_hardening(tmp_path):
             await cluster.close()
 
     asyncio.run(main())
-
-
-# ------------------------------------------- cost-analysis capture ----
-
-def test_cost_analysis_recorded():
-    """analyze_cost on a trivial program lands numeric estimates in the
-    device registry (and tolerates backends without cost_analysis)."""
-    from upow_tpu import profiling
-    from upow_tpu.telemetry import device
-
-    import jax.numpy as jnp
-
-    def f(x):
-        return (x * 2.0 + 1.0).sum()
-
-    out = profiling.analyze_cost("toy_sum", f, jnp.ones((8, 8)))
-    if out is None:  # backend exposes no cost model: recorded nothing
-        assert "toy_sum" not in device.cost_estimates()
-        return
-    assert all(isinstance(v, float) for v in out.values())
-    stored = device.cost_estimates()["toy_sum"]
-    assert stored and all(" " not in k and "-" not in k for k in stored)
-
-
-def test_record_cost_bounds():
-    from upow_tpu.telemetry import device
-
-    for i in range(200):
-        device.record_cost(f"k{i}", {"flops": float(i)})
-    assert len(device.cost_estimates()) <= 64
-    device.record_cost("wide", {f"key{i}": 1.0 for i in range(50)})
-    wide = device.cost_estimates().get("wide")
-    assert wide is None or len(wide) <= 16
 
 
 # ------------------------------------------------- profiler session ----

@@ -1,0 +1,432 @@
+"""The one span system: telemetry spans as profiler host events, the
+miner's job-rooted spans and counters, the compile listener's durations
+and the miner process's profiler hook.  Registries are process-wide and
+other tests feed them, so every count is a difference."""
+
+import glob
+import hashlib
+import importlib.util
+import os
+import random
+import signal
+import subprocess
+import sys
+
+import pytest
+
+from upow_tpu import telemetry
+from upow_tpu.mine import engine, miner
+from upow_tpu.mine.engine import MiningJob, mine
+from upow_tpu.telemetry import tracing
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+rng = random.Random(2525)
+
+
+def _job(difficulty="9", prev=None) -> MiningJob:
+    from upow_tpu.core import curve, point_to_string
+
+    prev = prev or bytes(rng.randrange(256) for _ in range(32)).hex()
+    _, pub = curve.keygen(rng=rng.randrange(1, 1 << 200))
+    return MiningJob.from_header_fields(
+        previous_hash=prev, address=point_to_string(pub),
+        merkle_root=hashlib.sha256(b"").hexdigest(),
+        timestamp=1_753_791_000, difficulty=difficulty)
+
+
+def _counter(name: str) -> int:
+    return telemetry.counters().get(name, 0)
+
+
+def _nodes(tree: dict) -> int:
+    return 1 + sum(_nodes(c) for c in tree.get("spans", []))
+
+
+# ------------------------------------------------ the profiler bridge --
+
+class FakeAnnotation:
+    log: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, kw
+
+    def __enter__(self):
+        FakeAnnotation.log.append(("open", self.name, dict(self.kw)))
+        return self
+
+    def __exit__(self, *exc):
+        FakeAnnotation.log.append(("close", self.name, dict(self.kw)))
+        return False
+
+
+@pytest.fixture
+def fake_annotations(monkeypatch):
+    FakeAnnotation.log = []
+    monkeypatch.setattr(tracing, "_annotation_cls", FakeAnnotation)
+    return FakeAnnotation.log
+
+
+def test_spans_open_and_close_annotations_lifo_with_trace_id(
+        fake_annotations):
+    with telemetry.request_trace("req", block=7) as root:
+        with telemetry.span("outer", kernel="k", blob=object()):
+            with telemetry.span("inner", light=True):
+                pass
+        child = telemetry.child_span(root, "explicit", n=3)
+        telemetry.finish_child(child)
+    tid = root.trace_id
+    assert [(what, name) for what, name, _kw in fake_annotations] == [
+        ("open", "req"), ("open", "outer"), ("open", "inner"),
+        ("close", "inner"), ("close", "outer"),
+        ("open", "explicit"), ("close", "explicit"), ("close", "req")]
+    by_name = {name: kw for what, name, kw in fake_annotations
+               if what == "open"}
+    assert all(kw["trace"] == tid for kw in by_name.values())
+    assert by_name["req"]["block"] == 7
+    # small fields only: an object is no keyword of a profiler event
+    assert by_name["outer"] == {"kernel": "k", "trace": tid}
+    assert by_name["explicit"]["n"] == 3
+
+
+def test_light_span_feeds_the_aggregate_and_leaves_the_tree_alone(
+        fake_annotations):
+    before = telemetry.stats().get("light.round", {}).get("count", 0)
+    with telemetry.request_trace("req") as root:
+        for _ in range(600):    # more than the 512-span budget of a root
+            with telemetry.span("light.round", light=True) as node:
+                assert node is None
+        with telemetry.span("job.level") as node:
+            assert node is not None
+    assert telemetry.stats()["light.round"]["count"] == before + 600
+    assert [c.name for c in root.children] == ["job.level"]
+    assert sum(1 for what, name, _kw in fake_annotations
+               if (what, name) == ("open", "light.round")) == 600
+
+
+def test_add_span_is_no_profiler_event(fake_annotations):
+    with telemetry.request_trace("req") as root:
+        telemetry.add_span(root, "already.over", 1.0, 2.0)
+    assert [c.name for c in root.children] == ["already.over"]
+    assert "already.over" not in {name for _w, name, _kw in fake_annotations}
+
+
+def test_a_failing_annotation_class_never_breaks_the_span(monkeypatch):
+    class Broken:
+        def __init__(self, name, **kw):
+            raise RuntimeError("profiler gone")
+
+    monkeypatch.setattr(tracing, "_annotation_cls", Broken)
+    with telemetry.request_trace("req") as root:
+        with telemetry.span("still.timed") as node:
+            pass
+    assert node.done and root.children == [node]
+
+
+def test_telemetry_spans_do_not_import_jax():
+    code = ("import sys\n"
+            "from upow_tpu import telemetry\n"
+            "with telemetry.request_trace('r'):\n"
+            "    with telemetry.span('a'):\n"
+            "        with telemetry.span('b', light=True):\n"
+            "            pass\n"
+            "assert telemetry.stats()['b']['count'] == 1\n"
+            "print('jax' in sys.modules)\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+    assert proc.stdout.strip() == "False"
+
+
+def test_only_tracing_names_the_profilers_annotation_class():
+    hits = []
+    for path in glob.glob(os.path.join(REPO, "upow_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            if "TraceAnnotation" in f.read():
+                hits.append(os.path.relpath(path, REPO))
+    assert hits == [os.path.join("upow_tpu", "telemetry", "tracing.py")]
+
+
+# ------------------------------------------- the miner's spans, counted --
+
+def test_a_job_counts_its_rounds_and_keeps_its_tree_small():
+    rounds, batch = 9, 512
+    before = {n: _counter(n) for n in
+              ("mine.rounds", "mine.nonces", "runtime.source.mine")}
+    agg = telemetry.stats()
+    with telemetry.request_trace("mine.job") as root:
+        result = mine(_job("9"), "jnp", batch=batch,
+                      stride_end=rounds * batch - 100)
+    assert result.nonce is None
+    assert result.hashes_tried == rounds * batch - 100
+    assert _counter("mine.rounds") - before["mine.rounds"] == rounds
+    assert _counter("mine.nonces") - before["mine.nonces"] == \
+        rounds * batch - 100
+    assert _counter("runtime.source.mine") \
+        - before["runtime.source.mine"] == rounds
+    tree = [t for t in telemetry.traces()["recent"]
+            if t["trace_id"] == root.trace_id][0]
+    assert _nodes(tree) <= 12
+    assert {c["name"] for c in tree["spans"]} == {"mine.prepare",
+                                                   "mine.first_issue"}
+
+    def grew(name):
+        return telemetry.stats()[name]["count"] \
+            - agg.get(name, {}).get("count", 0)
+
+    assert grew("mine.first_issue") == 1
+    assert grew("mine.round.issue") == rounds - 1
+    assert grew("mine.round.wait") == rounds
+    assert grew("runtime.call") == rounds
+
+
+def test_a_new_tip_is_one_compile_key_miss_and_the_same_tip_none():
+    name = "kernel.sha256_search.compile_cache_misses"
+    tip_a, tip_b = ("%064x" % 0xA11CE), ("%064x" % 0xB0B)
+    mine(_job("9", prev=tip_a), "jnp", batch=256, stride_end=256)
+    seen = _counter(name)
+    mine(_job("9", prev=tip_a), "jnp", batch=256, stride_end=256)
+    assert _counter(name) == seen
+    mine(_job("9", prev=tip_b), "jnp", batch=256, stride_end=256)
+    assert _counter(name) == seen + 1
+
+
+def test_mine_honours_a_patched_make_dispatcher(monkeypatch):
+    import numpy as np
+
+    from upow_tpu.crypto.sha256 import SENTINEL
+
+    calls = []
+
+    def make(job, backend, mesh_devices=0, batch=None):
+        def dispatch(start, count):
+            calls.append((start, count))
+            return np.uint32(SENTINEL)
+        return dispatch
+
+    monkeypatch.setattr(engine, "_make_dispatcher", make)
+    result = mine(_job("9"), "jnp", batch=100, stride_end=250)
+    assert calls == [(0, 100), (100, 100), (200, 50)]
+    assert result.nonce is None and result.hashes_tried == 250
+
+
+def test_search_programs_are_still_found_by_pow_search():
+    from upow_tpu.crypto import sha256
+    from upow_tpu.parallel import mesh
+
+    for fn in (sha256._pow_search_jnp, sha256._pow_search_pallas,
+               mesh._pow_search_mesh_resident):
+        assert "pow_search" in fn.__name__
+        assert "pow_search" in fn.__wrapped__.__name__
+
+
+def test_a_capture_holds_the_miners_spans_on_the_profilers_clock(tmp_path):
+    import jax
+
+    mine(_job("9"), "jnp", batch=256, stride_end=256)   # compile outside
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with telemetry.request_trace("mine.job"):
+            mine(_job("9"), "jnp", batch=256, stride_end=5 * 256)
+    finally:
+        jax.profiler.stop_trace()
+    found = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    assert found
+    data = jax.profiler.ProfileData.from_file(found[0])
+    lines_of: dict = {}
+    for plane in data.planes:
+        for n, line in enumerate(plane.lines):
+            for ev in line.events:
+                lines_of.setdefault(ev.name, set()).add((plane.name, n))
+    for name in ("mine.job", "mine.prepare", "mine.first_issue",
+                 "mine.round.issue", "mine.round.wait", "runtime.call"):
+        assert name in lines_of, sorted(lines_of)[:40]
+    # the device owner's thread is not the miner's
+    assert lines_of["runtime.call"].isdisjoint(lines_of["mine.round.wait"])
+    assert all(plane.startswith("/host:")
+               for plane, _n in lines_of["mine.round.wait"])
+
+
+# ------------------------------------------------ the compile listener --
+
+def test_a_fresh_jit_is_one_compile_and_its_second_call_none():
+    import jax
+    import jax.numpy as jnp
+
+    from upow_tpu import compile_cache
+
+    compile_cache.listen()
+    x = jnp.arange(8, dtype=jnp.uint32)
+    x.block_until_ready()
+
+    @jax.jit
+    def fresh_program_for_this_test(v):
+        return v * 3 + 1
+
+    before = _counter("compile.count")
+    backend = telemetry.histograms().get(
+        "compile.backend_seconds", {"count": 0})["count"]
+    with telemetry.request_trace("compile.test") as root:
+        fresh_program_for_this_test(x).block_until_ready()
+    assert _counter("compile.count") == before + 1
+    fresh_program_for_this_test(x).block_until_ready()
+    assert _counter("compile.count") == before + 1
+    hists = telemetry.histograms()
+    assert hists["compile.backend_seconds"]["count"] == backend + 1
+    assert hists["compile.trace_seconds"]["count"] >= 1
+    assert hists["compile.lower_seconds"]["count"] >= 1
+    record = telemetry.events.snapshot(kind="compile")[-1]
+    assert "fresh_program_for_this_test" in record["fun_name"]
+    assert record["trace_id"] == root.trace_id
+    assert record["backend_s"] > 0 and record["trace_s"] > 0
+
+
+# ------------------------------------------------------ the miner's CLI --
+
+def _minerlog():
+    spec = importlib.util.spec_from_file_location(
+        "benchmarks_minerlog",
+        os.path.join(REPO, "benchmarks", "harness", "minerlog.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _scripted_node(monkeypatch, difficulty, pushes):
+    from upow_tpu.core import curve, point_to_string
+
+    _, pub = curve.keygen(rng=77)
+    info = {"difficulty": difficulty,
+            "last_block": {"hash": "%064x" % 0xFEED, "id": 41},
+            "pending_transactions_hashes": ["%064x" % 5, "%064x" % 6]}
+    monkeypatch.setattr(miner, "fetch_mining_info", lambda node: dict(info))
+
+    def push(node, content, txs, block_no):
+        pushes.append((content, txs, block_no))
+        return {"ok": True}
+
+    monkeypatch.setattr(miner, "push_block", push)
+    return point_to_string(pub)
+
+
+@pytest.mark.parametrize("difficulty,ttl,kinds,rc", [
+    (1.0, 90.0, ["start", "job", "found", None, "mined"], 0),
+    (9.0, 0.0, ["start", "job", "round", "expired"], 1),
+])
+def test_the_miners_lines_are_the_ones_the_benchmark_parses(
+        monkeypatch, capsys, difficulty, ttl, kinds, rc):
+    monkeypatch.delenv("UPOW_PROFILE_ENABLED", raising=False)
+    pushes = []
+    address = _scripted_node(monkeypatch, difficulty, pushes)
+    before = {n: _counter(n) for n in
+              ("mine.jobs", "mine.jobs_found", "mine.jobs_expired")}
+    assert miner.run(address, "http://x/", "jnp", 4096, ttl,
+                     once=True) == rc
+    out = capsys.readouterr().out.splitlines()
+    log = _minerlog()
+    parsed = [log.parse_line(text) for text in out if text]
+    got = [None if rec is None else rec["kind"] for rec in parsed]
+    # a hit may take a few rounds: fold the progress lines before it
+    if rc == 0:
+        got = [k for k in got if k != "round"]
+    assert got == kinds, out
+    assert out[0] == ("upow_tpu miner: backend=jnp shard=0/1 "
+                      "nonces=[0, 4294967296) node=http://x/")
+    assert out[1] == (f"difficulty: {difficulty}  block: 42  "
+                      "confirming 2 transactions")
+    assert _counter("mine.jobs") == before["mine.jobs"] + 1
+    if rc == 0:
+        assert out[-3:] == ["{'ok': True}", "BLOCK MINED", ""]
+        assert len(pushes) == 1 and pushes[0][2] == 42
+        assert _counter("mine.jobs_found") == before["mine.jobs_found"] + 1
+    else:
+        assert out[-1] == "template expired after 4096 hashes; refreshing"
+        assert _counter("mine.jobs_expired") \
+            == before["mine.jobs_expired"] + 1
+    job = telemetry.traces()["recent"][-1]
+    assert job["name"] == "mine.job"
+    assert job["fields"]["end"] == ("found" if rc == 0 else "expired")
+    assert job["fields"]["tip"] == ("%064x" % 0xFEED)[-12:]
+    names = [c["name"] for c in job["spans"]]
+    assert names[:4] == ["mine.fetch", "mine.build_job", "mine.prepare",
+                         "mine.first_issue"]
+    assert ("mine.push" in names) == (rc == 0)
+
+
+def test_a_failed_fetch_is_counted_and_closes_its_span_with_the_error(
+        monkeypatch, capsys):
+    calls = []
+
+    def fetch(node):
+        calls.append(node)
+        if len(calls) == 1:
+            raise OSError("connection refused")
+        raise KeyboardInterrupt     # ends the loop at the second job
+
+    monkeypatch.setattr(miner, "fetch_mining_info", fetch)
+    monkeypatch.setattr(miner.time, "sleep", lambda s: None)
+    before = _counter("mine.fetch_errors")
+    with pytest.raises(KeyboardInterrupt):
+        miner.run("addr", "http://x/", "python", 64, 1.0, once=True)
+    assert _counter("mine.fetch_errors") == before + 1
+    assert "node unreachable: connection refused; retrying" in \
+        capsys.readouterr().err
+    failed = telemetry.traces()["recent"][-2]
+    assert failed["fields"]["end"] == "fetch_error"
+    assert failed["spans"][0]["name"] == "mine.fetch"
+    assert failed["spans"][0]["error"] == "OSError"
+
+
+@pytest.fixture
+def restored_signals():
+    sigs = (signal.SIGUSR1, signal.SIGUSR2, signal.SIGTERM)
+    saved = {s: signal.getsignal(s) for s in sigs}
+    yield
+    for s, handler in saved.items():
+        signal.signal(s, handler)
+
+
+def _main_with_a_stubbed_run(monkeypatch, rc=0):
+    monkeypatch.setenv("UPOW_MINER_CHILD", "1")
+    monkeypatch.setattr(miner, "_start_device", lambda device: 0)
+    monkeypatch.setattr(miner, "run", lambda *a, **kw: rc)
+    return miner.main(["addr", "--device", "python", "--once"])
+
+
+def test_main_leaves_an_installed_handler_alone_and_says_goodbye(
+        monkeypatch, capsys, restored_signals):
+    def theirs(*_a):
+        pass
+
+    signal.signal(signal.SIGUSR1, theirs)
+    signal.signal(signal.SIGUSR2, signal.SIG_DFL)
+    signal.signal(signal.SIGTERM, signal.SIG_DFL)
+    monkeypatch.setenv("UPOW_PROFILE_ENABLED", "1")
+    assert _main_with_a_stubbed_run(monkeypatch) == 0
+    assert signal.getsignal(signal.SIGUSR1) is theirs
+    assert signal.getsignal(signal.SIGUSR2) not in (signal.SIG_DFL, theirs)
+    with pytest.raises(SystemExit) as term:
+        signal.getsignal(signal.SIGTERM)()
+    assert term.value.code == 0
+    out = capsys.readouterr().out.splitlines()
+    assert out[-2].startswith("memory: peak_bytes=")
+    log = _minerlog()
+    assert log.parse_line(out[-2])["kind"] == "memory"
+    import json
+
+    flat = json.loads(out[-1][len("telemetry: "):])
+    assert set(flat) == {"spans", "counters"}
+
+
+def test_main_prints_and_installs_nothing_with_the_switch_off(
+        monkeypatch, capsys, restored_signals):
+    for s in (signal.SIGUSR1, signal.SIGUSR2, signal.SIGTERM):
+        signal.signal(s, signal.SIG_DFL)
+    monkeypatch.delenv("UPOW_PROFILE_ENABLED", raising=False)
+    assert _main_with_a_stubbed_run(monkeypatch, rc=1) == 1
+    for s in (signal.SIGUSR1, signal.SIGUSR2, signal.SIGTERM):
+        assert signal.getsignal(s) is signal.SIG_DFL
+    assert capsys.readouterr().out == ""

@@ -121,6 +121,7 @@ def enable() -> str:
     import jax
 
     global _enabled_dir
+    listen()
     placed = os.environ.get(ENV_DIR)
     path = placed or os.path.join(DEFAULT_ROOT, host_fingerprint())
     try:
@@ -128,7 +129,6 @@ def enable() -> str:
             jax.config.update("jax_compilation_cache_dir", path)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
-        _count_persistent_lookups()
         _enabled_dir = path
         return path
     except Exception as e:
@@ -144,23 +144,75 @@ PERSISTENT_EVENTS = {
     "/jax/compilation_cache/cache_hits": "compile_cache.persistent_hits",
     "/jax/compilation_cache/cache_misses": "compile_cache.persistent_misses",
 }
+#: JAX's duration events (the names jax 0.9.0 emits) -> histograms, and
+#: the key each takes in a ``compile`` event record.  A backend compile
+#: is the last step of one program's trace -> lower -> cache lookup ->
+#: compile, so the record made there carries the longest of each seen
+#: since the previous one on that thread (a nested jaxpr trace, a Pallas
+#: kernel's body, reports inside its parent's duration: the outermost
+#: is the program's).
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration":
+        ("compile.trace_seconds", "trace_s"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration":
+        ("compile.lower_seconds", "lower_s"),
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        ("compile.cache_retrieval_seconds", "cache_retrieval_s"),
+    BACKEND_COMPILE_EVENT: ("compile.backend_seconds", "backend_s"),
+}
+COMPILE_BUCKETS = (0.01, 0.05, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0,
+                   120.0, 300.0, 600.0)
 _listening = False
 
 
-def _count_persistent_lookups() -> None:
+def listen() -> None:
+    """Count JAX's persistent-cache lookups and time its compiles, once
+    a process: the counters above, the ``compile.*_seconds`` histograms,
+    ``compile.count`` and one ``compile`` record a backend compile in
+    the ``telemetry.events`` ring (function name, the four durations,
+    the trace id of the span it ran under).  This is what splits a
+    miner's ``mine.first_issue``, the arm and a node's first dispatch
+    into trace / lower / cache lookup / compile.  Called by
+    :func:`enable` whether or not the persistent cache could be set up;
+    on the profiler's clock jax marks the compile itself
+    (``backend_compile_and_load``)."""
     global _listening
     if _listening:
         return
+    import threading
+
     import jax.monitoring
 
-    from .telemetry import metrics
+    from . import telemetry
+
+    pending = threading.local()   # a program compiles on one thread
 
     def on_event(event: str, **_kw) -> None:
         name = PERSISTENT_EVENTS.get(event)
         if name is not None:
-            metrics.inc(name)
+            telemetry.inc(name)
+
+    def on_duration(event: str, duration_secs: float, **kw) -> None:
+        known = DURATION_EVENTS.get(event)
+        if known is None:
+            return
+        hist, key = known
+        telemetry.observe(hist, duration_secs, buckets=COMPILE_BUCKETS)
+        seen = getattr(pending, "seen", None)
+        if seen is None:
+            seen = pending.seen = {}
+        seen[key] = max(seen.get(key, 0.0), duration_secs)
+        if event == BACKEND_COMPILE_EVENT:
+            telemetry.inc("compile.count")
+            # the ring stamps the record with the current trace id
+            telemetry.event(
+                "compile", fun_name=str(kw.get("fun_name", "")),
+                **{k: round(v, 6) for k, v in seen.items()})
+            seen.clear()
 
     jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
     _listening = True
 
 

@@ -450,9 +450,6 @@ class ProfilingConfig:
     max_capture_seconds: float = 120.0  # auto-stop: a capture left
                                     # running past this is closed on the
                                     # next /debug/profile touch
-    cost_analysis: bool = False     # record compiled.cost_analysis()
-                                    # FLOPs/bytes next to the
-                                    # compile-cache counters
 
 
 @dataclass

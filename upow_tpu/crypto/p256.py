@@ -1273,8 +1273,9 @@ def _unpack_fused(packed):
 @functools.partial(jax.jit, static_argnames=("tile",))
 def _prep_and_verify_pallas(packed, tile: int):
     """One dispatch: device scalar prep -> Pallas ladder kernel (RCB16)."""
-    args = _scalar_prep(*_unpack_fused(packed))
-    return _verify_device_pallas(*args, tile=tile)
+    with jax.named_scope("upow.p256_verify"):
+        args = _scalar_prep(*_unpack_fused(packed))
+        return _verify_device_pallas(*args, tile=tile)
 
 
 def _jac_body(packed, tile: int, w: int):
@@ -1282,9 +1283,10 @@ def _jac_body(packed, tile: int, w: int):
     ladder kernel -> stacked (2, N) bool (row 0 accept verdicts, row 1
     exception flags; those lanes need the host oracle).  One input and
     one output array = one transfer each way."""
-    args = _scalar_prep(*_unpack_fused(packed), w=w)
-    ok, exc = _verify_device_pallas_jac(*args, tile=tile, w=w)
-    return jnp.stack([ok, exc])
+    with jax.named_scope("upow.p256_verify"):
+        args = _scalar_prep(*_unpack_fused(packed), w=w)
+        ok, exc = _verify_device_pallas_jac(*args, tile=tile, w=w)
+        return jnp.stack([ok, exc])
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "w"))
@@ -1319,9 +1321,11 @@ def _prep_and_verify_pallas_jac_sharded(packed, tile: int, mesh,
 
 @jax.jit
 def _prep_and_verify_jnp(packed):
-    d1, d2, qxm, qym, rmp, rnmp, flags = _scalar_prep(*_unpack_fused(packed))
-    return _verify_device(d1, d2, qxm, qym, rmp, rnmp,
-                          flags[0] != 0, flags[1] != 0)
+    with jax.named_scope("upow.p256_verify"):
+        d1, d2, qxm, qym, rmp, rnmp, flags = _scalar_prep(
+            *_unpack_fused(packed))
+        return _verify_device(d1, d2, qxm, qym, rmp, rnmp,
+                              flags[0] != 0, flags[1] != 0)
 
 
 def _pack_device_inputs(digests, signatures, pubkeys, padded: int):

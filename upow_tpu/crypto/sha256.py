@@ -322,12 +322,14 @@ def _hit_nonce_dynamic(digest, nonces, target, valid=None):
 @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec", "spec"))
 def _pow_search_jnp(midstate, tail_words, nonce_base, batch: int,
                     nonce_spec, spec: TargetSpec):
-    nonces = nonce_base + jnp.arange(batch, dtype=jnp.uint32)
-    state = tuple(midstate[i] for i in range(8))
-    w = _build_w(tail_words, nonces, nonce_spec)
-    digest = _compress_tail(state, w)
-    t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
-    return _hit_nonce(digest, nonces, *t, spec)
+    with jax.named_scope("upow.sha256_search"):
+        nonces = nonce_base + jnp.arange(batch, dtype=jnp.uint32)
+        state = tuple(midstate[i] for i in range(8))
+        w = _build_w(tail_words, nonces, nonce_spec)
+        digest = _compress_tail(state, w)
+        t = [jnp.uint32(x)
+             for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
+        return _hit_nonce(digest, nonces, *t, spec)
 
 
 def pow_search_jnp(template: SearchTemplate, spec: TargetSpec,
@@ -397,18 +399,20 @@ def _pow_search_pallas(midstate, tail_words, nonce_base, batch: int,
     kernel = functools.partial(
         _pallas_kernel, tile_rows=tile_rows, nonce_spec=nonce_spec, spec=spec
     )
-    per_tile = pl.pallas_call(
-        kernel,
-        grid=(grid,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        interpret=interpret,
-    )(midstate, tail_words, nonce_base.reshape(1))
+    with jax.named_scope("upow.sha256_search"):
+        per_tile = pl.pallas_call(
+            kernel,
+            grid=(grid,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
+                                   memory_space=pltpu.SMEM),
+            out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+            interpret=interpret,
+        )(midstate, tail_words, nonce_base.reshape(1))
     return per_tile[0, 0].astype(jnp.uint32) ^ jnp.uint32(0x80000000)
 
 

@@ -53,7 +53,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..logger import get_logger
 from ..telemetry import device as ktel
-from ..telemetry import metrics
+from ..telemetry import metrics, tracing
 
 log = get_logger("device.runtime")
 
@@ -476,13 +476,14 @@ class DeviceRuntime:
 
         waits = {m.source: time.perf_counter() - m.t0 for m in group}
         def dispatch(be: str):
-            self._fire_fault("sig:" + ",".join(
-                sorted({m.source for m in group})))
-            return txverify.run_sig_checks(
-                flat, backend=be, pad_block=pad_block,
-                device_timeout=device_timeout,
-                precomputed=group[0].precomputed,
-                mesh_devices=mesh_devices)
+            sources = ",".join(sorted({m.source for m in group}))
+            self._fire_fault("sig:" + sources)
+            with tracing.span("runtime.sig", sources=sources, n=len(flat)):
+                return txverify.run_sig_checks(
+                    flat, backend=be, pad_block=pad_block,
+                    device_timeout=device_timeout,
+                    precomputed=group[0].precomputed,
+                    mesh_devices=mesh_devices)
 
         try:
             # run inside the triggering submitter's contextvars so
@@ -531,7 +532,10 @@ class DeviceRuntime:
 
         def wrapped():
             self._fire_fault("call:%s" % item.kernel)
-            return item.fn()
+            # light: a miner submits one call a round
+            with tracing.span("runtime.call", light=True,
+                              kernel=item.kernel, source=item.source):
+                return item.fn()
 
         try:
             if item.timeout is not None:

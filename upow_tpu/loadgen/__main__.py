@@ -28,9 +28,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--device", action="store_true",
                     help="probe/arm a real accelerator (provenance "
                          "records the failure reason if it degrades)")
-    ap.add_argument("--cost", action="store_true",
-                    help="record XLA cost_analysis for the jnp search "
-                         "kernel (forces a compile)")
     ap.add_argument("--out", default="observatory.json",
                     help="artifact path (default observatory.json)")
     ap.add_argument("--progress", default=None,
@@ -65,7 +62,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         co_spec.seed = args.seed
 
     artifact = run_observatory(spec, bench_seconds=args.bench_seconds,
-                               device=args.device, cost=args.cost,
+                               device=args.device,
                                readpath_spec=rp_spec,
                                coresidency_spec=co_spec)
     write_artifact(artifact, args.out)

@@ -167,36 +167,9 @@ def _arm_device(probe_timeout: float) -> dict:
             "arm_failure_reason": None}
 
 
-def _kernel_cost_analysis() -> Optional[dict]:
-    """Record the XLA cost analysis of the production jnp search
-    program at a small batch (compile on whatever backend is armed)."""
-    from .. import profiling
-    from ..core import curve, point_to_string
-    from ..core.header import BlockHeader
-    from ..core.merkle import merkle_root
-    from ..crypto import make_template, target_spec
-    from ..crypto import sha256 as sk
-
-    import jax.numpy as jnp
-
-    _, pub = curve.keygen(rng=0xBE7C)
-    header = BlockHeader(
-        previous_hash=bytes(range(32)).hex(), address=point_to_string(pub),
-        merkle_root=merkle_root([]), timestamp=1_753_791_000,
-        difficulty_x10=90, nonce=0)
-    template = make_template(header.prefix_bytes())
-    spec = target_spec(header.previous_hash, "9.0")
-    batch = 1 << 10
-    return profiling.analyze_cost(
-        f"sha256_pow_search_jnp_b{batch}", sk._pow_search_jnp,
-        jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
-        jnp.uint32(0), batch, template.nonce_spec, spec)
-
-
 def run_observatory(spec: Optional[PopulationSpec] = None,
                     bench_seconds: float = 0.4,
                     device: bool = False,
-                    cost: bool = False,
                     probe_timeout: float = 90.0,
                     readpath_spec=None,
                     coresidency_spec=None) -> dict:
@@ -294,15 +267,6 @@ def run_observatory(spec: Optional[PopulationSpec] = None,
         # archive_parity_ok zeroes on ANY failed core assertion in the
         # pruned-vs-twin scenario, defeating any gate tolerance
         kernels.update(archive["kernels"])
-
-    if cost:
-        try:
-            analysis = _kernel_cost_analysis()
-            if analysis:
-                kernels["search_jnp_cost_analysis"] = {
-                    k: analysis[k] for k in sorted(analysis)[:8]}
-        except Exception as e:
-            log.warning("cost analysis skipped: %s", e)
 
     artifact = {
         "kind": "perf_observatory",

@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from decimal import Decimal
 from typing import Callable, Optional
 
+from .. import telemetry
 from ..core.difficulty import check_pow_hash, pow_target
 from ..core.header import BlockHeader
 from ..crypto import sha256 as sha_kernel
@@ -112,6 +113,13 @@ def _make_dispatcher(job: MiningJob, backend: str,
     template = sha_kernel.make_template(job.prefix)
     spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
     fn = sha_kernel.pow_search_pallas if backend == "pallas" else sha_kernel.pow_search_jnp
+    # once a job, not a round: the target is a static argument of the
+    # program, so a new tip is a new compile key and counts as a miss
+    # (the resident mesh program's twin is kernel.mine_mesh.*)
+    lanes = batch or 1
+    telemetry.device.record_batch(
+        "sha256_search", real=lanes, padded=lanes,
+        compile_key=(batch, template.nonce_spec, spec))
 
     def dispatch(start: int, count: int):
         return fn(template, spec, nonce_base=start, batch=count)
@@ -184,8 +192,9 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
     tried = 0
     cursor = start
 
-    dispatch = _make_dispatcher(job, backend, mesh_devices=mesh_devices,
-                                batch=batch)
+    with telemetry.span("mine.prepare", backend=backend):
+        dispatch = _make_dispatcher(job, backend, mesh_devices=mesh_devices,
+                                    batch=batch)
     if dispatch is not None:
         # Pipelined device rounds: keep `depth` dispatches in flight so the
         # chip never idles while the host blocks on a result.  A hit wastes
@@ -197,13 +206,23 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
         while cursor < stride_end or inflight:
             while len(inflight) < depth and cursor < stride_end:
                 count = min(batch, stride_end - cursor)
-                inflight.append((dispatch(cursor, count), cursor, count))
                 if first is None:
+                    # on the static-target engine a new tip's trace and
+                    # compile live in this dispatch
+                    with telemetry.span("mine.first_issue"):
+                        handle = dispatch(cursor, count)
                     first = time.time() - t0
+                else:
+                    with telemetry.span("mine.round.issue", light=True):
+                        handle = dispatch(cursor, count)
+                inflight.append((handle, cursor, count))
                 cursor += count
             handle, _, count = inflight.pop(0)
-            hit = int(handle)
+            with telemetry.span("mine.round.wait", light=True):
+                hit = int(handle)
             tried += count
+            telemetry.inc("mine.rounds")
+            telemetry.inc("mine.nonces", count)
             if hit != int(sha_kernel.SENTINEL):
                 if job.check(hit):
                     if backend == "mesh":
@@ -215,7 +234,8 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
                     f"backend {backend} returned nonce {hit} failing host check")
             elapsed = time.time() - t0
             if progress is not None:
-                progress(tried, elapsed)
+                with telemetry.span("mine.progress", light=True):
+                    progress(tried, elapsed)
             if elapsed > ttl:
                 break
         return MineResult(None, tried, time.time() - t0, first or 0.0)
@@ -225,6 +245,8 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
         count = min(batch, stride_end - cursor)
         hit = search(cursor, count)
         tried += count
+        telemetry.inc("mine.rounds")
+        telemetry.inc("mine.nonces", count)
         if hit is not None:
             # device says hit; host double-checks before shipping (cheap)
             if job.check(hit):

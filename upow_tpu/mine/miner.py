@@ -23,6 +23,7 @@ import urllib.error
 import urllib.request
 from typing import Optional
 
+from .. import telemetry
 from ..core.clock import timestamp
 from ..core.merkle import miner_merkle_root
 from .engine import MiningJob, mine
@@ -168,49 +169,75 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
                  "limit": ttl + hang_grace + first_round_grace}
     if backend in ("pallas", "jnp", "mesh") and not once:
         _start_hang_watchdog(heartbeat, ttl + hang_grace)
-    while True:
+
+    def progress(tried, elapsed):
         heartbeat["t"] = time.monotonic()
+        heartbeat["limit"] = ttl + hang_grace  # compiled: steady budget
+        print(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
+
+    def one_job(root) -> Optional[int]:
+        """Fetch, search and push one job under its ``mine.job`` root.
+        Returns what ``--once`` exits with, or None after a failed
+        fetch (the caller waits and retries)."""
         try:
-            info = fetch_mining_info(node)
+            with telemetry.span("mine.fetch"):
+                info = fetch_mining_info(node)
         except (urllib.error.URLError, OSError, ValueError,
                 RuntimeError) as e:
             # RuntimeError carries a node error envelope (syncing,
             # rate-limited) — transient, retry like unreachable
+            telemetry.inc("mine.fetch_errors")
+            root.fields["end"] = "fetch_error"
             print(f"node unreachable: {e}; retrying", file=sys.stderr)
-            time.sleep(1)
-            continue
-        job, pending_hashes, block_no = build_job(info, address)
+            return None
+        with telemetry.span("mine.build_job") as built:
+            job, pending_hashes, block_no = build_job(info, address)
+            if built is not None:
+                built.fields["pending"] = len(pending_hashes)
+        telemetry.inc("mine.jobs")
+        root.fields.update(
+            block=block_no, difficulty=str(info["difficulty"]),
+            tip=str(getattr(job, "previous_hash", ""))[-12:])
         print(f"difficulty: {info['difficulty']}  block: {block_no}  "
               f"confirming {len(pending_hashes)} transactions")
-
-        def progress(tried, elapsed):
-            heartbeat["t"] = time.monotonic()
-            heartbeat["limit"] = ttl + hang_grace  # compiled: steady budget
-            print(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
-
         result = mine(job, backend, start=lo, stride_end=hi, batch=batch,
                       ttl=ttl, progress=progress, mesh_devices=mesh_devices)
         if result.nonce is None:
+            telemetry.inc("mine.jobs_expired")
+            root.fields["end"] = "expired"
             print(f"template expired after {result.hashes_tried} hashes; refreshing")
-            if once:
-                return 1
-            continue
+            return 1
+        telemetry.inc("mine.jobs_found")
+        root.fields["end"] = "found"
         content = job.block_content(result.nonce)
         print(f"found nonce {result.nonce} at {result.hashrate / 1e6:.2f} MH/s"
               f" ({result.hashes_tried} hashes in {result.elapsed:.2f}s, first"
               f" dispatch {result.first_dispatch:.2f}s)")
         if backend == "mesh":
             _print_mesh_accounting(mesh_devices)
-        try:
-            reply = push_block(node, content, pending_hashes, block_no)
-        except (urllib.error.URLError, OSError, ValueError) as e:
-            print(f"push_block failed: {e}", file=sys.stderr)
-            reply = {"ok": False}
+        with telemetry.span("mine.push") as pushed:
+            try:
+                reply = push_block(node, content, pending_hashes, block_no)
+            except (urllib.error.URLError, OSError, ValueError) as e:
+                telemetry.inc("mine.push_errors")
+                print(f"push_block failed: {e}", file=sys.stderr)
+                reply = {"ok": False}
+            if pushed is not None:
+                pushed.fields["ok"] = bool(reply.get("ok"))
         print(reply)
         if reply.get("ok"):
             print("BLOCK MINED\n")
-        if once:
-            return 0 if reply.get("ok") else 1
+        return 0 if reply.get("ok") else 1
+
+    while True:
+        heartbeat["t"] = time.monotonic()
+        with telemetry.request_trace("mine.job", backend=backend,
+                                     shard=f"{i}/{k}") as root:
+            rc = one_job(root)
+        if rc is None:
+            time.sleep(1)
+        elif once:
+            return rc
 
 
 def _print_mesh_accounting(mesh_devices: int) -> None:
@@ -243,6 +270,63 @@ def _start_device(device: str) -> int:
     if info:
         print(_dr.device_line(info), flush=True)
     return 0
+
+
+def _say(line: str) -> None:
+    """One write, newline and all: a hook's thread and the miner's
+    thread both print, and ``print`` writes a line and its end apart."""
+    sys.stdout.write(line + "\n")
+    sys.stdout.flush()
+
+
+def _install_profile_hooks(profile) -> None:
+    """The searching process's profiler hook (``ProfilingConfig.enabled``
+    only): SIGUSR1 starts a ``jax.profiler`` capture into
+    ``profile.trace_dir``, SIGUSR2 stops it, each answered by one
+    ``profile:`` line on stdout; SIGTERM exits cleanly, through the exit
+    lines.  A signal whose handler somebody installed before
+    (``benchmarks/launch/miner_child.py``) is left alone.  The capture
+    starts and stops on a thread of its own: a handler runs on the
+    miner's thread, between two rounds."""
+    import signal
+    import threading
+
+    from .. import profiling
+
+    def answer(what, call):
+        threading.Thread(
+            target=lambda: _say(f"profile: {what} {json.dumps(call())}"),
+            daemon=True, name="miner-profile").start()
+
+    def on_term(*_a):
+        raise SystemExit(0)
+
+    hooks = {
+        signal.SIGUSR1: lambda *_a: answer("start", lambda: profiling.start(
+            profile.trace_dir, profile.max_capture_seconds)),
+        signal.SIGUSR2: lambda *_a: answer("stop", profiling.stop),
+        signal.SIGTERM: on_term,
+    }
+    for sig, handler in hooks.items():
+        if signal.getsignal(sig) is signal.SIG_DFL:
+            signal.signal(sig, handler)
+
+
+def _print_exit_lines() -> None:
+    """What the searching process leaves on stdout under
+    ``ProfilingConfig.enabled``: the device's peak memory and the flat
+    span and counter aggregates.  An open capture is closed first."""
+    from .. import profiling
+
+    if profiling.status().get("active"):
+        _say(f"profile: stop {json.dumps(profiling.stop())}")
+    peaks = [int(mem["peak_bytes_in_use"])
+             for mem in telemetry.device.device_memory().values()
+             if "peak_bytes_in_use" in mem]
+    _say(f"memory: peak_bytes={max(peaks) if peaks else 'null'}")
+    _say("telemetry: " + json.dumps(
+        {"spans": telemetry.stats(), "counters": telemetry.counters()},
+        sort_keys=True))
 
 
 def _reap(procs, timeout: float = 5.0) -> None:
@@ -399,9 +483,15 @@ def main(argv=None) -> int:
     if rc:
         return rc
     node = args.node.rstrip("/") + "/"
-    return run(args.address, node, args.device, args.batch, args.ttl,
-               shard=(i, k), once=args.once,
-               mesh_devices=cfg.device.mesh_devices)
+    if cfg.profile.enabled:
+        _install_profile_hooks(cfg.profile)
+    try:
+        return run(args.address, node, args.device, args.batch, args.ttl,
+                   shard=(i, k), once=args.once,
+                   mesh_devices=cfg.device.mesh_devices)
+    finally:
+        if cfg.profile.enabled:
+            _print_exit_lines()
 
 
 if __name__ == "__main__":

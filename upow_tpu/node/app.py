@@ -944,12 +944,6 @@ class Node:
             for key, value in sorted(mem.items()):
                 e.gauge(f"device_{label}_{key}", value,
                         "Best-effort device memory_stats() value")
-        # XLA cost-analysis estimates (upow_tpu/profiling.analyze_cost),
-        # next to the compile-cache counters they contextualize
-        for kern, costs in sorted(telemetry.device.cost_estimates().items()):
-            for key, value in sorted(costs.items()):
-                e.gauge(f"kernel_{kern}_cost_{key}", value,
-                        "XLA compiled.cost_analysis() estimate")
         # alert families are emitted unconditionally (zeros when the
         # watchtower is off) so make metrics-check can pin their names
         wt = self.watchtower

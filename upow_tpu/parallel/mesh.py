@@ -129,13 +129,14 @@ def _pow_search_mesh_resident(midstate, tail_words, bases, limits, target,
         hit = sha_kernel._hit_nonce_dynamic(digest, nonces, tgt, valid)
         return jax.lax.pmin(hit.reshape(1), "dp")
 
-    return shard_map(
-        per_device,
-        mesh=mesh,
-        in_specs=(P(), P(), P("dp"), P("dp"), P()),
-        out_specs=P(),
-        **check_kw,
-    )(midstate, tail_words, bases, limits, target)[0]
+    with jax.named_scope("upow.sha256_search"):
+        return shard_map(
+            per_device,
+            mesh=mesh,
+            in_specs=(P(), P(), P("dp"), P("dp"), P()),
+            out_specs=P(),
+            **check_kw,
+        )(midstate, tail_words, bases, limits, target)[0]
 
 
 def pow_search_resident(midstate, tail_words, bases, limits, target,

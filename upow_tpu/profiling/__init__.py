@@ -1,30 +1,24 @@
-"""Opt-in kernel profiling: jax.profiler capture + XLA cost analysis.
+"""Opt-in kernel profiling: a ``jax.profiler`` capture session.
 
-Two capabilities, both off unless asked for (``UPOW_PROFILE_*`` /
-``ProfilingConfig``), both safe to call when jax is absent or broken —
-profiling must never take the node down:
+Off unless asked for (``UPOW_PROFILE_*`` / ``ProfilingConfig``), and
+safe to call when jax is absent or broken — profiling must never take
+the process down.
 
-* :func:`start` / :func:`stop` / :func:`status` — a process-wide
-  ``jax.profiler`` capture session (xprof trace directory), driven by
-  the ``/debug/profile?action=start|stop|status`` endpoint.  One
-  capture at a time; a capture left running past
-  ``max_capture_seconds`` is auto-closed on the next touch so a
-  forgotten ``action=start`` can't fill the disk.
-* :func:`analyze_cost` — per-compile XLA cost analysis
-  (``fn.lower(*args).compile().cost_analysis()``): FLOPs / bytes
-  accessed estimates recorded into :mod:`..telemetry.device` next to
-  the compile-cache counters, so kernel-occupancy stalls have
-  attributable arithmetic-intensity numbers.
+:func:`start` / :func:`stop` / :func:`status` drive one process-wide
+``jax.profiler`` capture (xprof trace directory): the node's
+``/debug/profile?action=start|stop|status`` endpoint and the miner's
+SIGUSR1 / SIGUSR2 hook (``mine/miner.py``) call them.  One capture at a
+time; a capture left running past ``max_capture_seconds`` is auto-closed
+on the next touch so a forgotten start can't fill the disk.  Every
+telemetry span open during a capture is a host event of the trace
+(``telemetry/tracing.py``), on the device events' clock.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Optional
-
 from ..logger import get_logger
-from ..telemetry import device as _device
 from ..telemetry import event as _event
 
 log = get_logger("profiling")
@@ -104,39 +98,3 @@ def reset() -> None:
     """Forget any active session without touching jax (tests)."""
     with _lock:
         _session.clear()
-
-
-def analyze_cost(kernel: str, fn, *args,
-                 static_argnums=None) -> Optional[dict]:
-    """AOT-compile ``fn(*args)`` and record its XLA cost analysis.
-
-    ``fn`` may be jitted or plain (plain callables are wrapped).  The
-    normalized numeric entries (``flops``, ``bytes accessed``, ...) are
-    stored via :func:`telemetry.device.record_cost` and returned; any
-    failure returns None — estimates are observability, never
-    correctness.
-    """
-    try:
-        import jax
-
-        if not hasattr(fn, "lower"):
-            # offline cost analysis lowers the kernel without dispatching;
-            # the profiler is a dev tool outside the runtime's hot path
-            fn = jax.jit(fn, static_argnums=static_argnums)  # upowlint: disable=DR003
-        compiled = fn.lower(*args).compile()
-        analysis = compiled.cost_analysis()
-        # older jax returns a per-computation list; newest a flat dict
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
-        if not isinstance(analysis, dict) or not analysis:
-            return None
-        clean = {k: float(v) for k, v in analysis.items()
-                 if isinstance(v, (int, float))
-                 and not isinstance(v, bool)}
-        if not clean:
-            return None
-        _device.record_cost(kernel, clean)
-        return clean
-    except Exception as e:
-        log.debug("cost analysis for %s failed: %s", kernel, e)
-        return None

@@ -175,20 +175,21 @@ def _probe_kernel(keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi,
     lanes.  Returns per query: full 128-bit hit, 64-bit key hit (the
     prefilter contract), run overflow (ambiguity), and the amount lanes
     gathered at the matched row."""
-    cap = keys_hi.shape[0]
-    pos = jnp.searchsorted(keys_hi, q_hi, side="left")
-    idx = pos[:, None] + jnp.arange(window)[None, :]
-    valid = idx < n_live
-    idx_c = jnp.clip(idx, 0, cap - 1)
-    hi_eq = (keys_hi[idx_c] == q_hi[:, None]) & valid
-    key_eq = hi_eq & (keys_lo[idx_c] == q_lo[:, None])
-    full_eq = key_eq & (chk_a[idx_c] == q_ca[:, None]) \
-        & (chk_b[idx_c] == q_cb[:, None])
-    hit = full_eq.any(axis=1)
-    key_hit = key_eq.any(axis=1)
-    overflow = hi_eq[:, window - 1]
-    row = jnp.clip(pos + jnp.argmax(full_eq, axis=1), 0, cap - 1)
-    return hit, key_hit, overflow, amt_lo[row], amt_hi[row]
+    with jax.named_scope("upow.utxo_probe"):
+        cap = keys_hi.shape[0]
+        pos = jnp.searchsorted(keys_hi, q_hi, side="left")
+        idx = pos[:, None] + jnp.arange(window)[None, :]
+        valid = idx < n_live
+        idx_c = jnp.clip(idx, 0, cap - 1)
+        hi_eq = (keys_hi[idx_c] == q_hi[:, None]) & valid
+        key_eq = hi_eq & (keys_lo[idx_c] == q_lo[:, None])
+        full_eq = key_eq & (chk_a[idx_c] == q_ca[:, None]) \
+            & (chk_b[idx_c] == q_cb[:, None])
+        hit = full_eq.any(axis=1)
+        key_hit = key_eq.any(axis=1)
+        overflow = hi_eq[:, window - 1]
+        row = jnp.clip(pos + jnp.argmax(full_eq, axis=1), 0, cap - 1)
+        return hit, key_hit, overflow, amt_lo[row], amt_hi[row]
 
 
 class DeviceUtxoIndex:

@@ -34,10 +34,7 @@ DISPATCH_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 10.0)
 
 _lock = threading.Lock()
 _seen_keys: Dict[str, Set[Hashable]] = {}
-_costs: Dict[str, Dict[str, float]] = {}
 _MAX_KEYS_PER_KERNEL = 4096
-_MAX_COST_KERNELS = 64
-_MAX_COST_KEYS = 16
 
 
 def preregister(kernel: str) -> None:
@@ -215,33 +212,6 @@ def record_mine_hit(latency_seconds: float) -> None:
                     buckets=HIT_LATENCY_BUCKETS)
 
 
-def record_cost(kernel: str, analysis: dict) -> None:
-    """Store an XLA ``compiled.cost_analysis()`` estimate for ``kernel``
-    (``upow_tpu/profiling``): numeric entries only, keys sanitized to
-    metric-name charset, bounded per kernel and overall so a pathological
-    analysis dict cannot grow /metrics without limit."""
-    clean: Dict[str, float] = {}
-    for key in sorted(analysis):
-        value = analysis[key]
-        if not isinstance(value, (int, float)) or isinstance(value, bool):
-            continue
-        clean[key.replace(" ", "_").replace("-", "_")] = float(value)
-        if len(clean) >= _MAX_COST_KEYS:
-            break
-    if not clean:
-        return
-    with _lock:
-        if kernel not in _costs and len(_costs) >= _MAX_COST_KERNELS:
-            return
-        _costs[kernel] = clean
-
-
-def cost_estimates() -> Dict[str, Dict[str, float]]:
-    """Snapshot of recorded per-compile cost analyses, keyed by kernel."""
-    with _lock:
-        return {k: dict(v) for k, v in _costs.items()}
-
-
 def device_memory() -> Dict[str, dict]:
     """Best-effort per-device memory stats; {} when jax isn't loaded
     or the backend doesn't expose memory_stats (CPU)."""
@@ -271,4 +241,3 @@ def device_memory() -> Dict[str, dict]:
 def reset() -> None:
     with _lock:
         _seen_keys.clear()
-        _costs.clear()

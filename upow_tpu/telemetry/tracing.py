@@ -20,6 +20,16 @@ record against each submitter's tree explicitly.
 
 Every span also feeds the flat ``metrics`` aggregates, so the
 pre-existing ``trace.stats()`` consumers see identical numbers.
+
+Every span is also a host event of the JAX profiler: ``request_trace``,
+``span`` and the ``child_span``/``finish_child`` pair hold a
+``jax.profiler.TraceAnnotation`` open for their duration, so whenever a
+profiler session runs (the benchmark's ``--trace 1``, the node's
+``/debug/profile``, the miner's SIGUSR1 hook) the program's spans are in
+the device trace, on the device events' clock.  This module never
+imports jax: the class is looked up in ``sys.modules`` once jax is
+loaded, and a process that never loads it (wallet, supervisor) pays one
+dict lookup a span.
 """
 
 from __future__ import annotations
@@ -27,6 +37,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import re
+import sys
 import threading
 import time
 import uuid
@@ -44,6 +55,47 @@ _current: contextvars.ContextVar[Optional["Span"]] = \
     contextvars.ContextVar("upow_trace_span", default=None)
 
 _LEVELS = {"debug": 10, "info": 20}
+
+
+#: ``jax.profiler.TraceAnnotation`` once jax is loaded (tests inject a
+#: fake here); None until then
+_annotation_cls: Any = None
+_ANNOTATION_TEXT = 64   # characters of a string field kept in an event
+
+
+def _annotate(name: str, trace_id: Optional[str], fields: Dict[str, Any]):
+    """Open a profiler host event ``name`` carrying the small fields and
+    the root's trace id (``trace=<id>``); None when jax is not loaded.
+    The profiler must never break the caller."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation",
+                      None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    try:
+        kw = {k: (v[:_ANNOTATION_TEXT] if isinstance(v, str) else v)
+              for k, v in fields.items()
+              if isinstance(v, (str, int, float)) and k != "name"}
+        if trace_id:
+            kw["trace"] = trace_id
+        ann = cls(name, **kw)
+        ann.__enter__()
+        return ann
+    except Exception as e:
+        log.debug("profiler annotation %s not opened: %s", name, e)
+        return None
+
+
+def _annotate_end(ann) -> None:
+    if ann is not None:
+        try:
+            ann.__exit__(None, None, None)
+        except Exception as e:
+            log.debug("profiler annotation not closed: %s", e)
 
 
 def new_trace_id() -> str:
@@ -66,7 +118,7 @@ class Span:
     """
 
     __slots__ = ("name", "trace_id", "fields", "start_ts", "_t0",
-                 "duration_s", "children", "root", "done", "error")
+                 "duration_s", "children", "root", "done", "error", "_ann")
 
     def __init__(self, name: str, trace_id: Optional[str] = None,
                  root: Optional["Span"] = None, **fields: Any):
@@ -80,6 +132,7 @@ class Span:
         self.root = root if root is not None else self
         self.done = False
         self.error: Optional[str] = None
+        self._ann = None        # child_span's open profiler event
 
     def finish(self, **fields: Any) -> float:
         if fields:
@@ -232,12 +285,14 @@ def request_trace(name: str, trace_id: Optional[str] = None,
     buf = _buf()  # pin the buffer so open/record hit the same scope
     buf.record_open(root)
     token = _current.set(root)
+    ann = _annotate(name, tid, fields)
     try:
         yield root
     except BaseException as e:
         root.error = type(e).__name__
         raise
     finally:
+        _annotate_end(ann)
         _current.reset(token)
         root.finish()
         root.fields.pop("_spans", None)
@@ -246,17 +301,23 @@ def request_trace(name: str, trace_id: Optional[str] = None,
 
 
 @contextlib.contextmanager
-def span(name: str, level: str = "debug", **fields: Any):
-    """Time a section: flat aggregate always, tree node when traced."""
+def span(name: str, level: str = "debug", light: bool = False,
+         **fields: Any):
+    """Time a section: flat aggregate and profiler event always, tree
+    node when traced.  ``light`` is for per-round work (a sweep of 128
+    rounds): no tree node is made, so a job's tree and its root's span
+    budget are left to the job-level spans."""
     parent = _current.get()
     node: Optional[Span] = None
     token = None
-    if parent is not None and not parent.root.done:
+    if not light and parent is not None and not parent.root.done:
         node = Span(name, root=parent.root, **fields)
         if _attach(parent, node):
             token = _current.set(node)
         else:
             node = None
+    ann = _annotate(name, parent.root.trace_id if parent is not None
+                    else None, fields)
     t0 = time.perf_counter()
     try:
         yield node
@@ -266,6 +327,7 @@ def span(name: str, level: str = "debug", **fields: Any):
         raise
     finally:
         dt = time.perf_counter() - t0
+        _annotate_end(ann)
         if token is not None:
             _current.reset(token)
         if node is not None:
@@ -291,6 +353,7 @@ def child_span(parent: Optional[Span], name: str,
     node = Span(name, root=parent.root, **fields)
     if not _attach(parent, node):
         return None
+    node._ann = _annotate(name, parent.root.trace_id, fields)
     return node
 
 
@@ -298,6 +361,8 @@ def finish_child(node: Optional[Span], name: Optional[str] = None,
                  **fields: Any) -> None:
     if node is None:
         return
+    _annotate_end(node._ann)
+    node._ann = None
     dt = node.finish(**fields)
     metrics.record_span(name or node.name, dt)
 
@@ -306,7 +371,9 @@ def add_span(parent: Optional[Span], name: str, t0: float, t1: float,
              **fields: Any) -> None:
     """Attach an already-timed section (perf_counter endpoints) under
     ``parent``.  Used for work shared by many requests (one coalesced
-    sig dispatch) that must appear in each requester's tree."""
+    sig dispatch) that must appear in each requester's tree.  A tree
+    node only: an interval that is already over cannot become a
+    profiler event, so it is in no device trace."""
     if parent is None:
         return
     node = Span(name, root=parent.root, **fields)
