@@ -212,7 +212,7 @@ def mine_block(node: NodeProc, address: str, device: str, work: str,
             f"miner for {label} exited rc={proc.returncode}: "
             f"{(err or out).strip()[-800:]}")
     rec = {"label": label, "seconds": round(time.time() - t0, 2),
-           "device": None, "mesh": None}
+           "device": None, "mesh": None, "counters": None}
     for line in out.splitlines():
         m = _MINER_LINE.search(line)
         if m:
@@ -227,6 +227,9 @@ def mine_block(node: NodeProc, address: str, device: str, work: str,
             rec["backend"] = line.split("backend=")[1].split()[0]
         if line.startswith("mesh: "):
             rec["mesh"] = json.loads(line[len("mesh: "):])
+        if line.startswith("telemetry: "):   # UPOW_PROFILE_ENABLED only
+            rec["counters"] = json.loads(
+                line[len("telemetry: "):])["counters"]
     if "nonce" not in rec or "BLOCK MINED" not in out:
         raise SmokeFailure(f"miner for {label} found no block: {out[-800:]}")
     return rec
@@ -583,7 +586,8 @@ def run_four_chips(args, work: str) -> dict:
     of the same difficulty, both accepted by a host-verify node."""
     wallet = Wallet(args.seed)
     device = "cpu" if args.rehearse_cpu else "tpu"
-    env = {"UPOW_DEVICE_DEVICE": device}
+    # profile.enabled: the miner prints its counters at exit
+    env = {"UPOW_DEVICE_DEVICE": device, "UPOW_PROFILE_ENABLED": "1"}
     if args.rehearse_cpu:
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     a = NodeProc("A", work, "cpu")
@@ -609,6 +613,23 @@ def run_four_chips(args, work: str) -> dict:
         if len(shards) != n or any(hi <= lo for lo, hi in shards) or any(
                 shards[i][1] != shards[i + 1][0] for i in range(n - 1)):
             raise SmokeFailure(f"mesh x{n} shards not disjoint: {shards}")
+        want_body = "jnp" if args.rehearse_cpu else "pallas"
+        if mesh.get("body") != want_body:
+            raise SmokeFailure(f"mesh x{n} ran the {mesh.get('body')} body "
+                               f"of the resident program, not {want_body}")
+        counters = rec["counters"] or {}
+        seen_rounds = {k: counters.get(k) for k in (
+            "mine.mesh.rounds_pallas", "mine.rounds",
+            "kernel.mine_mesh.compile_cache_misses")}
+        say(f"[4] mesh x{n}: dispatches={mesh.get('dispatches')} "
+            f"{seen_rounds}")
+        # every dispatched round ran the kernel (none on the CPU), and
+        # the resident program was one compile key
+        if seen_rounds["mine.mesh.rounds_pallas"] != (
+                0 if args.rehearse_cpu else mesh.get("dispatches")) \
+                or seen_rounds["kernel.mine_mesh.compile_cache_misses"] != 1:
+            raise SmokeFailure(f"mesh x{n} counters {seen_rounds} against "
+                               f"{mesh.get('dispatches')} dispatches")
         recs[n] = rec
     a.stop()
     seen = recs[4]["device"]

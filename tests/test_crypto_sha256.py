@@ -165,3 +165,145 @@ def test_txid_batch_integrity_sample_falls_back(monkeypatch):
     monkeypatch.setattr(sha_mod, "sha256_batch_jnp", corrupt)
     got = sha_mod.txid_batch(payloads, backend="device", min_batch=1)
     assert got == [hashlib.sha256(p).hexdigest() for p in payloads]
+
+
+# --- the data-target Pallas kernel (the resident mesh program's body) ------
+
+_DATA_TILE_ROWS = 8          # interpret mode: 1,024 lanes a tile
+_DATA_TILE = _DATA_TILE_ROWS * 128
+_U32_END = (1 << 32) - 1     # engine.MAX_SEARCH_END: SENTINEL is never searched
+
+
+def _digest_hex(prefix: bytes, nonce: int) -> str:
+    return hashlib.sha256(prefix + nonce.to_bytes(4, "little")).hexdigest()
+
+
+def _prev_hash_hit_by(prefix: bytes, nonce: int, k: int) -> str:
+    """A previous hash whose last ``k`` chars are the first ``k`` of the
+    real digest of ``nonce``: a target of any depth with a known hit."""
+    return "0" * (64 - k) + _digest_hex(prefix, nonce)[:k]
+
+
+def _nonce_with_nibble_below(prefix: bytes, lo: int, hi: int, pos: int,
+                             count: int) -> int:
+    """The first nonce of [lo, hi) whose digest's hex char ``pos`` is
+    among the first ``count`` of the charset."""
+    return next(n for n in range(lo, hi)
+                if int(_digest_hex(prefix, n)[pos], 16) < count)
+
+
+def _data_case(name):
+    """(prefix, previous_hash, difficulty, base, limit, batch) of one case
+    of the data-target kernel's test, built from a seed of its own."""
+    r = random.Random(f"data-kernel-{name}")
+    prefix = bytes(r.randrange(256) for _ in range(104))
+    prev = bytes(r.randrange(256) for _ in range(32)).hex()
+    base, batch = r.randrange(1 << 28), 2 * _DATA_TILE
+    if name == "charset16":
+        return prefix, prev, "1", base, base + batch, batch
+    if name == "nibble_word0":           # 1 char and a fractional second
+        return prefix, prev, "1.5", base, base + batch, batch
+    if name == "nibble_word1":           # 9 chars: mask1 set, nibble in h1
+        n = _nonce_with_nibble_below(prefix, base + 1100, base + batch, 9, 8)
+        return (prefix, _prev_hash_hit_by(prefix, n, 9), "9.5",
+                base, base + batch, batch)
+    if name == "nibble_word2":           # 16 chars: both masks full, h2
+        n = _nonce_with_nibble_below(prefix, base + 300, base + batch, 16, 12)
+        return (prefix, _prev_hash_hit_by(prefix, n, 16), "16.3",
+                base, base + batch, batch)
+    if name == "mask1_11.0":             # the window's own compare shape
+        return (prefix, _prev_hash_hit_by(prefix, base + 1777, 11), "11.0",
+                base, base + batch, batch)
+    if name == "limit_mid_tile":         # hits past the limit are not hits
+        return prefix, prev, "1", base, base + _DATA_TILE + 476, batch
+    if name == "limit_before_only_hit":  # the known hit lies past the limit
+        return (prefix, _prev_hash_hit_by(prefix, base + 1500, 11), "11.0",
+                base, base + 1500, batch)
+    if name == "empty_shard":
+        return prefix, prev, "1", base, base, batch
+    if name == "ends_at_2^32-1":         # lanes past the limit wrap past 2^32
+        return prefix, prev, "1", _U32_END - 1500, _U32_END, batch
+    if name == "ragged_batch":           # no multiple of the tile, limit past it
+        return prefix, prev, "1", base, base + 3 * _DATA_TILE, _DATA_TILE + 476
+    if name == "two_tiles_lower_wins":
+        return prefix, prev, "2", base, base + batch, batch
+    raise KeyError(name)
+
+
+@pytest.fixture(scope="module")
+def data_kernels():
+    """The data-target kernel (interpret mode) and its jnp twin — the
+    resident program's other body — jitted once per batch: target and
+    range are data, so every case of one batch shares one compile."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from upow_tpu.crypto import sha256 as sk
+
+    @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec"))
+    def pallas(mid, tail, base, limit, tgt, batch, nonce_spec):
+        return sk.pow_search_pallas_data(
+            mid, tail, jnp.stack([base, limit], axis=1), tgt, batch=batch,
+            nonce_spec=nonce_spec, tile_rows=_DATA_TILE_ROWS, interpret=True)
+
+    @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec"))
+    def twin(mid, tail, base, limit, tgt, batch, nonce_spec):
+        nonces = base[0] + jnp.arange(batch, dtype=jnp.uint32)
+        valid = (nonces >= base[0]) & (nonces < limit[0])
+        digest = sk._compress_tail(
+            tuple(mid[i] for i in range(8)),
+            sk._build_w(tail, nonces, nonce_spec), unroll=False)
+        return sk._hit_nonce_dynamic(digest, nonces, tgt, valid)
+
+    return pallas, twin
+
+
+@pytest.mark.parametrize("name", [
+    "charset16", "nibble_word0", "nibble_word1", "nibble_word2",
+    "mask1_11.0", "limit_mid_tile", "limit_before_only_hit", "empty_shard",
+    "ends_at_2^32-1", "ragged_batch", "two_tiles_lower_wins"])
+def test_pallas_data_target_kernel(data_kernels, name):
+    """Target and [base, limit) as SMEM data: the kernel's answer is
+    hashlib's lowest hit of the range under the protocol's rule, and
+    ``_hit_nonce_dynamic``'s on the jnp digest."""
+    import jax.numpy as jnp
+
+    from upow_tpu.crypto.sha256 import pack_target
+
+    prefix, prev, difficulty, base, limit, batch = _data_case(name)
+    template = make_template(prefix)
+    spec = target_spec(prev, difficulty)
+    end = min(limit, base + batch)
+    hits = [n for n in range(base, end)
+            if check_pow_hash(_digest_hex(prefix, n), prev, difficulty)]
+    want = hits[0] if hits else int(SENTINEL)
+
+    # each case really is the case its name says
+    if name.startswith("nibble_word"):
+        assert spec.nibble_word == int(name[-1]) and spec.charset < 16
+    if name in ("nibble_word1", "nibble_word2", "mask1_11.0"):
+        assert int(spec.mask1) != 0 and len(hits) == 1
+    if name == "limit_mid_tile":
+        assert hits and (limit - base) % _DATA_TILE
+        assert any(check_pow_hash(_digest_hex(prefix, n), prev, difficulty)
+                   for n in range(limit, base + batch))
+    if name == "limit_before_only_hit":
+        assert not hits and check_pow_hash(
+            _digest_hex(prefix, limit), prev, difficulty)
+    if name == "ends_at_2^32-1":
+        assert hits and base + batch > 1 << 32
+    if name == "ragged_batch":
+        assert batch % _DATA_TILE and limit > base + batch
+    if name == "two_tiles_lower_wins":
+        assert len({(n - base) // _DATA_TILE for n in hits}) >= 2
+
+    args = (jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
+            jnp.array([base], jnp.uint32), jnp.array([limit], jnp.uint32),
+            jnp.asarray(pack_target(spec)))
+    pallas, twin = data_kernels
+    got = int(pallas(*args, batch=batch, nonce_spec=template.nonce_spec))
+    assert got == want
+    assert got == int(twin(*args, batch=batch,
+                           nonce_spec=template.nonce_spec))

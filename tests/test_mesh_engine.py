@@ -6,7 +6,10 @@ disjoint/exact per-round shard coverage straight from the engine's own
 dispatch accounting, the no-recompile job swap (compile-cache counters
 plus jax's jit cache size), single-dispatch-owner routing through the
 device runtime under source "mine", and the structured arm ladder with
-real exception text.
+real exception text.  The resident program's TPU body (the Pallas kernel,
+here in interpret mode) is held to the jnp body's answers, to the same
+no-recompile swap, and to its ``body`` / ``mine.mesh.rounds_pallas``
+accounting.
 """
 
 import random
@@ -104,6 +107,68 @@ def test_mine_mesh_backend_matches_jnp_backend():
     assert got.hashes_tried == want.hashes_tried
 
 
+# ------------------------------------------------- the Pallas body ----
+
+@pytest.fixture(scope="module")
+def pallas_engine():
+    """The resident program built with its TPU body — the Pallas kernel,
+    here in interpret mode — on the first two devices of the virtual CPU
+    mesh.  One engine for the module: interpret mode takes seconds a
+    tile, and a shard must span two tiles (parallel/mesh.py).  Not the
+    process-wide engine, so ``clean_state`` leaves it armed."""
+    eng = MeshEngine(mesh_devices=2, batch_per_device=2048, interpret=True)
+    info = eng.arm()
+    assert info["armed"], info
+    assert eng.n_devices == 2
+    return eng
+
+
+@pytest.mark.parametrize("seed,start,short", [
+    (101, 0, 0), (202, 1 << 20, 0), (303, 0, 0),
+    (7, 5, 1349),        # a tail round: shard 1 ends inside its second tile
+])
+def test_pallas_body_matches_jnp_body(pallas_engine, seed, start, short):
+    """One program, two bodies: on the seeded jobs and on a tail round
+    the Pallas body returns exactly what the jnp body returns (and that
+    is the serial path's lowest hit)."""
+    job = _seeded_job(seed, difficulty="1" if short else "1.5")
+    plain = _armed_engine(batch_per_device=512)   # 8 shards, jnp body
+    assert plain.capacity == pallas_engine.capacity
+    count = plain.capacity - short
+    plain.set_job(job)
+    pallas_engine.set_job(job)
+    got = int(pallas_engine.dispatch(start, count))
+    assert got == int(plain.dispatch(start, count))
+    template = make_template(job.prefix)
+    spec = target_spec(job.previous_hash, job.difficulty)
+    assert got == int(pow_search_jnp(template, spec, nonce_base=start,
+                                     batch=count))
+    if got != int(SENTINEL):
+        assert job.check(got)
+
+
+def test_body_is_chosen_by_platform_and_counted(pallas_engine):
+    """``stats()["body"]`` and ``mine.mesh.rounds_pallas``: a CPU mesh
+    runs the jnp body and counts no Pallas round; the Pallas body (what
+    a TPU mesh gets) counts every dispatched round."""
+    from upow_tpu.parallel import mesh as pmesh
+
+    plain = _armed_engine(batch_per_device=64)
+    assert pmesh.resident_body(plain._mesh) == "jnp"
+    assert plain.stats()["body"] == "jnp"
+    plain.set_job(_seeded_job(5))
+    plain.dispatch(0, plain.capacity)
+    assert metrics.counters().get("mine.mesh.rounds_pallas") == 0
+
+    assert pallas_engine.stats()["body"] == "pallas"
+    pallas_engine.set_job(_seeded_job(5))
+    before = pallas_engine.stats()["dispatches"]
+    int(pallas_engine.dispatch(0, pallas_engine.capacity))
+    assert metrics.counters().get("mine.mesh.rounds_pallas") == 1
+    assert pallas_engine.stats()["dispatches"] == before + 1
+    assert MeshEngine().stats()["body"] is None   # not armed: no program
+
+
 # ------------------------------------------------ disjoint coverage ----
 
 def test_dispatch_accounting_proves_disjoint_exact_coverage():
@@ -149,15 +214,19 @@ def test_dispatch_rejects_oversized_round():
 
 # ---------------------------------------------- no-recompile job swap ----
 
-def test_job_swap_is_pure_dispatch_no_recompile():
+@pytest.mark.parametrize("body", ["jnp", "pallas"])
+def test_job_swap_is_pure_dispatch_no_recompile(body, request):
     """A new job / chain-tip change must NOT recompile the resident
-    program: jax's jit cache size stays flat and the mine_mesh
-    compile-cache counters record one miss then only hits."""
+    program, whichever body it was built with: jax's jit cache size
+    stays flat and the mine_mesh compile-cache counters record one miss
+    then only hits."""
     from upow_tpu.parallel import mesh as pmesh
 
-    eng = _armed_engine(batch_per_device=256)
+    eng = (_armed_engine(batch_per_device=256) if body == "jnp"
+           else request.getfixturevalue("pallas_engine"))
+    assert eng.stats()["body"] == body
     eng.set_job(_seeded_job(1))
-    eng.dispatch(0, eng.capacity)
+    int(eng.dispatch(0, eng.capacity))   # waited for: interpret mode is slow
     jit_entries = pmesh._pow_search_mesh_resident._cache_size()
     misses0 = metrics.counters().get(
         "kernel.mine_mesh.compile_cache_misses", 0)
@@ -165,7 +234,7 @@ def test_job_swap_is_pure_dispatch_no_recompile():
 
     for seed in (2, 3, 4):  # three job swaps, different targets too
         eng.set_job(_seeded_job(seed, difficulty=str(1 + seed / 10)))
-        eng.dispatch(seed * 1000, eng.capacity)
+        int(eng.dispatch(seed * 1000, eng.capacity))
 
     assert pmesh._pow_search_mesh_resident._cache_size() == jit_entries
     counters = metrics.counters()

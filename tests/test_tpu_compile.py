@@ -158,8 +158,10 @@ def test_utxo_probe_kernel_compiles(one_chip, no_compile_cache):
 def test_mesh_resident_search_compiles_on_four_devices(topo,
                                                        no_compile_cache):
     """`miner --device mesh` over the four chips of one host: one SPMD
-    program, a quarter of the default round on each device, the hit
-    reduced by a collective."""
+    program, a quarter of the default round on each device, each shard
+    searched by the Pallas kernel with target and range as SMEM data
+    (the body is chosen from the mesh's platform), the hit reduced by a
+    collective."""
     from upow_tpu.config import DeviceConfig
     from upow_tpu.crypto import sha256 as sk
     from upow_tpu.parallel import mesh as pm
@@ -167,15 +169,24 @@ def test_mesh_resident_search_compiles_on_four_devices(topo,
     devices = topo.devices[:4]
     assert len(devices) == 4
     mesh = Mesh(np.array(devices), axis_names=("dp",))
+    assert pm.resident_body(mesh) == "pallas"
     rep = NamedSharding(mesh, P())
     dp = NamedSharding(mesh, P("dp"))
+    words = sk.RESIDENT_OPERAND_WORDS
+    assert sk.resident_operand([[1, 2]] * 4).shape == (4, words)
     compiled = pm._pow_search_mesh_resident.lower(
-        _shape((8,), jnp.uint32, rep), _shape((16,), jnp.uint32, rep),
-        _shape((4,), jnp.uint32, dp), _shape((4,), jnp.uint32, dp),
-        _shape((7,), jnp.uint32, rep),
+        _shape((words,), jnp.uint32, rep), _shape((words,), jnp.uint32, rep),
+        _shape((4, words), jnp.uint32, dp), _shape((words,), jnp.uint32, rep),
         batch_per_device=DeviceConfig().search_batch // 4,
         nonce_spec=sk.make_template(bytes(104)).nonce_spec,
         mesh=mesh).compile()
     text = compiled.as_text()
+    assert "tpu_custom_call" in text, \
+        "each shard must run the Mosaic kernel, not XLA fusions"
+    # operands of a page reach the kernel where they lie: no staging
+    # copy, so a round is the kernel and the collective and no third
+    # device operation (sha256.RESIDENT_OPERAND_WORDS)
+    assert "copy-start" not in text and " copy(" not in text, \
+        "XLA stages a kernel operand into scalar memory on every round"
     assert "all-reduce" in text or "all_reduce" in text, \
         "the pmin over dp must survive as a collective"

@@ -343,8 +343,13 @@ def pow_search_jnp(template: SearchTemplate, spec: TargetSpec,
 
 # --- Pallas TPU kernel ----------------------------------------------------
 
-def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
-                   nonce_spec, spec: TargetSpec):
+def _tile_digest(mid_ref, tail_ref, read_base, *, tile_rows: int, nonce_spec):
+    """The hashing body both Pallas kernels share: this grid step's
+    (tile_rows, 128) nonce tile from ``program_id``, the nonce bytes
+    scattered into the tail words, one unrolled compression.
+    ``read_base()`` loads the first nonce from wherever the kernel keeps
+    it.  Returns (grid step, lane-linear index within the tile, nonces,
+    digest)."""
     from jax.experimental import pallas as pl  # local: keep module importable sans pallas
 
     i = pl.program_id(0)
@@ -352,7 +357,7 @@ def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
     # nonce = base + program_id*tile + lane-linear index, as (tile_rows, 128)
     lin = (jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 0) * jnp.uint32(128)
            + jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 1))
-    nonces = base_ref[0] + jnp.uint32(i) * jnp.uint32(tile) + lin
+    nonces = read_base() + jnp.uint32(i) * jnp.uint32(tile) + lin
     state = tuple(mid_ref[j] for j in range(8))
     w = [jnp.full((tile_rows, 128), tail_ref[j], dtype=jnp.uint32) for j in range(16)]
     for j, (widx, shift) in enumerate(nonce_spec):
@@ -360,13 +365,13 @@ def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
         w[widx] = w[widx] | (byte << jnp.uint32(shift))
     # always unrolled here: the rolled form would capture the K table as a
     # pallas_call constant, and Mosaic compiles the flat 64 rounds fast
-    digest = _compress_tail(state, w, unroll=True)
-    t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
-    ok = (digest[0] & t[0]) == t[1]
-    ok &= (digest[1] & t[2]) == t[3]
-    if spec.charset < 16:
-        nib = (digest[spec.nibble_word] >> jnp.uint32(spec.nibble_shift)) & jnp.uint32(0xF)
-        ok &= nib < jnp.uint32(spec.charset)
+    return i, lin, nonces, _compress_tail(state, w, unroll=True)
+
+
+def _min_hit_into(out_ref, i, ok, nonces):
+    """Min-accumulate this tile's lowest hit into the (1,1) SMEM cell."""
+    from jax.experimental import pallas as pl
+
     cand = jnp.where(ok, nonces, jnp.uint32(SENTINEL))
     # Mosaic has no unsigned reductions (and no scalar bitcasts): flip the
     # sign bit (order-preserving u32 -> s32 map) on the vector, reduce in
@@ -384,6 +389,103 @@ def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
     @pl.when(i != 0)
     def _acc():
         out_ref[0, 0] = jnp.minimum(out_ref[0, 0], tile_min)
+
+
+def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
+                   nonce_spec, spec: TargetSpec):
+    i, _, nonces, digest = _tile_digest(
+        mid_ref, tail_ref, lambda: base_ref[0], tile_rows=tile_rows,
+        nonce_spec=nonce_spec)
+    t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
+    ok = (digest[0] & t[0]) == t[1]
+    ok &= (digest[1] & t[2]) == t[3]
+    if spec.charset < 16:
+        nib = (digest[spec.nibble_word] >> jnp.uint32(spec.nibble_shift)) & jnp.uint32(0xF)
+        ok &= nib < jnp.uint32(spec.charset)
+    _min_hit_into(out_ref, i, ok, nonces)
+
+
+def _pallas_kernel_data(mid_ref, tail_ref, span_ref, tgt_ref, out_ref, *,
+                        batch: int, tile_rows: int, nonce_spec):
+    """Data-target twin of :func:`_pallas_kernel`: the same hashing body,
+    :func:`_hit_nonce_dynamic`'s compare in operations Mosaic takes.  The
+    packed target (:func:`pack_target`) and the shard's ``[base, limit)``
+    (``span_ref[0, :2]``) are SMEM scalars, so one compiled kernel serves
+    every job, tip and difficulty.  Each ref may be longer than the words
+    read (:func:`resident_operand`)."""
+    base, limit = span_ref[0, 0], span_ref[0, 1]
+    i, lin, nonces, digest = _tile_digest(
+        mid_ref, tail_ref, lambda: base, tile_rows=tile_rows,
+        nonce_spec=nonce_spec)
+    ok = (digest[0] & tgt_ref[0]) == tgt_ref[1]
+    ok &= (digest[1] & tgt_ref[2]) == tgt_ref[3]
+    # nibble_word = k // 8 for k <= 16 hex chars: word 0, 1 or 2
+    nibble_word = tgt_ref[4]
+    word = jnp.where(nibble_word == jnp.uint32(0), digest[0],
+                     jnp.where(nibble_word == jnp.uint32(1), digest[1],
+                               digest[2]))
+    nib = (word >> tgt_ref[5]) & jnp.uint32(0xF)
+    # a nibble is under 16, so charset >= 16 passes every lane here as
+    # _hit_nonce_dynamic's explicit (charset >= 16) does
+    ok &= nib < tgt_ref[6]
+    # lanes of this tile inside the shard's range: counted from base, so
+    # a range that ends near 2^32 needs no care about u32 wrap; tiles
+    # wholly past the limit (or past batch, in a ragged last tile) are
+    # left with none
+    span = jnp.minimum(limit - base, jnp.uint32(min(batch, 0xFFFFFFFF)))
+    done = jnp.uint32(i) * jnp.uint32(tile_rows * 128)
+    ok &= lin < jnp.where(span > done, span - done, jnp.uint32(0))
+    _min_hit_into(out_ref, i, ok, nonces)
+
+
+#: words of each array the resident program is given.  Left at their own
+#: sizes (8, 16, 2 and 7 words), a custom call's operands past the second
+#: are staged into scalar memory by XLA:TPU itself, a copy-start /
+#: copy-done pair apiece on every execution: device operations beside the
+#: kernel's one, and nine profiler events a chip a round, which at the
+#: pod's hundreds of rounds a second decide whether a 45 s capture can be
+#: stopped at all (PERF.md section 5).  An operand of a page is handed to
+#: the kernel where it lies, and Mosaic's own prologue loads it.
+RESIDENT_OPERAND_WORDS = 1024
+
+
+def resident_operand(words) -> np.ndarray:
+    """``words`` (u32, 1-D, or 2-D rows) zero-padded along the last axis
+    to :data:`RESIDENT_OPERAND_WORDS`: the form in which the mesh engine
+    hands midstate, tail, target and ranges to the resident program."""
+    words = np.asarray(words, dtype=np.uint32)
+    out = np.zeros(words.shape[:-1] + (RESIDENT_OPERAND_WORDS,), np.uint32)
+    out[..., :words.shape[-1]] = words
+    return out
+
+
+def pow_search_pallas_data(midstate, tail_words, span, target, *,
+                           batch: int, nonce_spec, tile_rows: int = 64,
+                           interpret: bool = False):
+    """Pallas search of ``[base, min(limit, base + batch))`` against a
+    packed runtime ``target`` — min hit or SENTINEL, as u32.  ``span`` is
+    (1, >= 2) u32, ``[base, limit, ...]``: a shard's row of the resident
+    mesh program's ranges.  Traced inside the caller's jit
+    (``parallel.mesh._pow_search_mesh_resident``): no jit of its own.  The
+    grid rounds ``batch`` up to whole tiles; the range mask drops the
+    surplus lanes."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    kernel = functools.partial(
+        _pallas_kernel_data, batch=batch, tile_rows=tile_rows,
+        nonce_spec=nonce_spec)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    flipped = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(batch, tile_rows * 128),),
+        in_specs=[smem] * 4,
+        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
+        interpret=interpret,
+    )(midstate, tail_words, span, target)
+    return flipped[0, 0].astype(jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
 @functools.partial(jax.jit, static_argnames=("batch", "tile_rows", "nonce_spec", "spec", "interpret"))

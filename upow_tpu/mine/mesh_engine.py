@@ -10,7 +10,10 @@ This module owns the multi-device path:
   job-specific field — midstate, tail words, per-shard ranges, packed
   target — rides as runtime data, so a new job or chain-tip change is a
   pure dispatch: zero recompilation, asserted by the ``mine_mesh``
-  compile-cache counters.
+  compile-cache counters.  Its per-shard body is the Pallas sha256
+  kernel on a TPU mesh and the jnp one elsewhere
+  (``parallel.mesh.resident_body``); ``stats()["body"]`` and the
+  ``mine.mesh.rounds_pallas`` counter say which one the rounds ran.
 * **Disjoint shard ranges** — each round's [start, start+count) window
   is split across the mesh with :func:`parallel.mesh.shard_bounds`; the
   per-round plan is retained in the dispatch accounting so tests (and
@@ -40,6 +43,7 @@ from typing import Callable, List, Optional, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..crypto import sha256 as sha_kernel
 from ..telemetry import device as _ktel
 
@@ -77,10 +81,14 @@ class MeshEngine:
 
     def __init__(self, mesh_devices: int = 0,
                  batch_per_device: Optional[int] = None,
-                 round_hint: Optional[int] = None):
+                 round_hint: Optional[int] = None,
+                 interpret: bool = False):
         self._mesh_devices = int(mesh_devices)
         self._batch_per_device = batch_per_device
         self._round_hint = round_hint
+        # tests only: the Pallas body in interpret mode on a CPU mesh
+        self._interpret = bool(interpret)
+        self._body: Optional[str] = None   # "pallas" | "jnp", set at arm
         self._mesh = None
         self._n_dev = 0
         self._armed = False
@@ -160,7 +168,8 @@ class MeshEngine:
         (submitted through the runtime like every later round)."""
         from ..config import DeviceConfig, _apply_env_fields
         from ..device.runtime import get_runtime
-        from ..parallel.mesh import make_mesh, pow_search_resident
+        from ..parallel.mesh import (make_mesh, pow_search_resident,
+                                     resident_body)
 
         runtime = get_runtime()
         devices = runtime.devices()
@@ -170,6 +179,10 @@ class MeshEngine:
             devices = devices[: self._mesh_devices]
         self._n_dev = len(devices)
         self._mesh = make_mesh(devices)
+        self._body = resident_body(self._mesh, self._interpret)
+        # exported at zero from the arm on: a CPU mesh reads 0, not "no
+        # such counter"
+        telemetry.ensure_counter("mine.mesh.rounds_pallas")
         if self._batch_per_device is None:
             if self._round_hint:
                 # ceil: one round of round_hint nonces must fit capacity
@@ -187,15 +200,14 @@ class MeshEngine:
         import jax.numpy as jnp
 
         spec = sha_kernel.make_template(bytes(104)).nonce_spec
-        zeros8 = jnp.zeros(8, jnp.uint32)
-        zeros16 = jnp.zeros(16, jnp.uint32)
-        zn = jnp.zeros(self._n_dev, jnp.uint32)
-        zt = jnp.zeros(7, jnp.uint32)
+        zeros = jnp.asarray(sha_kernel.resident_operand([0]))
+        no_ranges = sha_kernel.resident_operand(
+            np.zeros((self._n_dev, 2), np.uint32))
 
         def warm():
             return int(pow_search_resident(
-                zeros8, zeros16, zn, zn, zt,
-                self._batch_per_device, spec, self._mesh))
+                zeros, zeros, no_ranges, zeros,
+                self._batch_per_device, spec, self._mesh, self._interpret))
 
         runtime.submit_call(
             warm, kernel="sha256_search_mesh", source="mine").result()
@@ -213,11 +225,10 @@ class MeshEngine:
             return
         template = sha_kernel.make_template(job.prefix)
         spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
-        self._job_arrays = (
-            jnp.asarray(template.midstate),
-            jnp.asarray(template.tail_words),
-            jnp.asarray(sha_kernel.pack_target(spec)),
-        )
+        self._job_arrays = tuple(
+            jnp.asarray(sha_kernel.resident_operand(words)) for words in (
+                template.midstate, template.tail_words,
+                sha_kernel.pack_target(spec)))
         self._nonce_spec = template.nonce_spec
         self._job_key = key
         self._job_t0 = time.perf_counter()
@@ -252,8 +263,7 @@ class MeshEngine:
         from ..parallel.mesh import pow_search_resident
 
         shards = self.plan_round(start, count)
-        bases = np.array([lo for lo, _ in shards], dtype=np.uint32)
-        limits = np.array([hi for _, hi in shards], dtype=np.uint32)
+        ranges = sha_kernel.resident_operand(shards)
         self._dispatches += 1
         self._nonces_planned += count
         self._rounds.append(
@@ -263,14 +273,18 @@ class MeshEngine:
             del self._rounds[0]
         mid, tail, target = self._job_arrays
         nonce_spec, batch, mesh = self._nonce_spec, self._batch_per_device, self._mesh
+        interpret = self._interpret
         _ktel.record_mine_round(
             [hi - lo for lo, hi in shards], batch,
             compile_key=(batch, self._n_dev, nonce_spec))
+        if self._body == "pallas":
+            # the share of rounds that ran the kernel: 1.0 on a chip
+            telemetry.inc("mine.mesh.rounds_pallas")
         runtime = get_runtime()
         return runtime.submit_call(
             lambda: pow_search_resident(
-                mid, tail, bases, limits, target,
-                batch, nonce_spec, mesh),
+                mid, tail, ranges, target,
+                batch, nonce_spec, mesh, interpret),
             kernel="sha256_search_mesh", source="mine").result()
 
     def dispatcher(self, job) -> Callable:
@@ -297,6 +311,7 @@ class MeshEngine:
         return {
             "armed": self._armed,
             "devices": self._n_dev,
+            "body": self._body,
             "batch_per_device": self.batch_per_device,
             "capacity": self.capacity,
             "dispatches": self._dispatches,

@@ -251,6 +251,7 @@ def _print_mesh_accounting(mesh_devices: int) -> None:
     last = stats["rounds"][-1] if stats["rounds"] else {}
     print("mesh: " + json.dumps({
         "devices": [f"{d.platform}:{d.id}" for d in eng.mesh_devices()],
+        "body": stats["body"],
         "batch_per_device": stats["batch_per_device"],
         "dispatches": stats["dispatches"],
         "last_round_shards": last.get("shards", [])}), flush=True)

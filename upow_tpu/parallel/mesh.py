@@ -95,11 +95,23 @@ def _pow_search_mesh(midstate, tail_words, nonce_base, batch_per_device: int,
     )(midstate, tail_words, nonce_base.reshape(1))[0]
 
 
+def resident_body(mesh: Mesh, interpret: bool = False) -> str:
+    """Which per-shard body :func:`_pow_search_mesh_resident` builds for
+    ``mesh``: ``"pallas"`` on TPU devices (or in a test's interpret
+    mode), ``"jnp"`` on anything else — chosen from the platform of the
+    mesh's own devices, by no option.  On a TPU nothing leads back to
+    the jnp body: a kernel Mosaic refuses fails the compile."""
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+    return "pallas" if on_tpu or interpret else "jnp"
+
+
 @functools.partial(
-    jax.jit, static_argnames=("batch_per_device", "nonce_spec", "mesh")
+    jax.jit,
+    static_argnames=("batch_per_device", "nonce_spec", "mesh", "interpret"),
 )
-def _pow_search_mesh_resident(midstate, tail_words, bases, limits, target,
-                              batch_per_device: int, nonce_spec, mesh: Mesh):
+def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
+                              batch_per_device: int, nonce_spec, mesh: Mesh,
+                              interpret: bool = False):
     """Resident mesh search: one compiled SPMD program per (batch,
     nonce_spec, mesh) whose template AND target ride as runtime data.
 
@@ -109,16 +121,30 @@ def _pow_search_mesh_resident(midstate, tail_words, bases, limits, target,
     a new job / chain-tip / difficulty change is a pure dispatch: zero
     recompilation (asserted by the mine_mesh compile-cache counters).
 
-    ``bases``/``limits`` are (n_devices,) u32, sharded over "dp": shard i
-    scans ``[bases[i], bases[i] + batch_per_device)`` with lanes at or
-    past ``limits[i]`` masked off, so uneven ``shard_bounds`` spans and
-    tail rounds need no recompile either.  An empty shard passes
-    ``bases[i] == limits[i]`` (every lane invalid).
+    ``ranges`` is (n_devices, >= 2) u32, a ``[base, limit, ...]`` row a
+    shard, sharded over "dp": shard i scans ``[base, base +
+    batch_per_device)`` with lanes at or past ``limit`` masked off, so
+    uneven ``shard_bounds`` spans and tail rounds need no recompile
+    either.  An empty shard passes ``base == limit`` (every lane
+    invalid).  Every array may be longer than the words read: the mesh
+    engine pads each to a page (``sha256.resident_operand``), which keeps
+    XLA:TPU from staging them into scalar memory with a copy operation
+    apiece on every round.
+
+    The per-shard body is :func:`resident_body`'s: on a TPU mesh the
+    Pallas kernel with target and range in SMEM
+    (``sha256.pow_search_pallas_data``), elsewhere the jnp body below —
+    the CPU path of ``--device mesh`` and the plain twin the tests
+    compare the kernel against.  ``interpret`` runs the Pallas body in
+    interpret mode on any platform (tests), in the smallest (8, 128)
+    tiles: a test-sized shard then still spans several grid steps, and
+    XLA:CPU all but hangs on a grid of one step (the unrolled rounds
+    land outside any loop).
     """
     shard_map, check_kw = shard_map_compat()
 
-    def per_device(mid, tail, base, limit, tgt):
-        my_base, my_limit = base[0], limit[0]
+    def per_device_jnp(mid, tail, span, tgt):
+        my_base, my_limit = span[0, 0], span[0, 1]
         nonces = my_base + jnp.arange(batch_per_device, dtype=jnp.uint32)
         # u32 wrap past 2**32 makes a lane compare below my_base: both
         # wrapped and past-limit lanes drop out of the same mask
@@ -129,19 +155,28 @@ def _pow_search_mesh_resident(midstate, tail_words, bases, limits, target,
         hit = sha_kernel._hit_nonce_dynamic(digest, nonces, tgt, valid)
         return jax.lax.pmin(hit.reshape(1), "dp")
 
+    def per_device_pallas(mid, tail, span, tgt):
+        hit = sha_kernel.pow_search_pallas_data(
+            mid, tail, span, tgt, batch=batch_per_device,
+            nonce_spec=nonce_spec, tile_rows=8 if interpret else 64,
+            interpret=interpret)
+        return jax.lax.pmin(hit.reshape(1), "dp")
+
+    pallas = resident_body(mesh, interpret) == "pallas"
     with jax.named_scope("upow.sha256_search"):
         return shard_map(
-            per_device,
+            per_device_pallas if pallas else per_device_jnp,
             mesh=mesh,
-            in_specs=(P(), P(), P("dp"), P("dp"), P()),
+            in_specs=(P(), P(), P("dp"), P()),
             out_specs=P(),
             **check_kw,
-        )(midstate, tail_words, bases, limits, target)[0]
+        )(midstate, tail_words, ranges, target)[0]
 
 
-def pow_search_resident(midstate, tail_words, bases, limits, target,
+def pow_search_resident(midstate, tail_words, ranges, target,
                         batch_per_device: int, nonce_spec,
-                        mesh: Optional[Mesh] = None):
+                        mesh: Optional[Mesh] = None,
+                        interpret: bool = False):
     """Dispatch the resident program over explicit per-shard ranges.
 
     Arguments are already device-typed arrays (the mesh engine keeps the
@@ -150,8 +185,8 @@ def pow_search_resident(midstate, tail_words, bases, limits, target,
     """
     mesh = mesh or make_mesh()
     return _pow_search_mesh_resident(
-        midstate, tail_words, bases, limits, target,
-        batch_per_device, nonce_spec, mesh,
+        midstate, tail_words, ranges, target,
+        batch_per_device, nonce_spec, mesh, interpret,
     )
 
 
