@@ -11,7 +11,11 @@ on.  After the window the stub serves one job at each of
 child is stopped and the reference searches the round of every such
 block for a lower nonce.  A traced run also holds the
 rounds the miner's lines claim against the search program's events on
-the device.
+the device; where the traffic has ``traced_window_s``, its window is
+that long at most (the profiler's stop costs a time an event, so a
+round: a fast miner's whole window cannot be stopped in
+``STOP_TRACE_WAIT_S``), and the end-to-end metrics, which come from
+untraced runs, never see it.
 """
 
 from __future__ import annotations
@@ -43,6 +47,12 @@ CONTROLS = {"tighten_target": {"tighten_check": 2},
 #: two can be two apart at either end.  Sound runs read 0 to -2; the
 #: control's smallest is 7, on four chips in 10 s (PERF.md section 6).
 TRACE_EDGE_ROUNDS = 4
+
+#: seconds the driver waits for the child's 'trace: stopped' line after
+#: SIGUSR2.  A stop that takes over half of it is said as a warning: the
+#: traffic's ``traced_window_s`` is then due to go down.  Not longer: a
+#: run has 360 s for set-up, window, stop and the reading of the trace.
+STOP_TRACE_WAIT_S = 120
 
 
 def _phases(job_list: list) -> list:
@@ -99,6 +109,12 @@ def run(ctx) -> dict:
         stub.stop()
 
 
+def _rounds_between(job_list: list, t0: float, t1: float) -> int:
+    """Rounds whose lines arrived in (t0, t1]."""
+    return sum(1 for job in job_list for t, _n in job["rounds"]
+               if t0 < t <= t1)
+
+
 def _check_traced_rounds(ctx, check, events, job_list, trace_dir):
     """The rounds whose lines the miner printed while the trace ran,
     against the search program's events that ended on the device in the
@@ -112,8 +128,7 @@ def _check_traced_rounds(ctx, check, events, job_list, trace_dir):
     if set(span) != {"started", "stopped"}:
         raise BenchError(f"the child's trace lines are not a start and a "
                          f"stop: {span}")
-    claimed = sum(1 for job in job_list for t, _n in job["rounds"]
-                  if span["started"] < t <= span["stopped"])
+    claimed = _rounds_between(job_list, span["started"], span["stopped"])
     program = ctx.traffic["search_program"]
     ended = xplane.program_seconds(records, program)["ended"]
     batch = int(ctx.traffic["round_nonces"])
@@ -127,8 +142,41 @@ def _check_traced_rounds(ctx, check, events, job_list, trace_dir):
     return records
 
 
+def _stop_trace(ctx, miner, w0, w1) -> None:
+    """SIGUSR2, then the child's 'trace: stopped' line, timed."""
+    t_signal = time.time()
+    miner.signal(signal.SIGUSR2)
+    try:
+        t_line, _text = miner.wait_for(
+            lambda s: "trace: stopped" in s, STOP_TRACE_WAIT_S,
+            "'trace: stopped' line")
+    except BenchError:
+        if miner.proc.poll() is not None:
+            raise
+        rounds = _rounds_between(
+            minerlog.jobs(minerlog.parse(list(miner.lines))), w0, w1)
+        raise BenchError(
+            f"stop_trace did not answer in the {STOP_TRACE_WAIT_S} s the "
+            f"driver waits: the traced window of {w1 - w0:.1f}s held "
+            f"{rounds} rounds, and the profiler's stop costs a time for "
+            "every event of every round (PERF.md section 5).  Trace a "
+            "shorter window: 'traced_window_s' in benchmarks/traffic/"
+            f"{ctx.cell['traffic']}.json or the mix it includes; its "
+            f"'why_traced_window' has the arithmetic.  {miner.tail(4)}")
+    took = t_line - t_signal
+    late = took > STOP_TRACE_WAIT_S / 2
+    ctx.say(f"[trace] {'WARNING: ' if late else ''}stop_trace answered in "
+            f"{took:.1f} s of the {STOP_TRACE_WAIT_S} s the driver waits"
+            + ("; over half: lower 'traced_window_s' in the cell's traffic "
+               "before the miner gets any faster" if late else ""))
+
+
 def _drive(ctx, stub, miner, trace_dir) -> dict:
     traffic, say, seconds = ctx.traffic, ctx.say, ctx.seconds
+    if trace_dir and "traced_window_s" in traffic:
+        seconds = min(seconds, float(traffic["traced_window_s"]))
+        say(f"[trace] this traced run's window is {seconds:.1f}s of "
+            f"--seconds {ctx.seconds:.1f}: the traffic's traced_window_s")
     batch = int(traffic["round_nonces"])
     # ---- set-up: reach the device, the warm job, its block ----
     t_dev, dev_line = miner.wait_for(
@@ -166,9 +214,7 @@ def _drive(ctx, stub, miner, trace_dir) -> dict:
         time.sleep(min(0.05, max(0.0, w1 - time.time())))
     lateness = time.time() - w1
     if trace_dir:
-        miner.signal(signal.SIGUSR2)
-        miner.wait_for(lambda s: "trace: stopped" in s, 120,
-                       "'trace: stopped' line")
+        _stop_trace(ctx, miner, w0, w1)
     exited_early = miner.proc.poll() is not None
     # ---- after the window: jobs mined to a hit, for the reference ----
     t_after = time.time()

@@ -8,11 +8,16 @@ answer where it is produced:
     second_hit   pushes the second-lowest hit of the warm round
     short_sweep  ends every job one round early ('template expired')
     bad_nonce    pushes a nonce that does not meet the target
+
+Like the launcher it answers SIGUSR1 and SIGUSR2 with ``trace: started``
+and ``trace: stopped`` (at its next round; it writes no trace), and
+with ``--mute-stop`` never answers the second.
 """
 
 import argparse
 import hashlib
 import json
+import signal
 import sys
 import time
 import urllib.request
@@ -39,8 +44,15 @@ def main():
     ap.add_argument("--range", type=int, required=True)
     ap.add_argument("--fault", default="")
     ap.add_argument("--platform", default="tpu")
+    ap.add_argument("--mute-stop", action="store_true")
     a = ap.parse_args()
     out = lambda s: print(s, flush=True)  # noqa: E731
+    signalled = {}     # what -> unix, said from the loop, not the handler
+    signal.signal(signal.SIGUSR1,
+                  lambda *_a: signalled.setdefault("started", time.time()))
+    signal.signal(
+        signal.SIGUSR2, signal.SIG_IGN if a.mute_stop else
+        lambda *_a: signalled.setdefault("stopped", time.time()))
     out(f"upow_tpu miner: backend=fake shard=0/1 nonces=[0, {a.range}) "
         f"node={a.node}")
     out(f"device: platform={a.platform} kind=TPU v5 lite count=1 "
@@ -78,6 +90,8 @@ def main():
                     break
             else:
                 time.sleep(0.002)
+            for what in sorted(signalled):
+                out(f"trace: {what} unix={signalled.pop(what):.6f}")
             tried += a.batch
             out(f"{tried / max(time.time() - t0, 1e-6) / 1e6:.2f} MH/s "
                 f"({tried} hashes)")

@@ -57,6 +57,19 @@ def test_traffic_include_starts_from_the_named_mix(tmp_path):
         manifest.load_traffic("absent", str(tmp_path))
 
 
+def test_only_the_pods_traced_runs_hold_a_shorter_window(mf):
+    """``traced_window_s`` reaches the pod's cell through its ``include``
+    and stays under the run's length; the one-chip mix has none."""
+    pod = manifest.load_traffic("mine-sweep-pod")
+    assert 0 < pod["traced_window_s"] < mf["run_seconds"]
+    assert pod["why_traced_window"] and pod["driver"] == "mine_sweep"
+    assert "traced_window_s" not in manifest.load_traffic("mine-sweep")
+    by_cell = {w["name"]: manifest.load_traffic(w["traffic"])
+               for w in mf["workloads"]}
+    assert [n for n, t in by_cell.items() if "traced_window_s" in t] == \
+        ["mine-sweep-4chip"]
+
+
 def test_unknown_names_are_refused(mf):
     with pytest.raises(BenchError, match="no workload"):
         manifest.find_cell(mf, "no-such-cell")

@@ -8,8 +8,11 @@ configuration's guarantee broken where it is checked (the control),
 import io
 import json
 import os
+import re
 import sys
+import time
 from contextlib import redirect_stdout
+from types import SimpleNamespace
 
 import pytest
 
@@ -22,7 +25,8 @@ TINY = {"warm_difficulties": [2.0], "after_difficulties": [2.5],
         "arm_timeout_s": 30, "warm_timeout_s": 60, "miner_args": []}
 
 
-def drive(fault="", control=None, seed=7, platform="tpu"):
+def drive(fault="", control=None, seed=7, platform="tpu", trace=0,
+          traffic=None, child_args=()):
     from upow_tpu.core import curve
     from upow_tpu.core.codecs import point_to_string, string_to_bytes
 
@@ -32,14 +36,15 @@ def drive(fault="", control=None, seed=7, platform="tpu"):
             "--batch", "4096", "--range", "65536", "--platform", platform]
     if fault:
         argv += ["--fault", fault]
+    argv += list(child_args)
     args = ["--workload", "mine-sweep-1chip", "--seed", str(seed),
-            "--seconds", "1.5", "--trace", "0"]
+            "--seconds", "1.5", "--trace", str(trace)]
     if control:
         args += ["--control", control]
     out = io.StringIO()
     with redirect_stdout(out):
-        rc = bench_run.main(args, faults={"child_argv": argv,
-                                          "traffic": TINY})
+        rc = bench_run.main(args, faults={
+            "child_argv": argv, "traffic": dict(TINY, **(traffic or {}))})
     lines = out.getvalue().strip().splitlines()
     return rc, lines
 
@@ -96,6 +101,105 @@ def test_no_chip_no_result():
     assert lines[-1].startswith("FAILED:")
     with pytest.raises(ValueError):
         json.loads(lines[-1])
+
+
+# ---- a traced run: how long its window is, and the stop ----
+
+@pytest.fixture
+def driven(monkeypatch):
+    """What the driver handed back, kept for the test to look at.  There
+    is no profiler here: the trace a traced run finds is one window span,
+    and the wait for the stop is two seconds."""
+    from harness import manifest, xplane
+
+    seen = []
+    monkeypatch.setattr(xplane, "find_trace", lambda _d: "x.xplane.pb")
+    monkeypatch.setattr(xplane, "extract", lambda _p: [
+        {"plane": "/host:CPU", "line": "python3",
+         "name": "perfbench.window", "start_ns": 0.0, "dur_ns": 1e9}])
+    load = manifest.load_module
+
+    def load_and_watch(kind, name, *rest):
+        module = load(kind, name, *rest)
+        if kind == "drivers":
+            run = module.run
+
+            def run_and_keep(ctx):
+                seen.append(run(ctx))
+                return seen[-1]
+
+            module.run = run_and_keep
+            module.STOP_TRACE_WAIT_S = 2.0
+        return module
+
+    monkeypatch.setattr(manifest, "load_module", load_and_watch)
+    return seen
+
+
+@pytest.mark.parametrize("trace,traced_window_s,window_s", [
+    (1, 0.5, 0.5),     # traced, and the traffic says shorter: shorter
+    (0, 0.5, 1.5),     # the same traffic untraced: --seconds
+    (1, 4.0, 1.5),     # never longer than --seconds
+    (1, None, 1.5),    # a traffic without the key: --seconds, traced
+    (0, None, 1.5),    # or not
+])
+def test_only_a_traced_run_takes_the_traffics_traced_window(
+        driven, trace, traced_window_s, window_s):
+    rc, lines = drive(trace=trace, traffic={} if traced_window_s is None
+                      else {"traced_window_s": traced_window_s})
+    assert rc == 0, lines[-5:]
+    observed = driven[0]["observed"]
+    w0, w1 = observed["window"]
+    assert w1 - w0 == pytest.approx(window_s)
+    assert any(ln.startswith(f"[window] {window_s:.1f}s: ") for ln in lines)
+    stops = [e["unix"] for e in observed["events"]
+             if e["kind"] == "trace" and e["what"] == "stopped"]
+    answered = [ln for ln in lines
+                if ln.startswith("[trace] stop_trace answered in ")]
+    if not trace:
+        assert not stops and not answered
+        return
+    # the stop is signalled where the window closes, and is timed
+    assert len(stops) == 1 and 0 <= stops[0] - w1 < 0.5
+    assert len(answered) == 1
+    assert answered[0].endswith(" s of the 2.0 s the driver waits")
+    # and the rounds held against the device are the shorter window's
+    assert any(ln.startswith("[check] traced_rounds_claimed_minus_on_device")
+               for ln in lines)
+
+
+def test_a_stop_that_never_answers_names_the_rounds_and_the_key(driven):
+    rc, lines = drive(trace=1, traffic={"traced_window_s": 0.5},
+                      child_args=["--mute-stop"])
+    assert rc == 1 and not driven
+    said = re.match(r"FAILED: stop_trace did not answer in the 2.0 s the "
+                    r"driver waits: the traced window of 0.5s held (\d+) "
+                    r"rounds", lines[-1])
+    assert said and int(said.group(1)) > 10, lines[-1]
+    assert "'traced_window_s' in benchmarks/traffic/mine-sweep.json" \
+        in lines[-1]
+
+
+@pytest.mark.parametrize("share,warned", [(0.2, False), (0.6, True)])
+def test_a_stop_over_half_the_wait_is_said_as_a_warning(share, warned):
+    from harness.manifest import load_module
+
+    driver = load_module("drivers", "mine_sweep")
+    said = []
+
+    class Miner:
+        def signal(self, _sig):
+            pass
+
+        def wait_for(self, _predicate, timeout, _what):
+            return time.time() + share * timeout, "trace: stopped unix=1.0"
+
+    driver._stop_trace(SimpleNamespace(say=said.append), Miner(), 0.0, 10.0)
+    took = share * driver.STOP_TRACE_WAIT_S
+    assert len(said) == 1 and re.match(
+        rf"\[trace\] {'WARNING: ' if warned else ''}stop_trace answered in "
+        rf"{took:.1f} s of the 120 s the driver waits", said[0]), said
+    assert ("lower 'traced_window_s'" in said[0]) is warned
 
 
 # ---- a traced run: the rounds the lines claim against the device's ----
