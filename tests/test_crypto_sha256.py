@@ -307,3 +307,103 @@ def test_pallas_data_target_kernel(data_kernels, name):
     assert got == want
     assert got == int(twin(*args, batch=batch,
                            nonce_spec=template.nonce_spec))
+
+
+# --- the static-target programs: the range's end as data --------------------
+
+_STATIC_BATCH = 4 * _DATA_TILE   # one program of 4,096 lanes, four grid steps
+
+
+def _static_case(name):
+    """(prefix, previous_hash, difficulty, base, limit, batch) of one case
+    of the static-target programs' test.  Cases of one difficulty share
+    prefix and previous hash, so the target, which is compiled in, is one
+    program for all of them: only ``[base, limit)`` differs, as data."""
+    dense = name != "hit_at_limit_not_answered"
+    r = random.Random("static-kernel-" + ("dense" if dense else "sparse"))
+    prefix = bytes(r.randrange(256) for _ in range(104))
+    prev = bytes(r.randrange(256) for _ in range(32)).hex()
+    base, batch = r.randrange(1 << 28), _STATIC_BATCH
+    if name == "limit_inside_a_tile":
+        return prefix, prev, "1", base, base + 2 * _DATA_TILE + 476, batch
+    if name == "limit_on_a_tile_edge":
+        return prefix, prev, "1", base, base + 2 * _DATA_TILE, batch
+    if name == "limit_is_base":              # no lane may answer
+        return prefix, prev, "1", base, base, batch
+    if name == "full_round":                 # the old contract
+        return prefix, prev, "1", base, base + batch, batch
+    if name == "full_round_no_limit_given":
+        return prefix, prev, "1", base, None, batch
+    if name == "hit_at_limit_not_answered":  # the range's only hit is its end
+        first = next(n for n in range(base, base + batch) if check_pow_hash(
+            _digest_hex(prefix, n), prev, "2"))
+        return prefix, prev, "2", base, first, batch
+    if name == "ends_at_2^32-1":             # the default range's last round
+        return prefix, prev, "1", (1 << 32) - batch, _U32_END, batch
+    if name == "sentinels_neighbour":        # 2^32 - 2, the last nonce tried
+        return prefix, prev, "1", _U32_END - 3, _U32_END, batch
+    raise KeyError(name)
+
+
+@pytest.mark.parametrize("program", ["pallas", "jnp"])
+@pytest.mark.parametrize("name", [
+    "limit_inside_a_tile", "limit_on_a_tile_edge", "limit_is_base",
+    "full_round", "full_round_no_limit_given", "hit_at_limit_not_answered",
+    "ends_at_2^32-1", "sentinels_neighbour"])
+def test_static_search_takes_the_ranges_end_as_data(program, name):
+    """``pow_search_pallas`` (interpret mode) and its twin
+    ``pow_search_jnp``: one program of ``batch`` lanes answers hashlib's
+    lowest hit of ``[base, limit)`` under the protocol's rule; lanes at
+    or past the limit never answer, wherever the limit falls."""
+    prefix, prev, difficulty, base, limit, batch = _static_case(name)
+    template = make_template(prefix)
+    spec = target_spec(prev, difficulty)
+    end = base + batch if limit is None else min(limit, base + batch)
+    hits = [n for n in range(base, end)
+            if check_pow_hash(_digest_hex(prefix, n), prev, difficulty)]
+    want = hits[0] if hits else int(SENTINEL)
+
+    # each case really is the case its name says
+    past = [n for n in range(end, min(base + batch, _U32_END))
+            if check_pow_hash(_digest_hex(prefix, n), prev, difficulty)]
+    if name == "limit_inside_a_tile":
+        assert hits and past and (limit - base) % _DATA_TILE
+    if name == "limit_on_a_tile_edge":
+        assert hits and past and (limit - base) % _DATA_TILE == 0
+    if name == "limit_is_base":
+        assert not hits and past
+    if name == "hit_at_limit_not_answered":
+        assert not hits and past[0] == limit
+    if name == "ends_at_2^32-1":
+        assert hits and base + batch == 1 << 32 and limit == _U32_END
+    if name == "sentinels_neighbour":
+        assert base + batch > 1 << 32 and end - base == 3
+
+    if program == "pallas":
+        got = pow_search_pallas(template, spec, nonce_base=base, batch=batch,
+                                limit=limit, tile_rows=_DATA_TILE_ROWS,
+                                interpret=True)
+    else:
+        got = pow_search_jnp(template, spec, nonce_base=base, batch=batch,
+                             limit=limit)
+    assert int(got) == want
+
+
+def test_static_pallas_takes_a_batch_of_no_whole_tiles():
+    """The grid rounds the batch up (no assert on ``batch % tile``): the
+    surplus lanes of the last tile are hashed and never answer."""
+    prefix, prev, _d, base, _limit, _batch = _static_case("full_round")
+    template, spec = make_template(prefix), target_spec(prev, "3")
+    batch = _DATA_TILE + 476
+    hits = [n for n in range(base, base + (1 << 16))
+            if check_pow_hash(_digest_hex(prefix, n), prev, "3")]
+    # a hit with none in the ``batch`` nonces below it
+    hit = next(h for below, h in zip(hits, hits[1:]) if h - below > batch)
+
+    def search(start):
+        return int(pow_search_pallas(
+            template, spec, nonce_base=start, batch=batch,
+            tile_rows=_DATA_TILE_ROWS, interpret=True))
+
+    assert search(hit - batch) == int(SENTINEL)   # the first surplus lane
+    assert search(hit - batch + 1) == hit         # the batch's last lane

@@ -46,6 +46,83 @@ def test_mine_respects_ttl_and_range():
     assert result.hashes_tried == 512
 
 
+def _counters(*names):
+    from upow_tpu import telemetry
+
+    seen = telemetry.counters()
+    return {n: seen.get(n, 0) for n in names}
+
+
+_RAGGED = ("mine.rounds", "mine.nonces", "mine.rounds_masked",
+           "mine.lanes_masked", "kernel.sha256_search.compile_cache_misses",
+           "kernel.sha256_search.lanes_real",
+           "kernel.sha256_search.lanes_padded")
+
+
+def test_a_range_of_no_whole_rounds_is_one_program_and_one_masked_round():
+    """The CLI's default range in small: three rounds of ``batch`` and
+    one of 100.  The short round runs the job's one program, its surplus
+    lanes masked; what is counted as tried is the live lanes."""
+    from upow_tpu.crypto import sha256
+
+    batch, length = 512, 3 * 512 + 100
+    before = _counters(*_RAGGED)
+    programs = sha256._pow_search_jnp._cache_size()
+    result = mine(_job("9"), "jnp", batch=batch, start=7 * batch,
+                  stride_end=7 * batch + length)
+    grew = {n: v - before[n] for n, v in _counters(*_RAGGED).items()}
+    assert result.nonce is None
+    assert result.hashes_tried == length
+    assert grew["mine.rounds"] == 4                  # ceil(length / batch)
+    assert grew["mine.nonces"] == length
+    assert grew["mine.rounds_masked"] == 1
+    assert grew["mine.lanes_masked"] == batch - 100
+    # one compile key a job, and one traced program for all four rounds
+    assert grew["kernel.sha256_search.compile_cache_misses"] == 1
+    assert sha256._pow_search_jnp._cache_size() - programs == 1
+    # the padded lanes are told as the verify path tells them: the job's
+    # key (batch of batch) and the masked round (100 of batch)
+    assert grew["kernel.sha256_search.lanes_real"] == batch + 100
+    assert grew["kernel.sha256_search.lanes_padded"] == 2 * batch
+
+
+def test_a_hit_in_the_short_last_round_is_found():
+    """A job whose only hit lies in its masked round, and the same range
+    cut just below that hit, which then ends with none."""
+    job, batch = _job("2"), 64
+    hits = [n for n in range(1 << 14) if job.check(n)]
+    # a hit with three whole rounds and more of misses below it
+    hit = next(h for below, h in zip(hits, hits[1:])
+               if h - below > 3 * batch + 50)
+    start = hit - (3 * batch + 50)
+    before = _counters("mine.rounds_masked")
+    found = mine(job, "jnp", batch=batch, start=start, stride_end=hit + 10)
+    assert found.nonce == hit
+    assert found.hashes_tried == 3 * batch + 60      # the whole short round
+    missed = mine(job, "jnp", batch=batch, start=start, stride_end=hit)
+    assert missed.nonce is None
+    assert missed.hashes_tried == 3 * batch + 50
+    assert _counters("mine.rounds_masked")["mine.rounds_masked"] \
+        - before["mine.rounds_masked"] == 2
+
+
+def test_the_top_of_the_nonce_space_leaves_the_sentinel_out():
+    """[2^32 - 3*batch - 1, 2^32): the cap at MAX_SEARCH_END takes the
+    sentinel off the end, so the job is three whole rounds, none masked,
+    with bases above 2^31 and lanes up to 2^32 - 2."""
+    from upow_tpu.mine.engine import NONCE_SPACE
+
+    batch = 512
+    before = _counters("mine.rounds", "mine.rounds_masked")
+    result = mine(_job("9"), "jnp", batch=batch,
+                  start=NONCE_SPACE - 3 * batch - 1, stride_end=NONCE_SPACE)
+    grew = {n: v - before[n] for n, v in
+            _counters("mine.rounds", "mine.rounds_masked").items()}
+    assert result.nonce is None
+    assert result.hashes_tried == 3 * batch
+    assert grew == {"mine.rounds": 3, "mine.rounds_masked": 0}
+
+
 def test_shard_ranges_partition_nonce_space():
     from upow_tpu.mine.engine import NONCE_SPACE
 

@@ -180,6 +180,34 @@ def test_a_job_counts_its_rounds_and_keeps_its_tree_small():
     assert grew("runtime.call") == rounds
 
 
+@pytest.mark.parametrize("length,tails", [(3 * 256 + 40, 1), (3 * 256, 0)])
+def test_a_masked_round_issues_under_mine_round_tail(fake_annotations,
+                                                     length, tails):
+    """``mine.round.tail`` opens inside the ``mine.round.issue`` of a
+    round shorter than the program, once a job, and never over a range
+    that ends on a whole round."""
+    agg = telemetry.stats().get("mine.round.tail", {}).get("count", 0)
+    with telemetry.request_trace("mine.job") as root:
+        result = mine(_job("9"), "jnp", batch=256, stride_end=length)
+    assert result.hashes_tried == length
+    assert telemetry.stats().get("mine.round.tail", {}).get("count", 0) \
+        - agg == tails
+    names = [(what, name) for what, name, _kw in fake_annotations
+             if name in ("mine.round.issue", "mine.round.tail")]
+    assert names.count(("open", "mine.round.tail")) == tails
+    if tails:
+        at = names.index(("open", "mine.round.tail"))
+        assert names[at - 1:at + 3] == [
+            ("open", "mine.round.issue"), ("open", "mine.round.tail"),
+            ("close", "mine.round.tail"), ("close", "mine.round.issue")]
+        assert at + 3 == len(names)          # the job's last issue
+    # a light span: the job's tree is left to the job-level spans
+    tree = [t for t in telemetry.traces()["recent"]
+            if t["trace_id"] == root.trace_id][0]
+    assert {c["name"] for c in tree["spans"]} == {"mine.prepare",
+                                                   "mine.first_issue"}
+
+
 def test_a_new_tip_is_one_compile_key_miss_and_the_same_tip_none():
     name = "kernel.sha256_search.compile_cache_misses"
     tip_a, tip_b = ("%064x" % 0xA11CE), ("%064x" % 0xB0B)
@@ -350,6 +378,8 @@ def test_the_miners_lines_are_the_ones_the_benchmark_parses(
     assert job["name"] == "mine.job"
     assert job["fields"]["end"] == ("found" if rc == 0 else "expired")
     assert job["fields"]["tip"] == ("%064x" % 0xFEED)[-12:]
+    # the range's length at the default shard: the sentinel left out
+    assert job["fields"]["nonces"] == (1 << 32) - 1
     names = [c["name"] for c in job["spans"]]
     assert names[:4] == ["mine.fetch", "mine.build_job", "mine.prepare",
                          "mine.first_issue"]

@@ -76,7 +76,8 @@ def test_sha256_pallas_search_compiles(one_chip, no_compile_cache,
                                        difficulty):
     """The miner's default round: search_batch 2^24, tile_rows 64, at
     the protocol's start difficulty and at a fractional one (the
-    charset branch of the kernel)."""
+    charset branch of the kernel); ``[base, limit)`` is one SMEM operand
+    of two words."""
     from upow_tpu.config import DeviceConfig
     from upow_tpu.crypto import sha256 as sk
 
@@ -85,7 +86,7 @@ def test_sha256_pallas_search_compiles(one_chip, no_compile_cache,
     compiled = sk._pow_search_pallas.lower(
         _shape((8,), jnp.uint32, one_chip),
         _shape((16,), jnp.uint32, one_chip),
-        _shape((), jnp.uint32, one_chip),
+        _shape((2,), jnp.uint32, one_chip),
         batch=DeviceConfig().search_batch, tile_rows=64,
         nonce_spec=template.nonce_spec, spec=spec,
         interpret=False).compile()

@@ -278,12 +278,38 @@ def _build_w(tail_words, nonces, nonce_spec):
     return w
 
 
-def _hit_nonce(digest, nonces, mask0, val0, mask1, val1, spec: TargetSpec):
+def _lanes_in_range(lin, done, base, limit, batch: int):
+    """The range mask every search program shares.  Lane ``done + lin``
+    of a program of ``batch`` lanes holds nonce ``base + done + lin``; it
+    may answer only inside ``[base, min(limit, base + batch))``.  Counted
+    from ``base`` (``limit - base`` in u32), so a range that ends at
+    2^32 - 1, whose surplus lanes wrap past 2^32, needs no care; lanes
+    wholly past the limit (or past ``batch``, in a ragged last tile) are
+    left with none.  ``done`` is a scalar: a Pallas grid step's offset,
+    0 in a jnp program."""
+    span = jnp.minimum(limit - base, jnp.uint32(min(batch, 0xFFFFFFFF)))
+    return lin < jnp.where(span > done, span - done, jnp.uint32(0))
+
+
+def search_span(nonce_base: int, batch: int, limit=None) -> np.ndarray:
+    """``[base, limit)`` as the (2,) u32 operand of the static-target
+    programs, taken mod 2^32 (the mask counts from ``base``).  ``limit``
+    None is the whole program, ``nonce_base + batch``."""
+    if limit is None:
+        limit = nonce_base + batch
+    return np.array([int(nonce_base) & 0xFFFFFFFF, int(limit) & 0xFFFFFFFF],
+                    dtype=np.uint32)
+
+
+def _hit_nonce(digest, nonces, mask0, val0, mask1, val1, spec: TargetSpec,
+               valid=None):
     ok = (digest[0] & mask0) == val0
     ok &= (digest[1] & mask1) == val1
     if spec.charset < 16:
         nib = (digest[spec.nibble_word] >> jnp.uint32(spec.nibble_shift)) & jnp.uint32(0xF)
         ok &= nib < jnp.uint32(spec.charset)
+    if valid is not None:
+        ok &= valid
     return jnp.min(jnp.where(ok, nonces, jnp.uint32(SENTINEL)))
 
 
@@ -320,24 +346,31 @@ def _hit_nonce_dynamic(digest, nonces, target, valid=None):
 
 
 @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec", "spec"))
-def _pow_search_jnp(midstate, tail_words, nonce_base, batch: int,
+def _pow_search_jnp(midstate, tail_words, span, batch: int,
                     nonce_spec, spec: TargetSpec):
     with jax.named_scope("upow.sha256_search"):
-        nonces = nonce_base + jnp.arange(batch, dtype=jnp.uint32)
+        base, limit = span[0], span[1]
+        lin = jnp.arange(batch, dtype=jnp.uint32)
+        nonces = base + lin
         state = tuple(midstate[i] for i in range(8))
         w = _build_w(tail_words, nonces, nonce_spec)
         digest = _compress_tail(state, w)
         t = [jnp.uint32(x)
              for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
-        return _hit_nonce(digest, nonces, *t, spec)
+        valid = _lanes_in_range(lin, jnp.uint32(0), base, limit, batch)
+        return _hit_nonce(digest, nonces, *t, spec, valid)
 
 
 def pow_search_jnp(template: SearchTemplate, spec: TargetSpec,
-                   nonce_base: int, batch: int):
-    """Search [nonce_base, nonce_base+batch) — returns min hit or SENTINEL."""
+                   nonce_base: int, batch: int, limit=None):
+    """Search ``[nonce_base, min(limit, nonce_base + batch))`` with one
+    program of ``batch`` lanes — returns min hit or SENTINEL.  ``limit``
+    is data (:func:`search_span`): a round shorter than ``batch`` runs
+    the program its job's whole rounds run."""
     return _pow_search_jnp(
         jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
-        jnp.uint32(nonce_base), batch, template.nonce_spec, spec,
+        search_span(nonce_base, batch, limit), batch,
+        template.nonce_spec, spec,
     )
 
 
@@ -391,10 +424,14 @@ def _min_hit_into(out_ref, i, ok, nonces):
         out_ref[0, 0] = jnp.minimum(out_ref[0, 0], tile_min)
 
 
-def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
-                   nonce_spec, spec: TargetSpec):
-    i, _, nonces, digest = _tile_digest(
-        mid_ref, tail_ref, lambda: base_ref[0], tile_rows=tile_rows,
+def _pallas_kernel(mid_ref, tail_ref, span_ref, out_ref, *, batch: int,
+                   tile_rows: int, nonce_spec, spec: TargetSpec):
+    """Static-target kernel: the target is compiled in, the range
+    ``[base, limit)`` (``span_ref[:2]``) is SMEM data, so one program a
+    tip serves every round of a job, the short last one too."""
+    base, limit = span_ref[0], span_ref[1]
+    i, lin, nonces, digest = _tile_digest(
+        mid_ref, tail_ref, lambda: base, tile_rows=tile_rows,
         nonce_spec=nonce_spec)
     t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
     ok = (digest[0] & t[0]) == t[1]
@@ -402,6 +439,8 @@ def _pallas_kernel(mid_ref, tail_ref, base_ref, out_ref, *, tile_rows: int,
     if spec.charset < 16:
         nib = (digest[spec.nibble_word] >> jnp.uint32(spec.nibble_shift)) & jnp.uint32(0xF)
         ok &= nib < jnp.uint32(spec.charset)
+    ok &= _lanes_in_range(lin, jnp.uint32(i) * jnp.uint32(tile_rows * 128),
+                          base, limit, batch)
     _min_hit_into(out_ref, i, ok, nonces)
 
 
@@ -428,13 +467,8 @@ def _pallas_kernel_data(mid_ref, tail_ref, span_ref, tgt_ref, out_ref, *,
     # a nibble is under 16, so charset >= 16 passes every lane here as
     # _hit_nonce_dynamic's explicit (charset >= 16) does
     ok &= nib < tgt_ref[6]
-    # lanes of this tile inside the shard's range: counted from base, so
-    # a range that ends near 2^32 needs no care about u32 wrap; tiles
-    # wholly past the limit (or past batch, in a ragged last tile) are
-    # left with none
-    span = jnp.minimum(limit - base, jnp.uint32(min(batch, 0xFFFFFFFF)))
-    done = jnp.uint32(i) * jnp.uint32(tile_rows * 128)
-    ok &= lin < jnp.where(span > done, span - done, jnp.uint32(0))
+    ok &= _lanes_in_range(lin, jnp.uint32(i) * jnp.uint32(tile_rows * 128),
+                          base, limit, batch)
     _min_hit_into(out_ref, i, ok, nonces)
 
 
@@ -489,22 +523,19 @@ def pow_search_pallas_data(midstate, tail_words, span, target, *,
 
 
 @functools.partial(jax.jit, static_argnames=("batch", "tile_rows", "nonce_spec", "spec", "interpret"))
-def _pow_search_pallas(midstate, tail_words, nonce_base, batch: int,
+def _pow_search_pallas(midstate, tail_words, span, batch: int,
                        tile_rows: int, nonce_spec, spec: TargetSpec,
                        interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    tile = tile_rows * 128
-    assert batch % tile == 0, (batch, tile)
-    grid = batch // tile
     kernel = functools.partial(
-        _pallas_kernel, tile_rows=tile_rows, nonce_spec=nonce_spec, spec=spec
-    )
+        _pallas_kernel, batch=batch, tile_rows=tile_rows,
+        nonce_spec=nonce_spec, spec=spec)
     with jax.named_scope("upow.sha256_search"):
         per_tile = pl.pallas_call(
             kernel,
-            grid=(grid,),
+            grid=(pl.cdiv(batch, tile_rows * 128),),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -514,17 +545,19 @@ def _pow_search_pallas(midstate, tail_words, nonce_base, batch: int,
                                    memory_space=pltpu.SMEM),
             out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
             interpret=interpret,
-        )(midstate, tail_words, nonce_base.reshape(1))
+        )(midstate, tail_words, span)
     return per_tile[0, 0].astype(jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
 def pow_search_pallas(template: SearchTemplate, spec: TargetSpec,
-                      nonce_base: int, batch: int, tile_rows: int = 64,
-                      interpret: bool = False):
-    """Pallas-tiled search; same contract as :func:`pow_search_jnp`."""
+                      nonce_base: int, batch: int, limit=None,
+                      tile_rows: int = 64, interpret: bool = False):
+    """Pallas-tiled search; same contract as :func:`pow_search_jnp`.  The
+    grid rounds ``batch`` up to whole tiles; the range mask drops the
+    surplus lanes."""
     return _pow_search_pallas(
         jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
-        jnp.uint32(nonce_base).reshape(()), batch, tile_rows,
+        search_span(nonce_base, batch, limit), batch, tile_rows,
         template.nonce_spec, spec, interpret,
     )
 

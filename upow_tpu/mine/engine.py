@@ -74,7 +74,12 @@ def _make_dispatcher(job: MiningJob, backend: str,
 
     The handle resolves via ``int()``; keeping several dispatches in
     flight hides the host↔device round-trip (which otherwise caps the
-    hash rate).
+    hash rate).  ``count`` is the round's live lanes: on the pallas and
+    jnp backends every round of a job runs the program of ``batch``
+    lanes, and one with ``count < batch`` (a masked round: the last of a
+    range that is no multiple of ``batch``) passes ``start + count`` as
+    the limit, is counted in ``mine.rounds_masked`` / ``mine.lanes_masked``
+    and issues under the light span ``mine.round.tail``.
 
     ``backend='mesh'`` routes rounds through the resident mesh engine
     (mesh_engine.py): one compiled SPMD program per process whose
@@ -88,19 +93,6 @@ def _make_dispatcher(job: MiningJob, backend: str,
     from ..device.runtime import get_runtime
 
     runtime = get_runtime()
-
-    def _through_runtime(inner, kernel: str):
-        # dispatch ISSUANCE goes through the device owner (so miner
-        # rounds interleave fairly with verify/index batches); XLA's
-        # async dispatch returns the device handle immediately, and the
-        # caller still blocks on int(handle) — the pipelining depth in
-        # mine() keeps its overlap
-        def dispatch(start: int, count: int):
-            return runtime.submit_call(
-                lambda: inner(start, count), kernel=kernel,
-                source="mine").result()
-
-        return dispatch
 
     if backend == "mesh":
         from .mesh_engine import get_mesh_engine
@@ -121,10 +113,35 @@ def _make_dispatcher(job: MiningJob, backend: str,
         "sha256_search", real=lanes, padded=lanes,
         compile_key=(batch, template.nonce_spec, spec))
 
-    def dispatch(start: int, count: int):
-        return fn(template, spec, nonce_base=start, batch=count)
+    def issue(start: int, count: int, width: int):
+        # dispatch ISSUANCE goes through the device owner (so miner
+        # rounds interleave fairly with verify/index batches); XLA's
+        # async dispatch returns the device handle immediately, and the
+        # caller still blocks on int(handle) — the pipelining depth in
+        # mine() keeps its overlap
+        return runtime.submit_call(
+            lambda: fn(template, spec, nonce_base=start, batch=width,
+                       limit=start + count),
+            kernel="sha256_search", source="mine").result()
 
-    return _through_runtime(dispatch, "sha256_search")
+    def dispatch(start: int, count: int):
+        # always the one program of `batch` lanes (a caller that names no
+        # batch gets a program a count, as before): the range's end is
+        # data, so a job's short last round is neither a second trace and
+        # compile a tip nor a shape the tiled kernel refuses
+        width = batch or count
+        if count >= width:
+            return issue(start, count, width)
+        # a masked round: lanes at or past start + count are hashed and
+        # never answer (crypto/sha256.py _lanes_in_range)
+        telemetry.inc("mine.rounds_masked")
+        telemetry.inc("mine.lanes_masked", width - count)
+        telemetry.device.record_batch("sha256_search", real=count,
+                                      padded=width)
+        with telemetry.span("mine.round.tail", light=True):
+            return issue(start, count, width)
+
+    return dispatch
 
 
 def _make_searcher(job: MiningJob, backend: str) -> Callable[[int, int], Optional[int]]:
@@ -186,6 +203,13 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
     ``start``/``stride_end`` let a coordinator hand disjoint nonce ranges to
     multiple chips/hosts (the reference's worker striding, miner.py:140-148,
     without the per-nonce interleave that would defeat batching).
+
+    A round is ``batch`` nonces but the range's last, which is what is
+    left: at the CLI's defaults 255 rounds of 2^24 and one of 2^24 - 1
+    (``MAX_SEARCH_END``: the sentinel is never searched).  A device
+    backend runs that short round on the program of the whole ones, the
+    surplus lanes masked (:func:`_make_dispatcher`); ``hashes_tried``,
+    ``mine.nonces`` and the progress callback count live lanes only.
     """
     stride_end = min(stride_end, MAX_SEARCH_END)
     t0 = time.time()

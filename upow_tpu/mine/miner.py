@@ -10,7 +10,12 @@ Protocol (miner.py:126-156): GET {node}/get_mining_info → build a template
 {node}/push_block {block_content, txs, block_no}.  The ``--shard i/k`` flag
 assigns this process the i-th of k disjoint nonce ranges — the multi-chip /
 multi-host scale-out story (each shard is one device or one host; no
-communication needed until a hit).
+communication needed until a hit).  A job is searched in rounds of
+``--batch`` nonces, one progress line a round; the last round of a range
+that is no multiple of the batch is short (at the default ``--shard 0/1``
+the 256th has 2^24 - 1: nonce 2^32 - 1 is the searchers' no-hit sentinel
+and is never tried), runs the same device program with its surplus lanes
+masked, and counts its live nonces only.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ from typing import Optional
 from .. import telemetry
 from ..core.clock import timestamp
 from ..core.merkle import miner_merkle_root
-from .engine import MiningJob, mine
+from .engine import MAX_SEARCH_END, MiningJob, mine
 
 GENESIS_PREV_HASH = (18_884_643).to_bytes(32, "little").hex()  # miner.py:37-40
 
@@ -231,8 +236,9 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
 
     while True:
         heartbeat["t"] = time.monotonic()
-        with telemetry.request_trace("mine.job", backend=backend,
-                                     shard=f"{i}/{k}") as root:
+        with telemetry.request_trace(
+                "mine.job", backend=backend, shard=f"{i}/{k}",
+                nonces=min(hi, MAX_SEARCH_END) - lo) as root:
             rc = one_job(root)
         if rc is None:
             time.sleep(1)
