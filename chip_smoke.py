@@ -619,10 +619,11 @@ def run_four_chips(args, work: str) -> dict:
                                f"of the resident program, not {want_body}")
         counters = rec["counters"] or {}
         seen_rounds = {k: counters.get(k) for k in (
-            "mine.mesh.rounds_pallas", "mine.rounds",
+            "mine.mesh.rounds_pallas", "mine.rounds", "mine.mesh.job_layouts",
             "kernel.mine_mesh.compile_cache_misses")}
         say(f"[4] mesh x{n}: dispatches={mesh.get('dispatches')} "
-            f"{seen_rounds}")
+            f"job_layouts={mesh.get('job_layouts')} "
+            f"jit_entries={mesh.get('jit_entries')} {seen_rounds}")
         # every dispatched round ran the kernel (none on the CPU), and
         # the resident program was one compile key
         if seen_rounds["mine.mesh.rounds_pallas"] != (
@@ -630,6 +631,14 @@ def run_four_chips(args, work: str) -> dict:
                 or seen_rounds["kernel.mine_mesh.compile_cache_misses"] != 1:
             raise SmokeFailure(f"mesh x{n} counters {seen_rounds} against "
                                f"{mesh.get('dispatches')} dispatches")
+        # the one job was laid over the mesh once, and met the program
+        # the arm compiled: a second jit entry is a warm / job mismatch
+        if mesh.get("jit_entries") != 1 or mesh.get("job_layouts") != 1 \
+                or seen_rounds["mine.mesh.job_layouts"] != 1:
+            raise SmokeFailure(
+                f"mesh x{n} laid its job {mesh.get('job_layouts')} times "
+                f"(counter {seen_rounds['mine.mesh.job_layouts']}) into "
+                f"{mesh.get('jit_entries')} jit entries, wanted 1 and 1")
         recs[n] = rec
     a.stop()
     seen = recs[4]["device"]

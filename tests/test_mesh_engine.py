@@ -9,7 +9,11 @@ device runtime under source "mine", and the structured arm ladder with
 real exception text.  The resident program's TPU body (the Pallas kernel,
 here in interpret mode) is held to the jnp body's answers, to the same
 no-recompile swap, and to its ``body`` / ``mine.mesh.rounds_pallas``
-accounting.
+accounting.  A job's three arrays are laid over the engine's mesh once a
+job (ISSUE 31): committed, replicated, the sharding the arm's warm
+dispatch compiled for, so the resident program's jit cache holds one
+entry from the arm on and ``mine.mesh.job_layouts`` counts jobs, not
+rounds.
 """
 
 import random
@@ -60,6 +64,41 @@ def _armed_engine(batch_per_device=1024) -> MeshEngine:
     return eng
 
 
+def _jit_entries() -> int:
+    """The process's compiled variants of the resident program."""
+    from upow_tpu.parallel import mesh as pmesh
+
+    return pmesh._pow_search_mesh_resident._cache_size()
+
+
+def _arm_through_first_job(eng: MeshEngine) -> tuple:
+    """Arm a fresh engine and run its first job's first round; the jit
+    entries (before the arm, after it, after that round).  Nothing else
+    dispatches in between, so the differences are this engine's."""
+    before = _jit_entries()
+    info = eng.arm()
+    assert info["armed"], info
+    armed = _jit_entries()
+    eng.set_job(_seeded_job(1))
+    int(eng.dispatch(0, eng.capacity))   # waited for: interpret mode is slow
+    return before, armed, _jit_entries()
+
+
+def _assert_laid_over_mesh(eng: MeshEngine) -> None:
+    """Each of the job's three arrays is committed, fully replicated and
+    on exactly the engine's mesh devices: what the resident program takes
+    them as, so a round re-lays nothing."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    assert len(eng._job_arrays) == 3
+    for a in eng._job_arrays:
+        assert a.committed
+        assert a.sharding.is_fully_replicated
+        assert a.sharding == NamedSharding(eng._mesh, P())
+        assert a.devices() == set(eng.mesh_devices())
+        assert len(a.addressable_shards) == eng.n_devices
+
+
 # ------------------------------------------------- differential identity ----
 
 def test_differential_bit_identity_three_seeded_jobs():
@@ -70,6 +109,7 @@ def test_differential_bit_identity_three_seeded_jobs():
     for seed in (101, 202, 303, 404):
         job = _seeded_job(seed)
         eng.set_job(job)
+        _assert_laid_over_mesh(eng)
         template = make_template(job.prefix)
         spec = target_spec(job.previous_hash, job.difficulty)
         for start in (0, 1 << 20):
@@ -117,8 +157,7 @@ def pallas_engine():
     tile, and a shard must span two tiles (parallel/mesh.py).  Not the
     process-wide engine, so ``clean_state`` leaves it armed."""
     eng = MeshEngine(mesh_devices=2, batch_per_device=2048, interpret=True)
-    info = eng.arm()
-    assert info["armed"], info
+    eng.jit_entries_seen = _arm_through_first_job(eng)
     assert eng.n_devices == 2
     return eng
 
@@ -137,6 +176,7 @@ def test_pallas_body_matches_jnp_body(pallas_engine, seed, start, short):
     count = plain.capacity - short
     plain.set_job(job)
     pallas_engine.set_job(job)
+    _assert_laid_over_mesh(pallas_engine)
     got = int(pallas_engine.dispatch(start, count))
     assert got == int(plain.dispatch(start, count))
     template = make_template(job.prefix)
@@ -212,34 +252,87 @@ def test_dispatch_rejects_oversized_round():
         eng.dispatch(0, 0)
 
 
+def test_a_job_needs_the_armed_engines_mesh():
+    """``set_job`` lays the job over the engine's mesh, so it needs the
+    arm as ``dispatch`` does; an unarmed engine has no program either."""
+    eng = MeshEngine(batch_per_device=64)
+    with pytest.raises(RuntimeError, match="before arm"):
+        eng.set_job(_seeded_job(3))
+    with pytest.raises(RuntimeError, match="before arm"):
+        eng.dispatch(0, 1)
+    st = eng.stats()
+    assert st["job_layouts"] == 0 and st["jit_entries"] == 0
+
+
 # ---------------------------------------------- no-recompile job swap ----
 
 @pytest.mark.parametrize("body", ["jnp", "pallas"])
 def test_job_swap_is_pure_dispatch_no_recompile(body, request):
     """A new job / chain-tip change must NOT recompile the resident
-    program, whichever body it was built with: jax's jit cache size
-    stays flat and the mine_mesh compile-cache counters record one miss
-    then only hits."""
-    from upow_tpu.parallel import mesh as pmesh
-
-    eng = (_armed_engine(batch_per_device=256) if body == "jnp"
-           else request.getfixturevalue("pallas_engine"))
+    program, whichever body it was built with: the arm compiles one jit
+    entry, the first job meets it (its arrays have the warm dispatch's
+    sharding), and it stays the one over three more jobs of other
+    targets; the mine_mesh compile-cache counters record one miss then
+    only hits."""
+    if body == "jnp":
+        # a per-shard batch no other test compiles: the arm's entry is new
+        eng = get_mesh_engine(batch_per_device=320)
+        seen = _arm_through_first_job(eng)
+    else:
+        eng = request.getfixturevalue("pallas_engine")
+        seen = eng.jit_entries_seen
     assert eng.stats()["body"] == body
-    eng.set_job(_seeded_job(1))
-    int(eng.dispatch(0, eng.capacity))   # waited for: interpret mode is slow
-    jit_entries = pmesh._pow_search_mesh_resident._cache_size()
-    misses0 = metrics.counters().get(
-        "kernel.mine_mesh.compile_cache_misses", 0)
-    assert misses0 == 1  # the first dispatch's key
+    before, armed, first_job = seen
+    assert armed == before + 1     # one program, compiled at the arm
+    assert first_job == armed      # no second one at the first job
+    jit_entries = _jit_entries()
 
     for seed in (2, 3, 4):  # three job swaps, different targets too
         eng.set_job(_seeded_job(seed, difficulty=str(1 + seed / 10)))
         int(eng.dispatch(seed * 1000, eng.capacity))
 
-    assert pmesh._pow_search_mesh_resident._cache_size() == jit_entries
+    assert _jit_entries() == jit_entries
+    assert eng.stats()["jit_entries"] == jit_entries
     counters = metrics.counters()
-    assert counters.get("kernel.mine_mesh.compile_cache_misses", 0) == misses0
-    assert counters.get("kernel.mine_mesh.compile_cache_hits", 0) >= 3
+    # the first round since clean_state's reset is the key's one miss
+    assert counters.get("kernel.mine_mesh.compile_cache_misses", 0) == 1
+    assert counters.get("kernel.mine_mesh.compile_cache_hits", 0) >= 2
+
+
+@pytest.mark.parametrize("body", ["jnp", "pallas"])
+def test_job_layouts_count_jobs_not_rounds(body, request):
+    """``mine.mesh.job_layouts`` / ``stats()["job_layouts"]``: 0 from
+    the arm on, one a job whose arrays were placed, none for the same
+    job set again (however often ``dispatcher(job)`` is called), none
+    for a round."""
+    eng = (_armed_engine(batch_per_device=64) if body == "jnp"
+           else request.getfixturevalue("pallas_engine"))
+    laid0 = eng.stats()["job_layouts"]
+    counted0 = metrics.counters().get("mine.mesh.job_layouts")
+
+    def layouts():
+        return (metrics.counters()["mine.mesh.job_layouts"] - (counted0 or 0),
+                eng.stats()["job_layouts"] - laid0)
+
+    if body == "jnp":   # a fresh arm exports the counter at zero
+        assert counted0 == 0 and laid0 == 0
+    job = _seeded_job(11)
+    eng.set_job(job)
+    _assert_laid_over_mesh(eng)
+    arrays = eng._job_arrays
+    assert layouts() == (1, 1)
+    assert eng.dispatcher(job) == eng.dispatch   # the same job again
+    eng.set_job(job)
+    assert eng._job_arrays is arrays
+    assert layouts() == (1, 1)
+    if body == "jnp":   # a round lays nothing (seconds in interpret mode)
+        rounds = eng.stats()["dispatches"]
+        int(eng.dispatch(0, eng.capacity))
+        assert eng.stats()["dispatches"] == rounds + 1
+        assert layouts() == (1, 1)
+    eng.set_job(_seeded_job(12, difficulty="2.5"))
+    _assert_laid_over_mesh(eng)
+    assert layouts() == (2, 2)
 
 
 def test_engine_reuse_and_replacement_semantics():
@@ -341,3 +434,4 @@ def test_engine_stats_exported_for_node_gauges():
     st = mesh_engine.engine_stats()
     assert st["armed"] and st["devices"] == 8
     assert st["capacity"] == eng.capacity
+    assert st["job_layouts"] == 0 and st["jit_entries"] >= 1

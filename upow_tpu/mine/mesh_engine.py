@@ -14,6 +14,13 @@ This module owns the multi-device path:
   kernel on a TPU mesh and the jnp one elsewhere
   (``parallel.mesh.resident_body``); ``stats()["body"]`` and the
   ``mine.mesh.rounds_pallas`` counter say which one the rounds ran.
+* **A job's arrays laid over the mesh once a job** — :meth:`set_job`
+  places midstate, tail and target as committed arrays replicated on
+  every device of the engine's mesh, the sharding the program was
+  compiled for (the arm's warm dispatch lays its zeros the same way), so
+  a round hands pjit nothing to re-lay; only ``ranges`` crosses a round.
+  ``mine.mesh.job_layouts`` counts the placements, and
+  ``stats()["jit_entries"]`` stays 1.
 * **Disjoint shard ranges** — each round's [start, start+count) window
   is split across the mesh with :func:`parallel.mesh.shard_bounds`; the
   per-round plan is retained in the dispatch accounting so tests (and
@@ -101,6 +108,7 @@ class MeshEngine:
         self._rounds: List[dict] = []
         self._dispatches = 0
         self._nonces_planned = 0
+        self._job_layouts = 0
 
     # ------------------------------------------------------------- arm ---
 
@@ -183,6 +191,7 @@ class MeshEngine:
         # exported at zero from the arm on: a CPU mesh reads 0, not "no
         # such counter"
         telemetry.ensure_counter("mine.mesh.rounds_pallas")
+        telemetry.ensure_counter("mine.mesh.job_layouts")
         if self._batch_per_device is None:
             if self._round_hint:
                 # ceil: one round of round_hint nonces must fit capacity
@@ -196,11 +205,11 @@ class MeshEngine:
                     1, cfg.search_batch // self._n_dev)
         # dummy template: zero midstate/tail/target, every shard empty
         # (base == limit == 0) — compiles the exact program real jobs
-        # dispatch, costs one masked-out round of hashing
-        import jax.numpy as jnp
-
+        # dispatch, costs one masked-out round of hashing.  The zeros are
+        # laid as a job's arrays are: jit keys a program on the shardings
+        # of committed arguments, and the first job must find this one
         spec = sha_kernel.make_template(bytes(104)).nonce_spec
-        zeros = jnp.asarray(sha_kernel.resident_operand([0]))
+        zeros = self._lay_over_mesh([0])
         no_ranges = sha_kernel.resident_operand(
             np.zeros((self._n_dev, 2), np.uint32))
 
@@ -214,21 +223,38 @@ class MeshEngine:
 
     # ------------------------------------------------------------- job ---
 
+    def _lay_over_mesh(self, words):
+        """``words`` padded to a page (``sha256.resident_operand``) as a
+        committed array replicated on every device of the engine's mesh:
+        the sharding the resident program takes its three job operands
+        in, so a dispatch hands pjit nothing to lay again."""
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        # data placement onto the armed runtime's own devices, not a
+        # dispatch and no enumeration
+        return jax.device_put(  # upowlint: disable=DR001
+            sha_kernel.resident_operand(words),
+            NamedSharding(self._mesh, P()))
+
     def set_job(self, job) -> None:
         """Load a :class:`..mine.engine.MiningJob`: host-side midstate +
-        packed target become device arrays; the resident program is NOT
-        recompiled (all job fields are traced arguments)."""
-        import jax.numpy as jnp
-
+        packed target are laid over the mesh, once a job; the resident
+        program is NOT recompiled (all job fields are traced arguments
+        of the sharding the arm's warm dispatch compiled for)."""
+        if not self._armed:
+            raise RuntimeError("MeshEngine.set_job before arm()")
         key = (job.prefix, job.previous_hash, str(job.difficulty))
         if self._job_key == key:
             return
         template = sha_kernel.make_template(job.prefix)
         spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
         self._job_arrays = tuple(
-            jnp.asarray(sha_kernel.resident_operand(words)) for words in (
+            self._lay_over_mesh(words) for words in (
                 template.midstate, template.tail_words,
                 sha_kernel.pack_target(spec)))
+        self._job_layouts += 1
+        telemetry.inc("mine.mesh.job_layouts")
         self._nonce_spec = template.nonce_spec
         self._job_key = key
         self._job_t0 = time.perf_counter()
@@ -308,6 +334,13 @@ class MeshEngine:
     # ----------------------------------------------------------- stats ---
 
     def stats(self) -> dict:
+        jit_entries = 0
+        if self._mesh is not None:
+            from ..parallel.mesh import _pow_search_mesh_resident
+
+            # the process's compiled variants of the resident program:
+            # 1 for one engine, whatever jobs and targets it was given
+            jit_entries = _pow_search_mesh_resident._cache_size()
         return {
             "armed": self._armed,
             "devices": self._n_dev,
@@ -316,6 +349,8 @@ class MeshEngine:
             "capacity": self.capacity,
             "dispatches": self._dispatches,
             "nonces_planned": self._nonces_planned,
+            "job_layouts": self._job_layouts,
+            "jit_entries": jit_entries,
             "rounds": list(self._rounds),
             "arm_ladder": list(self.arm_ladder),
             "arm_failure_reason": self.arm_failure_reason,

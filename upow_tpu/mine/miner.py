@@ -260,6 +260,8 @@ def _print_mesh_accounting(mesh_devices: int) -> None:
         "body": stats["body"],
         "batch_per_device": stats["batch_per_device"],
         "dispatches": stats["dispatches"],
+        "job_layouts": stats["job_layouts"],
+        "jit_entries": stats["jit_entries"],
         "last_round_shards": last.get("shards", [])}), flush=True)
 
 
