@@ -1,7 +1,7 @@
 """DR fixture: device dispatches outside device/ (parsed, never run)."""
 import jax
 
-from upow_tpu import benchutil
+from upow_tpu.device import runtime
 from upow_tpu.device.runtime import get_runtime
 
 
@@ -26,7 +26,7 @@ def enumerate_backends():
 
 
 def dispatch_around_runtime(fn):
-    return benchutil.boxed_call(fn, 5.0)       # DR002
+    return runtime.boxed_call(fn, 5.0)         # DR002
 
 
 def stage_at_call_time(fn):

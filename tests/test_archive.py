@@ -485,3 +485,39 @@ def test_archive_prune_scenario_green_and_deterministic():
     assert a["core"]["hot_blocks_after"] < a["core"]["hot_blocks_before"]
     b = run_scenario("archive_prune", seed=7)
     assert a["fingerprint"] == b["fingerprint"]
+
+
+# ------------------------------------------------------ CLI exit code ----
+
+@pytest.mark.parametrize("case", ["clean", "parity_false", "resume_failed",
+                                  "fingerprint_moved"])
+def test_archive_cli_exit_code_holds_parity(monkeypatch, capsys, case):
+    """``make archive-smoke`` is ``python -m upow_tpu.archive
+    --check-determinism``: its exit code is what holds the pruned-vs-twin
+    parity of the ``archive_prune`` scenario (every core boolean), the
+    resume leg and the determinism double-run."""
+    from upow_tpu.archive import __main__ as cli
+
+    def artifact(fp):
+        return {"scenario": "archive_prune", "nodes": 3, "seed": 7,
+                "fingerprint": fp, "observed": {"elapsed_s": 0.5,
+                                                "probes": 40},
+                "core": {"archived_reads_match_twin": case != "parity_false",
+                         "archived_through": 192, "hot_blocks_before": 260,
+                         "hot_blocks_after": 68}}
+
+    runs = iter([artifact("aa" * 32),
+                 artifact(("bb" if case == "fingerprint_moved" else "aa")
+                          * 32)])
+
+    async def resume(seed, blocks):
+        return ["journal survived"] if case == "resume_failed" else []
+
+    monkeypatch.setattr(cli, "_differential", lambda seed, blocks: True)
+    monkeypatch.setattr(cli, "_drive_resume", resume)
+    monkeypatch.setattr(cli, "run_scenario", lambda name, seed: next(runs))
+    rc = cli.main(["--check-determinism"])
+    assert rc == (0 if case == "clean" else 1)
+    captured = capsys.readouterr()
+    if case == "parity_false":
+        assert "core failed: archived_reads_match_twin" in captured.err

@@ -24,7 +24,7 @@ else:
     del sys.modules["graft_entry"]
 
 # the loader's real message shape (double space included, as observed
-# live — MULTICHIP_r04.json tail)
+# live in a four-chip dry run's output)
 _LINE = ("E0801 14:49:04.127131  13650 cpu_aot_loader.cc:210] Loading "
          "XLA:CPU AOT result. Target machine feature {feat} is not "
          " supported on the host machine. Machine type used for XLA:CPU "

@@ -193,7 +193,7 @@ def test_pallas_ladder_matches_host():
     host ECDSA, valid + invalid lanes.  (The production limb-list kernel
     traces ~10x more ops — interpret mode is impractical for it; its
     field/point math is covered by the limb-list differentials below and
-    the assembled kernel by bench_suite config 3 on real TPU.)"""
+    the assembled kernel by chip_smoke.py on real TPU.)"""
     msgs, sigs, pubs = [], [], []
     for i in range(8):
         d, pub = curve.keygen(rng=5000 + i)
@@ -253,7 +253,7 @@ def test_mod_n_inversion_matches_pow():
 @pytest.mark.skipif(not os.environ.get("UPOW_SLOW_TESTS"),
                     reason="composed prep+ladder program is a ~1 min CPU "
                            "execute; set UPOW_SLOW_TESTS=1 to include "
-                           "(the TPU path is exercised by bench_suite)")
+                           "(the TPU path is exercised by chip_smoke.py)")
 def test_device_scalar_prep_full_differential():
     """scalar_prep="device" (the TPU production path: inversion, u1/u2,
     Montgomery conversion, on-curve and digit extraction all on device)
@@ -313,8 +313,8 @@ def test_device_scalar_prep_full_differential():
 # --- limb-list layout (Pallas kernel data path) ----------------------------
 # The list ops are plain jnp functions; testing them directly covers the
 # kernel's field arithmetic without a (slow) interpret-mode pallas_call.
-# The assembled kernel itself is exercised on real TPU by bench_suite
-# config 3 and the driver's compile gate.
+# The assembled kernel itself is exercised on real TPU by chip_smoke.py
+# and compiled for a described v5e by tests/test_tpu_compile.py.
 
 def _to_fl(xs, bound):
     limbs = fp.ints_to_limbs(xs)
@@ -518,3 +518,22 @@ def test_point_mul_jacobian_matches_affine_ladder():
             curve._point_mul_affine_ladder(k, p), k
     assert curve.point_mul(5, None) is None
     assert curve.point_mul(0, p) is None
+
+
+# --- env knobs --------------------------------------------------------------
+
+def test_env_choice_accepts_allowed(monkeypatch):
+    from upow_tpu.crypto.p256 import _env_choice
+
+    monkeypatch.setenv("UPOW_TEST_KNOB", " 5 ")
+    assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 5
+
+
+def test_env_choice_rejects_invalid(monkeypatch):
+    from upow_tpu.crypto.p256 import _env_choice
+
+    for bad in ("garbage", "", "6", "4.5"):
+        monkeypatch.setenv("UPOW_TEST_KNOB", bad)
+        assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 4
+    monkeypatch.delenv("UPOW_TEST_KNOB")
+    assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 4

@@ -9,8 +9,8 @@ cryptographically unreachable for honest signatures.
 
 Everything runs the shared round logic eagerly (no jit) with short
 ladders, so the suite stays fast; the assembled Pallas kernel is
-exercised on real TPU by bench_suite config 3 and an in-session
-differential against the host oracle.
+exercised on real TPU by chip_smoke.py (a ``device=tpu`` node against a
+host-verify node on the same block).
 """
 
 import random

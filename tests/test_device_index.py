@@ -3,6 +3,7 @@ fingerprinting, incremental sorted maintenance
 (upow_tpu/state/device_index.py; SURVEY §2.2, ISSUE 7 tentpole a)."""
 
 import numpy as np
+import pytest
 
 from upow_tpu.state.device_index import (DeviceUtxoIndex, fingerprint,
                                          fingerprint_batch)
@@ -189,7 +190,7 @@ def test_accept_path_steady_state_zero_shadow_consults():
     acceptance criterion, asserted on telemetry (ISSUE 11)."""
     import asyncio
 
-    from upow_tpu.benchutil import chain_with_utxo_fanout, leaf_spends
+    from upow_tpu.loadgen.fixtures import chain_with_utxo_fanout, leaf_spends
     from upow_tpu.core import clock, difficulty
     from upow_tpu.telemetry import metrics
 
@@ -232,6 +233,98 @@ def test_accept_path_steady_state_zero_shadow_consults():
                 - before.get("index.shadow_consults", 0))
     assert probes > 0, "fused accept path never dispatched a probe"
     assert consults == 0, "steady state accept consulted the host map"
+
+
+@pytest.fixture(scope="module")
+def accept_stages():
+    """One 32-tx block through the fused resident accept path and
+    through the serial SQL path (same frozen clock, so the same block
+    hashes), then a FORCED REORG (``remove_blocks``) and a re-accept on
+    the resident side.  Each stage keeps the three membership answers
+    over spends + creations + absent outpoints — resident probe, host
+    shadow map, SQL — and the unspent-set fingerprint."""
+    import asyncio
+
+    from upow_tpu.core import clock, difficulty
+    from upow_tpu.loadgen.fixtures import chain_with_utxo_fanout, leaf_spends
+
+    absent = [("ff" * 32, i) for i in range(16)]
+
+    async def scenario(resident: bool) -> dict:
+        state, manager, d, pub, addr, mids, mine_block = \
+            await chain_with_utxo_fanout(8, 4, 0xACC7)
+        try:
+            manager.fused_accept = resident
+            if resident:
+                state.enable_device_index()
+                assert state.resident_indexes(), "device index failed to arm"
+            txs = leaf_spends(mids, addr, d, pub)
+            sample = ([i.outpoint for t in txs for i in t.inputs]
+                      + [(t.hash(), 0) for t in txs] + absent)
+
+            async def stage() -> dict:
+                out = {"hash": await state.get_unspent_outputs_hash(),
+                       "sql": [bool(v) for v in await state.outpoints_exist(
+                           sample, "unspent_outputs")]}
+                if resident:
+                    idx = state.resident_indexes()["unspent_outputs"]
+                    out["dev"] = [bool(v)
+                                  for v in idx.contains_batch(sample)]
+                    out["shadow"] = [
+                        bool(v) for v in idx.shadow_contains_batch(sample)]
+                return out
+
+            stages = {"before": await stage()}
+            await mine_block(txs)
+            stages["accept"] = await stage()
+            if resident:
+                await state.remove_blocks(4)    # drop the leaf block
+                stages["reorg"] = await stage()
+                # the re-mined header gets a fresh timestamp, so its
+                # coinbase outpoint differs: the three-way parity is the
+                # byte-identity check of this stage
+                await mine_block(txs)
+                stages["reaccept"] = await stage()
+            stages["n_sample"] = len(sample)
+            return stages
+        finally:
+            state.close()
+
+    start_diff = difficulty.START_DIFFICULTY
+    try:
+        # both paths must see identical per-block timestamps or the block
+        # hashes (and the coinbase outpoints) diverge
+        clock.freeze(1_700_000_000)
+        serial = asyncio.run(scenario(False))
+        clock.freeze(1_700_000_000)
+        resident = asyncio.run(scenario(True))
+    finally:
+        clock.reset()
+        difficulty.START_DIFFICULTY = start_diff
+    return serial, resident
+
+
+@pytest.mark.parametrize("stage", ["accept", "reorg", "reaccept"])
+def test_accept_three_way_differential(accept_stages, stage):
+    """Resident probe == host shadow == SQL at every stage of accept,
+    forced reorg and re-accept; the fused path leaves the same unspent
+    set as the serial path, and the reorg returns it EXACTLY."""
+    serial, resident = accept_stages
+    got = resident[stage]
+    assert len(got["sql"]) == resident["n_sample"]
+    assert got["dev"] == got["shadow"] == got["sql"]
+    assert not any(got["sql"][-16:])            # absent stays absent
+    n = (resident["n_sample"] - 16) // 2
+    if stage == "reorg":
+        assert got["hash"] == resident["before"]["hash"]
+        assert got["sql"] == resident["before"]["sql"]
+        assert all(got["sql"][:n]) and not any(got["sql"][n:])
+    else:
+        assert not any(got["sql"][:n]) and all(got["sql"][n:2 * n])
+    if stage == "accept":
+        assert got["hash"] == serial["accept"]["hash"]
+        assert got["sql"] == serial["accept"]["sql"]
+        assert got["hash"] != resident["before"]["hash"]
 
 
 def test_apply_block_and_reorg_rollback_roundtrip():

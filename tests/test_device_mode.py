@@ -90,7 +90,7 @@ def test_pallas_failure_counts_in_auto_and_raises_in_tpu(monkeypatch):
 def test_device_verify_failure_raises_in_tpu_mode(monkeypatch):
     """``auto``: a failed device dispatch is re-run on the host and
     counted.  ``tpu``: it is the caller's error, nothing is re-run."""
-    from upow_tpu.benchutil import pipeline_verify_fixture
+    from upow_tpu.loadgen.fixtures import pipeline_verify_fixture
     from upow_tpu.crypto import p256
     from upow_tpu.verify import txverify
 
@@ -124,7 +124,7 @@ def test_first_dispatch_of_a_shape_gets_the_compile_allowance(monkeypatch):
     """A compile in flight is not a hang: the first dispatch of a padded
     shape is boxed by COMPILE_ALLOWANCE x device_timeout, later ones by
     device_timeout alone, and the first one is reported as an event."""
-    from upow_tpu.benchutil import pipeline_verify_fixture
+    from upow_tpu.loadgen.fixtures import pipeline_verify_fixture
     from upow_tpu.crypto import p256
     from upow_tpu.telemetry import events
     from upow_tpu.verify import txverify

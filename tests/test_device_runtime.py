@@ -11,9 +11,10 @@ import time
 import pytest
 
 from upow_tpu import telemetry
-from upow_tpu.benchutil import pipeline_verify_fixture
+from upow_tpu.loadgen.fixtures import pipeline_verify_fixture
 from upow_tpu.config import DeviceRuntimeConfig
-from upow_tpu.device.runtime import DeviceRuntime, boxed_call
+from upow_tpu.device import runtime as rt_mod
+from upow_tpu.device.runtime import DeviceRuntime
 from upow_tpu.resilience import faultinject
 from upow_tpu.resilience.degrade import DegradeManager
 from upow_tpu.telemetry import metrics
@@ -36,6 +37,35 @@ def rt():
     runtime = DeviceRuntime()
     yield runtime
     runtime.close()
+
+
+_HANG = ("backend init still inside jax.devices() after 3s (native hang; "
+         "no Python exception to show)")
+
+
+def _fake_probe(monkeypatch, platform, status="ok", error=None):
+    """The one seam: the process's probe.  Forgets the record this
+    process already holds and installs a counting fake; returns the
+    list of timeouts it was called with."""
+    calls = []
+
+    def probe(timeout):
+        calls.append(timeout)
+        return {"status": status, "platform": platform, "seconds": 0.0,
+                "error": error, "traceback_fingerprint": None}
+
+    monkeypatch.setattr(rt_mod, "_PROBE", None)
+    monkeypatch.setattr(rt_mod, "probe_platform", probe)
+    return calls
+
+
+@pytest.fixture
+def fresh_singleton():
+    """get_runtime() builds a new service for this test, and the next
+    test does not inherit it."""
+    rt_mod.reset_runtime()
+    yield
+    rt_mod.reset_runtime()
 
 
 def _host_compute(checks):
@@ -230,10 +260,7 @@ def test_arm_failure_serves_every_subsystem_on_cpu(monkeypatch):
     """A probe that hangs/fails arms the runtime WITHOUT a backend:
     platform() is None, devices() is [], and sig/call submissions from
     every source still complete on host paths without deadlock."""
-    from upow_tpu import benchutil
-
-    monkeypatch.setattr(benchutil, "probed_platform_cached",
-                        lambda timeout: None)
+    _fake_probe(monkeypatch, None, "timeout", _HANG)
     monkeypatch.setattr(txverify, "DEGRADE",
                         DegradeManager(failure_limit=3, cooldown=3600.0))
     rt = DeviceRuntime(DeviceRuntimeConfig(arm_timeout=5.0))
@@ -242,7 +269,8 @@ def test_arm_failure_serves_every_subsystem_on_cpu(monkeypatch):
         assert rt.devices() == []
         arm = rt.stats()["arm"]
         assert arm["armed"] and arm["platform"] is None
-        assert "hung/failed" in arm["arm_failure_reason"]
+        assert arm["arm_failure_reason"] == _HANG
+        assert arm["probe_status"] == "timeout"
 
         checks = pipeline_verify_fixture(12, n_unique=6, invalid_every=4)
         expected = _host_compute(checks)
@@ -259,18 +287,200 @@ def test_arm_failure_serves_every_subsystem_on_cpu(monkeypatch):
 
 
 def test_arm_failure_reason_in_structured_info(monkeypatch):
-    from upow_tpu import benchutil
-
-    monkeypatch.setattr(benchutil, "probed_platform_cached",
-                        lambda timeout: None)
+    calls = _fake_probe(monkeypatch, None, "err",
+                        "RuntimeError('PJRT: no device')")
     rt = DeviceRuntime(DeviceRuntimeConfig(arm_timeout=3.0))
     try:
         info = rt.arm(attempt="test-attempt")
+        assert calls == [3.0]
         assert info["platform"] is None
         assert info["attempt"] == "test-attempt"
-        assert "within 3s" in info["arm_failure_reason"]
+        assert info["arm_failure_reason"] == "RuntimeError('PJRT: no device')"
+        assert info["probe_status"] == "err"
+        assert info["device_count"] == 0
     finally:
         rt.close()
+
+
+# ---------------------------------------------------- the one probe ----
+
+def _raise(exc):
+    raise exc
+
+
+@pytest.mark.parametrize("case", ["ok-tpu", "ok-cpu", "timeout", "err"])
+def test_probe_platform_record(monkeypatch, case):
+    """The probe reports ``jax.devices()[0].platform`` as JAX names it
+    (every backend-routing comparison is written against "tpu"), and
+    keeps the failure when there is none to report."""
+    boom = None
+    if case == "err":
+        try:
+            _raise(RuntimeError("PJRT INTERNAL: boom"))
+        except RuntimeError as e:
+            boom = e
+    status, value = {"ok-tpu": ("ok", "tpu"), "ok-cpu": ("ok", "cpu"),
+                     "timeout": ("timeout", None),
+                     "err": ("err", boom)}[case]
+    monkeypatch.setattr(rt_mod, "boxed_call",
+                        lambda fn, timeout: (status, value))
+    rec = rt_mod.probe_platform(7.0)
+    assert set(rec) == {"status", "platform", "seconds", "error",
+                        "traceback_fingerprint"}
+    assert rec["status"] == status
+    assert rec["platform"] == (value if status == "ok" else None)
+    if case == "timeout":
+        assert "after 7s" in rec["error"] and "native hang" in rec["error"]
+    elif case == "err":
+        assert rec["error"] == repr(boom)
+        assert "PJRT INTERNAL: boom" in rec["error"]
+        assert rec["traceback_fingerprint"] == \
+            rt_mod.traceback_fingerprint(boom)
+        assert len(rec["traceback_fingerprint"]) == 12
+    else:
+        assert rec["error"] is None
+        assert rec["traceback_fingerprint"] is None
+
+
+def test_one_probe_a_process(monkeypatch, fresh_singleton):
+    """Whoever arms first probes; every later arm — another
+    DeviceRuntime, the device index, the sig path's backend gate, the
+    mesh miner — reads that record: a hung backend costs a process ONE
+    timeout."""
+    from upow_tpu.mine.mesh_engine import MeshEngine
+    from upow_tpu.state import ChainState
+
+    calls = _fake_probe(monkeypatch, "cpu")
+    monkeypatch.setattr(txverify, "DEGRADE",
+                        DegradeManager(failure_limit=3, cooldown=3600.0))
+    first = DeviceRuntime(DeviceRuntimeConfig(arm_timeout=4.0))
+    second = DeviceRuntime()
+    try:
+        assert first.arm()["platform"] == "cpu"
+        assert second.arm()["platform"] == "cpu"
+        assert second.platform() == "cpu"
+    finally:
+        first.close()
+        second.close()
+    state = ChainState()
+    try:
+        state.enable_device_index()
+        assert state.resident_indexes() is not None
+    finally:
+        state.close()
+    assert txverify._device_usable() is False       # "cpu": host path
+    assert txverify.DEGRADE.state == "ok"
+    eng = MeshEngine(mesh_devices=2, batch_per_device=64)
+    assert eng.arm()["armed"]
+    assert rt_mod.get_runtime().stats()["arm"]["platform"] == "cpu"
+    assert calls == [4.0]
+
+
+def test_arm_order_cache_probe_then_devices(monkeypatch):
+    """Inside arm(): the compile cache is enabled before the backend
+    exists, then the one probe, then ``jax.devices()`` for kind and
+    count — once each (the arm is 10-19 s of every cell's ``setup_s``;
+    a second probe or enumeration would show there)."""
+    import jax
+
+    from upow_tpu import compile_cache
+
+    order = []
+    real_devices = jax.devices
+
+    def probe(timeout):
+        order.append("probe")
+        return {"status": "ok", "platform": "cpu", "seconds": 0.0,
+                "error": None, "traceback_fingerprint": None}
+
+    monkeypatch.setattr(rt_mod, "_PROBE", None)
+    monkeypatch.setattr(rt_mod, "probe_platform", probe)
+    monkeypatch.setattr(compile_cache, "enable",
+                        lambda: order.append("cache") or "/cache/dir")
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: order.append("devices") or real_devices())
+    rt = DeviceRuntime()
+    try:
+        info = rt.arm(attempt="order")
+        again = rt.arm()
+    finally:
+        rt.close()
+    assert order == ["cache", "probe", "devices"]
+    assert again == info
+    assert info["compile_cache_dir"] == "/cache/dir"
+    assert info["device_count"] == len(real_devices())
+    assert rt_mod.device_line(info) == (
+        "device: platform=cpu kind=%s count=%d compile_cache=/cache/dir"
+        % (info["device_kind"], info["device_count"]))
+
+
+@pytest.mark.parametrize("site", ["storage", "pg", "txverify"])
+def test_no_platform_keeps_the_host_paths(monkeypatch, fresh_singleton,
+                                          caplog, site):
+    """No platform: the device index stays off and the sig path is
+    poisoned onto the host, with the warnings operators grep for."""
+    import logging
+
+    _fake_probe(monkeypatch, None, "timeout", _HANG)
+    monkeypatch.setattr(txverify, "DEGRADE",
+                        DegradeManager(failure_limit=3, cooldown=3600.0))
+    caplog.set_level(logging.WARNING)
+    if site == "txverify":
+        assert txverify._device_usable() is False
+        assert txverify.DEGRADE.state == "poisoned"
+        assert not txverify.device_verify_allowed()
+        assert ("jax backend init hung/failed; signature verification "
+                "pinned to the host path for this process") in caplog.text
+        return
+    if site == "storage":
+        from upow_tpu.state import ChainState
+
+        state = ChainState()
+    else:
+        from upow_tpu.state.pg import PgChainState
+        from upow_tpu.state.pgdriver import MockPgDriver
+
+        state = PgChainState(driver=MockPgDriver())
+    try:
+        state.enable_device_index()
+        assert state.resident_indexes() is None
+        assert state.index_stats() is None
+    finally:
+        state.close()
+    assert ("jax backend init hung/failed; device UTXO index disabled"
+            in caplog.text)
+
+
+def test_platform_asked_from_the_drainer_thread(fresh_singleton):
+    """A sig batch resolves its backend at pop time, on the drainer's
+    own thread, by asking the runtime that thread belongs to: the arm
+    happened before the first item was served, so it does not wait on
+    itself."""
+    rt = rt_mod.get_runtime()
+    checks = pipeline_verify_fixture(6, n_unique=3, invalid_every=4)
+    fut = rt.submit_sig_checks(checks, backend="auto", device_timeout=10.0,
+                               source="block")
+    assert fut.result(timeout=60.0) == _host_compute(checks)
+    seen = rt.submit_call(
+        lambda: (threading.current_thread().name, rt.platform()))
+    assert seen.result(timeout=10.0) == ("upow-device-runtime", "cpu")
+
+
+# ------------------------------------------------------ co-residency ----
+
+def test_coresidency_differential_and_coalescing():
+    """Miner + block verify + mempool intake on ONE runtime
+    (loadgen/coresidency.py at smoke size): every concurrent verdict
+    slice byte-identical to the serial host reference and to the
+    one-dispatch-per-batch pass, in fewer dispatches."""
+    from upow_tpu.loadgen.coresidency import CoresidencySpec, run_coresidency
+
+    r = run_coresidency(CoresidencySpec.smoke())
+    assert r["differential"]["ok"], r["differential"]
+    assert r["differential"]["checks"] > 0
+    assert r["differential"]["mismatches"] == 0
+    assert r["dispatch_reduction"] > 1
+    assert r["concurrent"]["sig_dispatches"] < r["serial"]["dispatches"]
 
 
 # -------------------------------------------------- service plumbing ----
@@ -282,15 +492,6 @@ def test_run_boxed_matches_boxed_call_contract(rt):
     assert status == "err" and isinstance(exc, ValueError)
     assert rt.run_boxed(lambda: time.sleep(5), timeout=0.1) \
         == ("timeout", None)
-
-
-def test_boxed_call_shim_still_exported():
-    """benchutil.boxed_call must keep working (deprecated shim) — the
-    probe path and external callers depend on the exact contract."""
-    from upow_tpu import benchutil
-
-    assert benchutil.boxed_call(lambda: 1, timeout=5.0) == ("ok", 1)
-    assert boxed_call(lambda: 1, timeout=5.0) == ("ok", 1)
 
 
 def test_inline_execution_from_drainer_thread(rt):

@@ -8,12 +8,14 @@ snapshots so failure messages point at the right layer.
 """
 
 import asyncio
+import copy
 import json
 import math
 import re
 
+import pytest
+
 from upow_tpu.fleet import propagation, recorder, scrape, stitch
-from upow_tpu.loadgen import gate
 from upow_tpu.resilience import faultinject
 from upow_tpu.swarm.harness import Swarm
 from upow_tpu.swarm.scenarios import (_wallet, deterministic_world,
@@ -230,6 +232,48 @@ def test_geo_soak_fleet_rows_shape():
     assert fleet_rows(broken)["kernels"]["fleet_core_ok"]["value"] == 0.0
 
 
+# ------------------------------------------------------ CLI exit code ----
+
+@pytest.fixture(scope="module")
+def geo_artifact():
+    return run_scenario("geo_soak", seed=7)
+
+
+@pytest.mark.parametrize("case", ["clean", "core_false", "alert_fired",
+                                  "trace_short", "fingerprint_moved"])
+def test_fleet_cli_exit_code_holds_the_core(geo_artifact, monkeypatch,
+                                            tmp_path, capsys, case):
+    """``make fleet`` is ``python -m upow_tpu.fleet --check-determinism
+    --trace --out``: its exit code is what holds ``fleet_core_ok`` and
+    ``watchtower_clean_ok`` (no gate on a committed baseline)."""
+    from upow_tpu.fleet import __main__ as cli
+
+    first = copy.deepcopy(geo_artifact)
+    again = copy.deepcopy(geo_artifact)
+    if case == "core_false":
+        first["core"]["healed_converged"] = False
+    elif case == "alert_fired":
+        first["core"]["watchtower_zero_alerts"] = False
+    elif case == "trace_short":
+        first["observed"]["stitched_push_tx"]["node_count"] = 2
+    elif case == "fingerprint_moved":
+        again["fingerprint"] = "0" * 64
+    runs = iter([first, again])
+    monkeypatch.setattr(cli, "run_geo_artifact",
+                        lambda nodes, seed: next(runs))
+    out = tmp_path / "fleet.json"
+    rc = cli.main(["--check-determinism", "--trace", "--out", str(out)])
+    assert rc == (0 if case == "clean" else 1)
+    # the artifact is written whole either way, and nothing is left over
+    assert json.loads(out.read_text())["fingerprint"] == first["fingerprint"]
+    assert [f.name for f in tmp_path.iterdir()] == ["fleet.json"]
+    rows = json.loads(capsys.readouterr().out.splitlines()[-1])["kernels"]
+    assert rows["fleet_core_ok"] == \
+        (0.0 if case in ("core_false", "alert_fired") else 1.0)
+    assert rows["watchtower_clean_ok"] == \
+        (0.0 if case == "alert_fired" else 1.0)
+
+
 # ----------------------------------------------- fleet exposition gate ----
 
 def test_render_fleet_validates_and_crafted_violations():
@@ -273,70 +317,40 @@ def test_render_fleet_empty_snapshot():
     assert "upow_fleet_nodes 0" in text
 
 
-# ------------------------------------------------------- gate --trend ----
-
-def test_gate_trend_skips_driver_lines_and_tracks_direction(tmp_path):
-    """Satellite 6: --trend reads only perf_observatory lines and
-    reports direction-aware per-metric trends."""
-    lines = [
-        {"ts": 1, "kind": "driver", "round": 1, "loc": 10},
-        {"kind": "perf_observatory",
-         "slo": {"push_tx": {"req_s": 100.0, "p95_ms": 20.0}},
-         "kernels": {"fleet_core_ok": 1.0, "verify_python": 100.0}},
-        "not json at all",
-        {"kind": "perf_observatory",
-         "slo": {"push_tx": {"req_s": 150.0, "p95_ms": 30.0}},
-         "kernels": {"fleet_core_ok": 1.0, "verify_python": 50.0}},
-    ]
-    path = tmp_path / "PROGRESS.jsonl"
-    path.write_text("".join(
-        (ln if isinstance(ln, str) else json.dumps(ln)) + "\n"
-        for ln in lines))
-
-    report = gate.trend_report(str(path))
-    assert report["observatory_lines"] == 2
-    rows = {r["metric"]: r for r in report["metrics"]}
-    assert "kernel.loc" not in rows     # driver line skipped
-    assert rows["slo.push_tx.req_s"]["trend"] == "improving"
-    assert rows["slo.push_tx.p95_ms"]["trend"] == "regressing"
-    assert rows["slo.push_tx.p95_ms"]["direction"] == "lower"
-    assert rows["kernel.verify_python"]["trend"] == "regressing"
-    assert rows["kernel.fleet_core_ok"]["trend"] == "flat"
-    # regressions sort first; trend mode never fails the build
-    assert report["metrics"][0]["trend"] == "regressing"
-    assert gate.main(["--trend", str(path)]) == 0
-
-
 # ------------------------------------------------------- log rotation ----
 
 def test_rotate_keep_tail_preserves_complete_lines(tmp_path):
     """Satellite 2: the size cap keeps the newest half, aligned to a
     line boundary, and is a no-op under the cap."""
-    import bench  # repo root: the bench event log's rotation
+    from upow_tpu.watchtower import benchlog
 
     p = tmp_path / "grow.log"
     p.write_text("".join(f"line {i:06d} {'x' * 40}\n"
                          for i in range(4000)))
     before = p.stat().st_size
-    bench._rotate_keep_tail(str(p), max_bytes=before + 1)
+    benchlog._rotate_keep_tail(str(p), max_bytes=before + 1)
     assert p.stat().st_size == before   # under cap: untouched
 
-    bench._rotate_keep_tail(str(p), max_bytes=10_000)
+    benchlog._rotate_keep_tail(str(p), max_bytes=10_000)
     assert p.stat().st_size <= 5_000
     kept = p.read_text().splitlines()
     assert kept[0].startswith("line ")      # no partial first line
     assert kept[-1] == f"line 003999 {'x' * 40}"
 
 
-def test_bench_event_log_rotates(tmp_path, monkeypatch):
-    import bench
+def test_alert_event_log_rotates(tmp_path, monkeypatch):
+    from types import SimpleNamespace
+
+    from upow_tpu.watchtower import benchlog
 
     events = tmp_path / ".bench_events.jsonl"
-    monkeypatch.setattr(bench, "_BENCH_EVENTS", str(events))
-    monkeypatch.setattr(bench, "_BENCH_EVENTS_MAX", 4096)
+    monkeypatch.setattr(benchlog, "MAX_BYTES", 4096)
+    rule = SimpleNamespace(name="rotation_probe", severity="page")
     for i in range(200):
-        bench._record_bench_event("rotation_probe", n=i, pad="y" * 64)
-    assert events.stat().st_size <= 4096 + 200
-    tail = events.read_text().splitlines()
-    assert all(json.loads(ln)["kind"] == "rotation_probe" for ln in tail)
-    assert json.loads(tail[-1])["n"] == 199
+        benchlog.record(str(events), SimpleNamespace(
+            rule=rule, key="k" * 64, value=i, exemplars=[]))
+    assert events.stat().st_size <= 4096 + 400
+    tail = [json.loads(ln) for ln in events.read_text().splitlines()]
+    assert all(e["kind"] == "alert_fired"
+               and e["rule"] == "rotation_probe" for e in tail)
+    assert tail[-1]["value"] == 199

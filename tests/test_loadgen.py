@@ -1,6 +1,5 @@
-"""Perf-observatory tests: schedule determinism, SLO histogram
-exposition, the regression gate's exit codes, and debug-endpoint limit
-hardening.
+"""Load-generator tests: schedule determinism, SLO histogram
+exposition, and debug-endpoint limit hardening.
 
 The in-process-node integration lives in ``test_loadgen_node`` — the
 pure pieces here run without booting anything, so the determinism
@@ -9,13 +8,11 @@ latency function of the seed).
 """
 
 import asyncio
-import json
 
 import pytest
 
 from test_node import Cluster, easy_difficulty  # noqa: F401
 from upow_tpu import telemetry
-from upow_tpu.loadgen import gate
 from upow_tpu.loadgen.population import (PopulationSpec, build_schedule,
                                          schedule_fingerprint)
 from upow_tpu.loadgen.runner import MockBackend, run_mock, run_schedule
@@ -139,132 +136,6 @@ def test_mock_backend_feeds_slo_registry():
     asyncio.run(MockBackend(seed=7)(
         build_schedule(PopulationSpec.smoke())[0]))
     assert any(n.startswith("slo.http.") for n in metrics.histograms())
-
-
-# -------------------------------------------------- regression gate ----
-
-def _artifact(p95=10.0, req_s=100.0, kernel=5.0):
-    return {"kind": "perf_observatory",
-            "slo": {"endpoints": {"push_tx": {
-                "req_s": req_s, "p50_ms": p95 / 2, "p95_ms": p95,
-                "p99_ms": p95 * 1.2}}},
-            "kernels": {"search_python_loop":
-                        {"value": kernel, "unit": "MH/s"}}}
-
-
-def _write(tmp_path, name, doc):
-    path = tmp_path / name
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def test_gate_fails_on_latency_regression(tmp_path, capsys):
-    base = _write(tmp_path, "base.json", _artifact())
-    cur = _write(tmp_path, "cur.json", _artifact(p95=20.0))
-    assert gate.main(["--against", base, "--current", cur]) == 1
-    report = json.loads(capsys.readouterr().out)
-    regressed = {r["metric"] for r in report["verdicts"] if r["regressed"]}
-    assert "slo.push_tx.p95_ms" in regressed
-    assert "slo.push_tx.req_s" not in regressed  # unchanged metric clean
-
-
-def test_gate_fails_on_throughput_regression(tmp_path):
-    base = _write(tmp_path, "base.json", _artifact())
-    cur = _write(tmp_path, "cur.json", _artifact(kernel=1.0))
-    assert gate.main(["--against", base, "--current", cur]) == 1
-
-
-def test_gate_passes_within_tolerance_and_on_improvement(tmp_path):
-    base = _write(tmp_path, "base.json", _artifact())
-    # 10% slower: inside the default 25% band
-    cur = _write(tmp_path, "cur.json", _artifact(p95=11.0))
-    assert gate.main(["--against", base, "--current", cur]) == 0
-    # faster everywhere: improvements never fail
-    cur = _write(tmp_path, "cur.json",
-                 _artifact(p95=1.0, req_s=900.0, kernel=50.0))
-    assert gate.main(["--against", base, "--current", cur]) == 0
-
-
-def test_gate_report_only_and_tolerance_flags(tmp_path):
-    base = _write(tmp_path, "base.json", _artifact())
-    cur = _write(tmp_path, "cur.json", _artifact(p95=20.0))
-    assert gate.main(["--against", base, "--current", cur,
-                      "--report-only"]) == 0
-    assert gate.main(["--against", base, "--current", cur,
-                      "--tolerance", "2.0"]) == 0
-
-
-def test_gate_enforce_overrides_report_only(tmp_path, capsys):
-    """--enforce SUBSTR promotes matching metrics to hard-gating even
-    under --report-only (the make perf-smoke contract), and
-    --metric-tolerance NAME=TOL pins a per-metric band."""
-    base = _write(tmp_path, "base.json", _artifact())
-    cur = _write(tmp_path, "cur.json", _artifact(p95=20.0, kernel=1.0))
-    # report-only hides both regressions ...
-    assert gate.main(["--against", base, "--current", cur,
-                      "--report-only"]) == 0
-    capsys.readouterr()
-    # ... but an enforced substring match fails the gate
-    assert gate.main(["--against", base, "--current", cur,
-                      "--report-only",
-                      "--enforce", "kernel.search_"]) == 1
-    report = json.loads(capsys.readouterr().out)
-    assert report["enforced_regressions"] == 1
-    # a wide per-metric band rescues ONLY the named metric
-    assert gate.main(["--against", base, "--current", cur,
-                      "--report-only", "--enforce", "kernel.search_",
-                      "--metric-tolerance",
-                      "kernel.search_python_loop=0.9"]) == 0
-    capsys.readouterr()
-    # the per-metric band also TIGHTENS: in-band globally, enforced out
-    cur2 = _write(tmp_path, "cur2.json", _artifact(kernel=4.5))
-    assert gate.main(["--against", base, "--current", cur2,
-                      "--report-only", "--enforce", "kernel.search_",
-                      "--metric-tolerance",
-                      "kernel.search_python_loop=0.05"]) == 1
-    capsys.readouterr()
-    # malformed specs are usage errors, not silent no-ops
-    assert gate.main(["--against", base, "--current", cur,
-                      "--metric-tolerance", "oops"]) == 2
-
-
-def test_gate_flattens_bench_wrapper(tmp_path):
-    """The driver's BENCH_r*.json capture shape gates transparently."""
-    wrapper = {"n": 5, "cmd": "python bench.py", "rc": 0, "tail": "...",
-               "parsed": {"metric": "sha256_pow_search_native_cpu",
-                          "value": 16.5, "unit": "MH/s",
-                          "verify": {"metric": "verify_batch_native_cpu",
-                                     "value": 3531.0}}}
-    flat = gate.load_metrics(_write(tmp_path, "bench.json", wrapper))
-    assert flat == {"sha256_pow_search_native_cpu": 16.5,
-                    "verify_batch_native_cpu": 3531.0}
-    regressed = dict(wrapper, parsed=dict(wrapper["parsed"], value=1.0))
-    base = _write(tmp_path, "b.json", wrapper)
-    cur = _write(tmp_path, "c.json", regressed)
-    assert gate.main(["--against", base, "--current", cur]) == 1
-
-
-def test_gate_jsonl_stream(tmp_path):
-    """bench_suite's JSON-lines output parses line by line."""
-    path = tmp_path / "suite.jsonl"
-    path.write_text(
-        'noise line\n'
-        '{"metric": "a_rate", "value": 10, "unit": "x"}\n'
-        '{"metric": "b_ms", "value": 5, "unit": "ms"}\n')
-    assert gate.load_metrics(str(path)) == {"a_rate": 10.0, "b_ms": 5.0}
-
-
-def test_gate_missing_artifact_is_usage_error(tmp_path):
-    base = _write(tmp_path, "base.json", _artifact())
-    assert gate.main(["--against", str(tmp_path / "nope.json"),
-                      "--current", base]) == 2
-
-
-def test_gate_direction_inference():
-    assert gate.lower_is_better("slo.push_tx.p95_ms")
-    assert gate.lower_is_better("intake_latency_seconds")
-    assert not gate.lower_is_better("sha256_pow_search_native_cpu")
-    assert not gate.lower_is_better("kernel.verify_python")
 
 
 # ------------------------------------------- debug-endpoint limits ----

@@ -1,13 +1,12 @@
-"""Perf-observatory integration: the load generator against a real
-in-process node, the merged artifact, and the /debug/profile endpoint.
+"""Load-generator integration: the load generator against a real
+in-process node, the readpath scenario, and the /debug/profile endpoint.
 
 Kept separate from test_loadgen.py because these boot nodes and build
 funded chain fixtures (seconds, not milliseconds); the pure-logic
-determinism and gate tests shouldn't pay for that.
+determinism tests shouldn't pay for that.
 """
 
 import asyncio
-import json
 
 import pytest
 
@@ -108,58 +107,6 @@ def test_readpath_differential_and_refusal(monkeypatch):
     assert "bypass" not in poisoned and "cached" not in poisoned
     stage0 = poisoned["differential"]["stages"][0]
     assert stage0["mismatches"]  # the evidence rides in the artifact
-
-
-def test_observatory_artifact_and_gate(tmp_path):
-    """Acceptance path: one run_observatory() artifact carries SLO +
-    kernels + provenance, self-gates clean, and an injected synthetic
-    regression makes the gate exit non-zero."""
-    from upow_tpu.loadgen import gate
-    from upow_tpu.loadgen.observatory import (append_progress,
-                                              run_observatory,
-                                              write_artifact)
-
-    artifact = run_observatory(PopulationSpec.smoke(), bench_seconds=0.05,
-                               readpath_spec=_tiny_readpath())
-    assert artifact["kind"] == "perf_observatory"
-    assert artifact["provenance"]["backend"] == "node-inprocess"
-    assert "arm_failure_reason" in artifact["provenance"]
-    assert artifact["kernels"]["search_python_loop"]["value"] > 0
-    assert artifact["slo"]["endpoints"]["push_tx"]["req_s"] > 0
-
-    # readpath rode along: differential green, headline mirrored into
-    # kernels with explicit gate directions
-    assert artifact["readpath"]["differential"]["ok"]
-    speedup = artifact["kernels"]["readpath_speedup_p99"]
-    assert speedup["direction"] == "higher" and speedup["value"] > 0
-    assert speedup["differential_ok"] is True
-    assert artifact["kernels"]["readpath_cached_p99_ms"]["direction"] \
-        == "lower"
-    assert 0 < artifact["kernels"]["readpath_hit_ratio"]["value"] <= 1
-
-    out = tmp_path / "observatory.json"
-    write_artifact(artifact, str(out))
-    on_disk = json.loads(out.read_text())
-    assert on_disk["schedule_fingerprint"] == \
-        artifact["schedule_fingerprint"]
-
-    progress = tmp_path / "PROGRESS.jsonl"
-    append_progress(artifact, str(progress))
-    line = json.loads(progress.read_text().splitlines()[-1])
-    assert line["kind"] == "perf_observatory"
-    assert line["slo"]["push_tx"]["p95_ms"] > 0
-    assert line["kernels"]["search_python_loop"] > 0
-
-    # identical artifact: clean pass
-    assert gate.main(["--against", str(out), "--current", str(out)]) == 0
-
-    # injected synthetic regression: non-zero exit
-    worse = json.loads(out.read_text())
-    worse["slo"]["endpoints"]["push_tx"]["p95_ms"] *= 10
-    worse_path = tmp_path / "worse.json"
-    worse_path.write_text(json.dumps(worse))
-    assert gate.main(["--against", str(out),
-                      "--current", str(worse_path)]) == 1
 
 
 def test_node_metrics_exports_slo_series(tmp_path):

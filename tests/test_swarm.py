@@ -273,6 +273,33 @@ def test_wallets_are_seed_deterministic():
     assert _wallet(7, "x") != _wallet(7, "y")
 
 
+# ------------------------------------------------------ CLI exit code ----
+
+@pytest.mark.parametrize("core_ok_", [True, False])
+def test_swarm_cli_exit_code_and_artifact(monkeypatch, tmp_path, core_ok_):
+    """``make swarm``: exit 1 when any core boolean came back false; the
+    ``--out`` artifact is written whole (tmp + fsync + rename, the
+    snapshot layout's writer) and nothing is left beside it."""
+    import json
+
+    from upow_tpu.swarm import __main__ as cli
+
+    canned = {"kind": "swarm_scenario", "scenario": "partition_heal",
+              "nodes": 3, "seed": 5, "fingerprint": "ab" * 32,
+              "core": {"converged_after_heal": core_ok_, "height": 9},
+              "observed": {"elapsed_s": 0.25},
+              "slo": {"endpoints": {"swarm.partition_heal.node0":
+                                    {"p50_ms": 1.5}}}}
+    monkeypatch.setattr(cli, "run_scenario",
+                        lambda name, nodes, seed: dict(canned, seed=seed))
+    out = tmp_path / "swarm.json"
+    rc = cli.main(["--scenario", "partition_heal", "--seed", "5",
+                   "--out", str(out)])
+    assert rc == (0 if core_ok_ else 1)
+    assert json.loads(out.read_text()) == canned
+    assert [f.name for f in tmp_path.iterdir()] == ["swarm.json"]
+
+
 # --------------------------------------------------------------- slow ----
 
 @pytest.mark.slow

@@ -11,8 +11,8 @@ the snapshot witness closure proves nothing below
   first, hot-delete second, resumable journal)
 * :mod:`.reader`  — transparent read fallthrough for both storage
   backends + peer archive fetch
-* :mod:`.parity`  — the pruned-vs-twin differential feeding the
-  ``archive_parity_ok`` observatory kernel
+* :mod:`.parity`  — the pruned-vs-twin differential
+  (``make archive-smoke``)
 """
 
 from .reader import ArchiveReader  # noqa: F401

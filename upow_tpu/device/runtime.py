@@ -4,10 +4,10 @@ ROADMAP item 3, the kernel-server refactor.  Every device dispatch in
 the package flows through this module's single drainer thread:
 
 * **One arm.**  The runtime owns backend arming — one probe per
-  process (thread-boxed: a backend init that hangs must not wedge the
-  process), under a deadline, with the structured
-  ``arm_failure_reason`` captured and the persistent compile cache
-  enabled (compile_cache.enable — the one place any process turns it
+  process (:func:`probe_platform`, thread-boxed: a backend init that
+  hangs must not wedge the process), under a deadline, with the
+  structured ``arm_failure_reason`` captured and the persistent compile
+  cache enabled (compile_cache.enable — the one place any process turns it
   on).  Nothing is compiled at arm: the first dispatch of each program
   compiles it, under the caller's compile allowance.  What a failed
   probe means is decided by config ``device.device`` (:func:`start`):
@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import hashlib
 import os
 import sys
 import threading
@@ -73,13 +74,10 @@ def boxed_call(fn: Callable[[], Any], timeout: float):
     """Run ``fn`` on a daemon thread with a deadline.
 
     Returns ("ok", result) | ("err", exception) | ("timeout", None).
-    The one home of the hang-survival idiom (moved here from benchutil,
-    which now delegates): a call stuck inside the PJRT client can
-    neither be interrupted nor joined — the daemon thread is abandoned
-    and the caller decides what degraded mode means.
+    The one home of the hang-survival idiom: a call stuck inside the
+    PJRT client can neither be interrupted nor joined — the daemon
+    thread is abandoned and the caller decides what degraded mode means.
     """
-    import contextvars
-
     _sanitizer_check("boxed_call")
 
     box: dict = {}
@@ -102,6 +100,61 @@ def boxed_call(fn: Callable[[], Any], timeout: float):
     if "err" in box:
         return "err", box["err"]
     return "timeout", None
+
+
+def text_fingerprint(text: str) -> str:
+    """Short stable hash of diagnostic text (stderr tails, frame lists)
+    so repeated arm failures can be grouped without comparing full
+    tracebacks."""
+    return hashlib.sha256(text.encode("utf-8", "replace")).hexdigest()[:12]
+
+
+def traceback_fingerprint(exc: BaseException) -> str:
+    """Fingerprint of an exception's traceback SHAPE (file:function per
+    frame, no line numbers or message text): two arm attempts that died
+    on the same code path share a fingerprint even when addresses or
+    timeouts in the message differ."""
+    import traceback as _tb
+
+    frames = _tb.extract_tb(exc.__traceback__) if exc.__traceback__ else []
+    sig = "|".join("%s:%s" % (f.filename.rsplit("/", 1)[-1], f.name)
+                   for f in frames[-8:])
+    return text_fingerprint("%s|%s" % (type(exc).__name__, sig))
+
+
+def probe_platform(timeout: float) -> dict:
+    """The backend probe: ``jax.devices()[0].platform`` under
+    :func:`boxed_call`, because backend init can HANG inside
+    ``jax.devices()`` (exceptions are the easy case).  Returns
+    ``{status, platform, seconds, error, traceback_fingerprint}``:
+    ``status`` is the boxed_call outcome ("ok" / "err" / "timeout"),
+    ``platform`` is None unless ok, and ``error`` is the actual
+    exception text.  Only :meth:`DeviceRuntime.arm` calls it, once a
+    process; everything else asks :meth:`DeviceRuntime.platform`."""
+    import jax
+
+    t0 = time.perf_counter()
+    status, value = boxed_call(lambda: jax.devices()[0].platform, timeout)
+    record = {"status": status, "platform": None,
+              "seconds": round(time.perf_counter() - t0, 3),
+              "error": None, "traceback_fingerprint": None}
+    if status == "ok":
+        record["platform"] = value
+    elif status == "timeout":
+        record["error"] = ("backend init still inside jax.devices() after "
+                           "%.0fs (native hang; no Python exception to "
+                           "show)" % timeout)
+    else:  # "err": value IS the exception boxed_call caught
+        record["error"] = repr(value)
+        if isinstance(value, BaseException):
+            record["traceback_fingerprint"] = traceback_fingerprint(value)
+    return record
+
+
+#: the process's one probe record, whichever DeviceRuntime arms first
+#: (tests build several): a hung backend costs a process ONE timeout
+_PROBE: Optional[dict] = None
+_PROBE_LOCK = threading.Lock()
 
 
 _WAITS_CAP = 8192  # per-source queue-wait samples kept for stats()
@@ -192,27 +245,29 @@ class DeviceRuntime:
             info = self._arm_info
             if info["armed"]:
                 return dict(info)
-            from .. import benchutil, compile_cache
+            from .. import compile_cache
 
             # before the backend exists, so every program this process
             # compiles goes through the one persistent cache
             info["compile_cache_dir"] = compile_cache.enable()
             timeout = self.cfg.arm_timeout if deadline is None else deadline
             t0 = time.perf_counter()
-            platform = benchutil.probed_platform_cached(timeout)
+            global _PROBE
+            with _PROBE_LOCK:
+                if _PROBE is None:
+                    _PROBE = probe_platform(timeout)
+                probe = _PROBE
             elapsed = time.perf_counter() - t0
+            platform = probe["platform"]
             info.update(platform=platform, attempt=attempt,
                         probe_seconds=round(elapsed, 3), armed=True)
             if platform is None:
-                # carry the probe's ACTUAL failure text when the cached
-                # detail record has one (exception repr or explicit-hang
-                # note) instead of only the generic "hung/failed"
-                detail = benchutil._PROBE_CACHE.get("detail") or {}
-                info["arm_failure_reason"] = detail.get("error") or (
-                    "backend probe hung/failed within %.0fs" % timeout)
-                info["probe_status"] = detail.get("status", "no-platform")
+                # the probe's ACTUAL failure text (exception repr or
+                # explicit-hang note), not a generic "hung/failed"
+                info["arm_failure_reason"] = probe["error"]
+                info["probe_status"] = probe["status"]
                 info["traceback_fingerprint"] = \
-                    detail.get("traceback_fingerprint")
+                    probe["traceback_fingerprint"]
                 log.warning("device runtime armed WITHOUT a backend (%s)",
                             info["arm_failure_reason"])
             else:

@@ -15,8 +15,7 @@ stays meaningful at fleet scale only with a layer that merges it:
   deltas, in-flight traces) dumped into scenario artifacts on
   failure, fault injection, or SLO breach.
 * :mod:`.geosoak` — the seeded asymmetric-latency geo soak scenario
-  whose rows feed the committed observatory gate (imported lazily:
-  it pulls in the swarm scenario registry).
+  (imported lazily: it pulls in the swarm scenario registry).
 
 ``python -m upow_tpu.fleet`` is the CLI (docs/OBSERVABILITY.md).
 """

@@ -1,8 +1,7 @@
-"""CLI entry: run the geo-soak, print the fleet view, gate or merge.
+"""CLI entry: run the geo-soak and print the fleet view.
 
     python -m upow_tpu.fleet                          # geo-soak, print rows
     python -m upow_tpu.fleet --check-determinism      # two runs, compare fp
-    python -m upow_tpu.fleet --merge-observatory observatory.json
     python -m upow_tpu.fleet --out fleet.json --trace
 
 Exit status is non-zero when a core assertion failed, the stitched
@@ -17,8 +16,7 @@ import argparse
 import json
 import sys
 
-from .geosoak import (GEO_NODES, GEO_SEED, fleet_rows, merge_into_observatory,
-                      run_geo_artifact)
+from .geosoak import GEO_NODES, GEO_SEED, fleet_rows, run_geo_artifact
 
 
 def _core_ok(core: dict) -> bool:
@@ -82,26 +80,7 @@ def main(argv=None) -> int:
     parser.add_argument("--check-determinism", action="store_true",
                         help="run twice with the same seed and fail "
                              "unless the core fingerprints are identical")
-    parser.add_argument("--merge-observatory", metavar="PATH",
-                        help="merge the fleet kernel/SLO rows into an "
-                             "existing observatory artifact (the "
-                             "perf-smoke baseline)")
-    parser.add_argument("--gate-against", metavar="PATH",
-                        help="after the run, gate the fleet rows "
-                             "against this observatory baseline "
-                             "(fleet_core_ok enforced, propagation "
-                             "quantiles report-only)")
     args = parser.parse_args(argv)
-
-    if args.merge_observatory:
-        merged = merge_into_observatory(args.merge_observatory,
-                                        nodes=args.nodes, seed=args.seed)
-        fleet = merged["section"]
-        good = bool(fleet["core_ok"])
-        print(f"{'ok  ' if good else 'FAIL'} merged fleet rows into "
-              f"{args.merge_observatory} "
-              f"(fp={fleet['fingerprint'][:16]})")
-        return 0 if good else 1
 
     artifact = run_geo_artifact(nodes=args.nodes, seed=args.seed)
     ok = _print_run(artifact)
@@ -128,9 +107,9 @@ def main(argv=None) -> int:
                   "reproduced")
 
     if args.out:
-        from ..loadgen.observatory import write_artifact
+        from ..snapshot.layout import write_manifest
 
-        write_artifact(artifact, args.out)
+        write_manifest(args.out, artifact)
 
     rows = fleet_rows(artifact)
     print(json.dumps({"kind": "fleet_observatory",
@@ -139,35 +118,6 @@ def main(argv=None) -> int:
                                   for k, v in rows["kernels"].items()}},
                      sort_keys=True))
 
-    if args.gate_against:
-        import os
-        import tempfile
-
-        from ..loadgen import gate
-
-        # shape the fleet rows like an observatory artifact so
-        # gate.flatten compares them against the committed baseline
-        current = {"kernels": rows["kernels"],
-                   "slo": {"endpoints": rows["slo_endpoints"]}}
-        fd, tmp = tempfile.mkstemp(prefix="fleet-gate-", suffix=".json")
-        try:
-            with os.fdopen(fd, "w") as f:
-                json.dump(current, f)
-            rc = gate.main([
-                "--against", args.gate_against, "--current", tmp,
-                "--report-only",
-                "--enforce", "kernel.fleet_core_ok",
-                # wall-clock quantiles on shared CI hosts are noisy;
-                # the correctness trip is fleet_core_ok's zeroing,
-                # which defeats any tolerance
-                "--metric-tolerance", "kernel.fleet_block_prop_p50_ms=3.0",
-                "--metric-tolerance", "kernel.fleet_block_prop_p95_ms=3.0",
-                "--metric-tolerance", "kernel.fleet_tx_prop_p50_ms=3.0",
-                "--metric-tolerance", "kernel.fleet_tx_prop_p95_ms=3.0",
-            ])
-        finally:
-            os.unlink(tmp)
-        ok = ok and rc == 0
     return 0 if ok else 1
 
 

@@ -10,11 +10,10 @@ a traced push_tx crossing the fleet (stitched into one fleet trace).
 The deterministic core carries only seed-functions: continent map,
 convergence/coverage booleans, final height/tip.  All timing — the
 propagation quantiles, per-node SLO rows, the stitched trace — goes to
-``observed``/``slo``, from where :func:`observatory_section` folds it
-into the committed ``observatory.json`` with explicit gate directions
-(``fleet_core_ok`` zeroes on any correctness break, so the ENFORCED
-perf gate also trips on broken distribution semantics, not just on
-slow propagation).
+``observed``/``slo``, from where :func:`fleet_rows` shapes it into
+direction-annotated rows (``fleet_core_ok`` is 0.0 on any correctness
+break; ``python -m upow_tpu.fleet`` exits non-zero on the same core
+booleans).
 
 Import discipline: swarm/scenarios.py registers this scenario at the
 bottom of its module, so imports from scenarios here are deferred to
@@ -28,13 +27,9 @@ import math
 from typing import Dict, List, Optional
 
 from .. import telemetry
-from ..logger import get_logger
 from . import propagation, scrape, stitch
 
-log = get_logger("fleet")
-
-#: canonical fleet shape used by `make fleet`, CI and the observatory —
-#: keep smoke and full identical so gate rows stay comparable.
+#: canonical fleet shape used by `make fleet` and CI
 GEO_NODES = 6
 GEO_SEED = 7
 
@@ -242,7 +237,7 @@ async def scenario_geo_soak(swarm, seed: int):
     return core, observed
 
 
-# ------------------------------------------------- observatory bridge ----
+# ---------------------------------------------------------- fleet rows ----
 
 def _num(v: float) -> float:
     return 0.0 if (v is None or (isinstance(v, float) and math.isnan(v))) \
@@ -255,15 +250,14 @@ def run_geo_artifact(nodes: int = GEO_NODES, seed: int = GEO_SEED) -> dict:
 
 
 def fleet_rows(art: dict) -> dict:
-    """Gate-facing rows from a geo-soak artifact.
+    """Summary rows from a geo-soak artifact.
 
-    * ``kernels`` — direction-annotated entries in the observatory
-      kernel table shape.  ``fleet_core_ok`` is the correctness trip:
-      any failed core boolean zeroes it, and a zero against a baseline
-      of 1.0 fails the ENFORCED gate regardless of tolerance (the
-      divergence-zeroing idiom the other enforced kernels use).
+    * ``kernels`` — direction-annotated ``{value, unit, direction,
+      desc}`` entries.  ``fleet_core_ok`` is the correctness trip: any
+      failed core boolean zeroes it; ``watchtower_clean_ok`` likewise
+      for the three watchtower booleans.
     * ``slo_endpoints`` — per-node latency rows plus the propagation
-      quantile rows, all in gate.flatten's endpoint shape.
+      quantile rows (``{p50_ms, p95_ms, p99_ms}`` each).
     """
     from ..swarm.scenarios import core_ok
 
@@ -306,51 +300,3 @@ def fleet_rows(art: dict) -> dict:
     slo_endpoints.update(
         propagation.gate_rows(prop, prefix="fleet.geo_soak"))
     return {"kernels": kernels, "slo_endpoints": slo_endpoints}
-
-
-def observatory_section(nodes: int = GEO_NODES,
-                        seed: int = GEO_SEED) -> dict:
-    """Run the geo soak and shape it for the observatory artifact."""
-    art = run_geo_artifact(nodes=nodes, seed=seed)
-    rows = fleet_rows(art)
-    prop = art["observed"]["propagation"]
-    stitched = art["observed"].get("stitched_push_tx") or {}
-    section = {
-        "scenario": "geo_soak",
-        "nodes": nodes,
-        "seed": seed,
-        "fingerprint": art["fingerprint"],
-        "core_ok": rows["kernels"]["fleet_core_ok"]["value"] == 1.0,
-        "propagation": {
-            kind: {k: prop[kind][k] for k in
-                   ("hashes", "covered", "p50_ms", "p95_ms", "p99_ms")}
-            for kind in ("blocks", "txs")},
-        "stitched_push_tx_nodes": stitched.get("node_count", 0),
-        "watchtower": art["observed"].get("watchtower", {}),
-        "flight_recorder": art.get("flight_recorder", {}).get("reason"),
-    }
-    return {"section": section, "kernels": rows["kernels"],
-            "slo_endpoints": rows["slo_endpoints"], "artifact": art}
-
-
-def merge_into_observatory(path: str, nodes: int = GEO_NODES,
-                           seed: int = GEO_SEED) -> dict:
-    """Surgically merge fresh fleet rows into a committed observatory
-    artifact (leaves every CI-measured kernel untouched)."""
-    import json
-    import os
-
-    with open(path, "r", encoding="utf-8") as fh:
-        doc = json.load(fh)
-    out = observatory_section(nodes=nodes, seed=seed)
-    doc.setdefault("kernels", {}).update(out["kernels"])
-    doc.setdefault("slo", {}).setdefault("endpoints", {}).update(
-        out["slo_endpoints"])
-    doc["fleet"] = out["section"]
-    tmp = path + ".tmp"
-    with open(tmp, "w", encoding="utf-8") as fh:
-        json.dump(doc, fh, indent=2, sort_keys=True, default=str)
-        fh.write("\n")
-    os.replace(tmp, path)
-    log.info("merged fleet rows into %s", path)
-    return out

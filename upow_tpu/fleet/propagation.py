@@ -118,8 +118,8 @@ def report(events_by_node: Dict[str, List[dict]],
 
 
 def gate_rows(prop: dict, prefix: str = "fleet") -> Dict[str, dict]:
-    """Propagation quantiles in the gate's slo-endpoint row shape
-    (loadgen/gate.py flatten: slo.{name}.{p50_ms,p95_ms,p99_ms})."""
+    """Propagation quantiles in the slo-endpoint row shape
+    (``{name: {p50_ms, p95_ms, p99_ms}}``)."""
     rows: Dict[str, dict] = {}
     for name, rep in (("block_prop", prop["blocks"]),
                       ("tx_prop", prop["txs"])):

@@ -7,17 +7,18 @@ subscriber churn — and records per-endpoint req/s plus p50/p95/p99
 latency, both client-side (exact quantiles in the run summary) and
 server-side (``slo.http.*`` histograms on ``/metrics``).
 
-Layout (import-light on purpose: :mod:`.gate` must run with stdlib
-only, and ``python -m upow_tpu.loadgen.gate`` imports this package):
+Layout (this package import is light: only :mod:`.population`):
 
 * :mod:`.population` — seeded schedule builder (stdlib only).
 * :mod:`.runner`     — schedule execution + summary (stdlib + asyncio);
   includes the deterministic mock backend the tests pin.
 * :mod:`.harness`    — the real in-process node target (aiohttp).
-* :mod:`.observatory` — merged SLO + kernel-bench artifact with
-  capture provenance; ``python -m upow_tpu.loadgen`` entry point.
-* :mod:`.gate`       — stdlib regression checker
-  (``python -m upow_tpu.loadgen.gate --against BENCH_r05.json``).
+* :mod:`.readpath`, :mod:`.coresidency` — the hot-state cache and the
+  shared device-runtime scenarios, each with its byte differential.
+* :mod:`.fixtures`   — seeded sig-check tuples and the funded chain.
+
+Rates on the chip are the benchmark's (``benchmarks/run.py``,
+``BENCHMARK.json``); nothing here gates on a CPU rate.
 """
 
 from .population import LoadEvent, PopulationSpec, build_schedule  # noqa: F401

@@ -1,5 +1,6 @@
 """Co-residency scenario: miner + block verify + mempool intake on ONE
-device runtime (ISSUE 10 acceptance, bench_suite config 14).
+device runtime (ISSUE 10 acceptance; run by
+tests/test_device_runtime.py at smoke size).
 
 Three subsystem clients hammer a fresh :class:`DeviceRuntime`
 concurrently — a saturating miner stream (``source='mine'``, weight 1),
@@ -13,9 +14,8 @@ The differential is built in and decides whether performance numbers
 are reported at all: every concurrent verdict slice must be
 byte-identical to the serial single-sig host reference AND to a serial
 one-dispatch-per-batch pass over the same deterministic batches.  A
-divergence zeroes ``coalesce_ratio`` (the headline the gate watches,
-direction=higher) and omits the latency/dispatch sections — the same
-refuse-to-report convention as readpath/verify_pipeline.
+divergence zeroes ``coalesce_ratio`` and omits the latency/dispatch
+sections — the same refuse-to-report convention as readpath.
 
 Reported deltas (ISSUE wording: "measurably fewer dispatches, no
 verify starvation"):
@@ -79,7 +79,7 @@ def _host_reference(checks) -> List[bool]:
 
 def _build_batches(spec: CoresidencySpec):
     """Deterministic (source, checks) work lists for both passes."""
-    from ..benchutil import pipeline_verify_fixture
+    from .fixtures import pipeline_verify_fixture
 
     total = (spec.verify_batches * spec.verify_batch
              + spec.intake_batches * spec.intake_batch)

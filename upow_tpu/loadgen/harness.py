@@ -1,11 +1,10 @@
 """In-process node target for the load generator.
 
 Boots a real :class:`~upow_tpu.node.app.Node` over an in-memory chain
-pre-funded through :func:`~upow_tpu.benchutil.chain_with_utxo_fanout`
+pre-funded through :func:`~upow_tpu.loadgen.fixtures.chain_with_utxo_fanout`
 (so push_tx bursts carry *valid, accepted* spends through the
 coalescing intake, not just parse errors) and serves it via aiohttp's
-TestServer — the same harness idiom as bench_suite configs 8/10 and
-the telemetry selfcheck.
+TestServer — the same harness idiom as the telemetry selfcheck.
 
 The executor translates abstract schedule events into wire requests:
 
@@ -142,7 +141,7 @@ async def run_against_node(spec: PopulationSpec) -> dict:
     node's own slo/ws/mempool counters)."""
     from aiohttp.test_utils import TestClient, TestServer
 
-    from ..benchutil import chain_with_utxo_fanout, leaf_spends
+    from .fixtures import chain_with_utxo_fanout, leaf_spends
     from ..config import Config
     from ..core import clock
     from ..node.app import Node

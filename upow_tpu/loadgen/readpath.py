@@ -14,8 +14,7 @@ re-accept) each sampled endpoint is fetched twice through the cache
 and once bypassed, and all three bodies must be byte-identical.  Any
 mismatch means the cache returned something state would not have — the
 scenario then refuses to report performance: latency sections are
-omitted and ``speedup_p99`` is zeroed, the same divergence-trips-the-
-gate convention as ``verify_pipeline_speedup``.
+omitted and ``speedup_p99`` is zeroed.
 """
 
 from __future__ import annotations
@@ -56,8 +55,7 @@ class ReadpathSpec:
 
     @classmethod
     def smoke(cls) -> "ReadpathSpec":
-        # same per-request weight as the default (so the smoke artifact
-        # gates cleanly against a full-run baseline); just fewer of them
+        # same per-request weight as the default; just fewer of them
         return cls(n_wallets=6, n_requests=1200, block_every=600)
 
     def to_dict(self) -> dict:
@@ -171,7 +169,7 @@ async def run_readpath(spec: ReadpathSpec = None) -> dict:
     """Run differential + both passes; return the scenario artifact."""
     from aiohttp.test_utils import TestClient, TestServer
 
-    from ..benchutil import chain_with_utxo_fanout
+    from .fixtures import chain_with_utxo_fanout
     from ..config import Config
     from ..core import clock, curve, point_to_string
     from ..node.app import Node

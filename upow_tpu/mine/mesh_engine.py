@@ -64,7 +64,7 @@ def _arm_attempt(name: str, ok: bool, seconds: float,
                  error: Optional[BaseException] = None,
                  detail: Optional[str] = None) -> dict:
     """The arm attempt's record, with the real failure text captured."""
-    from ..benchutil import traceback_fingerprint
+    from ..device.runtime import traceback_fingerprint
 
     rec = {"attempt": name, "ok": bool(ok), "seconds": round(seconds, 3)}
     if error is not None:

@@ -72,7 +72,9 @@ class PeerBook:
     def _load(self) -> None:
         if self.path and os.path.exists(self.path):
             try:
-                with open(self.path) as f:
+                # RC001: a few KB read once, while the node is built and
+                # before it serves traffic
+                with open(self.path) as f:  # upowlint: disable=RC001
                     self._data = json.load(f).get("nodes", {})
             except (json.JSONDecodeError, OSError):
                 self._data = {}

@@ -15,8 +15,9 @@ generation name followed by one ``os.replace`` of the CURRENT pointer
 file — readers either see the previous complete generation or the new
 one, never a half-written mix.  Housekeeping (generation pruning,
 stale staging sweep) follows the half-tail rotation stance of the
-bench event log (bench.py): best-effort, OSError swallowed, never raises into the
-caller — a full disk must degrade snapshot serving, not block accept.
+alert event log (watchtower/benchlog.py): best-effort, OSError
+swallowed, never raises into the caller — a full disk must degrade
+snapshot serving, not block accept.
 """
 
 from __future__ import annotations

@@ -302,9 +302,9 @@ class PgChainState(StateViews):
                 (r["tx_hash"], r["index"]) for r in rows)
 
     def _device_index_usable(self) -> bool:
-        from ..benchutil import probed_platform_cached
+        from ..device.runtime import get_runtime
 
-        if probed_platform_cached(timeout=90.0) is None:
+        if get_runtime().platform() is None:
             import logging
 
             logging.getLogger("upow_tpu.state").warning(

@@ -271,9 +271,9 @@ class ChainState(StateViews):
         No-op (with a warning) when the jax backend cannot initialize —
         an unreachable device can HANG backend init, and a node must boot and
         validate on the sqlite path rather than wedge here."""
-        from ..benchutil import probed_platform_cached
+        from ..device.runtime import get_runtime
 
-        if probed_platform_cached(timeout=90.0) is None:
+        if get_runtime().platform() is None:
             import logging
 
             logging.getLogger("upow_tpu.state").warning(

@@ -47,9 +47,9 @@ def main(argv=None) -> int:
         runs = artifact["runs"]
 
     if args.out:
-        from ..loadgen.observatory import write_artifact
+        from ..snapshot.layout import write_manifest
 
-        write_artifact(artifact, args.out)
+        write_manifest(args.out, artifact)
 
     ok = True
     for run in runs:

@@ -18,9 +18,8 @@ The artifact splits in two:
   counters, retry/round counts, wall-clock.  Diagnostics, not
   contract.
 
-``slo.endpoints`` carries per-node client-side latency quantiles in the
-exact shape the observatory gate's ``flatten()`` consumes, so swarm
-artifacts merge into the perf pipeline unchanged.
+``slo.endpoints`` carries per-node client-side latency quantiles
+(``{p50_ms, p95_ms, p99_ms}`` rows, the loadgen runner's shape).
 
 See docs/SWARM.md for the catalog and determinism contract.
 """

@@ -103,15 +103,15 @@ def _device_usable() -> bool:
 
     Backend init can hang rather than raise when the accelerator is
     unreachable.  A validating node must never wedge block accept on
-    that, so backend detection goes through the process-wide
-    thread-boxed probe (benchutil), and a hang poisons the device path
-    for the life of the process (the stuck thread cannot be
+    that, so backend detection asks the device runtime, whose arm ran
+    the process's one thread-boxed probe, and a hang poisons the device
+    path for the life of the process (the stuck thread cannot be
     recovered)."""
     if DEGRADE.state == _POISONED:
         return False
-    from ..benchutil import probed_platform_cached
+    from ..device.runtime import get_runtime
 
-    platform = probed_platform_cached(timeout=90.0)  # probe timeout, not consensus  # upowlint: disable=CP001
+    platform = get_runtime().platform()
     if platform is None:
         DEGRADE.poison("jax backend init hung/failed")
         import logging

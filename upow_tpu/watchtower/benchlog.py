@@ -1,10 +1,7 @@
-"""Bench-harness alert sink: ``alert_fired`` lines in .bench_events.jsonl.
-
-When a bench-driven node fires an alert, the incident belongs next to
-the bench's own event stream (``bench_arm_failed``, ``bench_step_killed``
-— bench.py's format) so the trajectory tooling sees the
-regression and its exemplar trace in one place.  Same record shape and
-the same size-capped keep-newest-half rotation as the harnesses.
+"""Alert sink: ``alert_fired`` lines in an append-only JSONL event log
+(config ``watchtower.bench_events``), so a harness that drives a
+node finds the incident and its exemplar trace in one place.  The log
+is size-capped with a keep-newest-half rotation.
 
 The engine calls :func:`record` from its evaluation task; the write is
 a tiny O(100 B) append on an alert *transition* — rare by construction
@@ -22,7 +19,7 @@ from ..logger import get_logger
 
 log = get_logger("watchtower")
 
-MAX_BYTES = 1 << 20   # matches bench.py _BENCH_EVENTS_MAX
+MAX_BYTES = 1 << 20
 
 
 def _rotate_keep_tail(path: str, max_bytes: int) -> None:
