@@ -8,10 +8,13 @@ opens when the stub serves the first job at ``difficulty`` and lasts
 (or, one run in some hundreds, a block), fetches the next job and goes
 on.  After the window the stub serves one job at each of
 ``after_difficulties``, mined to a hit like the warm ones; then the
-child is stopped and the reference searches the round of every such
-block for a lower nonce.  A traced run also holds the
-rounds the miner's lines claim against the search program's events on
-the device; where the traffic has ``traced_window_s``, its window is
+child is asked for the chip's peak memory, which it answers from a
+thread of its own (``launch/miner_child.py``), and only then stopped: a
+run on the chip whose child gave no number fails in words
+(``_stop_child``) and prints no result.  The reference then searches
+the round of every such block for a lower nonce.  A traced run also
+holds the rounds the miner's lines claim against the search program's
+events on the device; where the traffic has ``traced_window_s``, its window is
 that long at most (the profiler's stop costs a time an event, so a
 round: a fast miner's whole window cannot be stopped in
 ``STOP_TRACE_WAIT_S``), and the end-to-end metrics, which come from
@@ -39,8 +42,12 @@ from harness.stub_node import StubNode
 #: tested every nonce of its range" broken in the child
 #: (``launch/faults.py``): one round in sixteen is claimed and never sent
 #: to the device.  Only a traced run can see it.
+#: ``mute_memory``: not a guarantee of the configuration but one of the
+#: benchmark: the launcher never says its ``memory:`` line, and the run
+#: has to end ``FAILED:`` with no result line at all.
 CONTROLS = {"tighten_target": {"tighten_check": 2},
-            "skip_rounds": {"child_fault": "skip_rounds"}}
+            "skip_rounds": {"child_fault": "skip_rounds"},
+            "mute_memory": {"child_fault": "mute_memory"}}
 
 #: rounds by which the miner's lines and the device's events may differ
 #: over a traced window.  The engine keeps two rounds in flight, so the
@@ -53,6 +60,15 @@ TRACE_EDGE_ROUNDS = 4
 #: traffic's ``traced_window_s`` is then due to go down.  Not longer: a
 #: run has 360 s for set-up, window, stop and the reading of the trace.
 STOP_TRACE_WAIT_S = 120
+
+#: "say your peak memory": the launcher's ``MEMORY_SIGNAL``
+MEMORY_SIGNAL = signal.SIGRTMIN
+
+#: seconds the driver waits for the child's ``memory:`` line after the
+#: request (the reading takes milliseconds), and for its exit after
+#: SIGTERM before the session is killed
+MEMORY_WAIT_S = 30
+STOP_WAIT_S = 120
 
 
 def _phases(job_list: list) -> list:
@@ -171,6 +187,51 @@ def _stop_trace(ctx, miner, w0, w1) -> None:
                "before the miner gets any faster" if late else ""))
 
 
+def _stop_child(ctx, miner) -> tuple:
+    """The child asked for the chip's peak memory, then stopped, and one
+    ``[stop]`` line on how it went down.  Returns (exit code, events of
+    all its lines, how it went down).  The peak is the last ``memory``
+    event that holds a number: the answer to the request, or the line
+    the launcher says once more at exit.  Without one a run on the chip
+    has no result: BenchError, with the numbers that tell the cause."""
+    seen = len(miner.lines)
+    t_ask = time.time()
+    miner.signal(MEMORY_SIGNAL)
+    try:
+        t_line, _text = miner.wait_for(
+            lambda s: "memory: " in s, MEMORY_WAIT_S, "'memory:' line",
+            seen=seen)
+    except BenchError:
+        t_line = None
+    answer_s = (time.time() if t_line is None else t_line) - t_ask
+    rc = miner.stop(timeout=STOP_WAIT_S)
+    events = minerlog.parse(miner.lines)
+    said = [e for e in events if e["kind"] == "memory"]
+    stop = {
+        "memory_request_answered": int(t_line is not None),
+        "memory_answer_s": answer_s,    # or how long it went unanswered
+        "memory_lines": len(said),
+        "memory_peak_bytes": next(
+            (int(e["peak"]) for e in reversed(said)
+             if e["peak"] not in (None, "null")), None),
+        "child_rc": rc, "stop_s": miner.stop_s, "killed": int(miner.killed),
+        "exceptions_ignored": sum("Exception ignored" in text
+                                  for _t, text in miner.lines)}
+    numbers = " ".join(f"{k}={v:.4f}" if isinstance(v, float) else f"{k}={v}"
+                       for k, v in stop.items())
+    warning = "WARNING: the child had to be killed: " if miner.killed else ""
+    ctx.say(f"[stop] {warning}{numbers} (waits: {MEMORY_WAIT_S} s for the "
+            "answer, "
+            f"{STOP_WAIT_S} s from SIGTERM to exit)")
+    if stop["memory_peak_bytes"] is None and not ctx.rehearse:
+        raise BenchError(
+            "no reading of the chip's peak memory, so no result line: "
+            f"{numbers}; the child said of its memory: "
+            + str([e["reason"] or f"peak_bytes={e['peak']}" for e in said]
+                  or "nothing") + f"; its last lines: {miner.tail(6)}")
+    return rc, events, stop
+
+
 def _drive(ctx, stub, miner, trace_dir) -> dict:
     traffic, say, seconds = ctx.traffic, ctx.say, ctx.seconds
     if trace_dir and "traced_window_s" in traffic:
@@ -227,8 +288,7 @@ def _drive(ctx, stub, miner, trace_dir) -> dict:
             f"{stub.after_index} answered in {time.time() - t_after:.2f}s "
             "after the window closed (the sweep in hand, a new target, "
             "the search; no metric holds them)")
-    rc = miner.stop(timeout=120)
-    events = minerlog.parse(miner.lines)
+    rc, events, stop = _stop_child(ctx, miner)
     job_list = minerlog.jobs(events)
     # ---- what the window held ----
     nonces = minerlog.nonces_between(job_list, w0, w1)
@@ -258,7 +318,8 @@ def _drive(ctx, stub, miner, trace_dir) -> dict:
     checks = []
 
     def check(name, value, limit, ok, note=""):
-        checks.append(ok)
+        checks.append({"name": name, "value": value, "limit": limit,
+                       "ok": bool(ok)})
         say(f"[check] {name}: {value} (limit {limit}) "
             f"{'ok' if ok else 'FAILED'}{' - ' + note if note else ''}")
 
@@ -317,21 +378,20 @@ def _drive(ctx, stub, miner, trace_dir) -> dict:
                    and device["count"] >= ctx.cell["chips"])
     check("device_platform", f"{device['platform']} x{device['count']}",
           f"tpu x>={ctx.cell['chips']}", platform_ok)
-    memory = next((e for e in events if e["kind"] == "memory"), None)
-    peak = None if memory is None or memory["peak"] == "null" \
-        else int(memory["peak"])
     mesh = next((e["mesh"] for e in events if e["kind"] == "mesh"), None)
     if mesh:
         say(f"[mesh] {mesh}")
     return {
-        "correct": all(checks),
+        "correct": all(c["ok"] for c in checks),
+        "checks": checks,
         "attempted": len(in_window),
         "failed": failed,
         "values": {"search_mhs": nonces / seconds / 1e6,
                    "setup_s": setup_s},
         "device": {"platform": device["platform"],
                    "kind": device["device_kind"], "count": device["count"],
-                   "memory_peak_bytes": peak},
+                   "memory_peak_bytes": stop["memory_peak_bytes"]},
+        "stop": stop,
         "observed": {"events": events, "jobs": job_list, "window": (w0, w1),
                      "nonces": nonces, "trace_dir": trace_dir,
                      "round_nonces": batch, "phases": _phases(job_list),

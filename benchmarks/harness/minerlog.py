@@ -3,7 +3,9 @@
 The lines are ``mine/miner.py``'s own (``run``, ``progress``): one per
 job fetched, one per completed round, one when 2^32 nonces are spent
 (``template expired``) or a nonce is found.  The parent stamps each with
-its clock as it arrives; the child runs unbuffered.
+its clock as it arrives; the child runs unbuffered.  A ``memory`` event
+has ``peak`` (digits or ``null``) or, where the launcher could not read
+the device, ``reason``; the other of the two is None.
 """
 
 from __future__ import annotations
@@ -30,12 +32,17 @@ _PATTERNS = [
                          r"(?P<seconds>[\d.]+)s, first dispatch "
                          r"(?P<first>[\d.]+)s\)")),
     ("mined", re.compile(r"^BLOCK MINED")),
-    ("memory", re.compile(r"^memory: peak_bytes=(?P<peak>\d+|null)")),
+    ("memory", re.compile(r"^memory: (?:peak_bytes=(?P<peak>\d+|null)"
+                          r"|unreadable \((?P<reason>.*)\))")),
     ("trace", re.compile(r"^trace: (?P<what>started|stopped) "
                          r"unix=(?P<unix>[\d.]+)")),
     ("error", re.compile(r"^(node unreachable|push_block failed|no mining "
                          r"progress|Traceback|upow_tpu miner: )")),
 ]
+#: what starts a line of the launcher's own threads (``launch/
+#: miner_child.py``): written whole, but it can land between a line of
+#: the miner's thread and that line's end
+_LAUNCHER_LINE = re.compile(r"(?:trace|memory): ")
 _INT = ("lo", "hi", "count", "block", "txs", "tried", "nonce")
 _FLOAT = ("mhs", "seconds", "first", "unix", "difficulty")
 
@@ -65,10 +72,9 @@ def parse(lines: list) -> list:
     """[(unix seconds, text)] -> [{"t", "kind", ...}], unread lines dropped."""
     out = []
     for t, text in lines:
-        # the launcher's tracer thread writes its line whole, but it can
-        # land between a line of the miner's and that line's end
-        cut = text.find("trace: ", 1)
-        for part in ([text] if cut < 0 else [text[:cut], text[cut:]]):
+        cut = _LAUNCHER_LINE.search(text, 1)
+        for part in ([text] if cut is None else
+                     [text[:cut.start()], text[cut.start():]]):
             rec = parse_line(part)
             if rec is not None:
                 rec["t"] = t

@@ -36,6 +36,8 @@ class LineChild:
     def __init__(self, argv: list, cwd: str, env=None, log_path=None):
         self.argv = argv
         self.lines: list = []       # (unix seconds, text)
+        self.stop_s = None          # SIGTERM to exit, of the first stop
+        self.killed = False         # it outlived that stop's wait
         self._cond = threading.Condition()
         self._log = open(log_path, "w") if log_path else None
         self.proc = subprocess.Popen(
@@ -59,11 +61,12 @@ class LineChild:
         with self._cond:
             self._cond.notify_all()
 
-    def wait_for(self, predicate, timeout: float, what: str):
-        """The first line (t, text) for which ``predicate(text)`` holds;
-        BenchError if the child exits or ``timeout`` passes first."""
+    def wait_for(self, predicate, timeout: float, what: str,
+                 seen: int = 0):
+        """The first line (t, text), of those from the ``seen``-th on,
+        for which ``predicate(text)`` holds; BenchError if the child
+        exits or ``timeout`` passes first."""
         deadline = time.time() + timeout
-        seen = 0
         with self._cond:
             while True:
                 while seen < len(self.lines):
@@ -88,12 +91,16 @@ class LineChild:
 
     def stop(self, timeout: float = 120.0) -> int:
         """SIGTERM, wait, then kill the whole session; returns the exit
-        code.  The reader thread has drained the pipe when this returns."""
+        code.  The reader thread has drained the pipe when this returns.
+        ``stop_s`` and ``killed`` keep how the first call went."""
+        t0 = time.time()
         self.signal(signal.SIGTERM)
         try:
             self.proc.wait(timeout=timeout)
         except subprocess.TimeoutExpired:
-            pass
+            self.killed = True
+        if self.stop_s is None:
+            self.stop_s = time.time() - t0
         try:
             os.killpg(self.proc.pid, signal.SIGKILL)
         except (ProcessLookupError, PermissionError):

@@ -1,11 +1,15 @@
-"""The miner, broken on purpose, for a control run on the chip
-(``run.py --control``): ``correct`` has to come out false.  Nothing of
-the benchmark's own runs comes through here.
+"""The miner or its launcher, broken on purpose, for a control run on
+the chip (``run.py --control``): ``correct`` has to come out false, or
+the run has to fail.  Nothing of the benchmark's own runs comes through
+here.
 
     skip_rounds  one round in sixteen is claimed and never sent to the
                  device: the miner prints its hashes, the job ends
                  'template expired' with its whole range accounted for,
                  and the rate reads a sixteenth higher
+    mute_memory  the launcher never says a ``memory:`` line, asked or at
+                 exit: the run has no reading of the chip's peak memory
+                 and has to end ``FAILED:``, with no result line
 """
 
 from __future__ import annotations
@@ -38,7 +42,15 @@ def _skip_rounds() -> None:
           flush=True)
 
 
-FAULTS = {"skip_rounds": _skip_rounds}
+def _mute_memory() -> None:
+    import __main__ as launcher     # launch/miner_child.py
+
+    launcher._say_memory = lambda: None
+    print("fault: mute_memory (the launcher says no 'memory:' line)",
+          flush=True)
+
+
+FAULTS = {"skip_rounds": _skip_rounds, "mute_memory": _mute_memory}
 
 
 def apply(name: str) -> None:

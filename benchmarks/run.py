@@ -6,7 +6,10 @@
                               [--control <name>]
 
 The last line of stdout is the result: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, in a traced run, ``breakdown``.
+``failed``, ``metrics``, ``device`` and, in a traced run, ``breakdown``;
+then two keys of the harness's own, ``stop`` (how the child went down)
+and, last, ``checks``: each number compared beside its limit, which are
+also the last lines on stderr.
 With ``--trace 0`` the metrics are the cell's end-to-end metrics, with
 ``--trace 1`` its per-layer metrics.  Earlier lines say what is worth
 keeping and is no metric.  A run that cannot be made — no chip, fewer
@@ -137,6 +140,10 @@ def run(args, faults=None) -> dict:
     if args.rehearse_cpu:
         say("[run] rehearsal: the children ran on the CPU, so the result "
             "is not correct whatever the checks said")
+    # how the child went down, and last each number compared beside its
+    # limit: keys of the harness's own, which the contract lets be
+    line["stop"] = result.get("stop")
+    line["checks"] = result.get("checks", [])
     return line
 
 
@@ -160,6 +167,10 @@ def main(argv=None, faults=None) -> int:
         return 1
     finally:
         procs.stop_all()
+    for c in line["checks"]:
+        print(f"[check] {c['name']}: {c['value']} (limit {c['limit']}) "
+              f"{'ok' if c['ok'] else 'FAILED'}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -11,7 +11,9 @@ answer where it is produced:
 
 Like the launcher it answers SIGUSR1 and SIGUSR2 with ``trace: started``
 and ``trace: stopped`` (at its next round; it writes no trace), and
-with ``--mute-stop`` never answers the second.
+with ``--mute-stop`` never answers the second; the memory request and
+SIGTERM it answers from a thread of its own (``child_signals.py``:
+``--memory`` and ``--ignore-term`` are its ways to go wrong).
 """
 
 import argparse
@@ -24,6 +26,7 @@ import urllib.request
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
 
+from child_signals import answer_signals  # noqa: E402
 from harness import powref  # noqa: E402
 
 
@@ -45,7 +48,10 @@ def main():
     ap.add_argument("--fault", default="")
     ap.add_argument("--platform", default="tpu")
     ap.add_argument("--mute-stop", action="store_true")
+    ap.add_argument("--memory", default="4096")
+    ap.add_argument("--ignore-term", action="store_true")
     a = ap.parse_args()
+    answer_signals(a.memory, a.ignore_term)
     out = lambda s: print(s, flush=True)  # noqa: E731
     signalled = {}     # what -> unix, said from the loop, not the handler
     signal.signal(signal.SIGUSR1,

@@ -24,8 +24,10 @@ TINY = {"warm_difficulties": [2.0], "after_difficulties": [2.5],
 
 FULL_RANGE_MINER = textwrap.dedent("""
     import hashlib, json, sys, time, urllib.request
-    sys.path.insert(0, {bench!r})
+    sys.path[:0] = [{bench!r}, {bench!r} + "/tests"]
+    from child_signals import answer_signals
     from harness import powref
+    answer_signals()
     address_hex, node, batch, last = sys.argv[1], sys.argv[2], {batch}, sys.argv[3]
     out = lambda s: print(s, flush=True)
 

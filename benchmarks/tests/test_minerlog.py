@@ -91,3 +91,18 @@ def test_a_tracer_line_written_into_a_miners_line_loses_neither():
     assert events[2]["unix"] == 1790000000.25
     assert minerlog.jobs(events)[0]["rounds"] == [(2.0, 16777216),
                                                   (3.0, 16777216)]
+
+
+def test_a_memory_line_written_into_a_miners_line_loses_neither():
+    lines = [(1.0, "difficulty: 11.0  block: 3  confirming 64 transactions"),
+             (2.0, "1890.12 MH/s (16777216 hashes)memory: peak_bytes=1254912"),
+             (2.0, ""),
+             (3.0, "memory: unreadable (RuntimeError: no client (gone))"),
+             (4.0, "memory: peak_bytes=null")]
+    events = minerlog.parse(lines)
+    assert [e["kind"] for e in events] == ["job", "round", "memory",
+                                           "memory", "memory"]
+    assert [(e["peak"], e["reason"]) for e in events[2:]] == [
+        ("1254912", None), (None, "RuntimeError: no client (gone)"),
+        ("null", None)]
+    assert minerlog.jobs(events)[0]["rounds"] == [(2.0, 16777216)]

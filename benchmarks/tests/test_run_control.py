@@ -49,24 +49,45 @@ def drive(fault="", control=None, seed=7, platform="tpu", trace=0,
     return rc, lines
 
 
-def test_a_sound_run_is_correct_and_prints_the_contracts_line():
+def test_a_sound_run_is_correct_and_prints_the_contracts_line(capsys):
     rc, lines = drive()
     assert rc == 0, lines[-5:]
     result = json.loads(lines[-1])
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    # the contract's keys, then the harness's own two, the checks last
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "stop", "checks"]
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 2
     assert set(result["metrics"]) == {"search_mhs", "setup_s"}
     assert result["metrics"]["search_mhs"]["value"] > 0
     assert set(result["device"]) == {"platform", "kind", "count",
                                      "memory_peak_bytes"}
+    # a number of 0 or more, not the key alone: what the driver refuses
+    peak = result["device"]["memory_peak_bytes"]
+    assert type(peak) is int and peak == 4096
     # each number compared is printed beside its limit
     checks = [ln for ln in lines if ln.startswith("[check] ")]
     assert len(checks) >= 7 and all("(limit " in ln for ln in checks)
     # a block mined before and one after the window, each under the reference
     assert sum("_nonce_minus_reference_lowest: 0 (limit 0) ok" in ln
                for ln in checks) == 2
+    # and again as the last lines on stderr and last in the result
+    said = capsys.readouterr().err.strip().splitlines()
+    assert [c["name"] for c in result["checks"]] == \
+        [ln.split()[1].rstrip(":") for ln in said[-len(checks):]] == \
+        [ln.split()[1].rstrip(":") for ln in checks]
+    assert all(c["ok"] for c in result["checks"])
+    # how the child went down: asked, answered, stopped, not killed
+    stops = [ln for ln in lines if ln.startswith("[stop] ")]
+    assert len(stops) == 1 and "WARNING" not in stops[0]
+    assert result["stop"] == dict(
+        result["stop"], memory_request_answered=1, memory_lines=2,
+        memory_peak_bytes=4096, child_rc=0, killed=0, exceptions_ignored=0)
+    assert 0 <= result["stop"]["memory_answer_s"] < 5
+    assert 0 <= result["stop"]["stop_s"] < 5
+    for key, value in result["stop"].items():
+        assert f" {key}={value:.4f}" in stops[0] if isinstance(value, float) \
+            else f" {key}={value} " in stops[0] + " ", (key, stops[0])
 
 
 @pytest.mark.parametrize("fault,check", [
@@ -103,20 +124,95 @@ def test_no_chip_no_result():
         json.loads(lines[-1])
 
 
+# ---- the chip's peak memory: asked for, then the child is stopped ----
+
+@pytest.mark.parametrize("child_args,rc,told", [
+    # never answers and says no line at exit either
+    (["--memory", "never"], 1,
+     ["memory_request_answered=0 ", "memory_answer_s=1.0", "memory_lines=0 ",
+      "child_rc=0 ",
+      "killed=0 ", "exceptions_ignored=0;",
+      "the child said of its memory: nothing"]),
+    (["--memory", "null"], 1,
+     ["memory_request_answered=1 ", "memory_lines=2 ",
+      "memory_peak_bytes=None ", "['peak_bytes=null', 'peak_bytes=null']"]),
+    (["--memory", "unreadable"], 1,
+     ["memory_request_answered=1 ", "memory_lines=2 ",
+      "['RuntimeError: backend gone', 'RuntimeError: backend gone']"]),
+    # the answer written behind an unfinished line of another thread
+    (["--memory", "inline"], 0, ["memory_request_answered=1 ",
+                                 "memory_lines=2 ", "killed=0 "]),
+    # SIGTERM ignored: sound on the early answer, and the kill is said
+    (["--ignore-term"], 0, ["WARNING: the child had to be killed: ",
+                            "memory_request_answered=1 ", "memory_lines=1 ",
+                            "child_rc=-9 ", "stop_s=1.", "killed=1 "]),
+])
+def test_the_ways_a_reading_of_the_peak_memory_goes_wrong(
+        monkeypatch, child_args, rc, told):
+    seen = _watch_the_driver(monkeypatch, MEMORY_WAIT_S=1.0, STOP_WAIT_S=1.0)
+    got, lines = drive(child_args=child_args)
+    assert got == rc, lines[-5:]
+    stops = [ln for ln in lines if ln.startswith("[stop] ")]
+    assert len(stops) == 1
+    if rc:
+        # no result line, and the cause in numbers on the last line
+        assert not seen
+        assert lines[-1].startswith("FAILED: no reading of the chip's peak "
+                                    "memory, so no result line: ")
+        with pytest.raises(ValueError):
+            json.loads(lines[-1])
+        assert "its last lines: " in lines[-1]
+        where = lines[-1]
+    else:
+        result = json.loads(lines[-1])
+        assert result["correct"] is True
+        assert result["device"]["memory_peak_bytes"] == 4096
+        where = stops[0]
+    for text in told:
+        assert text in where, (text, where)
+    assert ("WARNING" in stops[0]) == ("--ignore-term" in child_args)
+
+
+def test_a_rehearsal_on_the_cpu_may_read_null():
+    """The CPU keeps no such statistic: there, and only there, a run
+    without the number still prints its (never correct) result."""
+    from harness.manifest import load_module
+
+    driver = load_module("drivers", "mine_sweep")
+
+    class Miner:
+        lines = [(1.0, "memory: peak_bytes=null")]
+        killed, stop_s = False, 0.01
+
+        def signal(self, _sig):
+            pass
+
+        def wait_for(self, _predicate, _timeout, _what, seen=0):
+            return 1.0, "memory: peak_bytes=null"
+
+        def stop(self, timeout):
+            return 0
+
+        def tail(self, _n):
+            return "memory: peak_bytes=null"
+
+    said = []
+    rc, events, stop = driver._stop_child(
+        SimpleNamespace(say=said.append, rehearse=True), Miner())
+    assert (rc, stop["memory_peak_bytes"], len(said)) == (0, None, 1)
+    with pytest.raises(driver.BenchError, match="memory_lines=1 "):
+        driver._stop_child(
+            SimpleNamespace(say=said.append, rehearse=False), Miner())
+
+
 # ---- a traced run: how long its window is, and the stop ----
 
-@pytest.fixture
-def driven(monkeypatch):
-    """What the driver handed back, kept for the test to look at.  There
-    is no profiler here: the trace a traced run finds is one window span,
-    and the wait for the stop is two seconds."""
-    from harness import manifest, xplane
+def _watch_the_driver(monkeypatch, **waits):
+    """Every run's driver gets ``waits`` for its constants (a test does
+    not wait minutes); returns the list of what each run handed back."""
+    from harness import manifest
 
     seen = []
-    monkeypatch.setattr(xplane, "find_trace", lambda _d: "x.xplane.pb")
-    monkeypatch.setattr(xplane, "extract", lambda _p: [
-        {"plane": "/host:CPU", "line": "python3",
-         "name": "perfbench.window", "start_ns": 0.0, "dur_ns": 1e9}])
     load = manifest.load_module
 
     def load_and_watch(kind, name, *rest):
@@ -129,11 +225,27 @@ def driven(monkeypatch):
                 return seen[-1]
 
             module.run = run_and_keep
-            module.STOP_TRACE_WAIT_S = 2.0
+            for key, value in waits.items():
+                assert hasattr(module, key)
+                setattr(module, key, value)
         return module
 
     monkeypatch.setattr(manifest, "load_module", load_and_watch)
     return seen
+
+
+@pytest.fixture
+def driven(monkeypatch):
+    """What the driver handed back, kept for the test to look at.  There
+    is no profiler here: the trace a traced run finds is one window span,
+    and the wait for the stop is two seconds."""
+    from harness import xplane
+
+    monkeypatch.setattr(xplane, "find_trace", lambda _d: "x.xplane.pb")
+    monkeypatch.setattr(xplane, "extract", lambda _p: [
+        {"plane": "/host:CPU", "line": "python3",
+         "name": "perfbench.window", "start_ns": 0.0, "dur_ns": 1e9}])
+    return _watch_the_driver(monkeypatch, STOP_TRACE_WAIT_S=2.0)
 
 
 @pytest.mark.parametrize("trace,traced_window_s,window_s", [
