@@ -407,3 +407,159 @@ def test_static_pallas_takes_a_batch_of_no_whole_tiles():
 
     assert search(hit - batch) == int(SENTINEL)   # the first surplus lane
     assert search(hit - batch + 1) == hit         # the batch's last lane
+
+
+# --- the hoisted template: what the host finishes once a job ----------------
+
+_EDGE_NONCES = [0, 1, 0xFF, 0x100, 0x00FFFFFF, 1 << 31, (1 << 32) - 2]
+
+
+@pytest.fixture(scope="module")
+def edge_digests():
+    """{(prefix length, form): (prefix, digests)} of ``_search_digest`` at
+    the edge nonces, from the template's two arrays: unrolled (the kernels'
+    form, every hoisted word read; op by op here, no jit) and rolled."""
+    import jax.numpy as jnp
+
+    from upow_tpu.crypto import sha256 as sk
+
+    r = random.Random("hoisted-template")
+    out = {}
+    for prefix_len in (104, 134):
+        prefix = bytes(r.randrange(256) for _ in range(prefix_len))
+        template = make_template(prefix)
+        for form, unroll in (("unrolled", True), ("rolled", False)):
+            digest = sk._search_digest(
+                jnp.asarray(template.midstate),
+                jnp.asarray(template.tail_words),
+                jnp.asarray(_EDGE_NONCES, dtype=jnp.uint32),
+                template.nonce_spec, unroll=unroll)
+            out[prefix_len, form] = (prefix, np.stack(
+                [np.asarray(word) for word in digest], axis=1))
+    return out
+
+
+@pytest.mark.parametrize("form", ["unrolled", "rolled"])
+@pytest.mark.parametrize("nonce", _EDGE_NONCES)
+@pytest.mark.parametrize("prefix_len", [104, 134])
+def test_hoisted_template_digest_matches_hashlib(edge_digests, prefix_len,
+                                                 nonce, form):
+    """All eight digest words, from the hoisted state and schedule, are
+    hashlib's: at every nonce byte's carry and at the u32 edge."""
+    prefix, digests = edge_digests[prefix_len, form]
+    got = b"".join(int(word).to_bytes(4, "big")
+                   for word in digests[_EDGE_NONCES.index(nonce)])
+    assert got == hashlib.sha256(prefix + nonce.to_bytes(4, "little")).digest()
+
+
+@pytest.mark.parametrize("prefix_len,rounds,words,first_words", [
+    (104, 10, 4, [16, 18, 20, 22]),   # v2: the nonce is w10
+    (134, 1, 0, []),                  # v1: the nonce starts in w1
+])
+def test_template_hoists_what_no_nonce_reaches(prefix_len, rounds, words,
+                                               first_words):
+    """What the host finishes follows from where the nonce lands; the
+    template keeps the plain midstate and tail block beside it."""
+    from upow_tpu.crypto import sha256 as sk
+
+    prefix = _rand_bytes(prefix_len)
+    template = make_template(prefix)
+    reached = sk.nonce_reach(template.nonce_spec)
+    assert sk.hoisted_counts(template.nonce_spec) == (rounds, words)
+    assert [i for i in range(16, 64) if not reached[i]] == first_words
+    assert reached.index(True) == min(w for w, _ in template.nonce_spec)
+    assert template.midstate.shape == (16,)
+    assert template.tail_words.shape == (80,)
+
+    # [0:8] / [0:16]: the state after the whole blocks, the plain tail
+    state = tuple(int(x) for x in sk._H0)
+    for off in range(0, prefix_len - prefix_len % 64, 64):
+        state = sk._compress_py(state, prefix[off:off + 64])
+    assert tuple(int(x) for x in template.midstate[:8]) == state
+    block = (prefix[prefix_len - prefix_len % 64:] + bytes(4) + b"\x80")
+    block += bytes(56 - len(block)) + (8 * (prefix_len + 4)).to_bytes(8, "big")
+    tail = np.frombuffer(block, dtype=">u4")
+    assert (template.tail_words[:16] == tail).all()
+
+    # [8:16]: the plain rounds before the nonce's first word; a finished
+    # word carries K[i] + w[i] of the plain schedule of the zero nonce
+    w = [int(x) for x in tail]
+    for i in range(16, 64):
+        w.append(sum(sk._schedule_terms(w, i, sk._small_sigma_py))
+                 & 0xFFFFFFFF)
+    for i in range(rounds):
+        state = sk._round_py(state, int(sk._K[i]) + w[i])
+    assert tuple(int(x) for x in template.midstate[8:]) == state
+    for i in range(64):
+        if not reached[i]:
+            assert int(template.tail_words[16 + i]) == \
+                (int(sk._K[i]) + w[i]) & 0xFFFFFFFF
+
+
+_BIT_TRIPLES = [(x, y, z) for x in (0, 0xFFFFFFFF) for y in (0, 0xFFFFFFFF)
+                for z in (0, 0xFFFFFFFF)]
+
+
+@pytest.mark.parametrize("triple", _BIT_TRIPLES + ["random"],
+                         ids=lambda t: t if t == "random" else
+                         "".join("1" if x else "0" for x in t))
+def test_round_ch_maj_in_three_operations_match_the_plain_forms(triple):
+    """``_round``'s Ch ``g ^ (e & (f ^ g))`` and Maj ``b ^ ((a ^ b) &
+    (b ^ c))`` against the host round's ``(e & f) ^ (~e & g)`` and
+    ``(a & b) ^ (a & c) ^ (b & c)``: every bit triple, and random words."""
+    import jax.numpy as jnp
+
+    from upow_tpu.crypto import sha256 as sk
+
+    r = random.Random("ch-maj")
+    if triple == "random":
+        states = [tuple(r.getrandbits(32) for _ in range(8))
+                  for _ in range(64)]
+    else:  # (a, b, c) and (e, f, g) each run through the triple
+        x, y, z = triple
+        states = [(x, y, z, r.getrandbits(32), x, y, z, r.getrandbits(32))]
+    kw = r.getrandbits(32)
+    lanes = tuple(jnp.asarray([s[j] for s in states], dtype=jnp.uint32)
+                  for j in range(8))
+    new, ab = sk._round(lanes, lanes[1] ^ lanes[2], jnp.uint32(kw))
+    for lane, state in enumerate(states):
+        assert tuple(int(word[lane]) for word in new) == \
+            sk._round_py(state, kw)
+        assert int(ab[lane]) == state[0] ^ state[1]
+
+
+@pytest.mark.parametrize("form", ["unrolled", "rolled"])
+def test_compress_tail_of_words_that_all_differ_matches_hashlib(form):
+    """The txid path's compression (every word differs by lane, nothing
+    to hoist), in both of its forms, two blocks deep."""
+    import jax.numpy as jnp
+
+    from upow_tpu.crypto import sha256 as sk
+
+    msgs = [_rand_bytes(100) for _ in range(5)]
+    rows = np.stack([np.frombuffer(
+        m + b"\x80" + bytes(19) + (800).to_bytes(8, "big"), dtype=">u4")
+        for m in msgs]).astype(np.uint32)
+    state = tuple(jnp.full((len(msgs),), h, jnp.uint32) for h in sk._H0)
+    for block in range(2):
+        state = sk._compress_tail(
+            state, [jnp.asarray(rows[:, block * 16 + i]) for i in range(16)],
+            unroll=form == "unrolled")
+    for lane, m in enumerate(msgs):
+        got = b"".join(int(word[lane]).to_bytes(4, "big") for word in state)
+        assert got == hashlib.sha256(m).digest()
+
+
+def test_pallas_v1_header_answers_hashlibs_lowest_hit():
+    """The kernel on a v1 header (one round hoisted, the nonce over
+    w1/w2, both tail words with bytes of their own), interpret mode."""
+    r = random.Random("pallas-v1")
+    prefix = bytes(r.randrange(256) for _ in range(134))
+    prev = bytes(r.randrange(256) for _ in range(32)).hex()
+    base, batch = 0x00FFFC00, 2 * _DATA_TILE   # a carry into the third byte
+    want = next(n for n in range(base, base + batch)
+                if check_pow_hash(_digest_hex(prefix, n), prev, "1"))
+    got = pow_search_pallas(make_template(prefix), target_spec(prev, "1"),
+                            nonce_base=base, batch=batch,
+                            tile_rows=_DATA_TILE_ROWS, interpret=True)
+    assert int(got) == want

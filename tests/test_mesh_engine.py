@@ -335,6 +335,40 @@ def test_job_layouts_count_jobs_not_rounds(body, request):
     assert layouts() == (2, 2)
 
 
+@pytest.mark.parametrize("prefix_len,rounds,words", [
+    (104, 10, 4),   # v2: the nonce is w10
+    (134, 1, 0),    # v1: the nonce starts in w1
+])
+def test_hoist_counters_say_once_a_job_what_the_host_finished(
+        prefix_len, rounds, words):
+    """``kernel.mine_mesh.rounds_hoisted`` / ``.words_hoisted``: once a
+    job laid over the mesh, what its header's nonce placement let
+    ``make_template`` finish; none for the same job again or a round.
+    The round's answer is hashlib's, whichever header."""
+    from decimal import Decimal
+
+    eng = _armed_engine(batch_per_device=64)
+
+    def hoisted():
+        counters = metrics.counters()
+        return (counters.get("kernel.mine_mesh.rounds_hoisted", 0),
+                counters.get("kernel.mine_mesh.words_hoisted", 0))
+
+    assert hoisted() == (0, 0)
+    r = random.Random(f"hoist-{prefix_len}")
+    job = MiningJob(bytes(r.randrange(256) for _ in range(prefix_len)),
+                    bytes(r.randrange(256) for _ in range(32)).hex(),
+                    Decimal("1"))
+    eng.set_job(job)
+    eng.set_job(job)
+    assert hoisted() == (rounds, words)
+    got = int(eng.dispatch(0, eng.capacity))
+    assert hoisted() == (rounds, words)
+    assert got == next(n for n in range(eng.capacity) if job.check(n))
+    eng.set_job(_seeded_job(13))
+    assert hoisted() == (rounds + 10, words + 4)
+
+
 def test_engine_reuse_and_replacement_semantics():
     """get_mesh_engine: armed engine is reused while the round fits its
     capacity; a larger round replaces it (one deliberate recompile)."""

@@ -86,6 +86,33 @@ def test_a_range_of_no_whole_rounds_is_one_program_and_one_masked_round():
     assert grew["kernel.sha256_search.lanes_padded"] == 2 * batch
 
 
+@pytest.mark.parametrize("prefix_len,rounds,words", [
+    (104, 10, 4),   # v2: the nonce is w10
+    (134, 1, 0),    # v1: the nonce starts in w1
+])
+def test_hoist_counters_say_what_the_host_finished_for_the_job(
+        prefix_len, rounds, words):
+    """``kernel.sha256_search.rounds_hoisted`` / ``.words_hoisted`` grow
+    once a job by what the header's nonce placement let the host finish
+    of the 64 rounds and 48 schedule words, however many rounds the job
+    runs; the job's answer is the plain loop's."""
+    from decimal import Decimal
+
+    names = ("kernel.sha256_search.rounds_hoisted",
+             "kernel.sha256_search.words_hoisted", "mine.rounds")
+    job = MiningJob(bytes(rng.randrange(256) for _ in range(prefix_len)),
+                    bytes(rng.randrange(256) for _ in range(32)).hex(),
+                    Decimal("2"))
+    first = next(n for n in range(1 << 14) if job.check(n))
+    before = _counters(*names)
+    result = mine(job, "jnp", batch=64, stride_end=1 << 14)
+    grew = {n: v - before[n] for n, v in _counters(*names).items()}
+    assert result.nonce == first
+    assert grew["mine.rounds"] >= first // 64 + 1
+    assert grew["kernel.sha256_search.rounds_hoisted"] == rounds
+    assert grew["kernel.sha256_search.words_hoisted"] == words
+
+
 def test_a_hit_in_the_short_last_round_is_found():
     """A job whose only hit lies in its masked round, and the same range
     cut just below that hit, which then ends with none."""

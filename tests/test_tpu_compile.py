@@ -71,21 +71,24 @@ def _shape(shape, dtype, sharding):
 
 # ------------------------------------------------------------ sha256 ----
 
-@pytest.mark.parametrize("difficulty", ["6.0", "6.3"])
+@pytest.mark.parametrize("difficulty,prefix_len", [
+    ("6.0", 104), ("6.3", 104),
+    ("6.0", 134),   # a v1 header: one round hoisted, the nonce over w1/w2
+])
 def test_sha256_pallas_search_compiles(one_chip, no_compile_cache,
-                                       difficulty):
+                                       difficulty, prefix_len):
     """The miner's default round: search_batch 2^24, tile_rows 64, at
     the protocol's start difficulty and at a fractional one (the
     charset branch of the kernel); ``[base, limit)`` is one SMEM operand
-    of two words."""
+    of two words, midstate and tail the template's own two arrays."""
     from upow_tpu.config import DeviceConfig
     from upow_tpu.crypto import sha256 as sk
 
-    template = sk.make_template(bytes(104))
+    template = sk.make_template(bytes(prefix_len))
     spec = sk.target_spec("ab" * 32, Decimal(difficulty))
     compiled = sk._pow_search_pallas.lower(
-        _shape((8,), jnp.uint32, one_chip),
-        _shape((16,), jnp.uint32, one_chip),
+        _shape(template.midstate.shape, jnp.uint32, one_chip),
+        _shape(template.tail_words.shape, jnp.uint32, one_chip),
         _shape((2,), jnp.uint32, one_chip),
         batch=DeviceConfig().search_batch, tile_rows=64,
         nonce_spec=template.nonce_spec, spec=spec,

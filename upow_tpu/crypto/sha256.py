@@ -8,7 +8,10 @@ mining template factors as
 where ``midstate`` covers every complete 64-byte block of the prefix (host,
 once per template) and only ONE compression runs per nonce on device
 (reference hot loop: /root/reference/miner.py:83-98 does the full hash per
-nonce in Python).
+nonce in Python).  Of that compression, the rounds and schedule words no
+nonce byte reaches are the same for every nonce of a job: the host
+finishes them once a job too (:func:`make_template`), and the search
+programs start at the first round a nonce reaches (:func:`_search_digest`).
 
 Three implementations share the same round logic:
 
@@ -67,6 +70,35 @@ def _rotr_py(x: int, n: int) -> int:
     return ((x >> n) | (x << (32 - n))) & 0xFFFFFFFF
 
 
+def _small_sigma_py(x: int, r1: int, r2: int, shift: int) -> int:
+    return _rotr_py(x, r1) ^ _rotr_py(x, r2) ^ (x >> shift)
+
+
+#: w[i] = w[i-16] + s0(w[i-15]) + w[i-7] + s1(w[i-2]): each term's tap and
+#: its small sigma's (rotate, rotate, shift), None for the word itself
+_SCHEDULE = ((16, None), (15, (7, 18, 3)), (7, None), (2, (17, 19, 10)))
+
+
+def _schedule_terms(w, i: int, small_sigma, keep=lambda j: True):
+    """The terms of schedule word ``i``'s recurrence over ``w`` whose
+    word ``keep`` keeps (all four by default), on the host
+    (``_small_sigma_py``) or traced (``_small_sigma``)."""
+    return [w[i - tap] if sigma is None else small_sigma(w[i - tap], *sigma)
+            for tap, sigma in _SCHEDULE if keep(i - tap)]
+
+
+def _round_py(state: Sequence[int], kw: int) -> Tuple[int, ...]:
+    """One round on the host; ``kw`` is ``K[i] + w[i]``."""
+    a, b, c, d, e, f, g, h = state
+    s1 = _rotr_py(e, 6) ^ _rotr_py(e, 11) ^ _rotr_py(e, 25)
+    ch = (e & f) ^ (~e & g)
+    t1 = (h + s1 + ch + kw) & 0xFFFFFFFF
+    s0 = _rotr_py(a, 2) ^ _rotr_py(a, 13) ^ _rotr_py(a, 22)
+    maj = (a & b) ^ (a & c) ^ (b & c)
+    t2 = (s0 + maj) & 0xFFFFFFFF
+    return (t1 + t2) & 0xFFFFFFFF, a, b, c, (d + t1) & 0xFFFFFFFF, e, f, g
+
+
 def _compress_py(state: Sequence[int], block: bytes) -> Tuple[int, ...]:
     """One SHA-256 compression on the host (64-byte block)."""
     # Host-only midstate prep (never traced); uint64 gives headroom for
@@ -74,19 +106,11 @@ def _compress_py(state: Sequence[int], block: bytes) -> Tuple[int, ...]:
     w = list(np.frombuffer(block, dtype=">u4").astype(np.uint64))  # upowlint: disable=DT001
     w = [int(x) for x in w]
     for i in range(16, 64):
-        s0 = _rotr_py(w[i - 15], 7) ^ _rotr_py(w[i - 15], 18) ^ (w[i - 15] >> 3)
-        s1 = _rotr_py(w[i - 2], 17) ^ _rotr_py(w[i - 2], 19) ^ (w[i - 2] >> 10)
-        w.append((w[i - 16] + s0 + w[i - 7] + s1) & 0xFFFFFFFF)
-    a, b, c, d, e, f, g, h = state
+        w.append(sum(_schedule_terms(w, i, _small_sigma_py)) & 0xFFFFFFFF)
+    out = tuple(state)
     for i in range(64):
-        s1 = _rotr_py(e, 6) ^ _rotr_py(e, 11) ^ _rotr_py(e, 25)
-        ch = (e & f) ^ (~e & g)
-        t1 = (h + s1 + ch + int(_K[i]) + w[i]) & 0xFFFFFFFF
-        s0 = _rotr_py(a, 2) ^ _rotr_py(a, 13) ^ _rotr_py(a, 22)
-        maj = (a & b) ^ (a & c) ^ (b & c)
-        t2 = (s0 + maj) & 0xFFFFFFFF
-        a, b, c, d, e, f, g, h = (t1 + t2) & 0xFFFFFFFF, a, b, c, (d + t1) & 0xFFFFFFFF, e, f, g
-    return tuple((x + y) & 0xFFFFFFFF for x, y in zip(state, (a, b, c, d, e, f, g, h)))
+        out = _round_py(out, int(_K[i]) + w[i])
+    return tuple((x + y) & 0xFFFFFFFF for x, y in zip(state, out))
 
 
 def sha256_py(message: bytes) -> bytes:
@@ -101,20 +125,75 @@ def sha256_py(message: bytes) -> bytes:
 # --- template preparation (host) -----------------------------------------
 
 class SearchTemplate(NamedTuple):
-    """Everything the device kernel needs for one mining template.
+    """Everything the device kernel needs for one mining template: the
+    job's nonce-free part of the tail block's compression, done once
+    here on the host, and where the nonce lands.
 
-    midstate      : (8,)  uint32 — state after the full prefix blocks
-    tail_words    : (16,) uint32 — final block with nonce bytes zeroed,
-                    padding + length already applied
+    midstate      : (16,) uint32 — [0:8] the state after the prefix's
+                    whole blocks (the digest's feed-forward); [8:16] the
+                    working variables a..h after the rounds no nonce
+                    reaches, run from it
+    tail_words    : (80,) uint32 — [0:16] the final block with nonce
+                    bytes zeroed, padding + length already applied;
+                    [16:80] the hoisted message schedule, word ``16 + i``
+                    for round ``i``: ``K[i] + w[i]`` where no nonce
+                    reaches ``w[i]``, else the sum of ``w[i]``'s
+                    nonce-free terms (:func:`nonce_reach` says which)
     nonce_spec    : 4×(word_index, left_shift) — where each little-endian
                     nonce byte lands in the tail words (static per header
                     version: v2 108-byte header → all four bytes in w10;
                     v1 138-byte header → split across w1/w2)
+
+    What is hoisted follows from ``nonce_spec`` alone: a v2 header's
+    nonce is w10, so rounds 0-9 and schedule words 16, 18, 20 and 22 are
+    finished here and words 17, 19, 21 and 23-38 have a hoisted part; a
+    v1 header's nonce starts in w1, so round 0, no whole word, and a
+    hoisted part of words 16-31.
     """
 
     midstate: np.ndarray
     tail_words: np.ndarray
     nonce_spec: Tuple[Tuple[int, int], ...]
+
+
+@functools.lru_cache(maxsize=None)
+def nonce_reach(nonce_spec) -> Tuple[bool, ...]:
+    """For each of the 64 schedule words, whether a nonce byte reaches
+    it: the tail words ``nonce_spec`` names and every later word whose
+    recurrence reads one.  Every other word, and every round before the
+    first such word, is the same for all of a job's nonces."""
+    reached = [False] * 64
+    for widx, _ in nonce_spec:
+        reached[widx] = True
+    for i in range(16, 64):
+        reached[i] = any(reached[i - tap] for tap, _ in _SCHEDULE)
+    return tuple(reached)
+
+
+def hoisted_counts(nonce_spec) -> Tuple[int, int]:
+    """(rounds of the 64, schedule words of the 48) that
+    :func:`make_template` finishes on the host for ``nonce_spec``."""
+    reached = nonce_reach(nonce_spec)
+    return reached.index(True), 48 - sum(reached[16:])
+
+
+def _hoist(midstate: Sequence[int], tail_words: Sequence[int], nonce_spec):
+    """The tail block's nonce-free work, in Python integers: (a..h after
+    the rounds before the first word a nonce reaches, the 64 hoisted
+    schedule words of :class:`SearchTemplate`)."""
+    reached = nonce_reach(nonce_spec)
+    # nonce bytes are zero in tail_words, so a reached word holds exactly
+    # its nonce-free part; no unreached word reads one
+    w = [int(x) for x in tail_words]
+    for i in range(16, 64):
+        w.append(sum(_schedule_terms(
+            w, i, _small_sigma_py, lambda j: not reached[j])) & 0xFFFFFFFF)
+    sched = [x if reached[i] else (x + int(_K[i])) & 0xFFFFFFFF
+             for i, x in enumerate(w)]
+    state = tuple(int(x) for x in midstate)
+    for i in range(reached.index(True)):
+        state = _round_py(state, sched[i])
+    return state, sched
 
 
 def make_template(prefix: bytes) -> SearchTemplate:
@@ -148,7 +227,11 @@ def make_template(prefix: bytes) -> SearchTemplate:
         ((nonce_off + j) // 4, 8 * (3 - (nonce_off + j) % 4)) for j in range(4)
     )
     tail_words = np.frombuffer(bytes(tail), dtype=">u4").astype(np.uint32)
-    return SearchTemplate(np.array(state, dtype=np.uint32), tail_words, nonce_spec)
+    hoisted_state, sched = _hoist(state, tail_words, nonce_spec)
+    return SearchTemplate(
+        np.array(state + hoisted_state, dtype=np.uint32),
+        np.concatenate([tail_words, np.array(sched, dtype=np.uint32)]),
+        nonce_spec)
 
 
 class TargetSpec(NamedTuple):
@@ -190,6 +273,51 @@ def _rotr(x, n: int):
     return (x >> n) | (x << (32 - n))
 
 
+def _small_sigma(x, r1: int, r2: int, shift: int):
+    return _rotr(x, r1) ^ _rotr(x, r2) ^ (x >> shift)
+
+
+def _sum(*terms):
+    """``terms`` added up, the scalars among them first: in a search
+    program a hoisted word or a not yet mixed state variable is a scalar
+    (an SMEM read, the scalar unit), and stays one until a vector meets
+    it."""
+    scalars = [t for t in terms if jnp.ndim(t) == 0]
+    vectors = [t for t in terms if jnp.ndim(t) != 0]
+    return functools.reduce(lambda x, y: x + y, scalars + vectors)
+
+
+def _round(state, bc, *kw):
+    """One round on ``state`` (a..h); ``kw`` are the terms of
+    ``K[i] + w[i]``, ``bc`` is ``b ^ c``.  Ch and Maj take three
+    operations each: ``g ^ (e & (f ^ g))``, and ``b ^ ((a ^ b) & (b ^ c))``
+    whose ``a ^ b`` is the next round's ``b ^ c``, returned beside the
+    new state."""
+    a, b, c, d, e, f, g, h = state
+    s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
+    ch = g ^ (e & (f ^ g))
+    t1 = _sum(h, *kw, s1, ch)
+    s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
+    ab = a ^ b
+    maj = b ^ (ab & bc)
+    return (_sum(t1, s0, maj), a, b, c, _sum(d, t1), e, f, g), ab
+
+
+def _unrolled(unroll: bool | None) -> bool:
+    """:func:`_compress_tail`'s default: unrolled exactly when the
+    default backend is a real accelerator."""
+    if unroll is None:
+        from ..device.runtime import get_runtime
+
+        unroll = get_runtime().platform() not in (None, "cpu")
+    return unroll
+
+
+def _feed_forward(midstate, state):
+    return tuple(jnp.asarray(m, jnp.uint32) + x
+                 for m, x in zip(midstate, state))
+
+
 def _compress_tail(midstate, w, unroll: bool | None = None):
     """One compression over message words ``w`` (list of 16 u32 arrays),
     starting from ``midstate`` (tuple of 8 u32 arrays/scalars).
@@ -209,73 +337,110 @@ def _compress_tail(midstate, w, unroll: bool | None = None):
     Default: unrolled exactly when the default backend is a real
     accelerator.
     """
-    if unroll is None:
-        from ..device.runtime import get_runtime
-
-        unroll = get_runtime().platform() not in (None, "cpu")
-    if not unroll:
-        return _compress_tail_rolled(midstate, w)
+    if not _unrolled(unroll):
+        return _feed_forward(midstate, _rounds_rolled(midstate, w, 0))
     w = list(w)
-    a, b, c, d, e, f, g, h = midstate
+    state, bc = tuple(midstate), midstate[1] ^ midstate[2]
     for i in range(64):
         if i >= 16:
-            w15, w2 = w[i - 15], w[i - 2]
-            s0 = _rotr(w15, 7) ^ _rotr(w15, 18) ^ (w15 >> 3)
-            s1 = _rotr(w2, 17) ^ _rotr(w2, 19) ^ (w2 >> 10)
-            w.append(w[i - 16] + s0 + w[i - 7] + s1)
-        s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
-        ch = (e & f) ^ (~e & g)
-        t1 = h + s1 + ch + jnp.uint32(_K[i]) + w[i]
-        s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
-        maj = (a & b) ^ (a & c) ^ (b & c)
-        t2 = s0 + maj
-        a, b, c, d, e, f, g, h = t1 + t2, a, b, c, d + t1, e, f, g
-    return tuple(x + y for x, y in zip(midstate, (a, b, c, d, e, f, g, h)))
+            w.append(_sum(*_schedule_terms(w, i, _small_sigma)))
+        state, bc = _round(state, bc, jnp.uint32(_K[i]), w[i])
+    return _feed_forward(midstate, state)
 
 
-def _compress_tail_rolled(midstate, w):
-    """Rolled form of :func:`_compress_tail` (see its docstring).
+def _rounds_rolled(state, window, first: int):
+    """Rounds ``first`` to 63 as a ``lax.fori_loop`` (the rolled form of
+    :func:`_compress_tail`, see its docstring), from ``state`` (a..h
+    before round ``first``) and ``window`` = ``w[first] .. w[first+15]``.
+    Returns a..h after round 63.
 
     Invariant: at the start of round ``i`` the window holds
     ``w[i] .. w[i+15]``; the body consumes ``window[0]`` and appends
     ``w[i+16] = w[i] + s0(w[i+1]) + w[i+9] + s1(w[i+14])`` (garbage past
-    round 47, never read)."""
-    shape = jnp.broadcast_shapes(*(jnp.shape(x) for x in w))
-    window = jnp.stack([jnp.broadcast_to(x, shape).astype(jnp.uint32) for x in w])
+    round 47, never read).  The carried state has a ninth row, ``b ^ c``
+    (:func:`_round`)."""
+    shape = jnp.broadcast_shapes(*(jnp.shape(x) for x in window))
+    window = jnp.stack(
+        [jnp.broadcast_to(x, shape).astype(jnp.uint32) for x in window])
     state = jnp.stack([
-        jnp.broadcast_to(jnp.asarray(s, jnp.uint32), shape) for s in midstate
-    ])
+        jnp.broadcast_to(jnp.asarray(x, jnp.uint32), shape)
+        for x in (*state, state[1] ^ state[2])])
     k_arr = jnp.asarray(_K)
 
     def body(i, carry):
         st, win = carry
-        a, b, c, d, e, f, g, h = (st[j] for j in range(8))
-        wi = win[0]
-        s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
-        ch = (e & f) ^ (~e & g)
-        t1 = h + s1 + ch + k_arr[i] + wi
-        s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
-        maj = (a & b) ^ (a & c) ^ (b & c)
-        st = jnp.stack([t1 + s0 + maj, a, b, c, d + t1, e, f, g])
-        w15, w2 = win[1], win[14]
-        ws0 = _rotr(w15, 7) ^ _rotr(w15, 18) ^ (w15 >> 3)
-        ws1 = _rotr(w2, 17) ^ _rotr(w2, 19) ^ (w2 >> 10)
-        wnew = win[0] + ws0 + win[9] + ws1
-        return st, jnp.concatenate([win[1:], wnew[None]], axis=0)
+        new, ab = _round(tuple(st[j] for j in range(8)), st[8],
+                         k_arr[i], win[0])
+        wnew = _sum(*_schedule_terms(win, 16, _small_sigma))
+        return (jnp.stack([*new, ab]),
+                jnp.concatenate([win[1:], wnew[None]], axis=0))
 
-    st, _ = jax.lax.fori_loop(0, 64, body, (state, window))
-    return tuple(
-        jnp.asarray(m, jnp.uint32) + st[j] for j, m in enumerate(midstate)
-    )
+    st, _ = jax.lax.fori_loop(first, 64, body, (state, window))
+    return tuple(st[j] for j in range(8))
+
+
+def _nonce_words(tail_words, nonces, nonce_spec) -> dict:
+    """The tail words a nonce byte lands in, by index, each with its
+    little-endian nonce bytes in place.  Byte ``j`` (bits ``8j`` up) goes
+    to bits ``shift`` up by one shift, and a mask unless that shift
+    already dropped every other bit; the tail word itself is or-ed in
+    only where it has a byte of its own (none in a v2 header's w10)."""
+    parts: dict = {}
+    for j, (widx, shift) in enumerate(nonce_spec):
+        move = shift - 8 * j
+        byte = (nonces << jnp.uint32(move) if move > 0 else
+                nonces >> jnp.uint32(-move) if move < 0 else nonces)
+        if abs(move) != 24:
+            byte = byte & jnp.uint32(0xFF << shift)
+        parts.setdefault(widx, []).append(byte)
+    return {widx: functools.reduce(
+        lambda x, y: x | y,
+        bytes_ if len(bytes_) == 4 else [tail_words[widx], *bytes_])
+        for widx, bytes_ in parts.items()}
 
 
 def _build_w(tail_words, nonces, nonce_spec):
-    """Scatter little-endian nonce bytes into the 16 tail words."""
-    w = [jnp.broadcast_to(tail_words[i], nonces.shape) for i in range(16)]
-    for j, (widx, shift) in enumerate(nonce_spec):
-        byte = (nonces >> jnp.uint32(8 * j)) & jnp.uint32(0xFF)
-        w[widx] = w[widx] | (byte << jnp.uint32(shift))
-    return w
+    """The 16 tail words of every nonce, as full arrays."""
+    w = _nonce_words(tail_words, nonces, nonce_spec)
+    return [w[i] if i in w else jnp.broadcast_to(tail_words[i], nonces.shape)
+            for i in range(16)]
+
+
+def _search_digest(mid, tail, nonces, nonce_spec, unroll: bool | None = None):
+    """The digest words of ``nonces``' headers from a
+    :class:`SearchTemplate`'s two arrays (``mid``, ``tail``: anything
+    indexable by word, a traced array or an SMEM ref): the compression
+    of the tail block from the first round a nonce reaches, on the
+    hoisted state.
+
+    Unrolled (:func:`_compress_tail`'s rule), only the schedule words a
+    nonce reaches are built, each from its hoisted part, and every other
+    word enters its round as one scalar with ``K[i]`` folded in.  Rolled
+    (a CPU), the loop starts at that round over a window built in full
+    from the plain tail words."""
+    reached = nonce_reach(nonce_spec)
+    first = reached.index(True)
+    midstate = tuple(mid[j] for j in range(8))
+    state = tuple(mid[8 + j] for j in range(8))
+    if not _unrolled(unroll):
+        w = _build_w(tail, nonces, nonce_spec)
+        for i in range(16, 16 + first):
+            w.append(_sum(*_schedule_terms(w, i, _small_sigma)))
+        return _feed_forward(
+            midstate, _rounds_rolled(state, w[first:], first))
+    w = _nonce_words(tail, nonces, nonce_spec)
+    bc = state[1] ^ state[2]
+    for i in range(first, 64):
+        if not reached[i]:
+            state, bc = _round(state, bc, tail[16 + i])
+            continue
+        if i >= 16:
+            terms = _schedule_terms(w, i, _small_sigma, lambda j: reached[j])
+            if len(terms) < len(_SCHEDULE):  # else no term is nonce-free
+                terms.append(tail[16 + i])
+            w[i] = _sum(*terms)
+        state, bc = _round(state, bc, jnp.uint32(_K[i]), w[i])
+    return _feed_forward(midstate, state)
 
 
 def _lanes_in_range(lin, done, base, limit, batch: int):
@@ -352,9 +517,7 @@ def _pow_search_jnp(midstate, tail_words, span, batch: int,
         base, limit = span[0], span[1]
         lin = jnp.arange(batch, dtype=jnp.uint32)
         nonces = base + lin
-        state = tuple(midstate[i] for i in range(8))
-        w = _build_w(tail_words, nonces, nonce_spec)
-        digest = _compress_tail(state, w)
+        digest = _search_digest(midstate, tail_words, nonces, nonce_spec)
         t = [jnp.uint32(x)
              for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
         valid = _lanes_in_range(lin, jnp.uint32(0), base, limit, batch)
@@ -378,8 +541,10 @@ def pow_search_jnp(template: SearchTemplate, spec: TargetSpec,
 
 def _tile_digest(mid_ref, tail_ref, read_base, *, tile_rows: int, nonce_spec):
     """The hashing body both Pallas kernels share: this grid step's
-    (tile_rows, 128) nonce tile from ``program_id``, the nonce bytes
-    scattered into the tail words, one unrolled compression.
+    (tile_rows, 128) nonce tile from ``program_id`` and its digests
+    (:func:`_search_digest`, unrolled; ``mid_ref`` and ``tail_ref`` hold
+    a :class:`SearchTemplate`'s two arrays, read word by word as SMEM
+    scalars).
     ``read_base()`` loads the first nonce from wherever the kernel keeps
     it.  Returns (grid step, lane-linear index within the tile, nonces,
     digest)."""
@@ -391,14 +556,10 @@ def _tile_digest(mid_ref, tail_ref, read_base, *, tile_rows: int, nonce_spec):
     lin = (jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 0) * jnp.uint32(128)
            + jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 1))
     nonces = read_base() + jnp.uint32(i) * jnp.uint32(tile) + lin
-    state = tuple(mid_ref[j] for j in range(8))
-    w = [jnp.full((tile_rows, 128), tail_ref[j], dtype=jnp.uint32) for j in range(16)]
-    for j, (widx, shift) in enumerate(nonce_spec):
-        byte = (nonces >> jnp.uint32(8 * j)) & jnp.uint32(0xFF)
-        w[widx] = w[widx] | (byte << jnp.uint32(shift))
     # always unrolled here: the rolled form would capture the K table as a
-    # pallas_call constant, and Mosaic compiles the flat 64 rounds fast
-    return i, lin, nonces, _compress_tail(state, w, unroll=True)
+    # pallas_call constant, and Mosaic compiles the flat rounds fast
+    return i, lin, nonces, _search_digest(
+        mid_ref, tail_ref, nonces, nonce_spec, unroll=True)
 
 
 def _min_hit_into(out_ref, i, ok, nonces):

@@ -112,6 +112,8 @@ def _make_dispatcher(job: MiningJob, backend: str,
     telemetry.device.record_batch(
         "sha256_search", real=lanes, padded=lanes,
         compile_key=(batch, template.nonce_spec, spec))
+    telemetry.device.record_hoist(
+        "sha256_search", *sha_kernel.hoisted_counts(template.nonce_spec))
 
     def issue(start: int, count: int, width: int):
         # dispatch ISSUANCE goes through the device owner (so miner

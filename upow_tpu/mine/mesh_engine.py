@@ -255,6 +255,8 @@ class MeshEngine:
                 sha_kernel.pack_target(spec)))
         self._job_layouts += 1
         telemetry.inc("mine.mesh.job_layouts")
+        _ktel.record_hoist(
+            "mine_mesh", *sha_kernel.hoisted_counts(template.nonce_spec))
         self._nonce_spec = template.nonce_spec
         self._job_key = key
         self._job_t0 = time.perf_counter()

@@ -79,9 +79,7 @@ def _pow_search_mesh(midstate, tail_words, nonce_base, batch_per_device: int,
         idx = jax.lax.axis_index("dp")
         my_base = base[0] + jnp.uint32(idx) * jnp.uint32(batch_per_device)
         nonces = my_base + jnp.arange(batch_per_device, dtype=jnp.uint32)
-        state = tuple(mid[i] for i in range(8))
-        w = sha_kernel._build_w(tail, nonces, nonce_spec)
-        digest = sha_kernel._compress_tail(state, w)
+        digest = sha_kernel._search_digest(mid, tail, nonces, nonce_spec)
         t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
         hit = sha_kernel._hit_nonce(digest, nonces, *t, spec)
         return jax.lax.pmin(hit.reshape(1), "dp")
@@ -149,9 +147,7 @@ def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
         # u32 wrap past 2**32 makes a lane compare below my_base: both
         # wrapped and past-limit lanes drop out of the same mask
         valid = (nonces >= my_base) & (nonces < my_limit)
-        state = tuple(mid[i] for i in range(8))
-        w = sha_kernel._build_w(tail, nonces, nonce_spec)
-        digest = sha_kernel._compress_tail(state, w)
+        digest = sha_kernel._search_digest(mid, tail, nonces, nonce_spec)
         hit = sha_kernel._hit_nonce_dynamic(digest, nonces, tgt, valid)
         return jax.lax.pmin(hit.reshape(1), "dp")
 

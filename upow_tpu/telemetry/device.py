@@ -14,6 +14,9 @@ kernel:
   given compile key (padded shape / static args) compiles, later
   ones reuse the traced program.
 
+``record_hoist`` adds, for the sha256 search kernels, once a job,
+``kernel.<name>.rounds_hoisted`` and ``kernel.<name>.words_hoisted``.
+
 Device memory gauges are best-effort: ``memory_stats()`` is populated
 on TPU/GPU backends and typically absent on CPU; we never import jax
 here — if the caller hasn't, there is nothing to report."""
@@ -70,6 +73,17 @@ def record_batch(kernel: str, real: int, padded: int,
                 seen.add(compile_key)
         metrics.inc("kernel.%s.compile_cache_%s"
                     % (kernel, "hits" if hit else "misses"))
+
+
+def record_hoist(kernel: str, rounds: int, words: int) -> None:
+    """Record, once a job, how much of the tail block's compression the
+    host finished for the job's nonce placement and the search kernel
+    therefore leaves out: ``rounds`` of the 64 and ``words`` of the 48
+    schedule words (``crypto/sha256.hoisted_counts``; 10 and 4 for a v2
+    header).  Over the kernel's jobs: 0 would say the kernel hashes
+    every round of every nonce again."""
+    metrics.inc("kernel.%s.rounds_hoisted" % kernel, int(rounds))
+    metrics.inc("kernel.%s.words_hoisted" % kernel, int(words))
 
 
 def preregister_stage(stage: str) -> None:
