@@ -171,10 +171,13 @@ def test_build_job_defaults_genesis():
         "pending_transactions_hashes": [],
         "merkle_root": hashlib.sha256(b"").hexdigest(),
     }
-    job, hashes, block_no = build_job(info, point_to_string(pub))
+    job, hashes, block_no, stamp = build_job(info, point_to_string(pub))
     assert block_no == 1
     assert hashes == []
     assert job.previous_hash == (18_884_643).to_bytes(32, "little").hex()
+    # no previous timestamp served: one second, the clock's
+    assert (stamp.behind_s, stamp.window_s, stamp.repeat) == (0, 1, 0)
+    assert job.prefix[-6:-2] == stamp.timestamp.to_bytes(4, "little")
 
 
 def test_fetch_mining_info_unwraps_node_errors(monkeypatch):
@@ -232,8 +235,8 @@ def test_supervisor_respawns_hung_child(tmp_path):
         def fake_fetch(node):
             return {{"difficulty": "1.0"}}
 
-        def fake_build(info, address):
-            return object(), [], 1
+        def fake_build(info, address, roll):
+            return object(), [], 1, miner.Stamp(0, 0, 1, 0)
 
         def hang(job, backend, **kw):
             time.sleep(600)

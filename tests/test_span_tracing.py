@@ -8,6 +8,7 @@ import hashlib
 import importlib.util
 import os
 import random
+import re
 import signal
 import subprocess
 import sys
@@ -341,8 +342,8 @@ def _scripted_node(monkeypatch, difficulty, pushes):
 
 
 @pytest.mark.parametrize("difficulty,ttl,kinds,rc", [
-    (1.0, 90.0, ["start", "job", "found", None, "mined"], 0),
-    (9.0, 0.0, ["start", "job", "round", "expired"], 1),
+    (1.0, 90.0, ["start", "job", None, "found", None, "mined"], 0),
+    (9.0, 0.0, ["start", "job", None, "round", "expired"], 1),
 ])
 def test_the_miners_lines_are_the_ones_the_benchmark_parses(
         monkeypatch, capsys, difficulty, ttl, kinds, rc):
@@ -365,6 +366,9 @@ def test_the_miners_lines_are_the_ones_the_benchmark_parses(
                       "nonces=[0, 4294967296) node=http://x/")
     assert out[1] == (f"difficulty: {difficulty}  block: 42  "
                       "confirming 2 transactions")
+    # the benchmark's older drivers pass over the line they do not know
+    assert re.fullmatch(r"header: timestamp=\d+ behind=0 window=1 repeat=0",
+                        out[2]), out[2]
     assert _counter("mine.jobs") == before["mine.jobs"] + 1
     if rc == 0:
         assert out[-3:] == ["{'ok': True}", "BLOCK MINED", ""]

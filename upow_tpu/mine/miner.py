@@ -16,6 +16,16 @@ that is no multiple of the batch is short (at the default ``--shard 0/1``
 the 256th has 2^24 - 1: nonce 2^32 - 1 is the searchers' no-hit sentinel
 and is never tried), runs the same device program with its surplus lanes
 masked, and counts its live nonces only.
+
+The header's timestamp is the miner's one extra nonce: the node takes any
+second in ``(previous block's timestamp, now]`` (verify/block.py), and a
+miner that spends its range in under a second would otherwise build the
+header it has just swept once more.  So within one tip, merkle root,
+difficulty and address a job is stamped with the newest second of that
+window this process has not swept yet (``HeaderRoll``), ``now`` first;
+the ``header:`` line after the ``difficulty:`` line says which, how far
+behind ``now`` it lies, how long the window is and whether it had to
+repeat (the window held no fresh second: then ``now``, as ever).
 """
 
 from __future__ import annotations
@@ -26,7 +36,7 @@ import sys
 import time
 import urllib.error
 import urllib.request
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from .. import telemetry
 from ..core.clock import timestamp
@@ -54,19 +64,78 @@ def fetch_mining_info(node: str) -> dict:
     return res["result"]
 
 
-def build_job(info: dict, address: str) -> tuple:
+#: one of the three a job, so their sum is ``mine.jobs``
+ROLL_COUNTERS = FRESH, ROLLED, REPEATED = (
+    "mine.headers_fresh", "mine.headers_rolled", "mine.headers_repeated")
+
+
+class Stamp(NamedTuple):
+    """The timestamp a job's header carries, and how it was come by."""
+
+    timestamp: int
+    behind_s: int   # now - timestamp: 0 unless rolled back
+    window_s: int   # now - the previous block's timestamp
+    repeat: int     # 1: the window held no fresh second, so ``now`` again
+
+
+def choose_timestamp(prev_ts: int, now: int, swept) -> tuple:
+    """(second, repeat): the newest second in ``(prev_ts, now]`` that is
+    not in ``swept``, or, where none is left, ``now`` once more.  ``now``
+    itself is always a candidate: where the window is empty (a node whose
+    clock runs ahead of this one) the miner stamps ``now`` as it always
+    has."""
+    for second in range(now, min(prev_ts, now - 1), -1):
+        if second not in swept:
+            return second, False
+    return now, True
+
+
+class HeaderRoll:
+    """The seconds this process has stamped a header with, and so swept
+    its nonce range under, for one ``key`` = (previous hash, merkle root,
+    difficulty, address).  Dropped when the key changes; every member
+    lies in the key's window ``(prev_ts, now]``, whose length bounds it."""
+
+    def __init__(self):
+        self.key = None
+        self.swept: set = set()
+
+    def stamp(self, key: tuple, prev_ts: int, now: int) -> Stamp:
+        if key != self.key:
+            self.key, self.swept = key, set()
+        second, repeat = choose_timestamp(prev_ts, now, self.swept)
+        # swept from here on, whatever ends the job: a job the TTL cuts
+        # short began at the range's start, and so would its twin
+        self.swept.add(second)
+        telemetry.inc(REPEATED if repeat else
+                      ROLLED if second < now else FRESH)
+        return Stamp(second, now - second, now - prev_ts, int(repeat))
+
+
+def build_job(info: dict, address: str,
+              roll: Optional[HeaderRoll] = None) -> tuple:
+    """(job, pending hashes, block number, Stamp).  ``roll`` is what the
+    caller has swept so far; without one the job is stamped ``now``."""
     last_block = dict(info["last_block"])
     last_block.setdefault("hash", GENESIS_PREV_HASH)
     last_block.setdefault("id", 0)
     pending_hashes = info["pending_transactions_hashes"]
+    merkle_root = miner_merkle_root(pending_hashes)
+    now = timestamp()
+    with telemetry.span("mine.roll", light=True):
+        # a node that names no previous timestamp leaves one second
+        stamp = (roll or HeaderRoll()).stamp(
+            (last_block["hash"], merkle_root, str(info["difficulty"]),
+             address),
+            int(last_block.get("timestamp", now - 1)), now)
     job = MiningJob.from_header_fields(
         previous_hash=last_block["hash"],
         address=address,
-        merkle_root=miner_merkle_root(pending_hashes),
-        timestamp=timestamp(),
+        merkle_root=merkle_root,
+        timestamp=stamp.timestamp,
         difficulty=info["difficulty"],
     )
-    return job, pending_hashes, last_block["id"] + 1
+    return job, pending_hashes, last_block["id"] + 1, stamp
 
 
 def push_block(node: str, block_content: str, txs: list, block_no: int) -> dict:
@@ -174,6 +243,9 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
                  "limit": ttl + hang_grace + first_round_grace}
     if backend in ("pallas", "jnp", "mesh") and not once:
         _start_hang_watchdog(heartbeat, ttl + hang_grace)
+    roll = HeaderRoll()
+    for name in ROLL_COUNTERS:   # exported at zero from the first scrape
+        telemetry.ensure_counter(name)
 
     def progress(tried, elapsed):
         heartbeat["t"] = time.monotonic()
@@ -196,15 +268,20 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
             print(f"node unreachable: {e}; retrying", file=sys.stderr)
             return None
         with telemetry.span("mine.build_job") as built:
-            job, pending_hashes, block_no = build_job(info, address)
+            job, pending_hashes, block_no, stamp = build_job(
+                info, address, roll)
             if built is not None:
-                built.fields["pending"] = len(pending_hashes)
+                built.fields.update(stamp._asdict(),
+                                    pending=len(pending_hashes))
         telemetry.inc("mine.jobs")
         root.fields.update(
+            stamp._asdict(),
             block=block_no, difficulty=str(info["difficulty"]),
             tip=str(getattr(job, "previous_hash", ""))[-12:])
         print(f"difficulty: {info['difficulty']}  block: {block_no}  "
               f"confirming {len(pending_hashes)} transactions")
+        print(f"header: timestamp={stamp.timestamp} behind={stamp.behind_s} "
+              f"window={stamp.window_s} repeat={stamp.repeat}")
         result = mine(job, backend, start=lo, stride_end=hi, batch=batch,
                       ttl=ttl, progress=progress, mesh_devices=mesh_devices)
         if result.nonce is None:
