@@ -402,7 +402,7 @@ def phase_a(args, work: str, wallet: Wallet, miner_device: str):
             f"difficulty={t['difficulty']} txs={t['pending'] - after['pending']}"
             f" nonce={rec['nonce']} reported={rec['mhs']} MH/s "
             f"({rec['hashes']} hashes in {rec['search_s']}s, first dispatch "
-            f"= compile {rec['first_dispatch_s']}s) process={rec['seconds']}s "
+            f"{rec['first_dispatch_s']}s) process={rec['seconds']}s "
             f"device={rec['device']}")
         return rec
 

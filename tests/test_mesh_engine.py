@@ -13,10 +13,17 @@ accounting.  A job's three arrays are laid over the engine's mesh once a
 job (ISSUE 31): committed, replicated, the sharding the arm's warm
 dispatch compiled for, so the resident program's jit cache holds one
 entry from the arm on and ``mine.mesh.job_layouts`` counts jobs, not
-rounds.
+rounds.  ``--device tpu`` is this engine on a mesh of one device (ISSUE
+39): what ``select_backend`` hands ``mine()``, the one program over jobs
+of other tips and difficulties, the ragged last round under
+``mine.round.tail``, and the top of the nonce space held to the
+benchmark's plain reference ``benchmarks/harness/powref.py``.
 """
 
+import importlib.util
+import os
 import random
+import sys
 
 import jax
 import pytest
@@ -24,7 +31,7 @@ import pytest
 from upow_tpu import telemetry
 from upow_tpu.crypto import SENTINEL, make_template, pow_search_jnp, target_spec
 from upow_tpu.mine import mesh_engine
-from upow_tpu.mine.engine import MiningJob, mine
+from upow_tpu.mine.engine import MAX_SEARCH_END, NONCE_SPACE, MiningJob, mine
 from upow_tpu.mine.mesh_engine import (MeshEngine, get_mesh_engine,
                                        reset_mesh_engine)
 from upow_tpu.telemetry import metrics
@@ -469,3 +476,221 @@ def test_engine_stats_exported_for_node_gauges():
     assert st["armed"] and st["devices"] == 8
     assert st["capacity"] == eng.capacity
     assert st["job_layouts"] == 0 and st["jit_entries"] >= 1
+
+
+# ------------------------- --device tpu: the engine on a one-device mesh ----
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def powref():
+    bench = os.path.join(REPO, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "harness.powref", os.path.join(bench, "harness", "powref.py"))
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+        return module
+    finally:
+        sys.path.remove(bench)
+
+
+def _tail_spans() -> int:
+    return telemetry.stats().get("mine.round.tail", {}).get("count", 0)
+
+
+def _one_device_engine(body: str, batch: int) -> MeshEngine:
+    """The process-wide engine ``mine(.., "mesh", mesh_devices=1)`` will
+    meet, armed over one device with a round of ``batch`` lanes; the
+    Pallas body (what the chip runs) in interpret mode, a shard of two
+    tiles."""
+    if body == "pallas":
+        mesh_engine._ENGINE = MeshEngine(
+            mesh_devices=1, batch_per_device=batch, interpret=True)
+    eng = get_mesh_engine(mesh_devices=1, round_hint=batch)
+    assert eng.arm()["armed"]
+    assert (eng.n_devices, eng.capacity, eng.stats()["body"]) == (
+        1, batch, body)
+    return eng
+
+
+@pytest.mark.parametrize("device,configured,want", [
+    ("tpu", 0, ("mesh", 1)),      # one chip, whatever the config says
+    ("tpu", 4, ("mesh", 1)),
+    ("mesh", 0, ("mesh", 0)),     # device.mesh_devices, 0 = all
+    ("mesh", 4, ("mesh", 4)),
+    ("pallas", 4, ("pallas", 4)),  # the static engine: no mesh to size
+])
+def test_select_backend_gives_tpu_the_resident_engine_over_one_device(
+        device, configured, want):
+    from upow_tpu.mine.miner import select_backend
+
+    assert select_backend(device, configured) == want
+
+
+def test_device_tpu_mines_through_the_resident_engine_on_one_device():
+    """What ``run`` does with ``--device tpu`` on a host of eight devices
+    configured for all of them: the resident engine over the first one,
+    a round of ``batch`` lanes on it, the nonce the python loop finds."""
+    from upow_tpu.mine.miner import select_backend
+
+    backend, mesh_devices = select_backend("tpu", 0)
+    job = _seeded_job(55, difficulty="1")
+    kw = dict(start=0, stride_end=1 << 14, batch=1 << 10, ttl=60.0)
+    got = mine(job, backend, mesh_devices=mesh_devices, **kw)
+    eng = get_mesh_engine(mesh_devices=1)
+    assert eng.armed and eng.stats()["dispatches"] > 0   # the one that mined
+    assert eng.mesh_devices() == jax.devices()[:1]
+    assert (eng.n_devices, eng.batch_per_device) == (1, 1 << 10)
+    want = mine(job, "python", **kw)
+    assert got.nonce == want.nonce and job.check(got.nonce)
+    assert got.hashes_tried == want.hashes_tried
+    # the static engine's per-tip programs: exported, and none
+    counters = metrics.counters()
+    assert counters["kernel.sha256_search.compile_cache_misses"] == 0
+    assert counters["kernel.mine_mesh.compile_cache_misses"] == 1
+    assert metrics.histograms()["mine.hit_latency"]["count"] == 1
+
+
+@pytest.mark.parametrize("body", ["jnp", "pallas"])
+def test_two_tips_on_one_device_are_one_program(body):
+    """Two jobs of other previous hashes and difficulties (a target of
+    whole chars, one with a fractional char) through ``mine()`` on a
+    one-device mesh: the arm's one jit entry serves both, the compile
+    key misses once, every round is counted for the body it ran."""
+    # per-shard batches no other test compiles: the arm's entry is new
+    batch = 3072 if body == "pallas" else 832
+    before = _jit_entries()
+    eng = _one_device_engine(body, batch)
+    armed = _jit_entries()
+    rounds = 0
+    for seed, difficulty in ((21, "1"), (22, "1.5")):
+        job = _seeded_job(seed, difficulty=difficulty)
+        got = mine(job, "mesh", mesh_devices=1, start=seed * batch,
+                   stride_end=(seed + 2) * batch, batch=batch, ttl=60.0)
+        want = next((n for n in range(seed * batch, (seed + 2) * batch)
+                     if job.check(n)), None)
+        assert got.nonce == want
+        rounds += -(-got.hashes_tried // batch)
+    assert armed == before + 1 and _jit_entries() == armed
+    st = eng.stats()
+    assert st["jit_entries"] == armed and st["job_layouts"] == 2
+    counters = metrics.counters()
+    assert counters["kernel.mine_mesh.compile_cache_misses"] == 1
+    assert counters["kernel.sha256_search.compile_cache_misses"] == 0
+    assert counters["mine.mesh.job_layouts"] == 2
+    assert counters["mine.rounds"] == rounds
+    # in-flight rounds past a hit were dispatched too
+    assert counters["mine.mesh.rounds_pallas"] == (
+        st["dispatches"] if body == "pallas" else 0)
+    assert st["dispatches"] >= rounds
+
+
+@pytest.mark.parametrize("body", ["jnp", "pallas"])
+def test_a_ragged_last_round_on_one_device_issues_under_mine_round_tail(
+        body, powref):
+    """Two whole rounds and one of 100 short: the short one runs the one
+    program with its end in the ``ranges`` row, opens ``mine.round.tail``
+    once and is counted; a hit that lies only in it is the python
+    reference's lowest, and the range cut below that hit ends with
+    none."""
+    batch = 2048
+    eng = _one_device_engine(body, batch)
+    job = _seeded_job(77, difficulty="3")
+    hits = [n for n in range(1 << 17) if job.check(n)]
+    hit = next(h for below, h in zip(hits, hits[1:])
+               if h - below > 2 * batch + 50)
+    start = hit - (2 * batch + 50)
+    entries, tails = _jit_entries(), _tail_spans()
+    found = mine(job, "mesh", mesh_devices=1, batch=batch, start=start,
+                 stride_end=hit + 50, ttl=60.0)
+    assert found.nonce == hit == powref.lowest_hit(
+        job.prefix, start, hit + 50, job.previous_hash, job.difficulty,
+        workers=1)
+    assert found.hashes_tried == 2 * batch + 100
+    assert _tail_spans() - tails == 1
+    counters = metrics.counters()
+    assert counters["mine.rounds_masked"] == 1
+    assert counters["mine.lanes_masked"] == batch - 100
+    assert counters["mine.rounds"] == 3
+    assert counters["mine.nonces"] == 2 * batch + 100
+    assert eng.stats()["rounds"][-1]["shards"] == [(hit - 50, hit + 50)]
+    missed = mine(job, "mesh", mesh_devices=1, batch=batch, start=start,
+                  stride_end=hit, ttl=60.0)
+    assert missed.nonce is None and missed.hashes_tried == 2 * batch + 50
+    assert _tail_spans() - tails == 2
+    assert metrics.counters()["mine.rounds_masked"] == 2
+    assert _jit_entries() == entries
+
+
+def test_a_whole_round_on_the_mesh_opens_no_tail_span():
+    batch = 512
+    _one_device_engine("jnp", batch)
+    tails = _tail_spans()
+    result = mine(_seeded_job(9, difficulty="9"), "mesh", mesh_devices=1,
+                  batch=batch, start=0, stride_end=3 * batch, ttl=60.0)
+    assert result.nonce is None and result.hashes_tried == 3 * batch
+    assert _tail_spans() == tails
+    assert metrics.counters().get("mine.rounds_masked", 0) == 0
+
+
+def test_the_full_range_plan_on_one_device_is_255_rounds_and_a_ragged_one():
+    """``[0, 2^32 - 1)`` in rounds of 2^24 on one device: each round is
+    one ``ranges`` row, ``[start, start + count)`` itself, the 256th one
+    short; the rows tile the range and the sentinel is in none."""
+    batch = 1 << 24
+    eng = MeshEngine(mesh_devices=1, batch_per_device=batch)
+    eng._n_dev = 1          # the plan needs no armed program
+    rows, cursor = [], 0
+    while cursor < MAX_SEARCH_END:
+        count = min(batch, MAX_SEARCH_END - cursor)
+        (row,) = eng.plan_round(cursor, count)
+        rows.append(row)
+        cursor += count
+    assert len(rows) == 256
+    assert all(hi - lo == batch for lo, hi in rows[:-1])
+    assert rows[-1] == (255 * batch, NONCE_SPACE - 1)      # 2^24 - 1 lanes
+    assert rows[0][0] == 0 and all(
+        a[1] == b[0] for a, b in zip(rows, rows[1:]))
+    assert int(SENTINEL) not in range(*rows[-1])
+
+
+@pytest.mark.parametrize("body", ["jnp", "pallas"])
+def test_the_top_of_the_nonce_space_on_one_device_matches_powref(
+        body, powref):
+    """Bases above 2^31, the ragged round that ends at the sentinel's
+    neighbour 2^32 - 2, and that neighbour alone: what the one-device
+    mesh answers is what ``powref.lowest_hit`` finds by hashlib (−1
+    where it finds none: the engine's SENTINEL)."""
+    batch = 2048
+    eng = _one_device_engine(body, batch)
+    # a job whose header at nonce 2^32 - 2 meets its target: the last
+    # lane ever searched has to answer
+    job = next(j for j in (_seeded_job(s, difficulty="1.5")
+                           for s in range(1000, 1400))
+               if j.check(MAX_SEARCH_END - 1))
+    eng.set_job(job)
+
+    def reference(lo, hi):
+        ref = powref.lowest_hit(job.prefix, lo, hi, job.previous_hash,
+                                job.difficulty, workers=1)
+        return int(SENTINEL) if ref < 0 else ref
+
+    top = MAX_SEARCH_END
+    for start, count in (((1 << 31) + 12345, batch),
+                         (top - batch - 777, batch),
+                         (top - 1349, 1349),          # the ragged round
+                         (top - 1, 1)):               # the neighbour alone
+        assert int(eng.dispatch(start, count)) == reference(
+            start, start + count), (start, count)
+    assert int(eng.dispatch(top - 1, 1)) == top - 1
+    # and through mine(): the cap takes the sentinel off the range's end
+    tails = _tail_spans()
+    hard = _seeded_job(1234, difficulty="9")
+    result = mine(hard, "mesh", mesh_devices=1, batch=batch,
+                  start=NONCE_SPACE - 2 * batch - 1, stride_end=NONCE_SPACE,
+                  ttl=60.0)
+    assert result.nonce is None and result.hashes_tried == 2 * batch
+    assert _tail_spans() == tails

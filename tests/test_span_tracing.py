@@ -181,15 +181,26 @@ def test_a_job_counts_its_rounds_and_keeps_its_tree_small():
     assert grew("runtime.call") == rounds
 
 
+@pytest.fixture
+def no_mesh_engine_left():
+    from upow_tpu.mine.mesh_engine import reset_mesh_engine
+
+    yield
+    reset_mesh_engine()
+
+
 @pytest.mark.parametrize("length,tails", [(3 * 256 + 40, 1), (3 * 256, 0)])
-def test_a_masked_round_issues_under_mine_round_tail(fake_annotations,
-                                                     length, tails):
+@pytest.mark.parametrize("backend", ["jnp", "mesh"])
+def test_a_masked_round_issues_under_mine_round_tail(
+        fake_annotations, no_mesh_engine_left, backend, length, tails):
     """``mine.round.tail`` opens inside the ``mine.round.issue`` of a
     round shorter than the program, once a job, and never over a range
-    that ends on a whole round."""
+    that ends on a whole round: on the static-target engine and on the
+    resident one over one device (``--device tpu``) alike."""
     agg = telemetry.stats().get("mine.round.tail", {}).get("count", 0)
     with telemetry.request_trace("mine.job") as root:
-        result = mine(_job("9"), "jnp", batch=256, stride_end=length)
+        result = mine(_job("9"), backend, batch=256, stride_end=length,
+                      mesh_devices=1)
     assert result.hashes_tried == length
     assert telemetry.stats().get("mine.round.tail", {}).get("count", 0) \
         - agg == tails

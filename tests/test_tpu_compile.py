@@ -159,29 +159,31 @@ def test_utxo_probe_kernel_compiles(one_chip, no_compile_cache):
 
 # ------------------------------------------------------- mesh search ----
 
-def test_mesh_resident_search_compiles_on_four_devices(topo,
-                                                       no_compile_cache):
-    """`miner --device mesh` over the four chips of one host: one SPMD
-    program, a quarter of the default round on each device, each shard
-    searched by the Pallas kernel with target and range as SMEM data
-    (the body is chosen from the mesh's platform), the hit reduced by a
-    collective."""
+@pytest.mark.parametrize("n", [4, 1])
+def test_mesh_resident_search_compiles_on_a_mesh_of(n, topo,
+                                                    no_compile_cache):
+    """`miner --device mesh` over the four chips of one host, and
+    `--device tpu`, the same program on a mesh of one: one SPMD program,
+    the default round split evenly over the devices, each shard searched
+    by the Pallas kernel with target and range as SMEM data (the body is
+    chosen from the mesh's platform), the hit reduced by a collective
+    where there is more than one shard."""
     from upow_tpu.config import DeviceConfig
     from upow_tpu.crypto import sha256 as sk
     from upow_tpu.parallel import mesh as pm
 
-    devices = topo.devices[:4]
-    assert len(devices) == 4
+    devices = topo.devices[:n]
+    assert len(devices) == n
     mesh = Mesh(np.array(devices), axis_names=("dp",))
     assert pm.resident_body(mesh) == "pallas"
     rep = NamedSharding(mesh, P())
     dp = NamedSharding(mesh, P("dp"))
     words = sk.RESIDENT_OPERAND_WORDS
-    assert sk.resident_operand([[1, 2]] * 4).shape == (4, words)
+    assert sk.resident_operand([[1, 2]] * n).shape == (n, words)
     compiled = pm._pow_search_mesh_resident.lower(
         _shape((words,), jnp.uint32, rep), _shape((words,), jnp.uint32, rep),
-        _shape((4, words), jnp.uint32, dp), _shape((words,), jnp.uint32, rep),
-        batch_per_device=DeviceConfig().search_batch // 4,
+        _shape((n, words), jnp.uint32, dp), _shape((words,), jnp.uint32, rep),
+        batch_per_device=DeviceConfig().search_batch // n,
         nonce_spec=sk.make_template(bytes(104)).nonce_spec,
         mesh=mesh).compile()
     text = compiled.as_text()
@@ -192,5 +194,6 @@ def test_mesh_resident_search_compiles_on_four_devices(topo,
     # device operation (sha256.RESIDENT_OPERAND_WORDS)
     assert "copy-start" not in text and " copy(" not in text, \
         "XLA stages a kernel operand into scalar memory on every round"
-    assert "all-reduce" in text or "all_reduce" in text, \
-        "the pmin over dp must survive as a collective"
+    if n > 1:
+        assert "all-reduce" in text or "all_reduce" in text, \
+            "the pmin over dp must survive as a collective"

@@ -8,14 +8,19 @@ template TTL maps to a wall-clock budget checked between rounds), with the
 host polling the round result for an early exit.
 
 Backends:
-    pallas  — Pallas TPU kernel (production path on TPU)
-    jnp     — pure jax.numpy/XLA (any device; also the CPU-mesh test path)
+    mesh    — the resident program (mesh_engine.py): target, template and
+              range are data, compiled once a process; the path on a TPU,
+              one chip (``--device tpu``) or several (``--device mesh``)
+    pallas  — Pallas TPU kernel with the target static: a program a tip
+              (``--device pallas``; goes with ROADMAP D2)
+    jnp     — pure jax.numpy/XLA, target static (any device)
     native  — C++ midstate loop via ctypes (fast host fallback)
     python  — hashlib loop (reference-shaped, last resort / oracle)
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import time
 from dataclasses import dataclass
@@ -67,6 +72,16 @@ class MiningJob:
         return check_pow_hash(digest, self.previous_hash, self.difficulty)
 
 
+@contextlib.contextmanager
+def _masked_round(width: int, count: int):
+    """The issue of a round of ``count`` nonces on a program of ``width``
+    lanes: counted, and under the light span ``mine.round.tail``."""
+    telemetry.inc("mine.rounds_masked")
+    telemetry.inc("mine.lanes_masked", width - count)
+    with telemetry.span("mine.round.tail", light=True):
+        yield
+
+
 def _make_dispatcher(job: MiningJob, backend: str,
                      mesh_devices: int = 0,
                      batch: Optional[int] = None) -> Optional[Callable]:
@@ -74,12 +89,14 @@ def _make_dispatcher(job: MiningJob, backend: str,
 
     The handle resolves via ``int()``; keeping several dispatches in
     flight hides the host↔device round-trip (which otherwise caps the
-    hash rate).  ``count`` is the round's live lanes: on the pallas and
-    jnp backends every round of a job runs the program of ``batch``
-    lanes, and one with ``count < batch`` (a masked round: the last of a
-    range that is no multiple of ``batch``) passes ``start + count`` as
-    the limit, is counted in ``mine.rounds_masked`` / ``mine.lanes_masked``
-    and issues under the light span ``mine.round.tail``.
+    hash rate).  ``count`` is the round's live lanes: every round of a
+    job runs the one program, of ``batch`` lanes on the pallas and jnp
+    backends and of the engine's capacity on the mesh, and one with fewer
+    (a masked round: the last of a range that is no multiple of
+    ``batch``) passes its end as data (``start + count`` as the limit;
+    on the mesh the ``ranges`` row of each shard), is counted in
+    ``mine.rounds_masked`` / ``mine.lanes_masked`` and issues under the
+    light span ``mine.round.tail``.
 
     ``backend='mesh'`` routes rounds through the resident mesh engine
     (mesh_engine.py): one compiled SPMD program per process whose
@@ -101,7 +118,16 @@ def _make_dispatcher(job: MiningJob, backend: str,
         # (kernel "sha256_search_mesh", source "mine") and keeps the
         # per-round shard accounting
         engine = get_mesh_engine(mesh_devices=mesh_devices, round_hint=batch)
-        return engine.dispatcher(job)
+        issue_round = engine.dispatcher(job)
+        capacity = engine.capacity
+
+        def dispatch_mesh(start: int, count: int):
+            if count >= capacity:
+                return issue_round(start, count)
+            with _masked_round(capacity, count):
+                return issue_round(start, count)
+
+        return dispatch_mesh
     template = sha_kernel.make_template(job.prefix)
     spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
     fn = sha_kernel.pow_search_pallas if backend == "pallas" else sha_kernel.pow_search_jnp
@@ -136,11 +162,9 @@ def _make_dispatcher(job: MiningJob, backend: str,
             return issue(start, count, width)
         # a masked round: lanes at or past start + count are hashed and
         # never answer (crypto/sha256.py _lanes_in_range)
-        telemetry.inc("mine.rounds_masked")
-        telemetry.inc("mine.lanes_masked", width - count)
         telemetry.device.record_batch("sha256_search", real=count,
                                       padded=width)
-        with telemetry.span("mine.round.tail", light=True):
+        with _masked_round(width, count):
             return issue(start, count, width)
 
     return dispatch

@@ -1,8 +1,11 @@
 """Persistent mesh-sharded nonce search: one resident SPMD program.
 
-The single-device dispatcher in :mod:`.engine` recompiles nothing per
-round but holds no mesh: on a v5e-8 seven chips idle while one scans.
-This module owns the multi-device path:
+The static-target dispatcher in :mod:`.engine` (``--device pallas``)
+recompiles nothing per round but traces and compiles a program for
+every tip, and holds no mesh: on a v5e-8 seven chips idle while one
+scans.  This module owns the path the miner runs on a chip: ``--device
+tpu`` on a mesh of one device, ``--device mesh`` over
+``device.mesh_devices`` of them:
 
 * **One compiled program** — ``parallel.mesh._pow_search_mesh_resident``
   is jitted once per (batch_per_device, nonce_spec, mesh) at arm time.
@@ -192,6 +195,9 @@ class MeshEngine:
         # such counter"
         telemetry.ensure_counter("mine.mesh.rounds_pallas")
         telemetry.ensure_counter("mine.mesh.job_layouts")
+        # the static-target engine's programs, one a tip: 0 for as long
+        # as the process runs this engine alone
+        telemetry.ensure_counter("kernel.sha256_search.compile_cache_misses")
         if self._batch_per_device is None:
             if self._round_hint:
                 # ceil: one round of round_hint nonces must fit capacity

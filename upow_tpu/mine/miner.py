@@ -146,16 +146,23 @@ def push_block(node: str, block_content: str, txs: list, block_no: int) -> dict:
     )
 
 
-def select_backend(device: str) -> str:
-    if device in ("pallas", "jnp", "native", "python", "mesh"):
-        return device
+def select_backend(device: str, mesh_devices: int = 0) -> tuple:
+    """(backend, mesh size) of ``--device``.  ``tpu`` is the resident
+    program (mesh_engine.py: target, template and range are data, one
+    compile a process) on a mesh of one device, the chip the static
+    engine used, whatever ``device.mesh_devices`` says; ``mesh`` takes
+    ``mesh_devices`` (0 = every visible device); ``pallas`` is the
+    static-target engine, one trace and compile a tip.  The mesh size
+    means nothing to the other backends and rides along unchanged."""
     if device == "tpu":
-        return "pallas"
+        return "mesh", 1
     if device == "cpu":
         from .. import native
 
-        return "native" if native.load() is not None else "jnp"
-    raise SystemExit(f"unknown device {device!r}")
+        device = "native" if native.load() is not None else "jnp"
+    elif device not in ("pallas", "jnp", "native", "python", "mesh"):
+        raise SystemExit(f"unknown device {device!r}")
+    return device, mesh_devices
 
 
 def _runtime_arm_reason() -> Optional[str]:
@@ -230,7 +237,7 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
         shard: tuple = (0, 1), once: bool = False,
         mesh_devices: int = 0, hang_grace: float = 90.0,
         first_round_grace: float = 240.0) -> int:
-    backend = select_backend(device)
+    backend, mesh_devices = select_backend(device, mesh_devices)
     i, k = shard
     from ..parallel.multihost import plan_nonce_ranges
 
@@ -522,8 +529,12 @@ def main(argv=None) -> int:
                     help="reference-compatible positional node URL")
     ap.add_argument("--node", default="http://localhost:3006/")
     ap.add_argument("--device", default="tpu",
-                    help="tpu|cpu or explicit backend "
-                         "pallas|jnp|mesh|native|python")
+                    help="tpu: the resident search program on one chip, "
+                         "target as data, no compile a tip; mesh: the same "
+                         "program over device.mesh_devices chips (0 = all); "
+                         "cpu; or an explicit backend pallas (the "
+                         "static-target engine, one compile a tip; kept "
+                         "until ROADMAP D2)|jnp|native|python")
     ap.add_argument("--batch", type=int, default=0,
                     help="nonces per dispatch (0 = config device.search_batch)")
     ap.add_argument("--ttl", type=float, default=90.0)
@@ -541,8 +552,8 @@ def main(argv=None) -> int:
         return _run_workers(args)
     import os
 
-    if (not args.once and select_backend(args.device) in ("pallas", "jnp",
-                                                          "mesh")
+    if (not args.once
+            and select_backend(args.device)[0] in ("pallas", "jnp", "mesh")
             and not os.environ.get("UPOW_MINER_CHILD")):
         # device backends run supervised: the hang watchdog hard-exits a
         # child stuck in a dispatch that never returns, and this loop
