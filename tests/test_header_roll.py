@@ -21,7 +21,8 @@ from upow_tpu.telemetry import scope
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEADER = re.compile(r"header: timestamp=(\d+) behind=(-?\d+) "
-                    r"window=(-?\d+) repeat=([01])")
+                    r"window=(-?\d+) repeat=([01])"
+                    r" held=[01] age=\d+\.\d")   # the feed's two, last
 
 
 @pytest.fixture(scope="module")

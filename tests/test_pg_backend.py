@@ -367,7 +367,7 @@ def test_cross_backend_fingerprint_equivalence(monkeypatch):
     # freeze the wall clock: block hashes are timestamp-dependent and
     # the two builds must not straddle a real-second boundary (the
     # autouse fixture's clock.reset() unfreezes at teardown)
-    clock.freeze(int(_time.time()))
+    now = int(_time.time())
 
     async def build(state):
         manager = BlockManager(state, sig_backend="host")
@@ -385,9 +385,9 @@ def test_cross_backend_fingerprint_equivalence(monkeypatch):
                 await state.get_address_balance(a_o))
 
     async def main():
-        clock.reset()
+        clock.freeze(now)    # and again: a build advances the clock
         sqlite_result = await build(ChainState())
-        clock.reset()
+        clock.freeze(now)
         pg_result = await build(PgChainState(driver=MockPgDriver()))
         assert sqlite_result == pg_result
     run(main())

@@ -378,8 +378,8 @@ def test_the_miners_lines_are_the_ones_the_benchmark_parses(
     assert out[1] == (f"difficulty: {difficulty}  block: 42  "
                       "confirming 2 transactions")
     # the benchmark's older drivers pass over the line they do not know
-    assert re.fullmatch(r"header: timestamp=\d+ behind=0 window=1 repeat=0",
-                        out[2]), out[2]
+    assert re.fullmatch(r"header: timestamp=\d+ behind=0 window=1 repeat=0"
+                        r" held=0 age=0\.0", out[2]), out[2]
     assert _counter("mine.jobs") == before["mine.jobs"] + 1
     if rc == 0:
         assert out[-3:] == ["{'ok': True}", "BLOCK MINED", ""]
@@ -403,13 +403,15 @@ def test_the_miners_lines_are_the_ones_the_benchmark_parses(
 
 def test_a_failed_fetch_is_counted_and_closes_its_span_with_the_error(
         monkeypatch, capsys):
+    """``--once``: the failed try and the next are two ``mine.fetch``
+    spans of the one job that waits for its template."""
     calls = []
 
     def fetch(node):
         calls.append(node)
         if len(calls) == 1:
             raise OSError("connection refused")
-        raise KeyboardInterrupt     # ends the loop at the second job
+        raise KeyboardInterrupt     # ends the loop at the second try
 
     monkeypatch.setattr(miner, "fetch_mining_info", fetch)
     monkeypatch.setattr(miner.time, "sleep", lambda s: None)
@@ -419,10 +421,13 @@ def test_a_failed_fetch_is_counted_and_closes_its_span_with_the_error(
     assert _counter("mine.fetch_errors") == before + 1
     assert "node unreachable: connection refused; retrying" in \
         capsys.readouterr().err
-    failed = telemetry.traces()["recent"][-2]
-    assert failed["fields"]["end"] == "fetch_error"
-    assert failed["spans"][0]["name"] == "mine.fetch"
-    assert failed["spans"][0]["error"] == "OSError"
+    job = telemetry.traces()["recent"][-1]
+    assert job["error"] == "KeyboardInterrupt" and "end" not in job["fields"]
+    failed, cut = job["spans"]
+    assert failed["name"] == cut["name"] == "mine.fetch"
+    assert failed["error"] == "OSError"
+    assert failed["fields"] == {"ok": False, "error": "connection refused"}
+    assert cut["error"] == "KeyboardInterrupt"
 
 
 @pytest.fixture

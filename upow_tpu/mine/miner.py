@@ -26,13 +26,33 @@ window this process has not swept yet (``HeaderRoll``), ``now`` first;
 the ``header:`` line after the ``difficulty:`` line says which, how far
 behind ``now`` it lies, how long the window is and whether it had to
 repeat (the window held no fresh second: then ``now``, as ever).
+
+The loop (``run``) owns the rounds and never fetches: a thread of its
+own (``TemplateFeed``) asks the node for the next template while the job
+in hand still has its last rounds in flight, and again about twice a
+second while the node does not answer (refused, an error envelope, a
+request that hangs).  At a job's end the loop takes the newest template
+that has arrived; where none has, it builds the next job from the one in
+hand, which ``HeaderRoll`` stamps with a second not swept yet (a *held*
+job: ``held=1`` at the end of the ``header:`` line, after ``age=``, the
+seconds since the fetch that brought the template was sent).  A template
+is held only while it is younger than ``--ttl``; past that, and before
+the first template, the loop waits for the feed with the device idle.  A
+found block is pushed until the node gives a verdict (``ok`` true or
+false), a transport error tried again about once a second while the
+block's template is younger than ``--ttl``; nothing is mined beside a
+pending push, and what was fetched before a push is dropped with it (an
+accepted block moves the tip).  ``--once`` fetches in the loop's own
+thread, one template, and starts no feed.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextvars
 import json
 import sys
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -62,6 +82,236 @@ def fetch_mining_info(node: str) -> dict:
     if "result" not in res:  # readable node error, not KeyError
         raise RuntimeError(f"node error: {res.get('error', res)!s:.200}")
     return res["result"]
+
+
+#: what a request to a node that is away raises: refused, reset, timed
+#: out, an HTTP error status, a body that is no JSON
+TRANSPORT_ERRORS = (urllib.error.URLError, OSError, ValueError)
+#: ... and what a failed ``fetch_mining_info`` raises besides: the node's
+#: error envelope (syncing, rate-limited) as RuntimeError.  All transient
+FETCH_ERRORS = TRANSPORT_ERRORS + (RuntimeError,)
+
+#: seconds from one try to the next while the node does not answer.  The
+#: feed's is the part of "how long after the node's return does a job
+#: begin on a fresh template" that is not the sweep in hand
+FETCH_RETRY_S = 0.5
+PUSH_RETRY_S = 1.0
+#: rounds ``engine.mine`` keeps in flight: a refresh asked for when so
+#: many are left arrives while the device still has work
+ROUNDS_IN_FLIGHT = 2
+
+#: exported at zero from the first scrape, like ``ROLL_COUNTERS``
+FEED_COUNTERS = ("mine.jobs_held", "mine.push_retries",
+                 "mine.templates_late")
+
+
+def _say(line: str) -> None:
+    """One write, newline and all: the feed's thread, a hook's thread and
+    the loop's thread all print, and ``print`` writes a line and its end
+    apart (a line cut in two is a line no reader of them parses)."""
+    sys.stdout.write(line + "\n")
+    sys.stdout.flush()
+
+
+def _warn(line: str) -> None:
+    """``_say`` on stderr."""
+    sys.stderr.write(line + "\n")
+    sys.stderr.flush()
+
+
+class Template(NamedTuple):
+    """One answer of ``get_mining_info``."""
+
+    info: dict
+    fetched: float  # time.monotonic() when its request was sent: upstream
+                    # stamps a template at its fetch, and its age runs
+                    # from there
+    took: float     # seconds the request took
+
+    def age(self) -> float:
+        return time.monotonic() - self.fetched
+
+
+class TemplateFeed:
+    """The newest template the node has served, fetched on a thread of
+    the feed's own so that the loop that owns the rounds never waits on
+    the node while it holds a template it may mine.
+
+    ``ask`` (the loop, near a job's end) wants a refresh for the next
+    job; the thread tries until one arrives, ``FETCH_RETRY_S`` apart,
+    each try the span ``mine.fetch`` in the tree of the job in hand
+    (``root``).  ``take`` (the loop, at a job's end) chooses what the
+    next job is built from.  ``forget`` (after a push) drops what was
+    fetched so far.  Whatever ends the thread but a failed fetch is
+    raised in the loop by the next ``take``: a miner whose feed is dead
+    must not mine on in silence."""
+
+    def __init__(self, node: str):
+        self.node = node
+        self.root = None     # the job in hand's root span
+        self.took = 0.0      # seconds the last good fetch took
+        self.jobs = 0        # jobs begun (``take`` returned)
+        self._cond = threading.Condition()
+        self._wanted = False
+        self._asked_for = 0  # the job the last ``ask`` wanted a refresh for
+        self._newest: Optional[Template] = None
+        self._not_before = 0.0
+        self._stopped = False
+        self._died: Optional[BaseException] = None
+
+    def start(self) -> None:
+        # in the caller's context: its telemetry scope counts the tries
+        threading.Thread(target=contextvars.copy_context().run,
+                         args=(self._run,), daemon=True,
+                         name="miner-feed").start()
+
+    def stop(self) -> None:
+        """The loop has ended: the thread makes no further try."""
+        with self._cond:
+            self._stopped = True
+            self._cond.notify_all()
+
+    def fetch(self) -> Optional[Template]:
+        """One try, on the caller's thread: the template, or None after
+        a failure that is counted, said and left in the span."""
+        t0 = time.monotonic()
+        with telemetry.attached(self.root), \
+                telemetry.span("mine.fetch") as span:
+            try:
+                info, error = fetch_mining_info(self.node), None
+            except FETCH_ERRORS as e:
+                info, error = None, e
+            if span is not None:
+                span.fields["ok"] = error is None
+                if error is not None:
+                    span.error = type(error).__name__
+                    span.fields["error"] = f"{error!s:.120}"
+        if error is None:
+            return Template(info, t0, time.monotonic() - t0)
+        telemetry.inc("mine.fetch_errors")
+        _warn(f"node unreachable: {error}; retrying")
+        return None
+
+    def fetch_until_good(self, beat) -> Template:
+        """``--once``: the one template, fetched here and now."""
+        while True:
+            beat()
+            got = self.fetch()
+            if got is not None:
+                return got
+            time.sleep(1)
+
+    def _run(self) -> None:
+        try:
+            while True:
+                with self._cond:
+                    while not self._wanted and not self._stopped:
+                        self._cond.wait()
+                    if self._stopped:
+                        return
+                    for_job = self.jobs + 1
+                t0 = time.monotonic()
+                got = self.fetch()
+                if got is None:
+                    time.sleep(max(0.0, FETCH_RETRY_S
+                                   - (time.monotonic() - t0)))
+                    continue
+                with self._cond:
+                    self._arrived(got, for_job)
+        except BaseException as e:   # handed to the loop, which raises it
+            with self._cond:
+                self._died = e
+                self._cond.notify_all()
+
+    def _arrived(self, got: Template, for_job: int) -> None:
+        """An answer (under the lock).  It is late where the job it was
+        fetched for has begun without it, or a newer answer is here
+        already; the newest stays the newest either way."""
+        newest = self._newest
+        newer = newest is None or got.fetched > newest.fetched
+        if self.jobs >= for_job or not newer:
+            telemetry.inc("mine.templates_late")
+        if got.fetched < self._not_before or not newer:
+            return
+        self._newest, self.took = got, got.took
+        self._wanted = False
+        self._cond.notify_all()
+
+    def _want(self) -> None:
+        if not self._wanted:
+            self._wanted = True
+            self._cond.notify_all()
+
+    def ask(self) -> None:
+        """A refresh for the next job, once a job."""
+        with self._cond:
+            if self._asked_for <= self.jobs:
+                self._asked_for = self.jobs + 1
+                self._want()
+
+    def forget(self) -> None:
+        """A block was pushed: the tip may have moved under every
+        template fetched up to now, one on its way included."""
+        with self._cond:
+            self._newest, self._not_before = None, time.monotonic()
+            self._want()
+
+    def take(self, have: Optional[Template], ttl: float, beat) -> Template:
+        """The template the next job is built from: the newest that has
+        arrived if it is newer than ``have``; else ``have`` again (a held
+        job) while it is younger than ``ttl``, with a refresh wanted;
+        else the next to arrive, waited for (``beat`` once a second: a
+        node that is away is no device hang)."""
+        with self._cond:
+            while True:
+                if self._died is not None:
+                    raise self._died
+                if self._newest is not None and self._newest is not have:
+                    chosen = self._newest
+                    break
+                self._want()
+                if have is not None and have.age() < ttl:
+                    chosen = have
+                    break
+                beat()
+                self._cond.wait(1.0)
+            self.jobs += 1
+            return chosen
+
+
+def push_until_verdict(node: str, content: str, txs: list, block_no: int,
+                       template: Template, ttl: float, beat) -> tuple:
+    """(the node's answer, tries).  An answer with ``ok`` in it is the
+    node's verdict, true or false, and ends the push, as does an HTTP
+    status that blames the request; a transport error (refused, reset,
+    timed out, 5xx, 429) is tried again, ``PUSH_RETRY_S`` apart and the
+    same bytes each time, while the block's template is younger than
+    ``ttl``."""
+    tries = 0
+    while True:
+        tries += 1
+        beat()
+        t0 = time.monotonic()
+        try:
+            reply = push_block(node, content, txs, block_no)
+            if isinstance(reply, dict) and "ok" in reply:
+                return reply, tries
+            error = f"no verdict in {reply!s:.100}"
+        except urllib.error.HTTPError as e:
+            if e.code < 500 and e.code != 429:
+                return {"ok": False, "error": f"HTTP {e.code} {e.reason}"}, \
+                    tries
+            error = e
+        except TRANSPORT_ERRORS as e:
+            error = e
+        telemetry.inc("mine.push_errors")
+        if template.age() >= ttl:
+            _warn(f"push_block failed: {error}; its template is past the "
+                  "ttl: dropped")
+            return {"ok": False}, tries
+        _warn(f"push_block failed: {error}; retrying")
+        telemetry.inc("mine.push_retries")
+        time.sleep(max(0.0, PUSH_RETRY_S - (time.monotonic() - t0)))
 
 
 #: one of the three a job, so their sum is ``mine.jobs``
@@ -139,6 +389,7 @@ def build_job(info: dict, address: str,
 
 
 def push_block(node: str, block_content: str, txs: list, block_no: int) -> dict:
+    """One try; the timeout is per try."""
     return _http_json(
         node + "push_block",
         {"block_content": block_content, "txs": txs, "block_no": block_no},
@@ -203,7 +454,6 @@ def _start_hang_watchdog(heartbeat: dict, limit: float, _exit=None):
     state budget) and drops it once progress ticks.
     """
     import os
-    import threading
 
     _exit = _exit or os._exit
 
@@ -251,83 +501,101 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
     if backend in ("pallas", "jnp", "mesh") and not once:
         _start_hang_watchdog(heartbeat, ttl + hang_grace)
     roll = HeaderRoll()
-    for name in ROLL_COUNTERS:   # exported at zero from the first scrape
+    for name in ROLL_COUNTERS + FEED_COUNTERS:   # at zero from scrape one
         telemetry.ensure_counter(name)
+    total = min(hi, MAX_SEARCH_END) - lo
+    feed = TemplateFeed(node)
+    if not once:
+        feed.start()
+
+    def beat():
+        heartbeat["t"] = time.monotonic()
 
     def progress(tried, elapsed):
-        heartbeat["t"] = time.monotonic()
+        beat()
         heartbeat["limit"] = ttl + hang_grace  # compiled: steady budget
-        print(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
+        _say(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
+        # the next template is asked for while this job's last rounds
+        # are in flight, and earlier where the node's last answer took
+        # longer than they do; a sweep the ttl will cut ends there
+        round_s = elapsed * batch / tried
+        left_s = min((total - tried) / batch * round_s, ttl - elapsed)
+        if not once and left_s <= max(ROUNDS_IN_FLIGHT * round_s, feed.took):
+            feed.ask()
 
-    def one_job(root) -> Optional[int]:
-        """Fetch, search and push one job under its ``mine.job`` root.
-        Returns what ``--once`` exits with, or None after a failed
-        fetch (the caller waits and retries)."""
-        try:
-            with telemetry.span("mine.fetch"):
-                info = fetch_mining_info(node)
-        except (urllib.error.URLError, OSError, ValueError,
-                RuntimeError) as e:
-            # RuntimeError carries a node error envelope (syncing,
-            # rate-limited) — transient, retry like unreachable
-            telemetry.inc("mine.fetch_errors")
-            root.fields["end"] = "fetch_error"
-            print(f"node unreachable: {e}; retrying", file=sys.stderr)
-            return None
+    def one_job(root, template: Template, held: bool) -> tuple:
+        """Build, search and push one job under its ``mine.job`` root.
+        Returns (what ``--once`` exits with, whether a block was
+        pushed)."""
+        info, age = template.info, template.age()
+        said = {"held": int(held), "template_age_s": round(age, 3)}
         with telemetry.span("mine.build_job") as built:
             job, pending_hashes, block_no, stamp = build_job(
                 info, address, roll)
             if built is not None:
-                built.fields.update(stamp._asdict(),
+                built.fields.update(stamp._asdict(), **said,
                                     pending=len(pending_hashes))
         telemetry.inc("mine.jobs")
+        if held:
+            telemetry.inc("mine.jobs_held")
         root.fields.update(
-            stamp._asdict(),
+            stamp._asdict(), **said,
             block=block_no, difficulty=str(info["difficulty"]),
             tip=str(getattr(job, "previous_hash", ""))[-12:])
-        print(f"difficulty: {info['difficulty']}  block: {block_no}  "
-              f"confirming {len(pending_hashes)} transactions")
-        print(f"header: timestamp={stamp.timestamp} behind={stamp.behind_s} "
-              f"window={stamp.window_s} repeat={stamp.repeat}")
+        _say(f"difficulty: {info['difficulty']}  block: {block_no}  "
+             f"confirming {len(pending_hashes)} transactions")
+        _say(f"header: timestamp={stamp.timestamp} behind={stamp.behind_s} "
+             f"window={stamp.window_s} repeat={stamp.repeat} "
+             f"held={int(held)} age={age:.1f}")
         result = mine(job, backend, start=lo, stride_end=hi, batch=batch,
                       ttl=ttl, progress=progress, mesh_devices=mesh_devices)
         if result.nonce is None:
             telemetry.inc("mine.jobs_expired")
             root.fields["end"] = "expired"
-            print(f"template expired after {result.hashes_tried} hashes; refreshing")
-            return 1
+            _say(f"template expired after {result.hashes_tried} hashes; "
+                 "refreshing")
+            return 1, False
         telemetry.inc("mine.jobs_found")
         root.fields["end"] = "found"
         content = job.block_content(result.nonce)
-        print(f"found nonce {result.nonce} at {result.hashrate / 1e6:.2f} MH/s"
-              f" ({result.hashes_tried} hashes in {result.elapsed:.2f}s, first"
-              f" dispatch {result.first_dispatch:.2f}s)")
+        _say(f"found nonce {result.nonce} at {result.hashrate / 1e6:.2f} MH/s"
+             f" ({result.hashes_tried} hashes in {result.elapsed:.2f}s, first"
+             f" dispatch {result.first_dispatch:.2f}s)")
         if backend == "mesh":
             _print_mesh_accounting(mesh_devices)
         with telemetry.span("mine.push") as pushed:
-            try:
-                reply = push_block(node, content, pending_hashes, block_no)
-            except (urllib.error.URLError, OSError, ValueError) as e:
-                telemetry.inc("mine.push_errors")
-                print(f"push_block failed: {e}", file=sys.stderr)
-                reply = {"ok": False}
+            reply, tries = push_until_verdict(
+                node, content, pending_hashes, block_no, template, ttl, beat)
             if pushed is not None:
-                pushed.fields["ok"] = bool(reply.get("ok"))
-        print(reply)
+                pushed.fields.update(ok=bool(reply.get("ok")),
+                                     attempts=tries)
+        _say(str(reply))
         if reply.get("ok"):
-            print("BLOCK MINED\n")
-        return 0 if reply.get("ok") else 1
+            _say("BLOCK MINED\n")
+        return (0 if reply.get("ok") else 1), True
 
-    while True:
-        heartbeat["t"] = time.monotonic()
-        with telemetry.request_trace(
-                "mine.job", backend=backend, shard=f"{i}/{k}",
-                nonces=min(hi, MAX_SEARCH_END) - lo) as root:
-            rc = one_job(root)
-        if rc is None:
-            time.sleep(1)
-        elif once:
-            return rc
+    template = None
+    try:
+        while True:
+            beat()
+            with telemetry.request_trace(
+                    "mine.job", backend=backend, shard=f"{i}/{k}",
+                    nonces=total) as root:
+                feed.root = root
+                # from the end of a job to the template of the next: any
+                # wait for the node is in here, and nowhere else
+                with telemetry.span("mine.take_template", light=True):
+                    have = template
+                    template = feed.fetch_until_good(beat) if once \
+                        else feed.take(have, ttl, beat)
+                rc, pushed = one_job(root, template, held=template is have)
+            if once:
+                return rc
+            if pushed:
+                template = None
+                feed.forget()
+    finally:
+        feed.stop()
 
 
 def _print_mesh_accounting(mesh_devices: int) -> None:
@@ -365,13 +633,6 @@ def _start_device(device: str) -> int:
     return 0
 
 
-def _say(line: str) -> None:
-    """One write, newline and all: a hook's thread and the miner's
-    thread both print, and ``print`` writes a line and its end apart."""
-    sys.stdout.write(line + "\n")
-    sys.stdout.flush()
-
-
 def _install_profile_hooks(profile) -> None:
     """The searching process's profiler hook (``ProfilingConfig.enabled``
     only): SIGUSR1 starts a ``jax.profiler`` capture into
@@ -382,7 +643,6 @@ def _install_profile_hooks(profile) -> None:
     starts and stops on a thread of its own: a handler runs on the
     miner's thread, between two rounds."""
     import signal
-    import threading
 
     from .. import profiling
 
