@@ -172,6 +172,7 @@ def test_txid_batch_integrity_sample_falls_back(monkeypatch):
 _DATA_TILE_ROWS = 8          # interpret mode: 1,024 lanes a tile
 _DATA_TILE = _DATA_TILE_ROWS * 128
 _U32_END = (1 << 32) - 1     # engine.MAX_SEARCH_END: SENTINEL is never searched
+_STEP_TILES = 80             # a batch of two grid steps: 64 tiles and 16
 
 
 def _digest_hex(prefix: bytes, nonce: int) -> str:
@@ -227,7 +228,78 @@ def _data_case(name):
         return prefix, prev, "1", base, base + 3 * _DATA_TILE, _DATA_TILE + 476
     if name == "two_tiles_lower_wins":
         return prefix, prev, "2", base, base + batch, batch
+    return _step_case(name, r, prefix, prev)
+
+
+def _planted(prefix: bytes, nonce: int, difficulty: str):
+    """(previous hash, difficulty): a target of ``difficulty``'s depth
+    that ``nonce``'s digest meets in its whole chars."""
+    return _prev_hash_hit_by(prefix, nonce, int(float(difficulty))), difficulty
+
+
+def _step_case(name, r, prefix, prev):
+    """Cases of a batch of ``_STEP_TILES`` tiles, two grid steps of the
+    kernel (ISSUE 41): where the range lies on the tiles, where the hits
+    lie in the step, and how deep the target is."""
+    tile, batch = _DATA_TILE, _STEP_TILES * _DATA_TILE
+    aligned = r.randrange(1 << 18) * tile
+    base = aligned + 300 + r.randrange(400)      # inside its first tile
+    if name == "unaligned_base":                 # 81 tiles touched, hits in most
+        return prefix, prev, "2", base, base + batch, batch
+    if name == "unaligned_only_hit_in_the_tile_past_the_grid":
+        return (prefix, *_planted(prefix, base + batch - 3, "11.0"),
+                base, base + batch, batch)
+    if name == "shorter_than_a_tile":            # hits below base and past limit
+        return prefix, prev, "1", base, base + 200, batch
+    if name == "unaligned_ends_at_2^32-1":
+        return prefix, prev, "2", _U32_END - 5000, _U32_END, batch
+    if name == "two_hits_in_tiles_of_one_step":
+        return prefix, prev, "3", aligned, aligned + batch, batch
+    if name == "hit_below_base_not_answered":    # hashed in base's tile
+        return (prefix, *_planted(prefix, base - 5, "11.0"),
+                base, base + batch, batch)
+    if name == "hit_at_limit_not_answered":
+        limit = base + 3 * tile + 77
+        return prefix, *_planted(prefix, limit, "11.0"), base, limit, batch
+    if name == "every_step_exact_at_1.0":
+        return prefix, prev, "1.0", aligned, aligned + batch, batch
+    if name == "hit_in_the_second_step_at_6.0":
+        return (prefix, *_planted(prefix, aligned + 70 * tile + 5, "6.0"),
+                aligned, aligned + batch, batch)
+    if name == "nibble_in_word0_at_7.5":
+        n = _nonce_with_nibble_below(prefix, base, base + batch, 7, 8)
+        return prefix, *_planted(prefix, n, "7.5"), base, base + batch, batch
+    if name == "nibble_in_word1_at_8.3":
+        n = _nonce_with_nibble_below(prefix, base, base + batch, 8, 12)
+        return prefix, *_planted(prefix, n, "8.3"), base, base + batch, batch
+    if name == "v1_header":                      # the nonce over w1 / w2
+        prefix = bytes(r.randrange(256) for _ in range(134))
+        return prefix, prev, "2", base, base + 2 * tile, 2 * tile
     raise KeyError(name)
+
+
+def _exact_steps(prefix, prev, difficulty, base, limit, batch) -> int:
+    """The kernel's ``exact_steps`` by its rule, from hashlib: the grid
+    steps in whose tiles some lane's digest starts with the first word's
+    share of the target, lanes outside ``[base, limit)`` too.  Tiles are
+    counted from ``base`` rounded down to a tile; a step holds
+    ``TILES_PER_STEP`` of them, the grid's last what is left."""
+    from upow_tpu.crypto.sha256 import TILES_PER_STEP
+
+    chars = min(int(float(difficulty)), 8)
+    want = prev[-int(float(difficulty)):][:chars]
+    start = base - base % _DATA_TILE
+    span = min((limit - base) % (1 << 32), batch)
+    tiles = -(-(base - start + span) // _DATA_TILE) if span else 0
+    steps = -(-(-(-batch // _DATA_TILE)) // TILES_PER_STEP)
+    count = 0
+    for step in range(steps):
+        first = step * TILES_PER_STEP
+        end = tiles if step == steps - 1 else min(first + TILES_PER_STEP, tiles)
+        count += any(
+            _digest_hex(prefix, n).startswith(want)
+            for n in range(start + first * _DATA_TILE, start + end * _DATA_TILE))
+    return count
 
 
 @pytest.fixture(scope="module")
@@ -244,9 +316,12 @@ def data_kernels():
 
     @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec"))
     def pallas(mid, tail, base, limit, tgt, batch, nonce_spec):
-        return sk.pow_search_pallas_data(
+        """(lowest hit, exact steps) out of the kernel's answer words."""
+        hit, steps = sk.pow_search_pallas_data(
             mid, tail, jnp.stack([base, limit], axis=1), tgt, batch=batch,
             nonce_spec=nonce_spec, tile_rows=_DATA_TILE_ROWS, interpret=True)
+        return (jax.lax.bitcast_convert_type(hit, jnp.uint32)
+                ^ jnp.uint32(1 << 31)), -steps
 
     @functools.partial(jax.jit, static_argnames=("batch", "nonce_spec"))
     def twin(mid, tail, base, limit, tgt, batch, nonce_spec):
@@ -263,13 +338,22 @@ def data_kernels():
 @pytest.mark.parametrize("name", [
     "charset16", "nibble_word0", "nibble_word1", "nibble_word2",
     "mask1_11.0", "limit_mid_tile", "limit_before_only_hit", "empty_shard",
-    "ends_at_2^32-1", "ragged_batch", "two_tiles_lower_wins"])
+    "ends_at_2^32-1", "ragged_batch", "two_tiles_lower_wins",
+    "unaligned_base", "unaligned_only_hit_in_the_tile_past_the_grid",
+    "shorter_than_a_tile", "unaligned_ends_at_2^32-1",
+    "two_hits_in_tiles_of_one_step", "hit_below_base_not_answered",
+    "hit_at_limit_not_answered", "every_step_exact_at_1.0",
+    "hit_in_the_second_step_at_6.0", "nibble_in_word0_at_7.5",
+    "nibble_in_word1_at_8.3", "v1_header"])
 def test_pallas_data_target_kernel(data_kernels, name):
     """Target and [base, limit) as SMEM data: the kernel's answer is
     hashlib's lowest hit of the range under the protocol's rule, and
-    ``_hit_nonce_dynamic``'s on the jnp digest."""
+    ``_hit_nonce_dynamic``'s on the jnp digest, wherever the range lies
+    on the kernel's tiles and steps; its second word counts the steps
+    that took the exact pass."""
     import jax.numpy as jnp
 
+    from upow_tpu.crypto import sha256 as sk
     from upow_tpu.crypto.sha256 import pack_target
 
     prefix, prev, difficulty, base, limit, batch = _data_case(name)
@@ -298,15 +382,52 @@ def test_pallas_data_target_kernel(data_kernels, name):
         assert batch % _DATA_TILE and limit > base + batch
     if name == "two_tiles_lower_wins":
         assert len({(n - base) // _DATA_TILE for n in hits}) >= 2
+    step = sk.TILES_PER_STEP * _DATA_TILE
+    if name.startswith("unaligned") or name == "shorter_than_a_tile":
+        assert base % _DATA_TILE and hits
+    if name == "unaligned_only_hit_in_the_tile_past_the_grid":
+        assert hits == [base + batch - 3]
+        assert (hits[0] - base + base % _DATA_TILE) // _DATA_TILE == _STEP_TILES
+    if name == "shorter_than_a_tile":
+        assert limit // _DATA_TILE == base // _DATA_TILE
+        assert all(any(check_pow_hash(_digest_hex(prefix, n), prev, difficulty)
+                       for n in outside)
+                   for outside in (range(base - base % _DATA_TILE, base),
+                                   range(limit, limit + 200)))
+    if name == "unaligned_ends_at_2^32-1":
+        assert base + batch > 1 << 32 and limit == _U32_END
+    if name == "two_hits_in_tiles_of_one_step":
+        assert len({(n - base) // _DATA_TILE for n in hits
+                    if (n - base) // step == (hits[0] - base) // step}) >= 2
+    if name in ("hit_below_base_not_answered", "hit_at_limit_not_answered"):
+        outside = base - 5 if "below" in name else limit
+        assert not hits and check_pow_hash(
+            _digest_hex(prefix, outside), prev, difficulty)
+    if name == "hit_in_the_second_step_at_6.0":
+        assert len(hits) == 1 and (hits[0] - base) // step == 1
+    if name.startswith("nibble_in_word"):
+        assert spec.nibble_word == int(name[14]) and spec.charset < 16
+        assert len(hits) == 1
+    if name == "v1_header":
+        assert hits and {w for w, _ in template.nonce_spec} == {1, 2}
+    want_steps = _exact_steps(prefix, prev, difficulty, base, limit, batch)
+    if name == "every_step_exact_at_1.0":
+        assert want_steps == 2
+    if name in ("hit_below_base_not_answered", "hit_at_limit_not_answered",
+                "hit_in_the_second_step_at_6.0", "mask1_11.0"):
+        assert want_steps == 1      # the planted digest's step alone
+    if name == "empty_shard":
+        assert want_steps == 0      # no tile is run
 
     args = (jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
             jnp.array([base], jnp.uint32), jnp.array([limit], jnp.uint32),
             jnp.asarray(pack_target(spec)))
     pallas, twin = data_kernels
-    got = int(pallas(*args, batch=batch, nonce_spec=template.nonce_spec))
-    assert got == want
-    assert got == int(twin(*args, batch=batch,
-                           nonce_spec=template.nonce_spec))
+    got, steps = pallas(*args, batch=batch, nonce_spec=template.nonce_spec)
+    assert int(got) == want
+    assert int(got) == int(twin(*args, batch=batch,
+                                nonce_spec=template.nonce_spec))
+    assert int(steps) == want_steps
 
 
 # --- the static-target programs: the range's end as data --------------------

@@ -216,6 +216,34 @@ def test_body_is_chosen_by_platform_and_counted(pallas_engine):
     assert MeshEngine().stats()["body"] is None   # not armed: no program
 
 
+def test_exact_steps_are_exported_from_the_arm_and_counted_a_round():
+    """``kernel.mine_mesh.exact_steps``: at zero from the arm on (the jnp
+    body leaves it there: it has no steps), and after a round the steps
+    of every shard's kernel that took the exact pass, read out of the
+    answer's words: both shards' one step at difficulty 1, none where no
+    digest meets the target's first word."""
+    name = "kernel.mine_mesh.exact_steps"
+    plain = _armed_engine(batch_per_device=64)
+    assert metrics.counters().get(name) == 0
+    plain.set_job(_seeded_job(5, difficulty="1"))
+    assert int(plain.dispatch(0, plain.capacity)) != int(SENTINEL)
+    assert metrics.counters().get(name) == 0
+
+    eng = MeshEngine(mesh_devices=2, batch_per_device=2048, interpret=True)
+    telemetry.reset()
+    telemetry.configure()
+    assert eng.arm()["armed"]
+    assert metrics.counters().get(name) == 0
+    eng.set_job(_seeded_job(5, difficulty="1"))
+    answer = eng.dispatch(0, eng.capacity)
+    assert metrics.counters().get(name) == 0    # not read yet
+    assert int(answer) != int(SENTINEL) and int(answer) == int(answer)
+    assert metrics.counters().get(name) == 2    # read once, counted once
+    eng.set_job(_seeded_job(5, difficulty="9.0"))
+    assert int(eng.dispatch(0, eng.capacity)) == int(SENTINEL)
+    assert metrics.counters().get(name) == 2
+
+
 # ------------------------------------------------ disjoint coverage ----
 
 def test_dispatch_accounting_proves_disjoint_exact_coverage():

@@ -20,6 +20,7 @@ the tier-1 run.
 """
 
 import os
+import re
 from decimal import Decimal
 
 import numpy as np
@@ -197,3 +198,14 @@ def test_mesh_resident_search_compiles_on_a_mesh_of(n, topo,
     if n > 1:
         assert "all-reduce" in text or "all_reduce" in text, \
             "the pmin over dp must survive as a collective"
+    # the kernel leaves its answer in the words the pmin reduces (the hit
+    # flipped to s32, a slot a shard for the exact steps): one kernel a
+    # shard, one collective, and no XLA operation between or after them
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    collectives = re.findall(
+        r"\b(all-reduce|all-gather|all-to-all|collective-permute|"
+        r"reduce-scatter)(?:-start)?\(", text)
+    assert collectives == (["all-reduce"] if n > 1 else []), \
+        "the exact steps ride in the hit's collective, not in a second"
+    assert " fusion(" not in text, \
+        "an XLA fusion beside the kernel is a device operation a round"

@@ -33,6 +33,7 @@ import time
 from typing import NamedTuple, Sequence, Tuple
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 
@@ -379,12 +380,13 @@ def _rounds_rolled(state, window, first: int):
     return tuple(st[j] for j in range(8))
 
 
-def _nonce_words(tail_words, nonces, nonce_spec) -> dict:
-    """The tail words a nonce byte lands in, by index, each with its
-    little-endian nonce bytes in place.  Byte ``j`` (bits ``8j`` up) goes
-    to bits ``shift`` up by one shift, and a mask unless that shift
-    already dropped every other bit; the tail word itself is or-ed in
-    only where it has a byte of its own (none in a v2 header's w10)."""
+def _nonce_bytes(nonces, nonce_spec) -> dict:
+    """``nonces``' little-endian bytes where the header keeps them, by
+    tail word index and or-ed together, with nothing of the tail itself.
+    Byte ``j`` (bits ``8j`` up) goes to bits ``shift`` up by one shift,
+    and a mask unless that shift already dropped every other bit.
+    Bitwise, so the bytes of ``x | y`` are the bytes of ``x`` or-ed with
+    the bytes of ``y``: a scalar's and a lane constant's (the kernel)."""
     parts: dict = {}
     for j, (widx, shift) in enumerate(nonce_spec):
         move = shift - 8 * j
@@ -393,10 +395,19 @@ def _nonce_words(tail_words, nonces, nonce_spec) -> dict:
         if abs(move) != 24:
             byte = byte & jnp.uint32(0xFF << shift)
         parts.setdefault(widx, []).append(byte)
-    return {widx: functools.reduce(
-        lambda x, y: x | y,
-        bytes_ if len(bytes_) == 4 else [tail_words[widx], *bytes_])
-        for widx, bytes_ in parts.items()}
+    return {widx: functools.reduce(lambda x, y: x | y, bytes_)
+            for widx, bytes_ in parts.items()}
+
+
+def _nonce_words(tail_words, nonces, nonce_spec) -> dict:
+    """The tail words a nonce byte lands in, by index, each with its
+    nonce bytes in place (:func:`_nonce_bytes`); the tail word itself is
+    or-ed in only where it has a byte of its own (none in a v2 header's
+    w10)."""
+    held = [widx for widx, _ in nonce_spec]
+    return {widx: bytes_ if held.count(widx) == 4
+            else tail_words[widx] | bytes_
+            for widx, bytes_ in _nonce_bytes(nonces, nonce_spec).items()}
 
 
 def _build_w(tail_words, nonces, nonce_spec):
@@ -406,31 +417,18 @@ def _build_w(tail_words, nonces, nonce_spec):
             for i in range(16)]
 
 
-def _search_digest(mid, tail, nonces, nonce_spec, unroll: bool | None = None):
-    """The digest words of ``nonces``' headers from a
-    :class:`SearchTemplate`'s two arrays (``mid``, ``tail``: anything
-    indexable by word, a traced array or an SMEM ref): the compression
-    of the tail block from the first round a nonce reaches, on the
-    hoisted state.
-
-    Unrolled (:func:`_compress_tail`'s rule), only the schedule words a
-    nonce reaches are built, each from its hoisted part, and every other
-    word enters its round as one scalar with ``K[i]`` folded in.  Rolled
-    (a CPU), the loop starts at that round over a window built in full
-    from the plain tail words."""
+def _search_state(mid, tail, w, nonce_spec):
+    """a..h after round 63 of the tail block's compression, unrolled,
+    from the first round a nonce reaches on a :class:`SearchTemplate`'s
+    hoisted state.  ``w`` holds the tail words a nonce lands in
+    (:func:`_nonce_words`); only the schedule words a nonce reaches are
+    built, each from its hoisted part, and every other word enters its
+    round as one scalar with ``K[i]`` folded in."""
     reached = nonce_reach(nonce_spec)
-    first = reached.index(True)
-    midstate = tuple(mid[j] for j in range(8))
+    w = dict(w)
     state = tuple(mid[8 + j] for j in range(8))
-    if not _unrolled(unroll):
-        w = _build_w(tail, nonces, nonce_spec)
-        for i in range(16, 16 + first):
-            w.append(_sum(*_schedule_terms(w, i, _small_sigma)))
-        return _feed_forward(
-            midstate, _rounds_rolled(state, w[first:], first))
-    w = _nonce_words(tail, nonces, nonce_spec)
     bc = state[1] ^ state[2]
-    for i in range(first, 64):
+    for i in range(reached.index(True), 64):
         if not reached[i]:
             state, bc = _round(state, bc, tail[16 + i])
             continue
@@ -440,20 +438,46 @@ def _search_digest(mid, tail, nonces, nonce_spec, unroll: bool | None = None):
                 terms.append(tail[16 + i])
             w[i] = _sum(*terms)
         state, bc = _round(state, bc, jnp.uint32(_K[i]), w[i])
-    return _feed_forward(midstate, state)
+    return state
 
 
-def _lanes_in_range(lin, done, base, limit, batch: int):
-    """The range mask every search program shares.  Lane ``done + lin``
-    of a program of ``batch`` lanes holds nonce ``base + done + lin``; it
-    may answer only inside ``[base, min(limit, base + batch))``.  Counted
+def _search_digest(mid, tail, nonces, nonce_spec, unroll: bool | None = None):
+    """The digest words of ``nonces``' headers from a
+    :class:`SearchTemplate`'s two arrays (``mid``, ``tail``: anything
+    indexable by word, a traced array or an SMEM ref): the compression
+    of the tail block from the first round a nonce reaches, on the
+    hoisted state.
+
+    Unrolled (:func:`_compress_tail`'s rule) it is :func:`_search_state`,
+    the hashing body the Pallas kernels run.  Rolled (a CPU), the loop
+    starts at that round over a window built in full from the plain tail
+    words."""
+    midstate = tuple(mid[j] for j in range(8))
+    if _unrolled(unroll):
+        return _feed_forward(midstate, _search_state(
+            mid, tail, _nonce_words(tail, nonces, nonce_spec), nonce_spec))
+    first = nonce_reach(nonce_spec).index(True)
+    w = _build_w(tail, nonces, nonce_spec)
+    for i in range(16, 16 + first):
+        w.append(_sum(*_schedule_terms(w, i, _small_sigma)))
+    return _feed_forward(midstate, _rounds_rolled(
+        tuple(mid[8 + j] for j in range(8)), w[first:], first))
+
+
+def _range_span(base, limit, batch: int):
+    """How many nonces from ``base`` up a search program of ``batch``
+    lanes may answer for: ``[base, min(limit, base + batch))``, counted
     from ``base`` (``limit - base`` in u32), so a range that ends at
-    2^32 - 1, whose surplus lanes wrap past 2^32, needs no care; lanes
-    wholly past the limit (or past ``batch``, in a ragged last tile) are
-    left with none.  ``done`` is a scalar: a Pallas grid step's offset,
-    0 in a jnp program."""
-    span = jnp.minimum(limit - base, jnp.uint32(min(batch, 0xFFFFFFFF)))
-    return lin < jnp.where(span > done, span - done, jnp.uint32(0))
+    2^32 - 1, whose surplus lanes wrap past 2^32, needs no care."""
+    return jnp.minimum(limit - base, jnp.uint32(min(batch, 0xFFFFFFFF)))
+
+
+def _lanes_in_range(nonces, base, limit, batch: int):
+    """The range mask every search program shares: a lane may answer
+    only for a nonce of :func:`_range_span`.  Counted from ``base`` too:
+    a lane below it (a tile that starts before an unaligned ``base``)
+    wraps to a count no span reaches."""
+    return nonces - base < _range_span(base, limit, batch)
 
 
 def search_span(nonce_base: int, batch: int, limit=None) -> np.ndarray:
@@ -520,7 +544,7 @@ def _pow_search_jnp(midstate, tail_words, span, batch: int,
         digest = _search_digest(midstate, tail_words, nonces, nonce_spec)
         t = [jnp.uint32(x)
              for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
-        valid = _lanes_in_range(lin, jnp.uint32(0), base, limit, batch)
+        valid = _lanes_in_range(nonces, base, limit, batch)
         return _hit_nonce(digest, nonces, *t, spec, valid)
 
 
@@ -539,98 +563,222 @@ def pow_search_jnp(template: SearchTemplate, spec: TargetSpec,
 
 # --- Pallas TPU kernel ----------------------------------------------------
 
-def _tile_digest(mid_ref, tail_ref, read_base, *, tile_rows: int, nonce_spec):
-    """The hashing body both Pallas kernels share: this grid step's
-    (tile_rows, 128) nonce tile from ``program_id`` and its digests
-    (:func:`_search_digest`, unrolled; ``mid_ref`` and ``tail_ref`` hold
-    a :class:`SearchTemplate`'s two arrays, read word by word as SMEM
-    scalars).
-    ``read_base()`` loads the first nonce from wherever the kernel keeps
-    it.  Returns (grid step, lane-linear index within the tile, nonces,
-    digest)."""
-    from jax.experimental import pallas as pl  # local: keep module importable sans pallas
-
-    i = pl.program_id(0)
-    tile = tile_rows * 128
-    # nonce = base + program_id*tile + lane-linear index, as (tile_rows, 128)
-    lin = (jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 0) * jnp.uint32(128)
-           + jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 1))
-    nonces = read_base() + jnp.uint32(i) * jnp.uint32(tile) + lin
-    # always unrolled here: the rolled form would capture the K table as a
-    # pallas_call constant, and Mosaic compiles the flat rounds fast
-    return i, lin, nonces, _search_digest(
-        mid_ref, tail_ref, nonces, nonce_spec, unroll=True)
+#: (tile_rows, 128) tiles a grid step of the search kernel hashes.  A
+#: step costs 0.30 us of its own on a v5e whatever it holds (the grid's
+#: bookkeeping, the lane constants, the one reduction to a scalar)
+#: beside 3.51 us a tile, and a step that holds a candidate hashes its
+#: tiles twice: at 64 the step's own cost is 0.13% of it, and a step in
+#: 32 runs twice at difficulty 6.0, one in 512 at 7.0 (PERF.md section
+#: 6, PR 41, has the chip's rounds at 1, 4, 16 and 64).
+TILES_PER_STEP = 64
 
 
-def _min_hit_into(out_ref, i, ok, nonces):
-    """Min-accumulate this tile's lowest hit into the (1,1) SMEM cell."""
+def _search_steps(batch: int, tile_rows: int) -> int:
+    """Grid steps of the search kernel over ``batch`` lanes."""
+    tiles = -(-batch // (tile_rows * 128))
+    return -(-tiles // TILES_PER_STEP)
+
+
+def _search_step(mid_ref, tail_ref, span_ref, target, out_ref, *, batch: int,
+                 tile_rows: int, nonce_spec, axis=None):
+    """One grid step of both Pallas kernels: :data:`TILES_PER_STEP`
+    tiles of (tile_rows, 128) nonces, hashed by :func:`_search_state`
+    (always unrolled: the rolled form would capture the K table as a
+    pallas_call constant, and Mosaic compiles the flat rounds fast).
+    ``mid_ref`` and ``tail_ref`` hold a :class:`SearchTemplate`'s two
+    arrays, ``span_ref[0, :2]`` is ``[base, limit)``, all SMEM scalars;
+    ``target`` is :func:`pack_target`'s seven words, indexable.
+    ``out_ref`` holds :func:`answer_words`, min-accumulated over the
+    sequential grid: the lowest hit, and the steps that took the exact
+    pass in this shard's slot, the shard being its index along the mesh
+    axis ``axis`` the kernel runs under (none: the only one).
+
+    Tiles are counted from ``base`` rounded down to a tile, so a tile's
+    first nonce ``b`` has no bit of a lane's index ``lin``: the nonce is
+    ``b | lin`` and its words are the words of ``b``, on the scalar
+    unit, or-ed with the words of ``lin``, built once a step.  The step
+    runs the tiles ``[base, limit)`` touches and no other (a loop bound
+    on the scalar unit; the grid's last step takes the one tile more an
+    unaligned base adds).
+
+    A lane's common path is the rounds and the first masked compare,
+    kept in a carried vector.  Only a step in which some lane passed it
+    runs its tiles again (the same loop, so the binary holds one hashing
+    body) with the whole test behind a scalar predicate: second word,
+    nibble, range, lowest nonce."""
     from jax.experimental import pallas as pl
 
-    cand = jnp.where(ok, nonces, jnp.uint32(SENTINEL))
-    # Mosaic has no unsigned reductions (and no scalar bitcasts): flip the
-    # sign bit (order-preserving u32 -> s32 map) on the vector, reduce in
-    # int32, and keep the accumulator in flipped-int32 space — the caller
-    # flips the final scalar back
-    flipped = jax.lax.bitcast_convert_type(
-        cand ^ jnp.uint32(0x80000000), jnp.int32)
-    tile_min = jnp.min(flipped)
-    # one (1,1) SMEM cell min-accumulated across the sequential TPU grid
-    # (a (1,1)-blocked (grid,1) output is not a legal Mosaic block shape)
-    @pl.when(i == 0)
-    def _init():
-        out_ref[0, 0] = tile_min
-
-    @pl.when(i != 0)
-    def _acc():
-        out_ref[0, 0] = jnp.minimum(out_ref[0, 0], tile_min)
-
-
-def _pallas_kernel(mid_ref, tail_ref, span_ref, out_ref, *, batch: int,
-                   tile_rows: int, nonce_spec, spec: TargetSpec):
-    """Static-target kernel: the target is compiled in, the range
-    ``[base, limit)`` (``span_ref[:2]``) is SMEM data, so one program a
-    tip serves every round of a job, the short last one too."""
-    base, limit = span_ref[0], span_ref[1]
-    i, lin, nonces, digest = _tile_digest(
-        mid_ref, tail_ref, lambda: base, tile_rows=tile_rows,
-        nonce_spec=nonce_spec)
-    t = [jnp.uint32(x) for x in (spec.mask0, spec.val0, spec.mask1, spec.val1)]
-    ok = (digest[0] & t[0]) == t[1]
-    ok &= (digest[1] & t[2]) == t[3]
-    if spec.charset < 16:
-        nib = (digest[spec.nibble_word] >> jnp.uint32(spec.nibble_shift)) & jnp.uint32(0xF)
-        ok &= nib < jnp.uint32(spec.charset)
-    ok &= _lanes_in_range(lin, jnp.uint32(i) * jnp.uint32(tile_rows * 128),
-                          base, limit, batch)
-    _min_hit_into(out_ref, i, ok, nonces)
-
-
-def _pallas_kernel_data(mid_ref, tail_ref, span_ref, tgt_ref, out_ref, *,
-                        batch: int, tile_rows: int, nonce_spec):
-    """Data-target twin of :func:`_pallas_kernel`: the same hashing body,
-    :func:`_hit_nonce_dynamic`'s compare in operations Mosaic takes.  The
-    packed target (:func:`pack_target`) and the shard's ``[base, limit)``
-    (``span_ref[0, :2]``) are SMEM scalars, so one compiled kernel serves
-    every job, tip and difficulty.  Each ref may be longer than the words
-    read (:func:`resident_operand`)."""
+    tile = tile_rows * 128
+    if tile & (tile - 1):
+        raise ValueError(f"a tile of {tile} lanes is no power of two")
+    shift, low = tile.bit_length() - 1, jnp.uint32(tile - 1)
+    step, steps = pl.program_id(0), _search_steps(batch, tile_rows)
     base, limit = span_ref[0, 0], span_ref[0, 1]
-    i, lin, nonces, digest = _tile_digest(
-        mid_ref, tail_ref, lambda: base, tile_rows=tile_rows,
-        nonce_spec=nonce_spec)
-    ok = (digest[0] & tgt_ref[0]) == tgt_ref[1]
-    ok &= (digest[1] & tgt_ref[2]) == tgt_ref[3]
-    # nibble_word = k // 8 for k <= 16 hex chars: word 0, 1 or 2
-    nibble_word = tgt_ref[4]
-    word = jnp.where(nibble_word == jnp.uint32(0), digest[0],
-                     jnp.where(nibble_word == jnp.uint32(1), digest[1],
-                               digest[2]))
-    nib = (word >> tgt_ref[5]) & jnp.uint32(0xF)
-    # a nibble is under 16, so charset >= 16 passes every lane here as
-    # _hit_nonce_dynamic's explicit (charset >= 16) does
-    ok &= nib < tgt_ref[6]
-    ok &= _lanes_in_range(lin, jnp.uint32(i) * jnp.uint32(tile_rows * 128),
-                          base, limit, batch)
-    _min_hit_into(out_ref, i, ok, nonces)
+    span = _range_span(base, limit, batch)
+    start = base & ~low
+    # the tiles [base, base + span) touches: the last nonce's tile and
+    # those before it, with no sum that could pass 2^32; an empty range
+    # touches none
+    last = span - jnp.uint32(1)
+    n_tiles = jnp.where(
+        span == jnp.uint32(0), jnp.uint32(0),
+        (last >> shift) + (((last & low) + (base & low)) >> shift)
+        + jnp.uint32(1)).astype(jnp.int32)
+    first_tile = step * TILES_PER_STEP
+    end_tile = jnp.where(
+        step == steps - 1, n_tiles,
+        jnp.minimum(first_tile + TILES_PER_STEP, n_tiles))
+
+    lin = (jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 0)
+           * jnp.uint32(128)
+           + jax.lax.broadcasted_iota(jnp.uint32, (tile_rows, 128), 1))
+    lane_words = _nonce_bytes(lin, nonce_spec)
+
+    # one SMEM row for the whole grid (a (1,1)-blocked (grid,1) output
+    # is not a legal Mosaic block shape)
+    slot = 1 + (0 if axis is None else jax.lax.axis_index(axis))
+
+    @pl.when(step == 0)
+    def _init():
+        out_ref[0, 0] = jnp.int32(0x7FFFFFFF)  # SENTINEL, flipped
+        for other in range(1, out_ref.shape[1]):
+            out_ref[0, other] = jnp.int32(0)
+
+    # the unrolled rounds are some 2,700 jnp operations to trace.  They
+    # are traced once, here, to a jaxpr whose primitives the loops below
+    # bind: traced where they run, inside three nested loop bodies, they
+    # took the chip's host 7.8 s of the miner's arm where this takes 4.5
+    # and the one-tile kernel took 4.0 (PERF.md section 6, PR 41)
+    def tile_state(*head):
+        state = _search_state(
+            mid_ref, tail_ref,
+            {i: h | lane_words[i] for i, h in zip(lane_words, head)},
+            nonce_spec)
+        return state[:3]        # a, b, c: the digest's first three words
+
+    tile_state = jax.extend.core.jaxpr_as_fun(jax.make_jaxpr(tile_state)(
+        *[jax.ShapeDtypeStruct((), jnp.uint32)] * len(lane_words)))
+
+    def hash_tiles(exact):
+        def one_tile(t, seen):
+            b = start + (t.astype(jnp.uint32) << jnp.uint32(shift))
+            head = _nonce_words(tail_ref, b, nonce_spec)
+            state = tile_state(*[head[i] for i in lane_words])
+            h0 = mid_ref[0] + state[0]
+            passed = (h0 & target[0]) == target[1]
+
+            @pl.when(exact)
+            def _exact():
+                h1, h2 = mid_ref[1] + state[1], mid_ref[2] + state[2]
+                ok = passed & ((h1 & target[2]) == target[3])
+                # nibble_word = k // 8 for k <= 16 hex chars: 0, 1 or 2
+                word = jnp.where(target[4] == jnp.uint32(0), h0,
+                                 jnp.where(target[4] == jnp.uint32(1), h1, h2))
+                # a nibble is under 16, so charset >= 16 passes every
+                # lane as _hit_nonce_dynamic's explicit test does
+                ok &= ((word >> target[5]) & jnp.uint32(0xF)) < target[6]
+                nonces = b | lin
+                ok &= _lanes_in_range(nonces, base, limit, batch)
+                # Mosaic has no unsigned reductions: reduce flipped
+                out_ref[0, 0] = jnp.minimum(out_ref[0, 0], jnp.min(_flipped(
+                    jnp.where(ok, nonces, jnp.uint32(SENTINEL)))))
+
+            return jnp.where(passed, jnp.int32(1), seen)
+
+        return jnp.max(jax.lax.fori_loop(
+            first_tile, end_tile, one_tile,
+            jnp.zeros((tile_rows, 128), jnp.int32)))
+
+    def one_pass(carry):
+        done, _ = carry
+
+        @pl.when(done == 1)
+        def _count():
+            out_ref[0, slot] = out_ref[0, slot] - 1
+
+        return done + 1, hash_tiles(done == 1)
+
+    # the common pass, then the exact one where it saw a candidate
+    jax.lax.while_loop(lambda carry: carry[0] <= carry[1], one_pass,
+                       (jnp.int32(0), jnp.int32(0)))
+
+
+def _pallas_kernel(mid_ref, tail_ref, span_ref, out_ref, *,
+                   spec: TargetSpec, **static):
+    """Static-target kernel: :func:`_search_step` with the target
+    compiled in; the range is SMEM data, so one program a tip serves
+    every round of a job, the short last one too."""
+    _search_step(mid_ref, tail_ref, span_ref,
+                 [jnp.uint32(x) for x in pack_target(spec)], out_ref,
+                 **static)
+
+
+def _pallas_kernel_data(mid_ref, tail_ref, span_ref, tgt_ref, out_ref,
+                        **static):
+    """Data-target kernel: :func:`_search_step` with the packed target
+    (:func:`pack_target`) in SMEM too, so one compiled kernel serves
+    every job, tip and difficulty.  Each ref may be longer than the
+    words read (:func:`resident_operand`)."""
+    _search_step(mid_ref, tail_ref, span_ref, tgt_ref, out_ref, **static)
+
+
+def _flipped(nonces):
+    """u32 to s32 with the sign bit flipped, which keeps the order: the
+    form in which a search program min-reduces and returns its hit."""
+    return jax.lax.bitcast_convert_type(
+        nonces ^ jnp.uint32(0x80000000), jnp.int32)
+
+
+def answer_words(hit, shards: int = 1):
+    """What a search program returns for the lowest hit ``hit`` (u32, or
+    SENTINEL), (1 + shards,) s32: the hit :func:`_flipped`, then a slot
+    a shard that holds minus the steps of that shard's kernel that took
+    the exact pass (none here: the kernel fills its own).  One signed
+    min over the shards (the mesh program's ``pmin``) leaves the lowest
+    hit and every shard's count, with no operation but the kernel and
+    the collective; :class:`SearchAnswer` reads them on the host, in the
+    round's one transfer."""
+    return jnp.concatenate(
+        [_flipped(hit).reshape(1), jnp.zeros(shards, jnp.int32)])
+
+
+class SearchAnswer:
+    """A search program's :func:`answer_words`, still on the device.
+    ``int()`` waits for them, as it would for a bare scalar: the lowest
+    hit or SENTINEL, with the exact steps of every shard added to
+    ``kernel.<kernel>.exact_steps`` on the way."""
+
+    def __init__(self, words, kernel: str):
+        self._words, self._kernel, self._hit = words, kernel, None
+
+    def __int__(self) -> int:
+        if self._hit is None:
+            from ..telemetry import device as _ktel
+
+            hit, *slots = (int(x) for x in np.asarray(self._words))
+            _ktel.record_exact_steps(self._kernel, -sum(slots))
+            self._hit = hit + 0x80000000
+        return self._hit
+
+
+def _search_call(kernel, operands, *, batch: int, tile_rows: int,
+                 nonce_spec, interpret: bool, axis=None):
+    """Run a search kernel over ``batch`` lanes, as one of the shards
+    along the mesh axis ``axis`` (or alone): its :func:`answer_words`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    shards = 1 if axis is None else jax.lax.psum(1, axis)  # the axis' size
+    return pl.pallas_call(
+        functools.partial(kernel, batch=batch, tile_rows=tile_rows,
+                          nonce_spec=nonce_spec, axis=axis),
+        grid=(_search_steps(batch, tile_rows),),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * len(operands),
+        out_specs=pl.BlockSpec((1, 1 + shards), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1, 1 + shards), jnp.int32),
+        interpret=interpret,
+    )(*operands).reshape(1 + shards)
 
 
 #: words of each array the resident program is given.  Left at their own
@@ -656,71 +804,44 @@ def resident_operand(words) -> np.ndarray:
 
 def pow_search_pallas_data(midstate, tail_words, span, target, *,
                            batch: int, nonce_spec, tile_rows: int = 64,
-                           interpret: bool = False):
+                           interpret: bool = False, axis=None):
     """Pallas search of ``[base, min(limit, base + batch))`` against a
-    packed runtime ``target`` — min hit or SENTINEL, as u32.  ``span`` is
+    packed runtime ``target``: :func:`answer_words`.  ``span`` is
     (1, >= 2) u32, ``[base, limit, ...]``: a shard's row of the resident
-    mesh program's ranges.  Traced inside the caller's jit
-    (``parallel.mesh._pow_search_mesh_resident``): no jit of its own.  The
-    grid rounds ``batch`` up to whole tiles; the range mask drops the
-    surplus lanes."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(
-        _pallas_kernel_data, batch=batch, tile_rows=tile_rows,
-        nonce_spec=nonce_spec)
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    flipped = pl.pallas_call(
-        kernel,
-        grid=(pl.cdiv(batch, tile_rows * 128),),
-        in_specs=[smem] * 4,
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
-                               memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-        interpret=interpret,
-    )(midstate, tail_words, span, target)
-    return flipped[0, 0].astype(jnp.uint32) ^ jnp.uint32(0x80000000)
+    mesh program's ranges, the shard one of those along the mesh axis
+    ``axis``.
+    Traced inside the caller's jit
+    (``parallel.mesh._pow_search_mesh_resident``): no jit of its own.
+    ``batch`` need be no multiple of anything, nor ``base``: the kernel
+    runs the tiles the range touches."""
+    return _search_call(
+        _pallas_kernel_data, (midstate, tail_words, span, target),
+        batch=batch, tile_rows=tile_rows, nonce_spec=nonce_spec,
+        interpret=interpret, axis=axis)
 
 
 @functools.partial(jax.jit, static_argnames=("batch", "tile_rows", "nonce_spec", "spec", "interpret"))
 def _pow_search_pallas(midstate, tail_words, span, batch: int,
                        tile_rows: int, nonce_spec, spec: TargetSpec,
                        interpret: bool):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    kernel = functools.partial(
-        _pallas_kernel, batch=batch, tile_rows=tile_rows,
-        nonce_spec=nonce_spec, spec=spec)
     with jax.named_scope("upow.sha256_search"):
-        per_tile = pl.pallas_call(
-            kernel,
-            grid=(pl.cdiv(batch, tile_rows * 128),),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-            ],
-            out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0),
-                                   memory_space=pltpu.SMEM),
-            out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            interpret=interpret,
-        )(midstate, tail_words, span)
-    return per_tile[0, 0].astype(jnp.uint32) ^ jnp.uint32(0x80000000)
+        return _search_call(
+            functools.partial(_pallas_kernel, spec=spec),
+            (midstate, tail_words, span.reshape(1, 2)),
+            batch=batch, tile_rows=tile_rows, nonce_spec=nonce_spec,
+            interpret=interpret)
 
 
 def pow_search_pallas(template: SearchTemplate, spec: TargetSpec,
                       nonce_base: int, batch: int, limit=None,
                       tile_rows: int = 64, interpret: bool = False):
-    """Pallas-tiled search; same contract as :func:`pow_search_jnp`.  The
-    grid rounds ``batch`` up to whole tiles; the range mask drops the
-    surplus lanes."""
-    return _pow_search_pallas(
+    """Pallas-tiled search; same contract as :func:`pow_search_jnp`, the
+    answer a :class:`SearchAnswer`."""
+    return SearchAnswer(_pow_search_pallas(
         jnp.asarray(template.midstate), jnp.asarray(template.tail_words),
         search_span(nonce_base, batch, limit), batch, tile_rows,
         template.nonce_spec, spec, interpret,
-    )
+    ), "sha256_search")
 
 
 # --- batched fixed-length digests (txids, tests) --------------------------

@@ -140,6 +140,7 @@ def _make_dispatcher(job: MiningJob, backend: str,
         compile_key=(batch, template.nonce_spec, spec))
     telemetry.device.record_hoist(
         "sha256_search", *sha_kernel.hoisted_counts(template.nonce_spec))
+    telemetry.ensure_counter("kernel.sha256_search.exact_steps")
 
     def issue(start: int, count: int, width: int):
         # dispatch ISSUANCE goes through the device owner (so miner
@@ -160,8 +161,9 @@ def _make_dispatcher(job: MiningJob, backend: str,
         width = batch or count
         if count >= width:
             return issue(start, count, width)
-        # a masked round: lanes at or past start + count are hashed and
-        # never answer (crypto/sha256.py _lanes_in_range)
+        # a masked round: the kernel runs the tiles [start, start + count)
+        # touches and lanes at or past its end never answer
+        # (crypto/sha256.py _search_step, _lanes_in_range)
         telemetry.device.record_batch("sha256_search", real=count,
                                       padded=width)
         with _masked_round(width, count):

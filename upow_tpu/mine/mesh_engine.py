@@ -195,6 +195,7 @@ class MeshEngine:
         # such counter"
         telemetry.ensure_counter("mine.mesh.rounds_pallas")
         telemetry.ensure_counter("mine.mesh.job_layouts")
+        telemetry.ensure_counter("kernel.mine_mesh.exact_steps")
         # the static-target engine's programs, one a tip: 0 for as long
         # as the process runs this engine alone
         telemetry.ensure_counter("kernel.sha256_search.compile_cache_misses")
@@ -211,7 +212,7 @@ class MeshEngine:
                     1, cfg.search_batch // self._n_dev)
         # dummy template: zero midstate/tail/target, every shard empty
         # (base == limit == 0) — compiles the exact program real jobs
-        # dispatch, costs one masked-out round of hashing.  The zeros are
+        # dispatch (the kernel runs no tile of it).  The zeros are
         # laid as a job's arrays are: jit keys a program on the shardings
         # of committed arguments, and the first job must find this one
         spec = sha_kernel.make_template(bytes(104)).nonce_spec
@@ -220,9 +221,9 @@ class MeshEngine:
             np.zeros((self._n_dev, 2), np.uint32))
 
         def warm():
-            return int(pow_search_resident(
-                zeros, zeros, no_ranges, zeros,
-                self._batch_per_device, spec, self._mesh, self._interpret))
+            return np.asarray(pow_search_resident(
+                zeros, zeros, no_ranges, zeros, self._batch_per_device,
+                spec, self._mesh, self._interpret))
 
         runtime.submit_call(
             warm, kernel="sha256_search_mesh", source="mine").result()
@@ -280,7 +281,8 @@ class MeshEngine:
 
     def dispatch(self, start: int, count: int):
         """Scan [start, start+count) across the mesh; returns the async
-        device handle (``int()`` blocks and yields min hit or SENTINEL).
+        device handle (a ``sha256.SearchAnswer``: ``int()`` blocks and
+        yields min hit or SENTINEL).
 
         ``count`` must fit one round (<= :attr:`capacity`); the caller's
         loop (engine.mine) sizes rounds accordingly."""
@@ -315,11 +317,11 @@ class MeshEngine:
             # the share of rounds that ran the kernel: 1.0 on a chip
             telemetry.inc("mine.mesh.rounds_pallas")
         runtime = get_runtime()
-        return runtime.submit_call(
+        return sha_kernel.SearchAnswer(runtime.submit_call(
             lambda: pow_search_resident(
                 mid, tail, ranges, target,
                 batch, nonce_spec, mesh, interpret),
-            kernel="sha256_search_mesh", source="mine").result()
+            kernel="sha256_search_mesh", source="mine").result(), "mine_mesh")
 
     def dispatcher(self, job) -> Callable:
         """dispatch(start, count) closure for :func:`engine.mine`'s

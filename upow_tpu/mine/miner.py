@@ -614,6 +614,8 @@ def _print_mesh_accounting(mesh_devices: int) -> None:
         "dispatches": stats["dispatches"],
         "job_layouts": stats["job_layouts"],
         "jit_entries": stats["jit_entries"],
+        "exact_steps": telemetry.counters().get(
+            "kernel.mine_mesh.exact_steps", 0),
         "last_round_shards": last.get("shards", [])}), flush=True)
 
 

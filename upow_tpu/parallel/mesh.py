@@ -120,11 +120,10 @@ def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
     recompilation (asserted by the mine_mesh compile-cache counters).
 
     ``ranges`` is (n_devices, >= 2) u32, a ``[base, limit, ...]`` row a
-    shard, sharded over "dp": shard i scans ``[base, base +
-    batch_per_device)`` with lanes at or past ``limit`` masked off, so
-    uneven ``shard_bounds`` spans and tail rounds need no recompile
-    either.  An empty shard passes ``base == limit`` (every lane
-    invalid).  Every array may be longer than the words read: the mesh
+    shard, sharded over "dp": shard i scans ``[base, min(limit, base +
+    batch_per_device))``, so uneven ``shard_bounds`` spans and tail
+    rounds need no recompile either.  An empty shard passes ``base ==
+    limit``.  Every array may be longer than the words read: the mesh
     engine pads each to a page (``sha256.resident_operand``), which keeps
     XLA:TPU from staging them into scalar memory with a copy operation
     apiece on every round.
@@ -135,9 +134,11 @@ def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
     the CPU path of ``--device mesh`` and the plain twin the tests
     compare the kernel against.  ``interpret`` runs the Pallas body in
     interpret mode on any platform (tests), in the smallest (8, 128)
-    tiles: a test-sized shard then still spans several grid steps, and
-    XLA:CPU all but hangs on a grid of one step (the unrolled rounds
-    land outside any loop).
+    tiles, so that a test-sized shard spans more than one.
+
+    Returns ``sha256.answer_words`` after the program's one collective,
+    a signed ``pmin`` over "dp": the lowest hit of all shards and each
+    shard's exact steps, which the kernel leaves in that form.
     """
     shard_map, check_kw = shard_map_compat()
 
@@ -149,14 +150,13 @@ def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
         valid = (nonces >= my_base) & (nonces < my_limit)
         digest = sha_kernel._search_digest(mid, tail, nonces, nonce_spec)
         hit = sha_kernel._hit_nonce_dynamic(digest, nonces, tgt, valid)
-        return jax.lax.pmin(hit.reshape(1), "dp")
+        return jax.lax.pmin(sha_kernel.answer_words(hit, mesh.size), "dp")
 
     def per_device_pallas(mid, tail, span, tgt):
-        hit = sha_kernel.pow_search_pallas_data(
+        return jax.lax.pmin(sha_kernel.pow_search_pallas_data(
             mid, tail, span, tgt, batch=batch_per_device,
             nonce_spec=nonce_spec, tile_rows=8 if interpret else 64,
-            interpret=interpret)
-        return jax.lax.pmin(hit.reshape(1), "dp")
+            interpret=interpret, axis="dp"), "dp")
 
     pallas = resident_body(mesh, interpret) == "pallas"
     with jax.named_scope("upow.sha256_search"):
@@ -166,7 +166,7 @@ def _pow_search_mesh_resident(midstate, tail_words, ranges, target,
             in_specs=(P(), P(), P("dp"), P()),
             out_specs=P(),
             **check_kw,
-        )(midstate, tail_words, ranges, target)[0]
+        )(midstate, tail_words, ranges, target)
 
 
 def pow_search_resident(midstate, tail_words, ranges, target,
@@ -176,8 +176,10 @@ def pow_search_resident(midstate, tail_words, ranges, target,
     """Dispatch the resident program over explicit per-shard ranges.
 
     Arguments are already device-typed arrays (the mesh engine keeps the
-    template resident and only swaps these between jobs); returns the
-    global minimum hit nonce (or SENTINEL) after the ``pmin`` collective.
+    template resident and only swaps these between jobs); returns
+    ``sha256.answer_words`` after the ``pmin`` collective: the global
+    minimum hit nonce (or SENTINEL) and each shard's exact steps, still
+    on the device (``sha256.SearchAnswer`` reads them).
     """
     mesh = mesh or make_mesh()
     return _pow_search_mesh_resident(

@@ -15,7 +15,8 @@ kernel:
   ones reuse the traced program.
 
 ``record_hoist`` adds, for the sha256 search kernels, once a job,
-``kernel.<name>.rounds_hoisted`` and ``kernel.<name>.words_hoisted``.
+``kernel.<name>.rounds_hoisted`` and ``kernel.<name>.words_hoisted``;
+``record_exact_steps``, once a round, ``kernel.<name>.exact_steps``.
 
 Device memory gauges are best-effort: ``memory_stats()`` is populated
 on TPU/GPU backends and typically absent on CPU; we never import jax
@@ -84,6 +85,17 @@ def record_hoist(kernel: str, rounds: int, words: int) -> None:
     every round of every nonce again."""
     metrics.inc("kernel.%s.rounds_hoisted" % kernel, int(rounds))
     metrics.inc("kernel.%s.words_hoisted" % kernel, int(words))
+
+
+def record_exact_steps(kernel: str, steps: int) -> None:
+    """Record, once a round, the grid steps of the sha256 search kernel
+    that took the exact pass: a step in which some lane passed the first
+    masked compare hashes its tiles again under the whole test
+    (``crypto/sha256._search_step``).  Beside ``mine.rounds`` it says
+    what share of the kernel's work the exact pass is at the difficulty
+    mined: of a one-chip round's 32 steps ~0.004 at 11.0, ~1 at 6.0,
+    all at 4.0 and under."""
+    metrics.inc("kernel.%s.exact_steps" % kernel, int(steps))
 
 
 def preregister_stage(stage: str) -> None:
