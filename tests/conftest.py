@@ -129,3 +129,32 @@ def _fresh_sig_verdicts():
 
     txverify.clear_sig_verdicts()
     yield
+
+
+@pytest.fixture
+def span_events(monkeypatch):
+    """Every telemetry span as the profiler would hear it, in order and
+    with its thread: a fake annotation class (no ``is_enabled``, so
+    always heard) that appends (open|close, name, thread name)."""
+    import threading
+
+    from upow_tpu.telemetry import tracing
+
+    events: list = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("open", self.name,
+                           threading.current_thread().name))
+            return self
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name,
+                           threading.current_thread().name))
+            return False
+
+    monkeypatch.setattr(tracing, "_annotation_cls", Annotation)
+    return events

@@ -579,6 +579,107 @@ def test_runtime_telemetry_families_exported(rt):
     assert "runtime.queue_wait.block" in hists
 
 
+def _runtime_families():
+    """Everything a dispatch leaves under ``runtime.*`` and
+    ``kernel.device_runtime.*``, zeros left out; a histogram as
+    (per-bucket counts, sum)."""
+    counters = {k: v for k, v in metrics.counters().items()
+                if k.startswith(("runtime.", "kernel.device_runtime."))
+                and v}
+    hists = {k: (h["counts"], h["sum"])
+             for k, h in metrics.histograms().items()
+             if k.startswith(("runtime.", "kernel.device_runtime."))
+             and h["count"]}
+    return counters, hists
+
+
+@pytest.mark.parametrize("kind", ["call", "sig"])
+def test_a_dispatch_leaves_in_the_runtime_families_what_it_always_left(
+        rt, kind):
+    """The families docs/OBSERVABILITY.md gives a node's operator, value
+    for value as before ISSUE 42 made a drain one registry update: a
+    ``call`` item (a miner's round) counts as a batch of one lane in
+    one, a signature group as its checks padded to ``pad_block``."""
+    from upow_tpu.telemetry.device import (DISPATCH_BUCKETS,
+                                           OCCUPANCY_BUCKETS,
+                                           RUNTIME_COALESCE_BUCKETS,
+                                           RUNTIME_QUEUE_DEPTH_BUCKETS)
+
+    def one_in(bounds, index, n=1):
+        counts = [0] * (len(bounds) + 1)
+        counts[index] = n
+        return counts
+
+    if kind == "call":
+        assert rt.submit_call(lambda: 7, kernel="sha256_search_mesh",
+                              source="mine").result(30.0) == 7
+        source, submissions, real, padded = "mine", 1, 1, 1
+    else:
+        checks = pipeline_verify_fixture(8, n_unique=4, invalid_every=3)
+        with rt.hold():      # two submitters share the one dispatch
+            futs = [rt.submit_sig_checks(checks[:5], backend="host",
+                                         source="block"),
+                    rt.submit_sig_checks(checks[5:], backend="host",
+                                         source="block")]
+        assert [len(f.result(60.0)) for f in futs] == [5, 3]
+        source, submissions, real, padded = "block", 2, 8, 128
+    # the drainer records after it resolves the future
+    deadline = time.time() + 10.0
+    while not metrics.counters().get("runtime.dispatches") \
+            and time.time() < deadline:
+        time.sleep(0.01)
+    counters, hists = _runtime_families()
+    assert counters == {
+        "runtime.submissions": submissions,
+        "runtime.source.%s" % source: submissions,
+        "runtime.dispatches": 1,
+        "kernel.device_runtime.lanes_real": real,
+        "kernel.device_runtime.lanes_padded": padded}
+    wait_counts, wait_sum = hists.pop("runtime.queue_wait.%s" % source)
+    took_counts, took_sum = hists.pop(
+        "kernel.device_runtime.dispatch_seconds")
+    assert sum(wait_counts) == sum(took_counts) == 1
+    assert 0.0 <= wait_sum < 30.0 and 0.0 < took_sum < 60.0
+    for counts, value in ((wait_counts, wait_sum), (took_counts, took_sum)):
+        at = counts.index(1)    # the first bound the value does not pass
+        assert at == len(DISPATCH_BUCKETS) or value <= DISPATCH_BUCKETS[at]
+        assert at == 0 or value > DISPATCH_BUCKETS[at - 1]
+    assert hists == {
+        "runtime.coalesced": (
+            one_in(RUNTIME_COALESCE_BUCKETS, submissions - 1),
+            submissions),
+        # two queued at the pop that drained both
+        "runtime.queue_depth": (
+            one_in(RUNTIME_QUEUE_DEPTH_BUCKETS, submissions - 1),
+            submissions),
+        "kernel.device_runtime.occupancy": (
+            one_in(OCCUPANCY_BUCKETS, 5 if kind == "call" else 0),
+            real / padded)}
+
+
+@pytest.mark.parametrize("value,index", [
+    (0.0, 0), (0.1, 0), (0.100001, 1), (0.5, 2), (1.0, 5), (1.5, 6),
+    (-3.0, 0)])
+def test_observe_finds_the_first_bound_the_value_does_not_pass(value,
+                                                               index):
+    from upow_tpu.telemetry.device import OCCUPANCY_BUCKETS
+
+    metrics.observe("test.bounds", value, buckets=OCCUPANCY_BUCKETS)
+    h = metrics.histograms()["test.bounds"]
+    assert h["counts"].index(1) == index and h["sum"] == value
+
+
+def test_an_update_is_its_incs_and_observations_under_one_lock():
+    metrics.update((("test.a", 2), ("test.b", 1), ("test.a", 3)),
+                   (("test.h", 0.3, (0.25, 0.5)), ("test.h", 9.0, None)))
+    counters = metrics.counters()
+    assert (counters["test.a"], counters["test.b"]) == (5, 1)
+    h = metrics.histograms()["test.h"]
+    # bounds are fixed by the first observation, as with observe()
+    assert h["bounds"] == (0.25, 0.5) and h["counts"] == [0, 1, 1]
+    assert h["count"] == 2 and h["sum"] == 9.3
+
+
 def test_weights_config_parsing():
     cfg = DeviceRuntimeConfig(weights="block=4, mine = 1,bad")
     w = cfg.parsed_weights()

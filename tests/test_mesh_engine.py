@@ -497,6 +497,96 @@ def test_mine_round_telemetry_families():
     assert hists["mine.hit_latency"]["count"] == 1
 
 
+@pytest.mark.parametrize("counts", [(1024,), (512,), (1024, 1024, 1001)],
+                         ids=["whole", "half", "ragged-third"])
+def test_a_rounds_families_value_for_value(counts):
+    """What ``record_mine_round`` leaves a round, as one registry update
+    since ISSUE 42: ``kernel.mine_mesh.*`` (the round as one batch of
+    real over padded lanes; a compile key seen before is a hit) and one
+    ``mine.shard_occupancy`` observation a shard."""
+    from upow_tpu.telemetry.device import OCCUPANCY_BUCKETS
+
+    eng = _armed_engine(batch_per_device=128)
+    assert eng.capacity == 1024
+    eng.set_job(_seeded_job(5, difficulty="9"))
+    seen = metrics.counters()
+
+    def bucket(share):
+        return next(i for i, b in enumerate(OCCUPANCY_BUCKETS)
+                    if share <= b)
+
+    start = 0
+    occupancy = [0] * (len(OCCUPANCY_BUCKETS) + 1)
+    shard_occupancy = list(occupancy)
+    for count in counts:
+        int(eng.dispatch(start, count))
+        start += count
+        occupancy[bucket(count / 1024)] += 1
+        for lo, hi in eng.plan_round(0, count):
+            shard_occupancy[bucket((hi - lo) / 128)] += 1
+
+    def grew(name):
+        return metrics.counters().get(name, 0) - seen.get(name, 0)
+
+    assert grew("kernel.mine_mesh.lanes_real") == sum(counts)
+    assert grew("kernel.mine_mesh.lanes_padded") == 1024 * len(counts)
+    # the arm's warm dispatch records nothing: the first round is the
+    # program's one miss, every later one a hit
+    assert grew("kernel.mine_mesh.compile_cache_misses") == 1
+    assert grew("kernel.mine_mesh.compile_cache_hits") == len(counts) - 1
+    hists = metrics.histograms()
+    assert hists["kernel.mine_mesh.occupancy"]["counts"] == occupancy
+    assert hists["kernel.mine_mesh.occupancy"]["sum"] == \
+        pytest.approx(sum(c / 1024 for c in counts))
+    assert hists["mine.shard_occupancy"]["counts"] == shard_occupancy
+    assert hists["mine.shard_occupancy"]["count"] == 8 * len(counts)
+    assert hists["kernel.mine_mesh.dispatch_seconds"]["count"] == 0
+
+
+def test_a_dispatch_is_a_plan_then_a_submit_with_the_call_inside(
+        span_events):
+    """One ``MeshEngine.dispatch`` over the 8-device CPU mesh: on the
+    caller's thread ``mine.round.plan`` closed before
+    ``mine.round.submit`` opens, and the device owner's ``runtime.call``
+    begun and ended inside the submit: submit less call is the hop
+    between the two threads and back."""
+    import threading
+
+    eng = _armed_engine(batch_per_device=128)
+    eng.set_job(_seeded_job(6, difficulty="9"))
+    del span_events[:]          # the arm's and the job's own
+    before = telemetry.stats()
+    with telemetry.span("mine.round.issue", light=True):
+        int(eng.dispatch(0, eng.capacity))
+    me, owner = threading.current_thread().name, "upow-device-runtime"
+    assert span_events == [
+        ("open", "mine.round.issue", me),
+        ("open", "mine.round.plan", me), ("close", "mine.round.plan", me),
+        ("open", "mine.round.submit", me),
+        ("open", "runtime.call", owner), ("close", "runtime.call", owner),
+        ("close", "mine.round.submit", me),
+        ("close", "mine.round.issue", me)]
+    after = telemetry.stats()
+
+    def took(name):
+        assert after[name]["count"] \
+            - before.get(name, {"count": 0})["count"] == 1
+        return after[name]["total_s"] \
+            - before.get(name, {"total_s": 0.0})["total_s"]
+
+    # the books of the issue: its two parts and a self time
+    assert took("runtime.call") <= took("mine.round.submit")
+    parts = took("mine.round.plan") + took("mine.round.submit")
+    assert parts <= took("mine.round.issue") < parts + 0.05
+    # a refused round is still a timed plan, and no submit
+    with pytest.raises(ValueError):
+        eng.dispatch(0, eng.capacity + 1)
+    assert telemetry.stats()["mine.round.plan"]["count"] \
+        == after["mine.round.plan"]["count"] + 1
+    assert telemetry.stats()["mine.round.submit"]["count"] \
+        == after["mine.round.submit"]["count"]
+
+
 def test_engine_stats_exported_for_node_gauges():
     assert mesh_engine.engine_stats() is None  # before first use
     eng = _armed_engine(batch_per_device=64)

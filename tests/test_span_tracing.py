@@ -123,6 +123,102 @@ def test_a_failing_annotation_class_never_breaks_the_span(monkeypatch):
     assert node.done and root.children == [node]
 
 
+class Unheard:
+    """A profiler class as jax's: ``is_enabled`` says whether a session
+    runs, whichever thread started it."""
+    session = False
+    built: list = []
+
+    @staticmethod
+    def is_enabled():
+        return Unheard.session
+
+    def __init__(self, name, **kw):
+        Unheard.built.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_an_event_is_built_only_while_a_session_can_hear_it(monkeypatch):
+    import threading
+
+    Unheard.session, Unheard.built = False, []
+    monkeypatch.setattr(tracing, "_annotation_cls", Unheard)
+
+    def spans():
+        with telemetry.request_trace("req"):
+            with telemetry.span("tree"):
+                with telemetry.span("light", light=True):
+                    pass
+
+    spans()
+    assert Unheard.built == []
+    # the launcher's thread starts the session and tells nobody
+    starter = threading.Thread(
+        target=lambda: setattr(Unheard, "session", True))
+    starter.start()
+    starter.join(timeout=10.0)
+    spans()
+    assert Unheard.built == ["req", "tree", "light"]
+    Unheard.session = False
+    spans()
+    assert len(Unheard.built) == 3
+
+
+def _no_profiler(monkeypatch):
+    monkeypatch.setattr(tracing, "_annotation_cls", None)
+    monkeypatch.setitem(sys.modules, "jax", None)    # jax never loaded
+
+
+def _failing(where):
+    class Failing:
+        def __init__(self, name, **kw):
+            if where == "init":
+                raise RuntimeError("profiler gone")
+
+        def __enter__(self):
+            if where == "enter":
+                raise RuntimeError("profiler gone")
+            return self
+
+        def __exit__(self, *exc):
+            raise RuntimeError("profiler gone")
+
+    if where == "is_enabled":
+        def is_enabled():
+            raise RuntimeError("profiler gone")
+
+        Failing.is_enabled = staticmethod(is_enabled)
+    return lambda monkeypatch: monkeypatch.setattr(
+        tracing, "_annotation_cls", Failing)
+
+
+@pytest.mark.parametrize("profiler", [
+    _no_profiler, _failing("init"), _failing("enter"), _failing("exit"),
+    _failing("is_enabled")],
+    ids=["none", "init", "enter", "exit", "is_enabled"])
+def test_a_light_span_times_whatever_the_profiler_does(monkeypatch,
+                                                       profiler):
+    profiler(monkeypatch)
+    before = telemetry.stats().get("light.timed", {"count": 0,
+                                                   "total_s": 0.0})
+    with telemetry.span("light.timed", light=True, kernel="k") as node:
+        tracing.time.sleep(0.002)
+    assert node is None
+    after = telemetry.stats()["light.timed"]
+    assert after["count"] == before["count"] + 1
+    assert after["total_s"] - before["total_s"] >= 0.002
+    # and the caller's own exception is the caller's
+    with pytest.raises(KeyError):
+        with telemetry.span("light.timed", light=True):
+            raise KeyError("theirs")
+    assert telemetry.stats()["light.timed"]["count"] == before["count"] + 2
+
+
 def test_telemetry_spans_do_not_import_jax():
     code = ("import sys\n"
             "from upow_tpu import telemetry\n"
@@ -220,6 +316,58 @@ def test_a_masked_round_issues_under_mine_round_tail(
                                                    "mine.first_issue"}
 
 
+ROUND = [("open", "mine.round.plan"), ("close", "mine.round.plan"),
+         ("open", "mine.round.submit"), ("open", "runtime.call"),
+         ("close", "runtime.call"), ("close", "mine.round.submit")]
+
+
+@pytest.mark.parametrize("around", ["mine.first_issue", "mine.round.issue",
+                                    "mine.round.tail"])
+def test_a_mesh_round_is_planned_then_submitted_inside_its_issue(
+        span_events, no_mesh_engine_left, around):
+    """``MeshEngine.dispatch`` cuts the host's issue in two where the
+    work is: ``mine.round.plan`` then ``mine.round.submit`` on the
+    miner's thread, ``runtime.call`` on the device owner's from inside
+    the submit to before its end; light spans all, in a job's first
+    round, a whole round and a masked one alike."""
+    import threading
+
+    agg = telemetry.stats()
+    rounds = 4
+    with telemetry.request_trace("mine.job") as root:
+        result = mine(_job("9"), "mesh", batch=256,
+                      stride_end=(rounds - 1) * 256 + 40, mesh_devices=1)
+    assert result.hashes_tried == (rounds - 1) * 256 + 40
+    log = span_events
+    me = threading.current_thread().name
+    # every enclosing span of this kind holds exactly one round's six
+    opened = [i for i, ev in enumerate(log) if ev[:2] == ("open", around)]
+    assert len(opened) == {"mine.first_issue": 1, "mine.round.issue":
+                           rounds - 1, "mine.round.tail": 1}[around]
+    for at in opened:
+        inside = log[at + 1:log.index(("close", around, me), at)]
+        if around == "mine.round.issue" and \
+                inside[0][:2] == ("open", "mine.round.tail"):
+            inside = inside[1:-1]
+        assert [ev[:2] for ev in inside] == ROUND
+        threads = {name: thread for _w, name, thread in inside}
+        assert threads["mine.round.plan"] == me
+        assert threads["mine.round.submit"] == me
+        assert threads["runtime.call"] == "upow-device-runtime"
+    assert log[opened[0]][2] == me
+
+    def grew(name):
+        return telemetry.stats()[name]["count"] \
+            - agg.get(name, {}).get("count", 0)
+
+    # the flat aggregate has them, the job's tree does not
+    assert grew("mine.round.plan") == grew("mine.round.submit") == rounds
+    tree = [t for t in telemetry.traces()["recent"]
+            if t["trace_id"] == root.trace_id][0]
+    assert {c["name"]: c.get("spans") for c in tree["spans"]} == {
+        "mine.prepare": None, "mine.first_issue": None}
+
+
 def test_a_new_tip_is_one_compile_key_miss_and_the_same_tip_none():
     name = "kernel.sha256_search.compile_cache_misses"
     tip_a, tip_b = ("%064x" % 0xA11CE), ("%064x" % 0xB0B)
@@ -288,6 +436,88 @@ def test_a_capture_holds_the_miners_spans_on_the_profilers_clock(tmp_path):
     assert lines_of["runtime.call"].isdisjoint(lines_of["mine.round.wait"])
     assert all(plane.startswith("/host:")
                for plane, _n in lines_of["mine.round.wait"])
+
+
+# ------------------------- ISSUE 42's four metrics, from their data files --
+
+@pytest.fixture(scope="module")
+def bench_manifest():
+    """``benchmarks/harness/manifest.py``, found as the benchmark finds
+    it (its readers import ``harness`` from ``benchmarks/``)."""
+    bench = os.path.join(REPO, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from harness import manifest, xplane  # noqa: F401  (readers' import)
+
+        yield manifest
+    finally:
+        sys.path.remove(bench)
+
+
+def _hand_made_records(with_the_new_spans=True):
+    """A 20 s window on one device.  The device runs all of it but
+    [20, 20.12): a round's answer reached the host 120 ms late, and the
+    miner's thread sat in ``mine.round.wait`` over [19.99, 20.125).
+    Three rounds' issues beside it: plan 0.2 / 0.3 / 0.4 ms, submit
+    1.0 / 1.1 / 1.3 ms, the device owner's call 0.8 / 0.9 / 1.2 ms
+    inside each submit."""
+    s = 1e9
+
+    def rec(plane, line, name, start_s, dur_s):
+        return {"plane": plane, "line": line, "name": name,
+                "start_ns": start_s * s, "dur_ns": dur_s * s}
+
+    host, dev = "/host:CPU", "/device:TPU:0"
+    out = [rec(host, "python3", "perfbench.window", 10.0, 20.0),
+           rec(dev, "XLA Ops", "%search.1", 10.0, 10.0),
+           rec(dev, "XLA Ops", "%search.1", 20.12, 9.88),
+           rec(dev, "XLA Modules", "jit__pow_search_x(1)", 10.0, 10.0),
+           rec(host, "python3", "mine.round.wait", 19.99, 0.135),
+           rec(host, "python3", "mine.round.wait", 12.0, 0.007)]
+    for at, plan, submit, call in ((13.0, 2e-4, 1.0e-3, 0.8e-3),
+                                   (14.0, 3e-4, 1.1e-3, 0.9e-3),
+                                   (15.0, 4e-4, 1.3e-3, 1.2e-3)):
+        out.append(rec(host, "python3", "mine.round.issue", at,
+                       plan + submit + 5e-6))
+        out.append(rec(host, "drainer", "runtime.call", at + plan + 5e-5,
+                       call))
+        if with_the_new_spans:
+            out.append(rec(host, "python3", "mine.round.plan", at, plan))
+            out.append(rec(host, "python3", "mine.round.submit",
+                           at + plan, submit))
+    return out
+
+
+@pytest.mark.parametrize("name,layer,reads,parent_reads", [
+    ("round_plan_ms.mine", "miner CLI and engine", 0.3, None),
+    ("round_submit_ms.mine", "device owner", 1.1, None),
+    ("round_call_ms.mine", "device owner", 0.9, 0.9),
+    ("idle_round_wait_share.mine", "device", 0.6, 0.6)])
+def test_a_new_metrics_data_file_reads_hand_made_records(
+        bench_manifest, name, layer, reads, parent_reads):
+    mf = bench_manifest.load_manifest()
+    entry = [m for m in mf["per_layer"] if m["name"] == name]
+    assert len(entry) == 1 and mf["per_layer"].index(entry[0]) >= 18
+    entry = entry[0]
+    assert entry["workloads"] == [w["name"] for w in mf["workloads"]]
+    assert (entry["layer"], entry["moves"], entry["source"],
+            entry["better"]) == (layer, "search_mhs", "program_span",
+                                 "lower")
+    # the cell finds the file by the metric's name, the reader by the file
+    specs = {e["name"]: spec for e, spec in
+             bench_manifest.layer_metrics_for(mf, "mine-roll-4chip")}
+    spec = specs[name]
+    assert all(spec[k] == entry[k] for k in ("layer", "unit", "moves",
+                                             "source"))
+    assert spec["reader"] in ("span_stat", "idle_by_span")  # no new reader
+    reader = bench_manifest.load_module("readers", spec["reader"])
+    got = reader.read({"records": _hand_made_records()}, spec)
+    assert got == pytest.approx(reads)
+    # a program without the two spans (the parent): nothing to read for
+    # them, and no exception; the other two read a span it already opens
+    old = reader.read({"records": _hand_made_records(False)}, spec)
+    assert old == (None if parent_reads is None
+                   else pytest.approx(parent_reads))
 
 
 # ------------------------------------------------ the compile listener --

@@ -419,8 +419,8 @@ class DeviceRuntime:
             self.submissions += 1
             self.source_submissions[item.source] = \
                 self.source_submissions.get(item.source, 0) + 1
-            metrics.inc("runtime.submissions")
-            metrics.inc("runtime.source.%s" % item.source)
+            metrics.update((("runtime.submissions", 1),
+                            ("runtime.source.%s" % item.source, 1)))
             self._cv.notify_all()
         self._ensure_thread()
 

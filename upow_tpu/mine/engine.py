@@ -273,8 +273,7 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
             with telemetry.span("mine.round.wait", light=True):
                 hit = int(handle)
             tried += count
-            telemetry.inc("mine.rounds")
-            telemetry.inc("mine.nonces", count)
+            telemetry.update((("mine.rounds", 1), ("mine.nonces", count)))
             if hit != int(sha_kernel.SENTINEL):
                 if job.check(hit):
                     if backend == "mesh":

@@ -286,42 +286,51 @@ class MeshEngine:
 
         ``count`` must fit one round (<= :attr:`capacity`); the caller's
         loop (engine.mine) sizes rounds accordingly."""
-        if not self._armed:
-            raise RuntimeError("MeshEngine.dispatch before arm()")
-        if self._job_arrays is None:
-            raise RuntimeError("MeshEngine.dispatch before set_job()")
-        if count <= 0 or count > self.capacity:
-            raise ValueError(
-                f"round of {count} nonces does not fit capacity "
-                f"{self.capacity} ({self._n_dev} shards x "
-                f"{self.batch_per_device})")
-        from ..device.runtime import get_runtime
-        from ..parallel.mesh import pow_search_resident
+        # the engine's own Python a round, then the hand-over: the two
+        # parts of the caller's mine.round.issue (or mine.first_issue)
+        with telemetry.span("mine.round.plan", light=True):
+            if not self._armed:
+                raise RuntimeError("MeshEngine.dispatch before arm()")
+            if self._job_arrays is None:
+                raise RuntimeError("MeshEngine.dispatch before set_job()")
+            if count <= 0 or count > self.capacity:
+                raise ValueError(
+                    f"round of {count} nonces does not fit capacity "
+                    f"{self.capacity} ({self._n_dev} shards x "
+                    f"{self.batch_per_device})")
+            from ..device.runtime import get_runtime
+            from ..parallel.mesh import pow_search_resident
 
-        shards = self.plan_round(start, count)
-        ranges = sha_kernel.resident_operand(shards)
-        self._dispatches += 1
-        self._nonces_planned += count
-        self._rounds.append(
-            {"round": self._dispatches, "lo": start, "hi": start + count,
-             "shards": shards})
-        if len(self._rounds) > ACCOUNTING_WINDOW:
-            del self._rounds[0]
-        mid, tail, target = self._job_arrays
-        nonce_spec, batch, mesh = self._nonce_spec, self._batch_per_device, self._mesh
-        interpret = self._interpret
-        _ktel.record_mine_round(
-            [hi - lo for lo, hi in shards], batch,
-            compile_key=(batch, self._n_dev, nonce_spec))
-        if self._body == "pallas":
-            # the share of rounds that ran the kernel: 1.0 on a chip
-            telemetry.inc("mine.mesh.rounds_pallas")
-        runtime = get_runtime()
-        return sha_kernel.SearchAnswer(runtime.submit_call(
-            lambda: pow_search_resident(
-                mid, tail, ranges, target,
-                batch, nonce_spec, mesh, interpret),
-            kernel="sha256_search_mesh", source="mine").result(), "mine_mesh")
+            shards = self.plan_round(start, count)
+            ranges = sha_kernel.resident_operand(shards)
+            self._dispatches += 1
+            self._nonces_planned += count
+            self._rounds.append(
+                {"round": self._dispatches, "lo": start,
+                 "hi": start + count, "shards": shards})
+            if len(self._rounds) > ACCOUNTING_WINDOW:
+                del self._rounds[0]
+            mid, tail, target = self._job_arrays
+            nonce_spec, batch, mesh = (
+                self._nonce_spec, self._batch_per_device, self._mesh)
+            interpret = self._interpret
+            _ktel.record_mine_round(
+                [hi - lo for lo, hi in shards], batch,
+                compile_key=(batch, self._n_dev, nonce_spec))
+            if self._body == "pallas":
+                # the share of rounds that ran the kernel: 1.0 on a chip
+                telemetry.inc("mine.mesh.rounds_pallas")
+            runtime = get_runtime()
+        # enqueue, the drainer's wake-up, runtime.call on its thread (the
+        # jitted call with its ranges placement), the future back: less
+        # runtime.call, the hop between the two threads
+        with telemetry.span("mine.round.submit", light=True):
+            words = runtime.submit_call(
+                lambda: pow_search_resident(
+                    mid, tail, ranges, target,
+                    batch, nonce_spec, mesh, interpret),
+                kernel="sha256_search_mesh", source="mine").result()
+        return sha_kernel.SearchAnswer(words, "mine_mesh")
 
     def dispatcher(self, job) -> Callable:
         """dispatch(start, count) closure for :func:`engine.mine`'s

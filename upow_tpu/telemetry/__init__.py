@@ -27,7 +27,7 @@ from ..logger import get_logger
 from . import device, events, exposition, metrics, scope, slo, tracing
 from .events import emit as event
 from .metrics import (counters, ensure_counter, ensure_histogram,  # noqa: F401
-                      histograms, inc, observe, stats)
+                      histograms, inc, observe, stats, update)
 from .scope import TelemetryScope  # noqa: F401
 from .tracing import (add_span, attached, child_span, current_span,  # noqa: F401
                       current_trace_id, finish_child, new_trace_id,
@@ -46,7 +46,7 @@ __all__ = [
     "event", "events", "exposition", "finish_child", "histograms",
     "inc", "metrics", "new_trace_id", "observe", "open_traces",
     "profile", "request_trace", "reset", "scope", "slo", "span",
-    "stats", "traces", "tracing", "valid_trace_id",
+    "stats", "traces", "tracing", "update", "valid_trace_id",
 ]
 
 

@@ -24,9 +24,10 @@ here — if the caller hasn't, there is nothing to report."""
 
 from __future__ import annotations
 
+import functools
 import sys
 import threading
-from typing import Dict, Hashable, Optional, Set
+from typing import Dict, Hashable, Optional, Set, Tuple
 
 from ..logger import get_logger
 from . import metrics
@@ -53,27 +54,47 @@ def preregister(kernel: str) -> None:
         metrics.ensure_counter("kernel.%s.%s" % (kernel, c))
 
 
-def record_batch(kernel: str, real: int, padded: int,
-                 seconds: Optional[float] = None,
-                 compile_key: Optional[Hashable] = None) -> None:
-    """Record one batch dispatch. ``real`` lanes of ``padded`` total."""
+_BATCH_FAMILIES = ("lanes_real", "lanes_padded", "occupancy",
+                   "dispatch_seconds", "compile_cache_hits",
+                   "compile_cache_misses")
+
+
+@functools.lru_cache(maxsize=None)
+def _batch_names(kernel: str) -> tuple:
+    """``kernel.<kernel>.<family>`` in ``_BATCH_FAMILIES``' order (the
+    kernels are a handful of literals; a round formats no name)."""
+    return tuple("kernel.%s.%s" % (kernel, f) for f in _BATCH_FAMILIES)
+
+
+def _batch_update(kernel: str, real: int, padded: int,
+                  seconds: Optional[float],
+                  compile_key: Optional[Hashable]) -> Tuple[list, list]:
+    """What one batch dispatch adds to ``kernel.<kernel>.*``, as the
+    (incs, observations) of one ``metrics.update``."""
+    (lanes_real, lanes_padded, occupancy, dispatch_seconds, hits,
+     misses) = _batch_names(kernel)
     padded = max(int(padded), 1)
     real = min(max(int(real), 0), padded)
-    metrics.inc("kernel.%s.lanes_real" % kernel, real)
-    metrics.inc("kernel.%s.lanes_padded" % kernel, padded)
-    metrics.observe("kernel.%s.occupancy" % kernel, real / padded,
-                    buckets=OCCUPANCY_BUCKETS)
+    incs = [(lanes_real, real), (lanes_padded, padded)]
+    observations = [(occupancy, real / padded, OCCUPANCY_BUCKETS)]
     if seconds is not None:
-        metrics.observe("kernel.%s.dispatch_seconds" % kernel, seconds,
-                        buckets=DISPATCH_BUCKETS)
+        observations.append((dispatch_seconds, seconds, DISPATCH_BUCKETS))
     if compile_key is not None:
         with _lock:
             seen = _seen_keys.setdefault(kernel, set())
             hit = compile_key in seen
             if not hit and len(seen) < _MAX_KEYS_PER_KERNEL:
                 seen.add(compile_key)
-        metrics.inc("kernel.%s.compile_cache_%s"
-                    % (kernel, "hits" if hit else "misses"))
+        incs.append((hits if hit else misses, 1))
+    return incs, observations
+
+
+def record_batch(kernel: str, real: int, padded: int,
+                 seconds: Optional[float] = None,
+                 compile_key: Optional[Hashable] = None) -> None:
+    """Record one batch dispatch. ``real`` lanes of ``padded`` total."""
+    metrics.update(*_batch_update(kernel, real, padded, seconds,
+                                  compile_key))
 
 
 def record_hoist(kernel: str, rounds: int, words: int) -> None:
@@ -159,17 +180,19 @@ def record_runtime_dispatch(n_submissions: int,
                             seconds: float) -> None:
     """Record one device-runtime drain: how many submissions shared the
     dispatch, how long each source's items queued, the queue depth seen
-    at pop time, and the occupancy of the padded batch."""
-    metrics.inc("runtime.dispatches")
-    metrics.observe("runtime.coalesced", n_submissions,
-                    buckets=RUNTIME_COALESCE_BUCKETS)
-    metrics.observe("runtime.queue_depth", max(depth, 1),
-                    buckets=RUNTIME_QUEUE_DEPTH_BUCKETS)
+    at pop time, and the occupancy of the padded batch.  One update: a
+    miner's ``call`` item pays this once a round."""
+    incs, observations = _batch_update("device_runtime", real, padded,
+                                       seconds, None)
+    incs.append(("runtime.dispatches", 1))
+    observations.append(("runtime.coalesced", n_submissions,
+                         RUNTIME_COALESCE_BUCKETS))
+    observations.append(("runtime.queue_depth", max(depth, 1),
+                         RUNTIME_QUEUE_DEPTH_BUCKETS))
     for source, wait in waits_by_source.items():
-        metrics.observe("runtime.queue_wait.%s" % source,
-                        max(wait, 0.0), buckets=DISPATCH_BUCKETS)
-    record_batch("device_runtime", real=real, padded=padded,
-                 seconds=seconds)
+        observations.append(("runtime.queue_wait.%s" % source,
+                             max(wait, 0.0), DISPATCH_BUCKETS))
+    metrics.update(incs, observations)
 
 
 INDEX_KERNELS = ("utxo_probe", "utxo_apply", "accept_fused")
@@ -225,11 +248,12 @@ def record_mine_round(shard_spans, batch_per_device: int,
     surface as a new key = a ``compile_cache_misses`` increment."""
     spans = [max(int(s), 0) for s in shard_spans]
     cap = max(int(batch_per_device), 1)
-    record_batch("mine_mesh", real=sum(spans), padded=cap * len(spans),
-                 seconds=seconds, compile_key=compile_key)
+    incs, observations = _batch_update(
+        "mine_mesh", sum(spans), cap * len(spans), seconds, compile_key)
     for span in spans:
-        metrics.observe("mine.shard_occupancy", min(span / cap, 1.0),
-                        buckets=OCCUPANCY_BUCKETS)
+        observations.append(("mine.shard_occupancy", min(span / cap, 1.0),
+                             OCCUPANCY_BUCKETS))
+    metrics.update(incs, observations)
 
 
 def record_mine_hit(latency_seconds: float) -> None:

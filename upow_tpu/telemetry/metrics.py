@@ -24,7 +24,8 @@ drops (that counter itself is exempt from the cap).
 from __future__ import annotations
 
 import threading
-from typing import Dict, Optional, Sequence
+from bisect import bisect_left
+from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 from ..logger import get_logger
 from . import scope
@@ -74,13 +75,16 @@ class MetricsRegistry:
 
     def record_span(self, name: str, seconds: float) -> None:
         with self._lock:
-            if not self._admit(self._stats, name, "span"):
-                return
-            agg = self._stats.setdefault(
-                name, {"count": 0, "total_s": 0.0, "max_s": 0.0})
+            agg = self._stats.get(name)
+            if agg is None:
+                if not self._admit(self._stats, name, "span"):
+                    return
+                agg = self._stats[name] = {
+                    "count": 0, "total_s": 0.0, "max_s": 0.0}
             agg["count"] += 1
             agg["total_s"] += seconds
-            agg["max_s"] = max(agg["max_s"], seconds)
+            if seconds > agg["max_s"]:
+                agg["max_s"] = seconds
 
     def stats(self) -> Dict[str, dict]:
         with self._lock:
@@ -90,8 +94,10 @@ class MetricsRegistry:
 
     def inc(self, name: str, n: int = 1) -> None:
         with self._lock:
-            if not self._admit(self._counters, name, "counter"):
-                return
+            self._inc_locked(name, n)
+
+    def _inc_locked(self, name: str, n: int) -> None:
+        if self._admit(self._counters, name, "counter"):
             self._counters[name] = self._counters.get(name, 0) + n
 
     def counters(self) -> Dict[str, int]:
@@ -111,19 +117,33 @@ class MetricsRegistry:
         Prometheus ``le`` semantics.
         """
         with self._lock:
-            h = self._hists.get(name)
-            if h is None:
-                if not self._admit(self._hists, name, "histogram"):
-                    return
-                h = self._new_hist(name, buckets)
-            h["count"] += 1
-            h["sum"] += value
-            for i, bound in enumerate(h["bounds"]):
-                if value <= bound:
-                    h["counts"][i] += 1
-                    break
-            else:
-                h["counts"][-1] += 1  # +Inf overflow bucket
+            self._observe_locked(name, value, buckets)
+
+    def _observe_locked(self, name: str, value: float,
+                        buckets: Optional[Sequence[float]]) -> None:
+        h = self._hists.get(name)
+        if h is None:
+            if not self._admit(self._hists, name, "histogram"):
+                return
+            h = self._new_hist(name, buckets)
+        h["count"] += 1
+        h["sum"] += value
+        # the first bound the value does not pass; past the last, the
+        # +Inf overflow bucket
+        h["counts"][bisect_left(h["bounds"], value)] += 1
+
+    def update(self, incs: Iterable[Tuple[str, int]] = (),
+               observations: Iterable[Tuple[str, float, Optional[
+                   Sequence[float]]]] = ()) -> None:
+        """Several ``inc`` and ``observe`` as one update, under one
+        lock: for a caller on a per-round path whose counters and
+        histograms always move together (a mining round, a device
+        call).  Each name ends with what the single calls would give."""
+        with self._lock:
+            for name, n in incs:
+                self._inc_locked(name, n)
+            for name, value, buckets in observations:
+                self._observe_locked(name, value, buckets)
 
     def observe_exemplar(self, name: str, value: float,
                          trace_id: str) -> None:
@@ -223,6 +243,10 @@ def stats() -> Dict[str, dict]:
 
 def inc(name: str, n: int = 1) -> None:
     _reg().inc(name, n)
+
+
+def update(incs=(), observations=()) -> None:
+    _reg().update(incs, observations)
 
 
 def counters() -> Dict[str, int]:

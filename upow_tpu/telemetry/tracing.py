@@ -25,11 +25,14 @@ Every span is also a host event of the JAX profiler: ``request_trace``,
 ``span`` and the ``child_span``/``finish_child`` pair hold a
 ``jax.profiler.TraceAnnotation`` open for their duration, so whenever a
 profiler session runs (the benchmark's ``--trace 1``, the node's
-``/debug/profile``, the miner's SIGUSR1 hook) the program's spans are in
-the device trace, on the device events' clock.  This module never
-imports jax: the class is looked up in ``sys.modules`` once jax is
-loaded, and a process that never loads it (wallet, supervisor) pays one
-dict lookup a span.
+``/debug/profile``, the miner's SIGUSR1 hook), whichever thread started
+it, the program's spans are in the device trace, on the device events'
+clock.  The event is built only while a session can hear it (the
+class's own ``is_enabled``, one flag read): with none running a span
+costs its clock reads and its aggregate.  This module never imports
+jax: the class is looked up in ``sys.modules`` once jax is loaded, and
+a process that never loads it (wallet, supervisor) pays one dict lookup
+a span.
 """
 
 from __future__ import annotations
@@ -63,10 +66,17 @@ _annotation_cls: Any = None
 _ANNOTATION_TEXT = 64   # characters of a string field kept in an event
 
 
-def _annotate(name: str, trace_id: Optional[str], fields: Dict[str, Any]):
+#: ``_annotate``'s trace id for "the ambient root's", looked up only
+#: once the event is known to be heard
+_AMBIENT = object()
+
+
+def _annotate(name: str, trace_id: Any, fields: Dict[str, Any]):
     """Open a profiler host event ``name`` carrying the small fields and
-    the root's trace id (``trace=<id>``); None when jax is not loaded.
-    The profiler must never break the caller."""
+    the root's trace id (``trace=<id>``); None when jax is not loaded or
+    no profiler session is there to hear it (a class without
+    ``is_enabled``, the tests' fake, is always heard).  The profiler
+    must never break the caller."""
     global _annotation_cls
     cls = _annotation_cls
     if cls is None:
@@ -77,9 +87,15 @@ def _annotate(name: str, trace_id: Optional[str], fields: Dict[str, Any]):
             return None
         _annotation_cls = cls
     try:
+        heard = getattr(cls, "is_enabled", None)
+        if heard is not None and not heard():
+            return None
         kw = {k: (v[:_ANNOTATION_TEXT] if isinstance(v, str) else v)
               for k, v in fields.items()
               if isinstance(v, (str, int, float)) and k != "name"}
+        if trace_id is _AMBIENT:
+            parent = _current.get()
+            trace_id = parent.root.trace_id if parent is not None else None
         if trace_id:
             kw["trace"] = trace_id
         ann = cls(name, **kw)
@@ -300,17 +316,52 @@ def request_trace(name: str, trace_id: Optional[str] = None,
         buf.record(root)
 
 
-@contextlib.contextmanager
 def span(name: str, level: str = "debug", light: bool = False,
          **fields: Any):
     """Time a section: flat aggregate and profiler event always, tree
     node when traced.  ``light`` is for per-round work (a sweep of 128
     rounds): no tree node is made, so a job's tree and its root's span
-    budget are left to the job-level spans."""
+    budget are left to the job-level spans, and the span is two clock
+    reads and one aggregate update."""
+    if light:
+        return _LightSpan(name, level, fields)
+    return _tree_span(name, level, fields)
+
+
+def _log_span(name: str, level: str, dt: float,
+              fields: Dict[str, Any]) -> None:
+    lvl = _LEVELS.get(level, 10)
+    if log.isEnabledFor(lvl):
+        extra = "".join(f" {k}={v}" for k, v in fields.items())
+        log.log(lvl, "%s took %.3fs%s", name, dt, extra)
+
+
+class _LightSpan:
+    """``span(..., light=True)``: yields None, touches no tree."""
+
+    __slots__ = ("_name", "_level", "_fields", "_ann", "_t0")
+
+    def __init__(self, name: str, level: str, fields: Dict[str, Any]):
+        self._name, self._level, self._fields = name, level, fields
+
+    def __enter__(self) -> None:
+        self._ann = _annotate(self._name, _AMBIENT, self._fields)
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        dt = time.perf_counter() - self._t0
+        _annotate_end(self._ann)
+        metrics.record_span(self._name, dt)
+        _log_span(self._name, self._level, dt, self._fields)
+        return False
+
+
+@contextlib.contextmanager
+def _tree_span(name: str, level: str, fields: Dict[str, Any]):
     parent = _current.get()
     node: Optional[Span] = None
     token = None
-    if not light and parent is not None and not parent.root.done:
+    if parent is not None and not parent.root.done:
         node = Span(name, root=parent.root, **fields)
         if _attach(parent, node):
             token = _current.set(node)
@@ -334,10 +385,7 @@ def span(name: str, level: str = "debug", light: bool = False,
             node.duration_s = dt
             node.done = True
         metrics.record_span(name, dt)
-        lvl = _LEVELS.get(level, 10)
-        if log.isEnabledFor(lvl):
-            extra = "".join(f" {k}={v}" for k, v in fields.items())
-            log.log(lvl, "%s took %.3fs%s", name, dt, extra)
+        _log_span(name, level, dt, fields)
 
 
 def child_span(parent: Optional[Span], name: str,
