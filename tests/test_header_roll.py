@@ -267,6 +267,40 @@ def test_a_fast_miner_hashes_no_header_twice_while_the_window_has_room(
     assert telemetry.stats()["mine.roll"]["count"] >= 12
 
 
+def test_a_dropped_jobs_second_is_spent_and_costs_nothing_else(
+        rollref, frozen_clock, monkeypatch, capsys):
+    """ISSUE 44: the second job finds a block in its last round, the
+    third, stamped a second further back, already issued behind it: that
+    one is dropped.  The node refuses the block, so the tip stands and
+    the job after the push is built on the same key: it takes the next
+    fresh second, not the dropped job's, which stays swept.  Nothing
+    repeats, every timestamp is inside the node's rule, and the three
+    counters add up to the jobs and the dropped one."""
+    import miner_seams
+
+    now = clock.timestamp()
+    device = miner_seams.FakeDevice(monkeypatch, hits={(1, 12288): 99})
+    names = ("mine.jobs", "mine.jobs_dropped")
+    before, rolled = miner_seams.counters(*names), _counters()
+    out, _ = miner_seams.run_jobs(
+        monkeypatch, capsys, 5, push=lambda *a: {"ok": False},
+        serve=lambda k: miner_seams.info(prev_ts=now - 8))
+    lines = [tuple(int(g) for g in m.groups())
+             for m in map(HEADER.fullmatch, out) if m]
+    # the jobs that became the job in hand: the third built is not one
+    assert [ts for ts, *_rest in lines] == [now, now - 1, now - 3, now - 4]
+    assert all(repeat == 0 for *_rest, repeat in lines)
+    stamped = [int.from_bytes(job.prefix[-6:-2], "little")
+               for job in device.jobs]
+    assert stamped == [now, now - 1, now - 2, now - 3, now - 4]
+    assert all(rollref.valid(now - 8, ts, now) for ts in stamped)
+    assert len({job.prefix for job in device.jobs}) == 5
+    assert device.rounds("wait", 2) == []           # the dropped one
+    assert miner_seams.grew(before) == {"mine.jobs": 4,
+                                        "mine.jobs_dropped": 1}
+    assert sum(_counters()) - sum(rolled) == 5
+
+
 def test_a_one_second_window_is_todays_miner(frozen_clock, monkeypatch,
                                               capsys):
     """What the benchmark's older stub serves, ``int(now) - 1``: every

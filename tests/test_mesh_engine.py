@@ -24,6 +24,7 @@ import importlib.util
 import os
 import random
 import sys
+import time
 
 import jax
 import pytest
@@ -97,8 +98,8 @@ def _assert_laid_over_mesh(eng: MeshEngine) -> None:
     them as, so a round re-lays nothing."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    assert len(eng._job_arrays) == 3
-    for a in eng._job_arrays:
+    assert len(eng._job.arrays) == 3
+    for a in eng._job.arrays:
         assert a.committed
         assert a.sharding.is_fully_replicated
         assert a.sharding == NamedSharding(eng._mesh, P())
@@ -354,11 +355,10 @@ def test_job_layouts_count_jobs_not_rounds(body, request):
     job = _seeded_job(11)
     eng.set_job(job)
     _assert_laid_over_mesh(eng)
-    arrays = eng._job_arrays
+    laid = eng._job
     assert layouts() == (1, 1)
-    assert eng.dispatcher(job) == eng.dispatch   # the same job again
-    eng.set_job(job)
-    assert eng._job_arrays is arrays
+    assert eng.dispatcher(job).args == (laid,)   # the same job again
+    assert eng.set_job(job) is laid
     assert layouts() == (1, 1)
     if body == "jnp":   # a round lays nothing (seconds in interpret mode)
         rounds = eng.stats()["dispatches"]
@@ -480,6 +480,70 @@ def test_arm_ladder_success_records_platform_rung():
     # re-arming is a no-op that returns the same ladder
     again = eng.arm()
     assert again["armed"] and again["ladder"] == ladder
+
+
+# ------------------------------------- two jobs laid at once (ISSUE 44) ----
+
+@pytest.mark.parametrize("next_is", ["a_new_key", "the_same_key"])
+def test_the_next_job_laid_leaves_the_rounds_in_flight_to_their_own_job(
+        next_is):
+    """The seam as the miner makes it: job A has a round in flight and
+    one still to issue when job B is laid and issues its first.  Every
+    answer is its own job's (the serial path's lowest hit), every round
+    record names its job, a hit's latency runs from its own job's
+    layout, a new key costs one layout and a key set again none, and
+    nothing is compiled after the arm."""
+    from upow_tpu.telemetry import device as ktel
+
+    eng = _armed_engine(batch_per_device=64)
+    cap = eng.capacity
+    job_a = _seeded_job(21, difficulty="1")
+    job_b = job_a if next_is == "the_same_key" \
+        else _seeded_job(22, difficulty="1")
+    jit_entries = eng.stats()["jit_entries"]
+    rounds_a = eng.dispatcher(job_a)
+    laid_a = eng._job
+    in_flight = rounds_a(0, cap)
+    time.sleep(0.02)                       # A's layout is the older
+    rounds_b = eng.dispatcher(job_b)       # set_job while A's round is out
+    first_b = rounds_b(0, cap)
+    last_a = rounds_a(cap, cap)            # A's own, after B was set
+    assert (eng._job is laid_a) == (next_is == "the_same_key")
+    layouts = 1 if next_is == "the_same_key" else 2
+    assert eng.stats()["job_layouts"] == layouts
+    assert metrics.counters()["mine.mesh.job_layouts"] == layouts
+    # the arm's one program (the process's others are other tests')
+    assert eng.stats()["jit_entries"] == jit_entries
+
+    def serial(job, start):
+        return int(pow_search_jnp(
+            make_template(job.prefix),
+            target_spec(job.previous_hash, job.difficulty),
+            nonce_base=start, batch=cap))
+
+    assert int(in_flight) == serial(job_a, 0)
+    assert int(first_b) == serial(job_b, 0)
+    assert int(last_a) == serial(job_a, cap)
+    assert [(r["job"], r["lo"], r["hi"]) for r in eng.stats()["rounds"]] \
+        == [(1, 0, cap), (layouts, 0, cap), (1, cap, 2 * cap)]
+    # a hit of A, found after B was set, is timed from A's layout
+    seen = []
+    real = ktel.record_mine_hit
+    try:
+        ktel.record_mine_hit = seen.append
+        eng.note_hit(job_a)
+        eng.note_hit(job_b)
+        eng.note_hit()                     # the job set last: B
+    finally:
+        ktel.record_mine_hit = real
+    assert seen[0] >= 0.02
+    assert (seen[0] - seen[1] >= 0.015) == (next_is == "a_new_key")
+    assert abs(seen[2] - seen[1]) < 0.015
+    # a third key drops the oldest layout, and with it that job's base
+    eng.set_job(_seeded_job(23))
+    eng.note_hit(job_a if next_is == "a_new_key" else _seeded_job(24))
+    assert metrics.histograms().get("mine.hit_latency", {}).get(
+        "count", 0) == 0
 
 
 # ------------------------------------------------------- telemetry ----

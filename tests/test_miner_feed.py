@@ -190,7 +190,8 @@ def test_a_node_that_is_away_gives_held_jobs_on_fresh_seconds(
         return k > 6 and jobs[-1][4] == 0     # a fresh one after the return
 
     names = ("mine.jobs", "mine.jobs_held", "mine.fetch_errors",
-             "mine.templates_late") + miner.ROLL_COUNTERS
+             "mine.templates_late") + miner.ROLL_COUNTERS \
+        + miner.SEAM_COUNTERS
     before = _counters(*names)
     t0 = time.monotonic()
     jobs, _end, err = run_miner(monkeypatch, capsys, node, address, before_job)
@@ -198,6 +199,10 @@ def test_a_node_that_is_away_gives_held_jobs_on_fresh_seconds(
                             zip(_counters(*names), before))))
     held = [j for j in jobs if j[4]]
     assert [j[4] for j in jobs[:7]] == [0] + [1] * 6
+    # a held job is never one issued ahead: its seam found no template
+    assert grew[miner.DRAINED] >= len(held) and grew[miner.DROPPED] == 0
+    assert grew[miner.OVERLAPPED] + grew[miner.DRAINED] \
+        == grew["mine.jobs"] - 1
     # fresh seconds: no header twice, none repeated, all inside the rule
     assert len({j[0] for j in jobs}) == len(jobs)
     assert all(j[3] == 0 and 0 <= j[1] < j[2] for j in jobs)
@@ -226,6 +231,106 @@ def test_a_node_that_is_away_gives_held_jobs_on_fresh_seconds(
     assert built["fields"]["template_age_s"] == \
         job["fields"]["template_age_s"] > 0
     assert telemetry.stats()["mine.take_template"]["count"] >= len(jobs)
+
+
+# ---- the seam and the feed (ISSUE 44) ----
+
+def test_the_asks_lead_covers_a_fetch_longer_than_the_rounds_in_flight(
+        monkeypatch, capsys):
+    """Rounds of 50 ms, a node that takes 120 ms to answer: asked for
+    only when two rounds are left (the rule before the seam) the
+    template would come 20 ms after the job's end, and the job after it
+    would be a held one.  The lead holds the fetch's seconds beside the
+    rounds in flight, so it is there while the last round runs: the
+    next job goes behind it, and none is held."""
+    import miner_seams
+
+    miner_seams.FakeDevice(monkeypatch, round_s=0.05)
+
+    def serve(k):
+        time.sleep(0.12)
+        return miner_seams.info()
+
+    names = ("mine.jobs", "mine.jobs_held") + miner.SEAM_COUNTERS
+    before = miner_seams.counters(*names)
+    out, _ = miner_seams.run_jobs(monkeypatch, capsys, 4, serve=serve,
+                                  rounds=8, feed=miner.TemplateFeed)
+    grew = miner_seams.grew(before)
+    lines = [m.groups() for m in map(HEADER.fullmatch, out) if m]
+    assert len(lines) == grew["mine.jobs"] == 4
+    assert [held for *_rest, held, _age in lines] == ["0"] * 4
+    assert grew["mine.jobs_held"] == 0 and grew[miner.DROPPED] == 0
+    # a loaded host may lose one seam to its scheduler, not two
+    assert grew[miner.OVERLAPPED] >= 2
+    assert grew[miner.OVERLAPPED] + grew[miner.DRAINED] == 3
+
+
+def test_a_template_that_comes_after_the_seams_last_look_makes_no_held_job(
+        monkeypatch, capsys):
+    """The node answers only once the last round of the job in hand is
+    being waited for: every look of the seam finds the fetch still out,
+    nothing is issued ahead, and the job's end takes the template as it
+    always has: a drained seam, and no held job where the loop before
+    the seam gave none."""
+    import miner_seams
+
+    asked, arrived = threading.Event(), threading.Event()
+    device = miner_seams.FakeDevice(monkeypatch)
+
+    class Feed(miner.TemplateFeed):
+        def _arrived(self, got, for_job):
+            super()._arrived(got, for_job)
+            arrived.set()
+
+    def serve(k):
+        if k:
+            assert asked.wait(WAIT_S)
+            asked.clear()
+        return miner_seams.info()
+
+    last = miner_seams.RANGE - miner_seams.RANGE // 4
+
+    def on_wait(number, start):
+        if start == last:
+            arrived.clear()
+            asked.set()
+            assert arrived.wait(WAIT_S)
+
+    device.on_wait = on_wait
+    names = ("mine.jobs", "mine.jobs_held") + miner.SEAM_COUNTERS
+    before = miner_seams.counters(*names)
+    out, _ = miner_seams.run_jobs(monkeypatch, capsys, 4, serve=serve,
+                                  feed=Feed)
+    grew = miner_seams.grew(before)
+    assert grew == {"mine.jobs": 4, "mine.jobs_held": 0, miner.OVERLAPPED: 0,
+                    miner.DRAINED: 3, miner.DROPPED: 0}
+    lines = [m.groups() for m in map(HEADER.fullmatch, out) if m]
+    assert [held for *_rest, held, _age in lines] == ["0"] * 4
+    # no round of a job before the last answer of the one before it
+    order = [n for _w, n, _s in device.log]
+    assert order == sorted(order)
+
+
+def test_one_fetch_a_job_across_overlapped_seams(monkeypatch, capsys, node,
+                                                 address):
+    """Jobs issued ahead ask the node once each, as drained ones do: the
+    rounds the job in hand still reads after the seam took its template
+    ask for nothing.  Rounds of 20 ms, so that the node's answer, a
+    few ms on a loaded host, is there before a job of four ends: a late
+    one would make a held job, as it always has."""
+    import miner_seams
+
+    miner_seams.FakeDevice(monkeypatch, round_s=0.02)
+    names = ("mine.jobs",) + miner.SEAM_COUNTERS
+    before = miner_seams.counters(*names)
+    jobs, _end, _err = run_miner(monkeypatch, capsys, node, address,
+                                 lambda k, jobs: k == 8)
+    grew = miner_seams.grew(before)
+    assert grew["mine.jobs"] == len(jobs) == 9
+    assert all(j[4] == 0 for j in jobs)
+    assert grew[miner.OVERLAPPED] + grew[miner.DRAINED] == 8
+    # the ninth job's template, and at most the tenth's on its way
+    assert len(jobs) <= len(node.fetches) <= len(jobs) + 1
 
 
 def test_a_template_past_its_ttl_is_not_mined(monkeypatch, capsys, node,

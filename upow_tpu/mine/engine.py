@@ -23,6 +23,7 @@ from __future__ import annotations
 import contextlib
 import hashlib
 import time
+from collections import deque
 from dataclasses import dataclass
 from decimal import Decimal
 from typing import Callable, Optional
@@ -222,10 +223,84 @@ class MineResult:
         return self.hashes_tried / self.elapsed if self.elapsed > 0 else 0.0
 
 
+#: rounds a job keeps in flight on a device backend, so that the chip
+#: never idles while the host blocks on a result.  A hit wastes at most
+#: the rounds in flight (already dispatched): negligible against the
+#: throughput the overlap buys
+ROUNDS_IN_FLIGHT = 2
+
+
+class Sweep:
+    """One job's rounds on a device backend: made ready (``mine.prepare``:
+    on the mesh the job's arrays laid), issued ``ROUNDS_IN_FLIGHT`` deep,
+    answered in order.  :func:`mine` drives it, and may be handed one
+    whose first rounds an earlier call issued behind the last rounds of
+    its own job (``ahead``): the device then goes from one job's last
+    round to the next job's first with no host between them.  Its spans
+    lie in the tree of the span that was ambient when it was made."""
+
+    def __init__(self, job: MiningJob, backend: str, *, start: int = 0,
+                 stride_end: int = NONCE_SPACE, batch: int = 1 << 22,
+                 mesh_devices: int = 0):
+        self.job, self.backend, self.batch = job, backend, batch
+        self.mesh_devices = mesh_devices
+        self.cursor, self.end = start, min(stride_end, MAX_SEARCH_END)
+        self.root = telemetry.current_span()
+        self.t0 = time.time()
+        self.tried = 0
+        self.first: Optional[float] = None
+        self.inflight: deque = deque()  # (handle, count), oldest first
+        with telemetry.span("mine.prepare", backend=backend):
+            self.dispatch = _make_dispatcher(
+                job, backend, mesh_devices=mesh_devices, batch=batch)
+
+    def issue(self) -> None:
+        """Rounds issued until ``ROUNDS_IN_FLIGHT`` are in flight or the
+        range is spent."""
+        while len(self.inflight) < ROUNDS_IN_FLIGHT and self.cursor < self.end:
+            count = min(self.batch, self.end - self.cursor)
+            if self.first is None:
+                # on the static-target engine a new tip's trace and
+                # compile live in this dispatch
+                with telemetry.span("mine.first_issue"):
+                    handle = self.dispatch(self.cursor, count)
+                self.first = time.time() - self.t0
+            else:
+                with telemetry.span("mine.round.issue", light=True):
+                    handle = self.dispatch(self.cursor, count)
+            self.inflight.append((handle, count))
+            self.cursor += count
+
+    def wait(self) -> Optional[int]:
+        """The oldest round's answer, counted: its hit, checked on the
+        host, or None."""
+        handle, count = self.inflight.popleft()
+        with telemetry.span("mine.round.wait", light=True):
+            hit = int(handle)
+        self.tried += count
+        telemetry.update((("mine.rounds", 1), ("mine.nonces", count)))
+        if hit == int(sha_kernel.SENTINEL):
+            return None
+        if not self.job.check(hit):
+            raise AssertionError(f"backend {self.backend} returned nonce "
+                                 f"{hit} failing host check")
+        if self.backend == "mesh":
+            from .mesh_engine import get_mesh_engine
+
+            get_mesh_engine(mesh_devices=self.mesh_devices).note_hit(self.job)
+        return hit
+
+    def result(self, nonce: Optional[int]) -> MineResult:
+        return MineResult(nonce, self.tried, time.time() - self.t0,
+                          self.first or 0.0)
+
+
 def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
          stride_end: int = NONCE_SPACE, batch: int = 1 << 22,
          ttl: float = 90.0, progress: Optional[Callable] = None,
-         mesh_devices: int = 0) -> MineResult:
+         mesh_devices: int = 0, ahead: Optional[Sweep] = None,
+         next_job: Optional[Callable[[], Optional[Sweep]]] = None
+         ) -> MineResult:
     """Search [start, stride_end) in fixed rounds until hit or TTL.
 
     ``start``/``stride_end`` let a coordinator hand disjoint nonce ranges to
@@ -238,76 +313,81 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
     backend runs that short round on the program of the whole ones, the
     surplus lanes masked (:func:`_make_dispatcher`); ``hashes_tried``,
     ``mine.nonces`` and the progress callback count live lanes only.
+
+    The pipeline does not drain at a job's end where the caller has the
+    next job ready: once the last round of ``job`` is issued and while
+    rounds are in flight, ``next_job()`` is asked, before each wait, for
+    the next job's :class:`Sweep` (None: not yet), whose first rounds are
+    then issued behind the ones in flight, under its own root span (so
+    twice ``ROUNDS_IN_FLIGHT`` are out for the length of a round); the
+    caller hands it back as ``ahead`` with that job (``start``,
+    ``stride_end`` and ``batch`` are then the sweep's own).  A hit in
+    ``job``'s last rounds leaves such a sweep to be dropped by the caller:
+    its rounds are never waited for and counted nowhere.  Host backends
+    keep nothing in flight and never ask.
+
+    An answer read, the queue is filled again (the next round issued, at
+    a seam the next job's) before ``progress`` hears of the round: with
+    ``ROUNDS_IN_FLIGHT`` out, the device is one round from idling when
+    an answer comes, and what the loop says lies behind that, not before
+    it.  A sweep the ``ttl`` cuts issues nothing after the cut.
     """
-    stride_end = min(stride_end, MAX_SEARCH_END)
-    t0 = time.time()
-    tried = 0
-    cursor = start
+    sweep = ahead if ahead is not None else Sweep(
+        job, backend, start=start, stride_end=stride_end, batch=batch,
+        mesh_devices=mesh_devices)
+    if sweep.dispatch is None:
+        return _mine_on_host(sweep, ttl, progress)
+    issued_ahead = False
 
-    with telemetry.span("mine.prepare", backend=backend):
-        dispatch = _make_dispatcher(job, backend, mesh_devices=mesh_devices,
-                                    batch=batch)
-    if dispatch is not None:
-        # Pipelined device rounds: keep `depth` dispatches in flight so the
-        # chip never idles while the host blocks on a result.  A hit wastes
-        # at most the in-flight rounds (already dispatched) — negligible
-        # against the throughput the overlap buys.
-        depth = 2
-        inflight = []  # (handle, base, count)
-        first = None
-        while cursor < stride_end or inflight:
-            while len(inflight) < depth and cursor < stride_end:
-                count = min(batch, stride_end - cursor)
-                if first is None:
-                    # on the static-target engine a new tip's trace and
-                    # compile live in this dispatch
-                    with telemetry.span("mine.first_issue"):
-                        handle = dispatch(cursor, count)
-                    first = time.time() - t0
-                else:
-                    with telemetry.span("mine.round.issue", light=True):
-                        handle = dispatch(cursor, count)
-                inflight.append((handle, cursor, count))
-                cursor += count
-            handle, _, count = inflight.pop(0)
-            with telemetry.span("mine.round.wait", light=True):
-                hit = int(handle)
-            tried += count
-            telemetry.update((("mine.rounds", 1), ("mine.nonces", count)))
-            if hit != int(sha_kernel.SENTINEL):
-                if job.check(hit):
-                    if backend == "mesh":
-                        from .mesh_engine import get_mesh_engine
+    def fill() -> None:
+        nonlocal issued_ahead
+        sweep.issue()
+        if (next_job is not None and not issued_ahead
+                and sweep.cursor >= sweep.end and sweep.inflight):
+            following = next_job()
+            if following is not None:
+                issued_ahead = True
+                with telemetry.attached(following.root):
+                    following.issue()
 
-                        get_mesh_engine(mesh_devices=mesh_devices).note_hit()
-                    return MineResult(hit, tried, time.time() - t0, first)
-                raise AssertionError(
-                    f"backend {backend} returned nonce {hit} failing host check")
-            elapsed = time.time() - t0
-            if progress is not None:
-                with telemetry.span("mine.progress", light=True):
-                    progress(tried, elapsed)
-            if elapsed > ttl:
-                break
-        return MineResult(None, tried, time.time() - t0, first or 0.0)
+    fill()
+    while sweep.inflight:
+        hit = sweep.wait()
+        if hit is not None:
+            return sweep.result(hit)
+        elapsed = time.time() - sweep.t0
+        cut = elapsed > ttl
+        if not cut:
+            fill()   # before anything is said: the device is waiting
+        if progress is not None:
+            with telemetry.span("mine.progress", light=True):
+                progress(sweep.tried, elapsed)
+        if cut:
+            break
+    return sweep.result(None)
 
+
+def _mine_on_host(sweep: Sweep, ttl: float,
+                  progress: Optional[Callable]) -> MineResult:
+    """``native`` and ``python``: a round is searched where it is issued."""
+    job, backend = sweep.job, sweep.backend
     search = _make_searcher(job, backend)
-    while cursor < stride_end:
-        count = min(batch, stride_end - cursor)
-        hit = search(cursor, count)
-        tried += count
+    while sweep.cursor < sweep.end:
+        count = min(sweep.batch, sweep.end - sweep.cursor)
+        hit = search(sweep.cursor, count)
+        sweep.tried += count
         telemetry.inc("mine.rounds")
         telemetry.inc("mine.nonces", count)
         if hit is not None:
             # device says hit; host double-checks before shipping (cheap)
             if job.check(hit):
-                return MineResult(hit, tried, time.time() - t0)
+                return MineResult(hit, sweep.tried, time.time() - sweep.t0)
             raise AssertionError(
                 f"backend {backend} returned nonce {hit} failing host check")
-        elapsed = time.time() - t0
+        elapsed = time.time() - sweep.t0
         if progress is not None:
-            progress(tried, elapsed)
+            progress(sweep.tried, elapsed)
         if elapsed > ttl:
             break
-        cursor += count
-    return MineResult(None, tried, time.time() - t0)
+        sweep.cursor += count
+    return MineResult(None, sweep.tried, time.time() - sweep.t0)

@@ -23,7 +23,11 @@ tpu`` on a mesh of one device, ``--device mesh`` over
   compiled for (the arm's warm dispatch lays its zeros the same way), so
   a round hands pjit nothing to re-lay; only ``ranges`` crosses a round.
   ``mine.mesh.job_layouts`` counts the placements, and
-  ``stats()["jit_entries"]`` stays 1.
+  ``stats()["jit_entries"]`` stays 1.  Two jobs stay laid
+  (``JOBS_LAID``): the miner lays the next job and issues its first
+  rounds while the last rounds of the job in hand are in flight, so a
+  job's rounds (:meth:`dispatcher`) are bound to the arrays of their own
+  job, whichever was set last.
 * **Disjoint shard ranges** — each round's [start, start+count) window
   is split across the mesh with :func:`parallel.mesh.shard_bounds`; the
   per-round plan is retained in the dispatch accounting so tests (and
@@ -47,9 +51,10 @@ mesh — DCN never sees the hot loop.
 
 from __future__ import annotations
 
+import functools
 import logging
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -62,6 +67,24 @@ log = logging.getLogger("upow.mine.mesh")
 #: rounds of per-shard range accounting retained (oldest dropped);
 #: totals keep counting past the window
 ACCOUNTING_WINDOW = 4096
+#: jobs whose arrays stay on the mesh: the one in hand and the one the
+#: miner issues behind its last rounds (engine.py ``mine``)
+JOBS_LAID = 2
+
+
+class LaidJob(NamedTuple):
+    """One job as the resident program takes it."""
+
+    arrays: tuple       # midstate, tail, target: a page each, replicated
+    nonce_spec: tuple
+    t0: float           # perf_counter when it was laid: note_hit's base
+    serial: int         # 1 for the engine's first layout; the ``job`` of
+                        # the round records
+
+
+def _job_key(job) -> tuple:
+    return (job.prefix, job.previous_hash, str(job.difficulty))
+
 
 def _arm_attempt(name: str, ok: bool, seconds: float,
                  error: Optional[BaseException] = None,
@@ -104,10 +127,8 @@ class MeshEngine:
         self._armed = False
         self.arm_ladder: List[dict] = []
         self.arm_failure_reason: Optional[str] = None
-        self._job_key: Optional[tuple] = None
-        self._job_arrays = None
-        self._nonce_spec = None
-        self._job_t0 = 0.0
+        self._laid: Dict[tuple, LaidJob] = {}   # the newest JOBS_LAID
+        self._job: Optional[LaidJob] = None     # the last one set
         self._rounds: List[dict] = []
         self._dispatches = 0
         self._nonces_planned = 0
@@ -216,7 +237,7 @@ class MeshEngine:
         # laid as a job's arrays are: jit keys a program on the shardings
         # of committed arguments, and the first job must find this one
         spec = sha_kernel.make_template(bytes(104)).nonce_spec
-        zeros = self._lay_over_mesh([0])
+        zeros, = self._lay_over_mesh([0])
         no_ranges = sha_kernel.resident_operand(
             np.zeros((self._n_dev, 2), np.uint32))
 
@@ -230,43 +251,52 @@ class MeshEngine:
 
     # ------------------------------------------------------------- job ---
 
-    def _lay_over_mesh(self, words):
-        """``words`` padded to a page (``sha256.resident_operand``) as a
-        committed array replicated on every device of the engine's mesh:
-        the sharding the resident program takes its three job operands
-        in, so a dispatch hands pjit nothing to lay again."""
+    def _lay_over_mesh(self, *operands) -> tuple:
+        """Each of ``operands`` padded to a page
+        (``sha256.resident_operand``) as a committed array replicated on
+        every device of the engine's mesh: the sharding the resident
+        program takes its three job operands in, so a dispatch hands
+        pjit nothing to lay again.  One placement for all of them: a
+        placement holds back the rounds already queued for about as long
+        as it takes, and three apart cost a bare loop of rounds on four
+        chips 4.2 ms where one costs 2.6 (PERF.md section 6, PR 44)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         # data placement onto the armed runtime's own devices, not a
         # dispatch and no enumeration
-        return jax.device_put(  # upowlint: disable=DR001
-            sha_kernel.resident_operand(words),
-            NamedSharding(self._mesh, P()))
+        return tuple(jax.device_put(  # upowlint: disable=DR001
+            [sha_kernel.resident_operand(words) for words in operands],
+            NamedSharding(self._mesh, P())))
 
-    def set_job(self, job) -> None:
+    def set_job(self, job) -> LaidJob:
         """Load a :class:`..mine.engine.MiningJob`: host-side midstate +
         packed target are laid over the mesh, once a job; the resident
         program is NOT recompiled (all job fields are traced arguments
-        of the sharding the arm's warm dispatch compiled for)."""
+        of the sharding the arm's warm dispatch compiled for).  Rounds
+        of an earlier job, in flight or still to be issued through its
+        own :meth:`dispatcher`, keep the arrays they were laid with."""
         if not self._armed:
             raise RuntimeError("MeshEngine.set_job before arm()")
-        key = (job.prefix, job.previous_hash, str(job.difficulty))
-        if self._job_key == key:
-            return
-        template = sha_kernel.make_template(job.prefix)
-        spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
-        self._job_arrays = tuple(
-            self._lay_over_mesh(words) for words in (
+        key = _job_key(job)
+        laid = self._laid.get(key)
+        if laid is None:
+            template = sha_kernel.make_template(job.prefix)
+            spec = sha_kernel.target_spec(job.previous_hash, job.difficulty)
+            arrays = self._lay_over_mesh(
                 template.midstate, template.tail_words,
-                sha_kernel.pack_target(spec)))
-        self._job_layouts += 1
-        telemetry.inc("mine.mesh.job_layouts")
-        _ktel.record_hoist(
-            "mine_mesh", *sha_kernel.hoisted_counts(template.nonce_spec))
-        self._nonce_spec = template.nonce_spec
-        self._job_key = key
-        self._job_t0 = time.perf_counter()
+                sha_kernel.pack_target(spec))
+            self._job_layouts += 1
+            telemetry.inc("mine.mesh.job_layouts")
+            _ktel.record_hoist(
+                "mine_mesh", *sha_kernel.hoisted_counts(template.nonce_spec))
+            laid = LaidJob(arrays, template.nonce_spec,
+                           time.perf_counter(), self._job_layouts)
+            while len(self._laid) >= JOBS_LAID:
+                del self._laid[next(iter(self._laid))]   # the oldest
+            self._laid[key] = laid
+        self._job = laid
+        return laid
 
     # -------------------------------------------------------- dispatch ---
 
@@ -280,18 +310,22 @@ class MeshEngine:
                 for i in range(self._n_dev)]
 
     def dispatch(self, start: int, count: int):
-        """Scan [start, start+count) across the mesh; returns the async
-        device handle (a ``sha256.SearchAnswer``: ``int()`` blocks and
-        yields min hit or SENTINEL).
+        """Scan [start, start+count) of the job set last across the
+        mesh; returns the async device handle (a
+        ``sha256.SearchAnswer``: ``int()`` blocks and yields min hit or
+        SENTINEL).
 
         ``count`` must fit one round (<= :attr:`capacity`); the caller's
         loop (engine.mine) sizes rounds accordingly."""
+        return self._issue(self._job, start, count)
+
+    def _issue(self, laid: Optional[LaidJob], start: int, count: int):
         # the engine's own Python a round, then the hand-over: the two
         # parts of the caller's mine.round.issue (or mine.first_issue)
         with telemetry.span("mine.round.plan", light=True):
             if not self._armed:
                 raise RuntimeError("MeshEngine.dispatch before arm()")
-            if self._job_arrays is None:
+            if laid is None:
                 raise RuntimeError("MeshEngine.dispatch before set_job()")
             if count <= 0 or count > self.capacity:
                 raise ValueError(
@@ -306,13 +340,13 @@ class MeshEngine:
             self._dispatches += 1
             self._nonces_planned += count
             self._rounds.append(
-                {"round": self._dispatches, "lo": start,
-                 "hi": start + count, "shards": shards})
+                {"round": self._dispatches, "job": laid.serial,
+                 "lo": start, "hi": start + count, "shards": shards})
             if len(self._rounds) > ACCOUNTING_WINDOW:
                 del self._rounds[0]
-            mid, tail, target = self._job_arrays
+            mid, tail, target = laid.arrays
             nonce_spec, batch, mesh = (
-                self._nonce_spec, self._batch_per_device, self._mesh)
+                laid.nonce_spec, self._batch_per_device, self._mesh)
             interpret = self._interpret
             _ktel.record_mine_round(
                 [hi - lo for lo, hi in shards], batch,
@@ -333,22 +367,24 @@ class MeshEngine:
         return sha_kernel.SearchAnswer(words, "mine_mesh")
 
     def dispatcher(self, job) -> Callable:
-        """dispatch(start, count) closure for :func:`engine.mine`'s
-        pipelined round loop — arms lazily, loads the job, and routes
-        every round through the runtime."""
+        """dispatch(start, count) of ``job``'s own rounds for
+        :func:`engine.mine`'s pipelined round loop — arms lazily, lays
+        the job, and routes every round through the runtime.  It stays
+        ``job``'s after a later ``set_job``."""
         if not self._armed:
             info = self.arm()
             if not info["armed"]:
                 raise RuntimeError(
                     "mesh engine failed to arm: "
                     + (self.arm_failure_reason or "unknown"))
-        self.set_job(job)
-        return self.dispatch
+        return functools.partial(self._issue, self.set_job(job))
 
-    def note_hit(self) -> None:
-        """Record time-to-hit for the current job (mine.hit_latency)."""
-        if self._job_t0:
-            _ktel.record_mine_hit(time.perf_counter() - self._job_t0)
+    def note_hit(self, job=None) -> None:
+        """Record time-to-hit (mine.hit_latency) for ``job`` while it is
+        laid, for the job set last without one."""
+        laid = self._job if job is None else self._laid.get(_job_key(job))
+        if laid is not None:
+            _ktel.record_mine_hit(time.perf_counter() - laid.t0)
 
     # ----------------------------------------------------------- stats ---
 

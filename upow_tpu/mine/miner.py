@@ -44,11 +44,28 @@ block's template is younger than ``--ttl``; nothing is mined beside a
 pending push, and what was fetched before a push is dropped with it (an
 accepted block moves the tip).  ``--once`` fetches in the loop's own
 thread, one template, and starts no feed.
+
+The rounds do not drain at a job's end (a *seam*) where the next
+template is there in time: once the job in hand has issued its last
+round, and while rounds are in flight, the loop builds the next job,
+prepares it (on the mesh: lays its arrays) and issues its first rounds
+behind them, and only then reads the last answers of the job in hand
+(``mine.jobs_overlapped``).  The lines keep their order all the same: the
+next job's ``difficulty:`` and ``header:`` lines are said after the
+``template expired`` line of the job in hand, though its timestamp was
+chosen a few milliseconds earlier.  Where no fresh template is there at
+the seam, after a found block, after a sweep the ``--ttl`` cut, under
+``--once`` and on the host backends, which keep nothing in flight, the
+next job begins when the job in hand has ended (``mine.jobs_drained``).
+A job issued ahead is thrown away where the job in hand finds a block in
+its last rounds (``mine.jobs_dropped``): its rounds are counted nowhere,
+its header's second stays spent.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import contextvars
 import json
 import sys
@@ -56,12 +73,14 @@ import threading
 import time
 import urllib.error
 import urllib.request
+from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
 from .. import telemetry
 from ..core.clock import timestamp
 from ..core.merkle import miner_merkle_root
-from .engine import MAX_SEARCH_END, MiningJob, mine
+from .engine import (MAX_SEARCH_END, ROUNDS_IN_FLIGHT, MiningJob, Sweep,
+                     mine)
 
 GENESIS_PREV_HASH = (18_884_643).to_bytes(32, "little").hex()  # miner.py:37-40
 
@@ -96,13 +115,17 @@ FETCH_ERRORS = TRANSPORT_ERRORS + (RuntimeError,)
 #: begin on a fresh template" that is not the sweep in hand
 FETCH_RETRY_S = 0.5
 PUSH_RETRY_S = 1.0
-#: rounds ``engine.mine`` keeps in flight: a refresh asked for when so
-#: many are left arrives while the device still has work
-ROUNDS_IN_FLIGHT = 2
 
 #: exported at zero from the first scrape, like ``ROLL_COUNTERS``
 FEED_COUNTERS = ("mine.jobs_held", "mine.push_retries",
                  "mine.templates_late")
+#: how the loop came from one job to the next, one of the first two a
+#: job but the process's first: ``OVERLAPPED`` + ``DRAINED`` =
+#: ``mine.jobs`` - 1.  A dropped job is no job: ``mine.jobs`` does not
+#: count it, but its header's second is spent, so the ``ROLL_COUNTERS``
+#: add up to ``mine.jobs`` + ``DROPPED``
+SEAM_COUNTERS = OVERLAPPED, DRAINED, DROPPED = (
+    "mine.jobs_overlapped", "mine.jobs_drained", "mine.jobs_dropped")
 
 
 def _say(line: str) -> None:
@@ -277,6 +300,21 @@ class TemplateFeed:
                 self._cond.wait(1.0)
             self.jobs += 1
             return chosen
+
+    def poll(self, have: Optional[Template]) -> Optional[Template]:
+        """``take`` for a seam, while the rounds of the job in hand are
+        in flight: no wait and no held job.  The newest template if it
+        is newer than ``have`` and none fresher is on its way, which
+        begins the next job; else None, and the seam is ``take``'s once
+        the job in hand has ended."""
+        with self._cond:
+            if self._died is not None:
+                raise self._died
+            if self._wanted or self._newest is None \
+                    or self._newest is have:
+                return None
+            self.jobs += 1
+            return self._newest
 
 
 def push_until_verdict(node: str, content: str, txs: list, block_no: int,
@@ -483,6 +521,35 @@ def _start_hang_watchdog(heartbeat: dict, limit: float, _exit=None):
     return t
 
 
+@dataclass
+class _Job:
+    """One job from its build to its end."""
+
+    root: object            # its ``mine.job`` root span
+    template: Template
+    held: bool
+    work: MiningJob
+    pending_hashes: list
+    block_no: int
+    stamp: Stamp
+    age: float                      # the template's, when the job was built
+    sweep: Optional[Sweep] = None   # issued ahead, at the seam before it
+
+
+@contextlib.contextmanager
+def _under(root, ends: bool):
+    """``root`` ambient for the block, and closed with it where the block
+    ``ends`` the job or raises."""
+    try:
+        with telemetry.attached(root):
+            yield
+    except BaseException as e:
+        telemetry.close_trace(root, e)
+        raise
+    if ends:
+        telemetry.close_trace(root)
+
+
 def run(address: str, node: str, device: str, batch: int, ttl: float,
         shard: tuple = (0, 1), once: bool = False,
         mesh_devices: int = 0, hang_grace: float = 90.0,
@@ -501,12 +568,15 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
     if backend in ("pallas", "jnp", "mesh") and not once:
         _start_hang_watchdog(heartbeat, ttl + hang_grace)
     roll = HeaderRoll()
-    for name in ROLL_COUNTERS + FEED_COUNTERS:   # at zero from scrape one
-        telemetry.ensure_counter(name)
+    for name in ROLL_COUNTERS + FEED_COUNTERS + SEAM_COUNTERS:
+        telemetry.ensure_counter(name)   # at zero from scrape one
     total = min(hi, MAX_SEARCH_END) - lo
     feed = TemplateFeed(node)
     if not once:
         feed.start()
+    job = ahead = None   # the job in hand; the one issued behind it
+    seam_s = 0.0         # what the last seam's build and prepare took
+    begun = 0            # jobs that became the job in hand
 
     def beat():
         heartbeat["t"] = time.monotonic()
@@ -515,40 +585,78 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
         beat()
         heartbeat["limit"] = ttl + hang_grace  # compiled: steady budget
         _say(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
-        # the next template is asked for while this job's last rounds
-        # are in flight, and earlier where the node's last answer took
-        # longer than they do; a sweep the ttl will cut ends there
+        # the next template is asked for so that it is in hand when the
+        # seam begins, at this job's last issue: the rounds then still in
+        # flight, one more because this look comes once a round, the
+        # fetch's seconds, and the last seam's build and prepare for
+        # room (a seam that finds no template looks again a round
+        # later); a sweep the ttl will cut ends there.  Once the seam has
+        # its template the next ask is the next job's
         round_s = elapsed * batch / tried
         left_s = min((total - tried) / batch * round_s, ttl - elapsed)
-        if not once and left_s <= max(ROUNDS_IN_FLIGHT * round_s, feed.took):
+        if not once and ahead is None and left_s <= (
+                (ROUNDS_IN_FLIGHT + 1) * round_s + feed.took + seam_s):
             feed.ask()
 
-    def one_job(root, template: Template, held: bool) -> tuple:
-        """Build, search and push one job under its ``mine.job`` root.
-        Returns (what ``--once`` exits with, whether a block was
-        pushed)."""
+    def open_root():
+        return telemetry.open_trace("mine.job", backend=backend,
+                                    shard=f"{i}/{k}", nonces=total)
+
+    def build(root, template: Template, held: bool) -> _Job:
         info, age = template.info, template.age()
         said = {"held": int(held), "template_age_s": round(age, 3)}
         with telemetry.span("mine.build_job") as built:
-            job, pending_hashes, block_no, stamp = build_job(
+            mining_job, pending_hashes, block_no, stamp = build_job(
                 info, address, roll)
             if built is not None:
                 built.fields.update(stamp._asdict(), **said,
                                     pending=len(pending_hashes))
-        telemetry.inc("mine.jobs")
-        if held:
-            telemetry.inc("mine.jobs_held")
         root.fields.update(
             stamp._asdict(), **said,
             block=block_no, difficulty=str(info["difficulty"]),
-            tip=str(getattr(job, "previous_hash", ""))[-12:])
-        _say(f"difficulty: {info['difficulty']}  block: {block_no}  "
-             f"confirming {len(pending_hashes)} transactions")
+            tip=str(getattr(mining_job, "previous_hash", ""))[-12:])
+        return _Job(root, template, held, mining_job, pending_hashes,
+                    block_no, stamp, age)
+
+    def next_job() -> Optional[Sweep]:
+        """``engine.mine`` at a seam, rounds of the job in hand still in
+        flight: the next job built and prepared under its own root, if
+        its template is here; its first rounds are the engine's to
+        issue."""
+        nonlocal ahead, seam_s
+        with telemetry.span("mine.take_template", light=True):
+            template = feed.poll(job.template)
+        if template is None:
+            return None
+        t0 = time.perf_counter()
+        root = open_root()
+        with _under(root, ends=False):
+            ahead = build(root, template, held=False)
+            ahead.sweep = Sweep(ahead.work, backend, start=lo, stride_end=hi,
+                                batch=batch, mesh_devices=mesh_devices)
+        seam_s = time.perf_counter() - t0
+        return ahead.sweep
+
+    def search_and_end() -> tuple:
+        """The job in hand from its lines to its end.  Returns (what
+        ``--once`` exits with, whether a block was pushed)."""
+        nonlocal ahead, begun
+        root, info = job.root, job.template.info
+        telemetry.inc("mine.jobs")
+        if begun:
+            telemetry.inc(DRAINED if job.sweep is None else OVERLAPPED)
+        begun += 1
+        if job.held:
+            telemetry.inc("mine.jobs_held")
+        stamp = job.stamp
+        _say(f"difficulty: {info['difficulty']}  block: {job.block_no}  "
+             f"confirming {len(job.pending_hashes)} transactions")
         _say(f"header: timestamp={stamp.timestamp} behind={stamp.behind_s} "
              f"window={stamp.window_s} repeat={stamp.repeat} "
-             f"held={int(held)} age={age:.1f}")
-        result = mine(job, backend, start=lo, stride_end=hi, batch=batch,
-                      ttl=ttl, progress=progress, mesh_devices=mesh_devices)
+             f"held={int(job.held)} age={job.age:.1f}")
+        result = mine(job.work, backend, start=lo, stride_end=hi, batch=batch,
+                      ttl=ttl, progress=progress, mesh_devices=mesh_devices,
+                      ahead=job.sweep, next_job=None if once else next_job)
         if result.nonce is None:
             telemetry.inc("mine.jobs_expired")
             root.fields["end"] = "expired"
@@ -557,7 +665,14 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
             return 1, False
         telemetry.inc("mine.jobs_found")
         root.fields["end"] = "found"
-        content = job.block_content(result.nonce)
+        if ahead is not None:
+            # found in the last rounds, the next job's first behind them:
+            # those are left where they are, and the job was none
+            telemetry.inc(DROPPED)
+            ahead.root.fields["end"] = "dropped"
+            telemetry.close_trace(ahead.root)
+            ahead = None
+        content = job.work.block_content(result.nonce)
         _say(f"found nonce {result.nonce} at {result.hashrate / 1e6:.2f} MH/s"
              f" ({result.hashes_tried} hashes in {result.elapsed:.2f}s, first"
              f" dispatch {result.first_dispatch:.2f}s)")
@@ -565,7 +680,8 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
             _print_mesh_accounting(mesh_devices)
         with telemetry.span("mine.push") as pushed:
             reply, tries = push_until_verdict(
-                node, content, pending_hashes, block_no, template, ttl, beat)
+                node, content, job.pending_hashes, job.block_no,
+                job.template, ttl, beat)
             if pushed is not None:
                 pushed.fields.update(ok=bool(reply.get("ok")),
                                      attempts=tries)
@@ -574,28 +690,38 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
             _say("BLOCK MINED\n")
         return (0 if reply.get("ok") else 1), True
 
-    template = None
+    def take_and_build(have: Optional[Template]) -> _Job:
+        """The seam with nothing in flight, and the first job."""
+        root = feed.root = open_root()   # a fetch waited for is this job's
+        with _under(root, ends=False):
+            # from the end of a job to the template of the next: any
+            # wait for the node is in here, and nowhere else
+            with telemetry.span("mine.take_template", light=True):
+                template = feed.fetch_until_good(beat) if once \
+                    else feed.take(have, ttl, beat)
+            return build(root, template, held=template is have)
+
+    have = None
     try:
         while True:
             beat()
-            with telemetry.request_trace(
-                    "mine.job", backend=backend, shard=f"{i}/{k}",
-                    nonces=total) as root:
-                feed.root = root
-                # from the end of a job to the template of the next: any
-                # wait for the node is in here, and nowhere else
-                with telemetry.span("mine.take_template", light=True):
-                    have = template
-                    template = feed.fetch_until_good(beat) if once \
-                        else feed.take(have, ttl, beat)
-                rc, pushed = one_job(root, template, held=template is have)
+            if ahead is not None:
+                job, ahead = ahead, None
+                feed.root = job.root
+            else:
+                job = take_and_build(have)
+            with _under(job.root, ends=True):
+                rc, pushed = search_and_end()
             if once:
                 return rc
+            have = job.template
             if pushed:
-                template = None
+                have = None
                 feed.forget()
     finally:
         feed.stop()
+        if ahead is not None:
+            telemetry.close_trace(ahead.root)
 
 
 def _print_mesh_accounting(mesh_devices: int) -> None:

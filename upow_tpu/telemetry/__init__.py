@@ -29,10 +29,10 @@ from .events import emit as event
 from .metrics import (counters, ensure_counter, ensure_histogram,  # noqa: F401
                       histograms, inc, observe, stats, update)
 from .scope import TelemetryScope  # noqa: F401
-from .tracing import (add_span, attached, child_span, current_span,  # noqa: F401
-                      current_trace_id, finish_child, new_trace_id,
-                      open_traces, request_trace, span, traces,
-                      valid_trace_id)
+from .tracing import (add_span, attached, child_span, close_trace,  # noqa: F401
+                      current_span, current_trace_id, finish_child,
+                      new_trace_id, open_trace, open_traces, request_trace,
+                      span, traces, valid_trace_id)
 
 log = get_logger("telemetry")
 
@@ -41,10 +41,10 @@ TRACE_HEADER = "X-Upow-Trace"
 
 __all__ = [
     "TRACE_HEADER", "TelemetryScope", "add_span", "attached",
-    "child_span", "configure", "counters", "current_span",
+    "child_span", "close_trace", "configure", "counters", "current_span",
     "current_trace_id", "device", "ensure_counter", "ensure_histogram",
     "event", "events", "exposition", "finish_child", "histograms",
-    "inc", "metrics", "new_trace_id", "observe", "open_traces",
+    "inc", "metrics", "new_trace_id", "observe", "open_trace", "open_traces",
     "profile", "request_trace", "reset", "scope", "slo", "span",
     "stats", "traces", "tracing", "update", "valid_trace_id",
 ]

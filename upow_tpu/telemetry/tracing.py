@@ -134,7 +134,8 @@ class Span:
     """
 
     __slots__ = ("name", "trace_id", "fields", "start_ts", "_t0",
-                 "duration_s", "children", "root", "done", "error", "_ann")
+                 "duration_s", "children", "root", "done", "error", "_ann",
+                 "_buf")
 
     def __init__(self, name: str, trace_id: Optional[str] = None,
                  root: Optional["Span"] = None, **fields: Any):
@@ -149,6 +150,7 @@ class Span:
         self.done = False
         self.error: Optional[str] = None
         self._ann = None        # child_span's open profiler event
+        self._buf = None        # open_trace's buffer, pinned for the close
 
     def finish(self, **fields: Any) -> float:
         if fields:
@@ -292,28 +294,46 @@ def _attach(parent: Span, child: Span) -> bool:
     return True
 
 
+def open_trace(name: str, trace_id: Optional[str] = None,
+               **fields: Any) -> Span:
+    """Open a root span that ``close_trace`` ends.  It does not become
+    the ambient span (``attached`` does that, for as long as the caller
+    works on it): for roots whose lives overlap, as the miner's jobs do
+    across a seam."""
+    tid = trace_id if valid_trace_id(trace_id) else new_trace_id()
+    root = Span(name, trace_id=tid, **fields)
+    root._buf = _buf()  # pinned, so that open and record hit one scope
+    root._buf.record_open(root)
+    root._ann = _annotate(name, tid, fields)
+    return root
+
+
+def close_trace(root: Span, error: Optional[BaseException] = None) -> None:
+    """Record ``root``'s tree into the ring buffer it was opened in."""
+    if error is not None:
+        root.error = type(error).__name__
+    _annotate_end(root._ann)
+    root._ann = None
+    root.finish()
+    root.fields.pop("_spans", None)
+    metrics.record_span(root.name, root.duration_s)
+    root._buf.record(root)
+
+
 @contextlib.contextmanager
 def request_trace(name: str, trace_id: Optional[str] = None,
                   **fields: Any):
     """Open a root span; on exit record the tree into the ring buffer."""
-    tid = trace_id if valid_trace_id(trace_id) else new_trace_id()
-    root = Span(name, trace_id=tid, **fields)
-    buf = _buf()  # pin the buffer so open/record hit the same scope
-    buf.record_open(root)
+    root = open_trace(name, trace_id, **fields)
     token = _current.set(root)
-    ann = _annotate(name, tid, fields)
     try:
         yield root
     except BaseException as e:
         root.error = type(e).__name__
         raise
     finally:
-        _annotate_end(ann)
         _current.reset(token)
-        root.finish()
-        root.fields.pop("_spans", None)
-        metrics.record_span(name, root.duration_s)
-        buf.record(root)
+        close_trace(root)
 
 
 def span(name: str, level: str = "debug", light: bool = False,
