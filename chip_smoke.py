@@ -31,7 +31,8 @@ To fit the driver's 1,200 s with an empty compile cache, the number of
 first dispatches is cut, never the width: B by default starts from a
 copy of A's database at height 3, verifies the 8,160-tx block as ONE
 8,192-lane dispatch and hashes txids on the host (two P-256 shapes at
-5-7 min each instead of four, and no sha256 crossover measurement).
+about a minute each since PR 46, 5-7 before, instead of four, and no
+sha256 crossover measurement).
 ``--b-start blank --b-microbatch 1024 --b-txid auto`` restores the full
 replay at the node's defaults; PERF.md has what that costs.
 
@@ -517,6 +518,13 @@ def device_check(b: NodeProc, report: dict, n_real: int) -> list:
     for f in firsts:
         say(f"[B] first dispatch at {f['padded']} lanes ({f['real']} real): "
             f"{f['status']} in {f['seconds']}s")
+    # what each of B's programs cost to make (compile_cache.listen)
+    compiles = [e.get("fields", e) for e in events(b)
+                if e.get("kind") == "compile"]
+    for c in compiles:
+        say(f"[B] compile {c.get('fun_name')}: trace {c.get('trace_s')}s "
+            f"lower {c.get('lower_s')}s cache retrieval "
+            f"{c.get('cache_retrieval_s')}s backend {c.get('backend_s')}s")
     lanes_real = metric(m, "kernel.p256_verify.lanes_real")
     lanes_padded = metric(m, "kernel.p256_verify.lanes_padded")
     hits = metric(m, "compile_cache.persistent_hits")

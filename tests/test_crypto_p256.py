@@ -185,50 +185,6 @@ def test_verify_batch_empty():
     assert p256.verify_batch([], [], []).shape == (0,)
 
 
-@pytest.mark.skipif(not os.environ.get("UPOW_SLOW_TESTS"),
-                    reason="pallas-interpret ladder is a ~2 min compile; "
-                           "set UPOW_SLOW_TESTS=1 to include")
-def test_pallas_ladder_matches_host():
-    """The stacked-layout Pallas verify kernel in interpret mode against
-    host ECDSA, valid + invalid lanes.  (The production limb-list kernel
-    traces ~10x more ops — interpret mode is impractical for it; its
-    field/point math is covered by the limb-list differentials below and
-    the assembled kernel by chip_smoke.py on real TPU.)"""
-    msgs, sigs, pubs = [], [], []
-    for i in range(8):
-        d, pub = curve.keygen(rng=5000 + i)
-        m = i.to_bytes(4, "big") * 4
-        r, s = curve.sign(m, d)
-        if i % 3 == 2:
-            s = (s + 1) % CURVE_N
-        msgs.append(m)
-        sigs.append((r, s))
-        pubs.append(pub)
-    msgs, sigs, pubs = msgs * 16, sigs * 16, pubs * 16
-    import hashlib
-
-    digests = [hashlib.sha256(m).digest() for m in msgs]
-    stacked = p256._verify_device_pallas_stacked
-
-    def interp(*a, **kw):
-        kw["interpret"] = True
-        kw["tile"] = 128
-        return stacked(*a, **kw)
-
-    orig = p256._verify_device_pallas
-    try:
-        p256._verify_device_pallas = interp
-        p256.PALLAS_STRICT = True  # a kernel failure must FAIL, not fall back
-        got = p256.verify_batch_prehashed(
-            digests, sigs, pubs, pad_block=128, backend="pallas",
-            scalar_prep="host")
-    finally:
-        p256.PALLAS_STRICT = False
-        p256._verify_device_pallas = orig
-    want = [curve.verify(sig, m, pk) for sig, m, pk in zip(sigs, msgs, pubs)]
-    assert list(got) == want
-
-
 # --- device-side scalar prep ----------------------------------------------
 
 def test_digits_from_limbs_matches_host():
@@ -311,10 +267,12 @@ def test_device_scalar_prep_full_differential():
 
 
 # --- limb-list layout (Pallas kernel data path) ----------------------------
-# The list ops are plain jnp functions; testing them directly covers the
-# kernel's field arithmetic without a (slow) interpret-mode pallas_call.
-# The assembled kernel itself is exercised on real TPU by chip_smoke.py
-# and compiled for a described v5e by tests/test_tpu_compile.py.
+# The list ops are plain functions; testing them directly covers the
+# kernel's field arithmetic without an interpret-mode pallas_call (the
+# Jacobian kernel's ~100,000 inline operations take XLA:CPU more than
+# half an hour).  The assembled kernel itself is exercised on real TPU
+# by chip_smoke.py and compiled for a described v5e by
+# tests/test_tpu_compile.py.
 
 def _to_fl(xs, bound):
     limbs = fp.ints_to_limbs(xs)
@@ -346,31 +304,6 @@ def test_limb_list_field_ops_match_bigint():
     assert list(np.asarray(fp.l_is_zero_mod_p(nz, _FS))) == [False, False]
 
 
-def test_limb_list_point_add_matches_stacked():
-    G = curve.G
-    P1 = curve.point_mul(rng.randrange(1, CURVE_N), G)
-    neg = (P1[0], CURVE_P - P1[1])
-    cases = [(P1, P1), (P1, neg), (None, P1), (G, G), (None, None), (P1, G)]
-
-    def pt_fl(points):
-        xs = [fp.to_mont(0 if p is None else p[0], _FS) for p in points]
-        ys = [fp.to_mont(1 if p is None else p[1], _FS) for p in points]
-        zs = [fp.to_mont(0 if p is None else 1, _FS) for p in points]
-        return tuple(_to_fl(v, CURVE_P) for v in (xs, ys, zs))
-
-    A, B = pt_fl([c[0] for c in cases]), pt_fl([c[1] for c in cases])
-    b_m = fp.l_const(p256._B_M, np.asarray(A[0].limbs[0]).shape, CURVE_P)
-    X, Y, Z = (_fl_ints(c) for c in p256._point_add_complete_l(A, B, b_m))
-    rinv = pow(1 << fp.R_BITS, -1, CURVE_P)
-    got = []
-    for x, y, z in zip(X, Y, Z):
-        x, y, z = (v * rinv % CURVE_P for v in (x, y, z))
-        got.append(None if z == 0 else
-                   (x * pow(z, -1, CURVE_P) % CURVE_P,
-                    y * pow(z, -1, CURVE_P) % CURVE_P))
-    assert got == [curve.point_add(a_, b_) for a_, b_ in cases]
-
-
 def test_limb_list_mont_sqr_matches_mul():
     xs = [rng.randrange(CURVE_P) for _ in range(8)] + [0, 1, CURVE_P - 1]
     a = _to_fl([fp.to_mont(x, _FS) for x in xs], CURVE_P)
@@ -382,38 +315,120 @@ def test_limb_list_mont_sqr_matches_mul():
     assert _fl_ints(fp.l_mont_sqr(b, _FS)) == _fl_ints(fp.l_mont_mul(b, b, _FS))
 
 
-def test_limb_list_point_dbl_matches_add():
-    G = curve.G
-    P1 = curve.point_mul(rng.randrange(1, CURVE_N), G)
-    cases = [P1, G, None, curve.point_mul(2, G)]
+# the ladder multiplies canonical limbs whose value may reach the loop
+# bound 64p; the worst limb tuple a caller can hand over is every limb
+# at 2^13 - 1 under that value
+_EDGE_VALUES = [0, 1, CURVE_P - 1, CURVE_P, 64 * CURVE_P - 1,
+                (1 << 262) - 1]
 
-    def pt_fl(points):
-        xs = [fp.to_mont(0 if p is None else p[0], _FS) for p in points]
-        ys = [fp.to_mont(1 if p is None else p[1], _FS) for p in points]
-        zs = [fp.to_mont(0 if p is None else 1, _FS) for p in points]
-        return tuple(_to_fl(v, CURVE_P) for v in (xs, ys, zs))
 
-    A = pt_fl(cases)
-    b_m = fp.l_const(p256._B_M, np.asarray(A[0].limbs[0]).shape, CURVE_P)
-    dbl = p256._point_dbl_complete_l(A, b_m)
-    add = p256._point_add_complete_l(A, A, b_m)
-    for c_d, c_a in zip(dbl, add):
-        assert _fl_ints(c_d) == _fl_ints(c_a)
-    # and folding 4 doublings == [16]P through the host oracle
-    cur = A
-    for _ in range(4):
-        cur = tuple(fp.l_wrap(c.limbs, p256._COORD_BOUND) for c in
-                    p256._point_dbl_complete_l(cur, b_m))
-    X, Y, Z = (_fl_ints(c) for c in cur)
-    rinv = pow(1 << fp.R_BITS, -1, CURVE_P)
-    for i, pt in enumerate(cases):
-        x, y, z = (v * rinv % CURVE_P for v in (X[i], Y[i], Z[i]))
-        want = curve.point_mul(16, pt) if pt is not None else None
-        if z == 0:
-            assert want is None
-        else:
-            zi = pow(z, -1, CURVE_P)
-            assert (x * zi % CURVE_P, y * zi % CURVE_P) == want
+@pytest.mark.parametrize("fn,field,shape", [
+    ("mul", "p", (16,)),
+    ("sqr", "p", (16,)),
+    ("reduce", "p", (16,)),
+    ("mul", "p", (2, 8)),     # a kernel tile's rank: limbs are 2-D there
+    ("mul", "n", (16,)),
+    ("sqr", "n", (16,)),
+])
+def test_traced_once_matches_inlined(fn, field, shape):
+    """The field arithmetic of the ladder is traced once a signature
+    (function, limb shape, field) and bound at every call; numpy limbs
+    still run the Python loops as they stand.  Both routes must give the
+    same limbs, bit for bit, and the value Montgomery's rule says."""
+    import jax.numpy as jnp
+
+    fs = _FS if field == "p" else p256._NS
+    n = int(np.prod(shape))
+    xs = _EDGE_VALUES + [rng.randrange(64 * CURVE_P) for _ in range(n - 6)]
+    ys = xs[3:] + xs[:3]
+    a = [np.asarray(l).reshape(shape) for l in fp.ints_to_limbs(xs)]
+    b = [np.asarray(l).reshape(shape) for l in fp.ints_to_limbs(ys)]
+    r_inv = pow(1 << fp.R_BITS, -1, fs.p)
+    if fn == "reduce":
+        # the rows of a product, as _mont_mul_limbs hands them over
+        rows = [sum(a[i] * b[k - i] for i in range(fp.NUM_LIMBS)
+                    if 0 <= k - i < fp.NUM_LIMBS)
+                for k in range(2 * fp.NUM_LIMBS - 1)]
+        inlined = fp._mont_reduce_rows(tuple(rows), fs=fs)
+        traced = fp._mont_reduce_rows(tuple(map(jnp.asarray, rows)), fs=fs)
+        want = [x * y * r_inv % fs.p for x, y in zip(xs, ys)]
+    elif fn == "mul":
+        inlined = fp._mont_mul_limbs(tuple(a), tuple(b), fs=fs)
+        traced = fp._mont_mul_limbs(tuple(map(jnp.asarray, a)),
+                                    tuple(map(jnp.asarray, b)), fs=fs)
+        want = [x * y * r_inv % fs.p for x, y in zip(xs, ys)]
+    else:
+        inlined = fp._mont_sqr_limbs(tuple(a), fs=fs)
+        traced = fp._mont_sqr_limbs(tuple(map(jnp.asarray, a)), fs=fs)
+        want = [x * x * r_inv % fs.p for x in xs]
+    assert all(isinstance(l, np.ndarray) for l in inlined)
+    assert not any(isinstance(l, np.ndarray) for l in traced)
+    for l_inl, l_tr in zip(inlined, traced):
+        assert np.array_equal(l_inl, np.asarray(l_tr))
+    got = _fl_ints(fp.l_wrap([l.reshape(-1) for l in inlined], 3 * fs.p),
+                   fs)
+    assert got == want
+
+
+def test_traced_once_is_one_trace_a_signature():
+    """Every product of one limb shape and field binds the same jaxpr,
+    in one program and in the next; another field has its own."""
+    import jax
+    import jax.numpy as jnp
+
+    a = tuple(jnp.zeros((24,), jnp.int32) for _ in range(fp.NUM_LIMBS))
+
+    def bound_jaxprs(fs, products):
+        def program(x):
+            for _ in range(products):
+                x = fp._mont_mul_limbs(x, a, fs=fs)
+            return x
+
+        eqns = jax.make_jaxpr(program)(a).jaxpr.eqns
+        assert len(eqns) == products    # a product is one equation
+        return {id(e.params["jaxpr"]) for e in eqns}
+
+    first = bound_jaxprs(_FS, 3)
+    assert len(first) == 1
+    assert bound_jaxprs(_FS, 5) == first
+    assert bound_jaxprs(p256._NS, 2).isdisjoint(first)
+
+
+def test_dispatch_frame_keeps_what_runs_below_it_in_one_stack_chunk():
+    """``_pallas_or_jnp`` calls the program from ``_one_stack_chunk``.
+    Without it a loop that happens to stand at the end of one of
+    CPython's 16 KiB frame chunks maps, faults in and unmaps a chunk at
+    every call it makes (here: one page fault a call at one starting
+    depth in about 130); the lowering of the ladder makes ten million
+    calls, and a node's worker thread started it at such a depth
+    (PERF.md section 6, PR 46)."""
+    import resource
+    import sys
+
+    def tiny(a):
+        return a
+
+    def hot():
+        for _ in range(2000):
+            tiny(1)
+
+    def at_depth(d, fn):
+        return fn() if d == 0 else at_depth(d - 1, fn)
+
+    def faults(fn):
+        before = resource.getrusage(resource.RUSAGE_THREAD).ru_minflt
+        fn()
+        return resource.getrusage(resource.RUSAGE_THREAD).ru_minflt - before
+
+    worst = max(faults(lambda: at_depth(
+        d, lambda: p256._one_stack_chunk(hot))) for d in range(160))
+    assert worst < 200, worst
+
+    seen = []
+    p256._pallas_or_jnp(
+        lambda: seen.append(sys._getframe(1).f_code) or np.ones(3, bool),
+        lambda: np.zeros(3, bool))
+    assert seen == [p256._one_stack_chunk.__code__]
 
 
 def test_device_prep_input_sanitation_fast():
@@ -519,21 +534,3 @@ def test_point_mul_jacobian_matches_affine_ladder():
     assert curve.point_mul(5, None) is None
     assert curve.point_mul(0, p) is None
 
-
-# --- env knobs --------------------------------------------------------------
-
-def test_env_choice_accepts_allowed(monkeypatch):
-    from upow_tpu.crypto.p256 import _env_choice
-
-    monkeypatch.setenv("UPOW_TEST_KNOB", " 5 ")
-    assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 5
-
-
-def test_env_choice_rejects_invalid(monkeypatch):
-    from upow_tpu.crypto.p256 import _env_choice
-
-    for bad in ("garbage", "", "6", "4.5"):
-        monkeypatch.setenv("UPOW_TEST_KNOB", bad)
-        assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 4
-    monkeypatch.delenv("UPOW_TEST_KNOB")
-    assert _env_choice("UPOW_TEST_KNOB", 4, {4, 5}) == 4

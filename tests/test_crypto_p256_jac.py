@@ -69,7 +69,7 @@ def _rand_pt():
 
 def test_mont_reduce_sweep_margin_worst_case():
     """The Montgomery tail runs 1 pre-/2 post-sweeps on an int32 overflow
-    budget (see fp._l_mont_reduce docstring).  Drive it with the worst
+    budget (see fp._mont_reduce_rows' docstring).  Drive it with the worst
     representation the pipeline can produce — every limb at the post-sweep
     cap (2^13 + 2^4) — through mul, sqr and chained add/sub + mul, against
     exact bigints."""
@@ -194,7 +194,7 @@ def test_jac_qtable_matches_scalar_mults():
 
 # --- the ladder round logic (short crafted ladders, eager) -----------------
 
-def _run_ladder(d1_rows, d2_rows, Q, r_vals=None, rn_vals=None, w=4):
+def _run_ladder(d1_rows, d2_rows, Q, r_vals=None, rn_vals=None):
     """d1/d2: list of per-round digit lists; Q: affine pubkey point.
     Returns (ok, exc, expected_points) where expected is computed via the
     host oracle from the digit values."""
@@ -213,13 +213,13 @@ def _run_ladder(d1_rows, d2_rows, Q, r_vals=None, rn_vals=None, w=4):
         if rn_vals is None else np.asarray(rn_vals)
     valid = np.ones(n, dtype=bool)
     ok, exc = p256._jac_verify_eager(d1, d2, qx, qy, rm, rnm, rn_ok, valid,
-                                     n_rounds=n_rounds, w=w)
+                                     n_rounds=n_rounds)
     expected = []
     for j in range(n):
         u1 = u2 = 0
         for k in range(n_rounds):
-            u1 = (u1 << w) + int(d1[k, j])
-            u2 = (u2 << w) + int(d2[k, j])
+            u1 = (u1 << 4) + int(d1[k, j])
+            u2 = (u2 << 4) + int(d2[k, j])
         pt = curve.point_add(curve.point_mul(u1, curve.G),
                              curve.point_mul(u2, Q))
         expected.append(pt)
@@ -306,17 +306,16 @@ def test_ladder_rn_wraparound_acceptance():
     assert list(ok) == [True, False]
 
 
-@pytest.mark.parametrize("w", [4, 5])
-def test_ladder_fuzz_random_digits_vs_oracle(w):
-    """Randomized 4-round ladders across many lanes (both window sizes):
+def test_ladder_fuzz_random_digits_vs_oracle():
+    """Randomized 4-round ladders across many lanes:
     verdicts must match the oracle point exactly, with zero spurious
     exception flags (the digit space is tiny, so collisions would need
     acc ≡ pick mod n — impossible below wraparound)."""
     Q = _rand_pt()
     n, rounds = 24, 4
-    d1 = [[rng.randrange(1 << w) for _ in range(n)] for _ in range(rounds)]
-    d2 = [[rng.randrange(1 << w) for _ in range(n)] for _ in range(rounds)]
-    _, _, expected = _run_ladder(d1, d2, Q, w=w)
+    d1 = [[rng.randrange(16) for _ in range(n)] for _ in range(rounds)]
+    d2 = [[rng.randrange(16) for _ in range(n)] for _ in range(rounds)]
+    _, _, expected = _run_ladder(d1, d2, Q)
     r_vals = []
     for j, pt in enumerate(expected):
         if pt is None:
@@ -325,18 +324,16 @@ def test_ladder_fuzz_random_digits_vs_oracle(w):
             r_vals.append((pt[0] + 1) % CURVE_P)   # wrong x -> reject
         else:
             r_vals.append(pt[0])
-    ok, exc, _ = _run_ladder(d1, d2, Q, r_vals=r_vals, w=w)
+    ok, exc, _ = _run_ladder(d1, d2, Q, r_vals=r_vals)
     assert not exc.any()
     for j, pt in enumerate(expected):
         want = pt is not None and j % 3 != 0
         assert bool(ok[j]) == want, (j, pt)
 
 
-@pytest.mark.parametrize("w", [4, 5])
-def test_full_ladder_real_signatures_eager(w):
+def test_full_ladder_real_signatures_eager():
     """The eager twin at full 256-bit scale with real signature-derived
-    digits — the exact data shape the Pallas kernel sees on TPU — at
-    both window sizes."""
+    digits — the exact data shape the Pallas kernel sees on TPU."""
     import hashlib
 
     from upow_tpu.crypto import fp as _fp
@@ -363,40 +360,117 @@ def test_full_ladder_real_signatures_eager(w):
         rnms.append(fp.to_mont((r + CURVE_N) % CURVE_P, _FS))
         rn_oks.append(r + CURVE_N < CURVE_P)
 
-    rounds = p256._jac_rounds(w)
-
     def digits(xs):
         return np.asarray(
-            [[(x >> (w * (rounds - 1 - k))) & ((1 << w) - 1) for x in xs]
-             for k in range(rounds)], dtype=np.int32)
+            [[(x >> (4 * (63 - k))) & 15 for x in xs] for k in range(64)],
+            dtype=np.int32)
 
     d1, d2 = digits(u1s), digits(u2s)
-    if w == 4:  # the device extractor must agree with the host split
-        limbs = _fp.ints_to_limbs(u1s)
-        assert np.array_equal(np.asarray(p256._digits_from_limbs(limbs, w)),
-                              d1)
+    # the device extractor must agree with the host split
+    limbs = _fp.ints_to_limbs(u1s)
+    assert np.array_equal(np.asarray(p256._digits_from_limbs(limbs)), d1)
     qx = _fp.ints_to_limbs([fp.to_mont(pk[0], _FS) for pk in pubs])
     qy = _fp.ints_to_limbs([fp.to_mont(pk[1], _FS) for pk in pubs])
     rm = _fp.ints_to_limbs(rms)
     rnm = _fp.ints_to_limbs(rnms)
     ok, exc = p256._jac_verify_eager(
         d1, d2, qx, qy, rm, rnm, np.asarray(rn_oks),
-        np.ones(len(msgs), dtype=bool), w=w)
+        np.ones(len(msgs), dtype=bool))
     assert not exc.any()
     assert list(ok) == want
 
 
-def test_digits_from_limbs_w5_matches_host():
-    """The static bit surgery at w=5 (uneven 52x5 split) against a plain
-    python digit split."""
-    xs = [rng.randrange(CURVE_N) for _ in range(10)] + [0, 1, CURVE_N - 1]
-    limbs = fp.ints_to_limbs(xs)
-    got = np.asarray(p256._digits_from_limbs(limbs, 5))
-    rounds = p256._jac_rounds(5)
-    want = np.asarray(
-        [[(x >> (5 * (rounds - 1 - k))) & 31 for x in xs]
-         for k in range(rounds)], dtype=np.int32)
-    assert np.array_equal(got, want)
+# --- what Python traces and what the compilers are handed -----------------
+
+def _count_equations(jaxpr, seen):
+    """(traced, inline): equations of ``jaxpr`` and below, a sub-jaxpr
+    bound more than once counted once / at every binding (a loop's body
+    is one binding: the compiler is handed it once)."""
+    traced = inline = 0
+    for eqn in jaxpr.eqns:
+        traced += 1
+        inline += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if not hasattr(sub, "eqns"):
+                    continue
+                if id(sub) not in seen:
+                    t, seen[id(sub)] = _count_equations(sub, seen)
+                    traced += t
+                inline += seen[id(sub)]
+    return traced, inline
+
+
+def test_equation_budget_of_the_dispatched_program():
+    """A ``device=tpu`` node's first dispatch of a shape is Python
+    tracing the program, Python lowering it and the compilers' own time,
+    and all three follow these counts (PERF.md section 6, PR 46: 493,446
+    equations traced and 439,371 handed to Mosaic inline took the chip's
+    host five minutes).  The program a node dispatches at 8,192 lanes:
+    the field arithmetic traced once, the Q table's additions and the
+    round's doublings one loop body each."""
+    import jax
+    import jax.numpy as jnp
+
+    packed = jax.ShapeDtypeStruct((42, 8192), jnp.uint32)
+    prep = lambda x: p256._scalar_prep(*p256._unpack_fused(x))
+    ladder_args = jax.eval_shape(prep, packed)
+    prep_traced, prep_inline = _count_equations(
+        jax.make_jaxpr(prep)(packed).jaxpr, {})
+    ladder_traced, ladder_inline = _count_equations(
+        jax.make_jaxpr(lambda *a: p256._verify_device_pallas_jac(
+            *a, tile=p256._pick_tile(8192)))(*ladder_args).jaxpr, {})
+    print(f"traced by Python: prep {prep_traced} ladder {ladder_traced}; "
+          f"inline: prep {prep_inline} ladder {ladder_inline}")
+    assert ladder_traced <= 35_000
+    assert prep_traced + ladder_traced <= 90_000
+    # inline, the ladder was 439,371 with its table and doublings unrolled
+    assert ladder_inline <= 110_000
+    # the prep was 55,061 either way; the inversion step's four squarings
+    # are the unrolled part that is left (a loop costs the device time)
+    assert prep_inline <= 25_000
+
+
+def test_unlike_batch_sizes_in_one_padded_shape_share_a_jit_entry(monkeypatch):
+    """100 and 120 signatures both pad to 128 lanes: the dispatch hands
+    jit the same shape and the same static tile, so the second compiles
+    nothing; 130 signatures are the next shape."""
+    import jax
+    import jax.numpy as jnp
+
+    d, pub = curve.keygen(rng=7300)
+    sig = curve.sign(b"shape", d)
+    digest = b"\x11" * 32
+
+    @jax.jit
+    def stand_in(packed):
+        return jnp.zeros((2, packed.shape[1]), dtype=bool)
+
+    tiles = []
+
+    def fused(packed, tile):
+        tiles.append(tile)
+        return stand_in(packed)
+
+    monkeypatch.setattr(p256, "_prep_and_verify_pallas_jac", fused)
+    for n in (100, 120):
+        got = p256.verify_batch_prehashed(
+            [digest] * n, [sig] * n, [pub] * n, backend="pallas",
+            scalar_prep="device")
+        assert got.shape == (n,)
+    assert stand_in._cache_size() == 1 and tiles == [128, 128]
+    p256.verify_batch_prehashed([digest] * 130, [sig] * 130, [pub] * 130,
+                                backend="pallas", scalar_prep="device")
+    assert stand_in._cache_size() == 2 and tiles[-1] == 256
+
+
+def test_pallas_backend_takes_the_device_prep_only():
+    d, pub = curve.keygen(rng=7301)
+    with pytest.raises(ValueError, match="device scalar prep"):
+        p256.verify_batch_prehashed(
+            [b"\x22" * 32], [curve.sign(b"x", d)], [pub],
+            backend="pallas", scalar_prep="host")
 
 
 # --- wrapper fallback plumbing --------------------------------------------
@@ -421,7 +495,7 @@ def test_exception_lanes_fall_back_to_host_oracle(monkeypatch):
 
     calls = []
 
-    def fake_kernel(packed, tile, w=4):
+    def fake_kernel(packed, tile):
         n = packed.shape[1]
         # kernel "flags" lanes 1 and 3 and returns garbage verdicts there
         ok = np.zeros(n, dtype=bool)

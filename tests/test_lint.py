@@ -197,6 +197,24 @@ def test_platform_questions_go_through_the_device_package():
     assert touches <= seen, sorted(touches - seen)
 
 
+def test_deleted_p256_knobs_leave_no_name_behind():
+    """PR 46 deleted the stacked and RCB16 ladder kernels with the four
+    values that selected among them; no identifier, string or comment
+    under upow_tpu/ still says their names."""
+    # spelled in halves so this file does not match itself
+    gone = ["PALLAS" + "_KERNEL", "PALLAS" + "_JAC_WINDOW",
+            "UPOW" + "_JAC_WINDOW", "UPOW" + "_TILE_CAP",
+            "verify" + "_kernel", "verify" + "_window", "_env" + "_choice",
+            "apply_kernel" + "_overrides", "_ladder_kernel" + "_list",
+            "_verify_device_pallas" + "_stacked"]
+    hits = []
+    for path in sorted(PACKAGE.rglob("*.py")):
+        text = path.read_text(encoding="utf-8")
+        hits += ["%s: %s" % (path.relative_to(PACKAGE), name)
+                 for name in gone if name in text]
+    assert hits == []
+
+
 def test_device_purity_fires_on_resident_index_paths():
     """The ISSUE 11 resident-index dispatch shortcuts (self-pinned HBM
     tables, probes around the fair queues, call-time kernel staging)

@@ -14,9 +14,11 @@ exits, so the topology is described inside this file's module fixture
 cases live in this one file so one xdist worker gets them.
 
 The whole fused verify program (scalar prep + ladder,
-``p256._prep_and_verify_pallas_jac``) costs minutes of trace, lower and
-compile per padded shape; it is the ``slow`` case at the bottom, outside
-the tier-1 run.
+``p256._prep_and_verify_pallas_jac``) is compiled at the two shapes a
+validator meets first, 8,192 lanes for a block and 128 for a burst: a
+minute and half a minute here since PR 46 (the field arithmetic traced
+once; the ladder alone took two minutes a shape before), most of it
+XLA:TPU's time over the scalar prep.
 """
 
 import os
@@ -105,8 +107,7 @@ def _ladder_args(n: int, sharding):
     from upow_tpu.crypto import p256
 
     outs = jax.eval_shape(
-        lambda packed: p256._scalar_prep(*p256._unpack_fused(packed),
-                                         w=p256.PALLAS_JAC_WINDOW),
+        lambda packed: p256._scalar_prep(*p256._unpack_fused(packed)),
         jax.ShapeDtypeStruct((42, n), jnp.uint32))
     return [_shape(o.shape, o.dtype, sharding) for o in outs]
 
@@ -117,25 +118,32 @@ def _ladder_args(n: int, sharding):
 ])
 def test_p256_jacobian_ladder_kernel_compiles(one_chip, no_compile_cache,
                                               lanes, tile):
+    """The kernel alone (a quarter of a minute a shape): what Mosaic
+    refuses shows here apart from what XLA:TPU says of the prep."""
     from upow_tpu.crypto import p256
 
     assert p256._pad_to_block(min(lanes, 1026)) == lanes
     assert p256._pick_tile(lanes) == tile
     compiled = p256._verify_device_pallas_jac.lower(
-        *_ladder_args(lanes, one_chip), tile=tile, interpret=False,
-        w=p256.PALLAS_JAC_WINDOW).compile()
+        *_ladder_args(lanes, one_chip), tile=tile,
+        interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.slow
-def test_p256_fused_verify_program_compiles(one_chip, no_compile_cache):
-    """Scalar prep + ladder as the node dispatches them (~3 min): the
-    2,048-lane shape of a default node's first micro-batch."""
+@pytest.mark.parametrize("lanes,tile", [
+    (8192, 1024),   # a full block's 8,160 signatures in one dispatch
+    (128, 128),     # a push_tx burst
+])
+def test_p256_fused_verify_program_compiles(one_chip, no_compile_cache,
+                                            lanes, tile):
+    """Scalar prep + ladder as the node dispatches them, one program a
+    padded shape."""
     from upow_tpu.crypto import p256
 
+    assert p256._pad_to_block(min(lanes, 8160)) == lanes
+    assert p256._pick_tile(lanes) == tile
     compiled = p256._prep_and_verify_pallas_jac.lower(
-        _shape((42, 2048), jnp.uint32, one_chip), tile=1024,
-        w=p256.PALLAS_JAC_WINDOW).compile()
+        _shape((42, lanes), jnp.uint32, one_chip), tile=tile).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -48,8 +48,6 @@ class DeviceConfig:
                                     # prefilter on block accept (worth it
                                     # with a real accelerator; on a CPU
                                     # node sqlite is already fast)
-    verify_kernel: str = ""         # "" = default | jac | complete
-    verify_window: int = 0          # 0 = default | 4 | 5  (jac ladder w)
     txid_backend: str = "auto"      # auto | device | host — batch txid
                                     # hashing for sync pages / block
                                     # accept (crypto/sha256.txid_batch);
@@ -60,31 +58,6 @@ class DeviceConfig:
                                     # digest prep of batch N overlaps the
                                     # in-flight sig verify of batch N-1
                                     # (verify/block.py); 0 = whole block
-
-    def apply_kernel_overrides(self) -> None:
-        """Push the A/B-able kernel knobs into crypto.p256 (module-level
-        so every dispatch path — node, bench, tests — sees one value).
-        No-op at defaults: importing p256 pulls in jax, which a host-path
-        node must not pay at startup."""
-        if not (self.verify_kernel or self.verify_window):
-            return
-        if self.verify_kernel and self.verify_kernel not in ("jac",
-                                                             "complete"):
-            raise ValueError(
-                f"device.verify_kernel must be 'jac' or 'complete', "
-                f"not {self.verify_kernel!r}")
-        window = self.verify_window
-        if window and (not isinstance(window, int) or isinstance(window, bool)
-                       or not 2 <= window <= 13):
-            raise ValueError(
-                f"device.verify_window must be an int in [2, 13], "
-                f"not {window!r}")
-        from .crypto import p256
-
-        if self.verify_kernel:
-            p256.PALLAS_KERNEL = self.verify_kernel
-        if window:
-            p256.PALLAS_JAC_WINDOW = window
 
 
 @dataclass

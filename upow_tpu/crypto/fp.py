@@ -26,9 +26,11 @@ reference, transaction_input.py:100-109):
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -41,6 +43,10 @@ R_BITS = LIMB_BITS * NUM_LIMBS  # Montgomery R = 2^273
 # carry sweep's top limb is always < 2^13 (no dropped carries), with room
 # for the K·p subtraction offsets.
 _BOUND_CAP = 1 << 270
+
+# a product is one jaxpr for each operand shape and field (a FieldSpec is
+# a tuple of integers): traced once, bound wherever a program multiplies
+_jit_by_field = functools.partial(jax.jit, static_argnames=("fs",))
 
 
 class FieldSpec(NamedTuple):
@@ -219,15 +225,18 @@ def _shift_add(t, x, off: int):
     return t + jnp.concatenate(parts, axis=0)
 
 
-def mont_mul(a: FE, b: FE, fs: FieldSpec) -> FE:
-    """Montgomery product a·b·R⁻¹ mod p; bound resets to ~2p for sane inputs."""
+@_jit_by_field
+def _mont_mul_arr(a, b, *, fs: FieldSpec):
+    """The stacked product on bare (21, N) arrays: one jaxpr for each
+    batch shape and field, bound by every product of the programs that
+    call it (the device scalar prep makes ~45 of them in two fields)."""
     L = NUM_LIMBS
-    n = a.arr.shape[1]
+    n = a.shape[1]
     t = jnp.zeros((2 * L, n), dtype=jnp.int32)
     for i in range(L):
-        t = _shift_add(t, a.arr[i] * b.arr, i)
+        t = _shift_add(t, a[i] * b, i)
     # sweep counts: pre 1 one-hop round (rows ≤ 2^13 + 2^17.4; the
-    # reduction-round budget in _l_mont_reduce's proof absorbs it);
+    # reduction-round budget in _mont_reduce_rows' proof absorbs it);
     # post 2 one-hop rounds (limbs ≤ 2^13 + 22 — see the FE docstring)
     t = _sweep(t, 1)
     # Montgomery rounds: zero the bottom L limbs; the single-limb carry per
@@ -238,8 +247,13 @@ def mont_mul(a: FE, b: FE, fs: FieldSpec) -> FE:
         mp = jnp.stack([m * pl for pl in fs.p_limbs])
         t = _shift_add(t, mp, i)
         t = _shift_add(t, (t[i] >> LIMB_BITS)[None], i + 1)
-    out = _sweep(t[L:], 2)
-    return FE(out, a.bound * b.bound // (1 << R_BITS) + 2 * fs.p)
+    return _sweep(t[L:], 2)
+
+
+def mont_mul(a: FE, b: FE, fs: FieldSpec) -> FE:
+    """Montgomery product a·b·R⁻¹ mod p; bound resets to ~2p for sane inputs."""
+    return FE(_mont_mul_arr(a.arr, b.arr, fs=fs),
+              a.bound * b.bound // (1 << R_BITS) + 2 * fs.p)
 
 
 # --- limb-list variant (Pallas kernel layout) ------------------------------
@@ -283,12 +297,6 @@ def l_wrap(limbs, bound: int) -> FL:
     return FL(tuple(limbs), bound)
 
 
-def l_const(x: int, shape, bound: int) -> FL:
-    limbs = int_to_limbs(x)
-    return FL(tuple(jnp.full(shape, int(l), dtype=jnp.int32) for l in limbs),
-              bound)
-
-
 def _l_sweep(t: list, rounds: int) -> list:
     """In-place-style carry sweep over a limb list (top carry provably 0)."""
     t = list(t)
@@ -319,10 +327,32 @@ def l_sub(a: FL, b: FL, fs: FieldSpec) -> FL:
     return FL(tuple(_l_sweep(t, 1)), a.bound + K)
 
 
-def _l_mont_reduce(t: list, bound_product: int, fs: FieldSpec) -> FL:
-    """Shared tail of the limb-list Montgomery entry points: sweep the
-    double-width accumulator, run the 21 reduction rounds, sweep the top
-    half.  ``t`` rows may be None (rows no product reached).
+def _traced_once(jitted):
+    """Over a jitted ``fn(*limb tuples, fs=...)``, as the ladder calls
+    it: numpy limbs run ``fn`` itself (the eager reference side of the
+    differential tests), traced limbs bind its jaxpr, which jit traces
+    once for each limb shape and field and every later call binds again.
+    A Pallas kernel body takes the bound jaxpr as it takes any other
+    (Mosaic's lowering inlines ``pjit``), so the arithmetic is the same
+    operations in the same order either way; what changes is that Python
+    walks the 21 × 21 loops once a program and not once a product
+    (PERF.md section 6, PR 46)."""
+    fn = jitted.__wrapped__
+
+    @functools.wraps(jitted)
+    def call(*limbs, fs):
+        flat = [x for t in limbs for x in t]
+        return (fn if _xp(*flat) is np else jitted)(*limbs, fs=fs)
+
+    return call
+
+
+@_traced_once
+@_jit_by_field
+def _mont_reduce_rows(rows, *, fs: FieldSpec) -> tuple:
+    """Shared tail of the limb-list Montgomery products: sweep the
+    double-width accumulator (``rows``: the 2L − 1 product rows), run the
+    21 reduction rounds, sweep the top half.
 
     Sweep-count proof (int32 overflow is the only constraint — m's
     exactness needs just "every contribution into row i lands before
@@ -341,58 +371,68 @@ def _l_mont_reduce(t: list, bound_product: int, fs: FieldSpec) -> FL:
       in one round, restoring the canonical limb range.
     """
     L = NUM_LIMBS
-    sample = next(x for x in t if x is not None)
-    t = [_xp(sample).zeros_like(sample) if r is None else r for r in t]
+    t = list(rows) + [_xp(rows[0]).zeros_like(rows[0])]
     t = _l_sweep(t, 1)
     for i in range(L):
         m = (t[i] * fs.pinv) & LIMB_MASK
         for j in range(L):
-            t[i + j] = t[i + j] + m * fs.p_limbs[j]
+            if fs.p_limbs[j]:  # nine of P-256's 21 limbs are zero
+                t[i + j] = t[i + j] + m * fs.p_limbs[j]
         t[i + 1] = t[i + 1] + (t[i] >> LIMB_BITS)
-    out = _l_sweep(t[L:], 1)
-    return FL(tuple(out), bound_product // (1 << R_BITS) + 2 * fs.p)
+    return tuple(_l_sweep(t[L:], 1))
 
 
-def l_mont_mul(a: FL, b: FL, fs: FieldSpec) -> FL:
-    """Montgomery product in limb-list form: the anti-diagonal accumulation
-    is Python indexing (t[i+j] += a_i·b_j) — no concatenates, every MAC one
-    full-tile VPU op."""
+@_traced_once
+@_jit_by_field
+def _mont_mul_limbs(a, b, *, fs: FieldSpec) -> tuple:
+    """The anti-diagonal accumulation is Python indexing
+    (t[i+j] += a_i·b_j) — no concatenates, every MAC one full-tile VPU
+    op."""
     L = NUM_LIMBS
-    t = [None] * (2 * L)
+    t = [None] * (2 * L - 1)
     for i in range(L):
-        ai = a.limbs[i]
         for j in range(L):
-            p_ij = ai * b.limbs[j]
+            p_ij = a[i] * b[j]
             k = i + j
             t[k] = p_ij if t[k] is None else t[k] + p_ij
-    return _l_mont_reduce(t, a.bound * b.bound, fs)
+    return _mont_reduce_rows(tuple(t), fs=fs)
 
 
-def l_mont_sqr(a: FL, fs: FieldSpec) -> FL:
-    """Montgomery square: the schoolbook product's symmetry halves the
-    cross-term MACs (t[i+j] gets 2·aᵢaⱼ once instead of aᵢaⱼ twice; the
-    factor 2 is applied once per row after accumulation).
+@_traced_once
+@_jit_by_field
+def _mont_sqr_limbs(a, *, fs: FieldSpec) -> tuple:
+    """The schoolbook product's symmetry halves the cross-term MACs
+    (t[i+j] gets 2·aᵢaⱼ once instead of aᵢaⱼ twice; the factor 2 is
+    applied once per row after accumulation).
 
     Bound safety: a row collects ≤10 doubled cross products (< 2²⁷ each)
     plus one square (< 2²⁶) — under 2³¹ in int32, same margin as
-    :func:`l_mont_mul`'s 21-term accumulation."""
+    :func:`_mont_mul_limbs`'s 21-term accumulation."""
     L = NUM_LIMBS
-    cross = [None] * (2 * L)  # Σ_{i<j} a_i·a_j per row (to be doubled)
+    cross = [None] * (2 * L - 1)  # Σ_{i<j} a_i·a_j per row (to be doubled)
     for i in range(L):
-        ai = a.limbs[i]
         for j in range(i + 1, L):
             k = i + j
-            p_ij = ai * a.limbs[j]
+            p_ij = a[i] * a[j]
             cross[k] = p_ij if cross[k] is None else cross[k] + p_ij
-    t = [None] * (2 * L)
-    for k in range(2 * L):
-        if cross[k] is not None:
-            t[k] = cross[k] + cross[k]
+    t = [None if c is None else c + c for c in cross]
     for i in range(L):  # diagonal squares
         k = 2 * i
-        sq = a.limbs[i] * a.limbs[i]
+        sq = a[i] * a[i]
         t[k] = sq if t[k] is None else t[k] + sq
-    return _l_mont_reduce(t, a.bound * a.bound, fs)
+    return _mont_reduce_rows(tuple(t), fs=fs)
+
+
+def l_mont_mul(a: FL, b: FL, fs: FieldSpec) -> FL:
+    """Montgomery product a·b·R⁻¹ mod p in limb-list form."""
+    return FL(_mont_mul_limbs(a.limbs, b.limbs, fs=fs),
+              a.bound * b.bound // (1 << R_BITS) + 2 * fs.p)
+
+
+def l_mont_sqr(a: FL, fs: FieldSpec) -> FL:
+    """Montgomery square: :func:`l_mont_mul` of a with itself, cheaper."""
+    return FL(_mont_sqr_limbs(a.limbs, fs=fs),
+              a.bound * a.bound // (1 << R_BITS) + 2 * fs.p)
 
 
 def l_canon(a: FL, fs: FieldSpec) -> list:
@@ -482,8 +522,3 @@ def eq_zero_canon(a):
 
 def is_zero_mod_p(a: FE, fs: FieldSpec):
     return eq_zero_canon(canon(a, fs))
-
-
-def select(cond, a: FE, b: FE) -> FE:
-    """cond ? a : b; cond has shape (N,)."""
-    return FE(jnp.where(cond[None, :], a.arr, b.arr), max(a.bound, b.bound))

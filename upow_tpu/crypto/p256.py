@@ -4,34 +4,40 @@ The reference verifies every transaction input serially through fastecdsa's
 C extension (transaction_input.py:100-109, called per input inside the block
 accept hot loop manager.py:628-632).  Here the whole block's signatures are
 verified in ONE jitted program: a fixed-window (w = 4) Strauss double-scalar
-ladder u₁·G + u₂·Q over *complete* projective addition formulas
-(Renes–Costello–Batina 2016, Algorithm 4, a = −3), batched across the lane
-axis in 13-bit-limb lazy Montgomery arithmetic (:mod:`.fp`).
+ladder u₁·G + u₂·Q, batched across the lane axis in 13-bit-limb lazy
+Montgomery arithmetic (:mod:`.fp`): 64 rounds of 4 doublings, one add from
+a 16-entry G table (constants) and one from a 16-entry Q table built on the
+device.
 
-The window structure: 64 iterations, each doing 4 doublings plus one add
-from a host-precomputed 16-entry G table (constants) and one add from an
-on-device 16-entry Q table (14 setup adds per batch) — 6 complete adds per
-4 scalar bits versus 12 for the bit-serial ladder.  Window digits are
-extracted on the host (u₁/u₂ are host bigints already) and shipped as
-(64, N) int32 arrays, MSB-digit first.
+What the file holds, and who runs each:
 
-Complete formulas are the consensus-safety choice: they are correct for
-EVERY input pair — identity, doubling, inverses — so adversarial signatures
-cannot steer the ladder into an exceptional case and flip a verdict.
+* ``_verify_device`` — the ladder over *complete* projective addition
+  (Renes–Costello–Batina 2016, Algorithm 4, a = −3) as a plain jnp program:
+  correct for EVERY input pair (identity, doubling, inverses), so no
+  signature can steer it into an exceptional case.  A CPU node runs it, and
+  ``device=auto`` falls back to it.
+* ``_ladder_kernel_jac`` — the one Pallas kernel: the same ladder in
+  Jacobian coordinates (fewer products; exceptional lanes flagged, see the
+  section comment), with ``_jac_verify_eager``, its numpy twin, as the
+  reference the tests compare it with.  A TPU node runs it, fused behind
+  the device scalar prep (``_prep_and_verify_pallas_jac``).
+* ``_host_verify_prehashed`` — the Python-integer oracle that judges the
+  lanes the Jacobian kernel flags.
 
-The final check avoids field inversion entirely: with R = (X : Y : Z),
-x = X/Z, and accept ⇔ x mod n == r ⇔ X ≡ r·Z or X ≡ (r+n)·Z (mod p)
-(valid because p < 2n on P-256).  Both are Montgomery products followed by
-one exact canonical reduction (:func:`fp.is_zero_mod_p`).
+The final check avoids field inversion: with R = (X : Y : Z) homogeneous,
+accept ⇔ x mod n == r ⇔ X ≡ r·Z or X ≡ (r+n)·Z (mod p) (valid because
+p < 2n on P-256); Jacobian, the same against Z².
 
-Scalar prep (s⁻¹ mod n, u₁, u₂, range checks, on-curve checks) stays on the
-host: per-signature Python bigint work is ~µs and latency-insensitive.
+Scalar prep (s⁻¹ mod n, u₁, u₂, range checks, on-curve checks) runs on the
+device in front of the Pallas kernel (``_scalar_prep``) and on the host in
+front of the jnp program.
 """
 
 from __future__ import annotations
 
 import functools
 import hashlib
+import math
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -65,93 +71,9 @@ def _point_add_complete(P1: Proj, P2: Proj, b_m: FE) -> Proj:
     is exactly what XLA wants.
     """
     fs = _FS
-    return _point_add_rcb16(
-        P1, P2, b_m,
-        mul=lambda x, y: fp.mont_mul(x, y, fs),
-        add_=fp.add,
-        sub_=lambda x, y: fp.sub(x, y, fs),
-    )
-
-
-def _point_add_complete_l(P1, P2, b_m):
-    """Same RCB16 program over limb-list elements (Pallas kernel layout)."""
-    fs = _FS
-    return _point_add_rcb16(
-        P1, P2, b_m,
-        mul=lambda x, y: fp.l_mont_mul(x, y, fs),
-        add_=fp.l_add,
-        sub_=lambda x, y: fp.l_sub(x, y, fs),
-    )
-
-
-def _point_dbl_complete_l(P, b_m):
-    """Limb-list doubling via :func:`_point_dbl_rcb16` (the layout the
-    Pallas kernel runs in).  The stacked jnp path deliberately does NOT
-    route doublings through a second program: ``_verify_device`` keeps a
-    single scanned add site precisely to bound XLA:CPU compile time
-    (see its docstring), and a dedicated doubling would double it."""
-    fs = _FS
-    return _point_dbl_rcb16(
-        P, b_m,
-        mul=lambda x, y: fp.l_mont_mul(x, y, fs),
-        sqr=lambda x: fp.l_mont_sqr(x, fs),
-        add_=fp.l_add,
-        sub_=lambda x, y: fp.l_sub(x, y, fs),
-    )
-
-
-def _point_dbl_rcb16(P, b_m, mul, sqr, add_, sub_):
-    """Doubling through the SAME RCB16 Algorithm-4 sequence as
-    :func:`_point_add_rcb16` with the six same-operand products routed to
-    the Montgomery square (~40% cheaper MAC count each).  Not a different
-    formula — completeness and the bound discipline carry over verbatim
-    from the addition program."""
-    X1, Y1, Z1 = P
-
-    t0 = sqr(X1)            # X1·X2
-    t1 = sqr(Y1)            # Y1·Y2
-    t2 = sqr(Z1)            # Z1·Z2
-    t3 = add_(X1, Y1)
-    t3 = sqr(t3)            # (X1+Y1)·(X2+Y2)
-    t4 = add_(t0, t1)
-    t3 = sub_(t3, t4)
-    t4 = add_(Y1, Z1)
-    t4 = sqr(t4)            # (Y1+Z1)·(Y2+Z2)
-    X3 = add_(t1, t2)
-    t4 = sub_(t4, X3)
-    X3 = add_(X1, Z1)
-    X3 = sqr(X3)            # (X1+Z1)·(X2+Z2)
-    Y3 = add_(t0, t2)
-    Y3 = sub_(X3, Y3)
-    Z3 = mul(b_m, t2)
-    X3 = sub_(Y3, Z3)
-    Z3 = add_(X3, X3)
-    X3 = add_(X3, Z3)
-    Z3 = sub_(t1, X3)
-    X3 = add_(t1, X3)
-    Y3 = mul(b_m, Y3)
-    t1 = add_(t2, t2)
-    t2 = add_(t1, t2)
-    Y3 = sub_(Y3, t2)
-    Y3 = sub_(Y3, t0)
-    t1 = add_(Y3, Y3)
-    Y3 = add_(t1, Y3)
-    t1 = add_(t0, t0)
-    t0 = add_(t1, t0)
-    t0 = sub_(t0, t2)
-    t1 = mul(t4, Y3)
-    t2 = mul(t0, Y3)
-    Y3 = mul(X3, Z3)
-    Y3 = add_(Y3, t2)
-    t2 = mul(t3, X3)
-    X3 = sub_(t2, t1)
-    t2 = mul(t4, Z3)
-    t1 = mul(t3, t0)
-    Z3 = add_(t2, t1)
-    return (X3, Y3, Z3)
-
-
-def _point_add_rcb16(P1, P2, b_m, mul, add_, sub_):
+    mul = lambda x, y: fp.mont_mul(x, y, fs)
+    add_ = fp.add
+    sub_ = lambda x, y: fp.sub(x, y, fs)
     X1, Y1, Z1 = P1
     X2, Y2, Z2 = P2
 
@@ -199,10 +121,6 @@ def _point_add_rcb16(P1, P2, b_m, mul, add_, sub_):
     t1 = mul(t3, t0)
     Z3 = add_(t2, t1)
     return (X3, Y3, Z3)
-
-
-def _select_point(cond, a: Proj, b: Proj) -> Proj:
-    return tuple(fp.select(cond, a[i], b[i]) for i in range(3))  # type: ignore
 
 
 def _clamp_point(P: Proj) -> Proj:
@@ -269,22 +187,28 @@ def _mod_n_inv_mont(s_m: FE) -> FE:
     """s_m (Montgomery domain mod n) -> s⁻¹ in Montgomery domain, via
     Fermat x^(n-2) with a 4-bit fixed window: 15-entry table (14 muls)
     then 64 scanned steps of 4 squarings + one table mul (~334 muls —
-    ~6% of the ladder's budget)."""
+    ~6% of the ladder's budget).  The table's products are one scan
+    body: XLA:TPU takes most of a second to compile each product it is
+    handed inline (PERF.md section 6, PR 46).  The step's four squarings
+    stay unrolled: as a loop of their own they cost the device 15% more
+    time (call 2, PR 46)."""
     ns = _NS
     n_lanes = s_m.arr.shape[1]
     one_m = fp.const(ns.r_mod_p, n_lanes, _SCALAR_BOUND)
-    table = [one_m.arr, s_m.arr]
-    for _ in range(14):
-        table.append(fp.mont_mul(fp.wrap(table[-1], _SCALAR_BOUND), s_m, ns).arr)
-    table = jnp.stack(table)  # (16, 21, N)
+
+    def mul(x, y):
+        return fp.mont_mul(fp.wrap(x, _SCALAR_BOUND),
+                           fp.wrap(y, _SCALAR_BOUND), ns).arr
+
+    _, powers = jax.lax.scan(
+        lambda prev, _: (mul(prev, s_m.arr),) * 2, s_m.arr, None, length=14)
+    table = jnp.concatenate([one_m.arr[None], s_m.arr[None], powers])
 
     def step(acc, digit):
-        x = fp.wrap(acc, _SCALAR_BOUND)
         for _ in range(_WINDOW):
-            x = fp.mont_mul(x, x, ns)
+            acc = mul(acc, acc)
         oh = jax.nn.one_hot(digit, 16, dtype=jnp.int32)  # (16,)
-        pick = fp.wrap((oh[:, None, None] * table).sum(axis=0), _SCALAR_BOUND)
-        return fp.mont_mul(x, pick, ns).arr, None
+        return mul(acc, (oh[:, None, None] * table).sum(axis=0)), None
 
     out, _ = jax.lax.scan(step, one_m.arr, jnp.asarray(_INV_DIGITS))
     return fp.wrap(out, _SCALAR_BOUND)
@@ -318,24 +242,23 @@ def _pack_words(xs, pad: int) -> np.ndarray:
     return np.pad(w, ((0, 0), (0, pad)), constant_values=0)
 
 
-def _digits_from_limbs(limbs, w: int = _WINDOW) -> jnp.ndarray:
-    """(21, N) canonical 13-bit limbs -> (rounds, N) w-bit digits, MSB
-    first.  Static bit surgery: a digit spans at most two limbs for any
-    w <= 13; the top digit of an uneven split reads zero high bits."""
+def _digits_from_limbs(limbs) -> jnp.ndarray:
+    """(21, N) canonical 13-bit limbs -> (64, N) window digits, MSB
+    first.  Static bit surgery: a digit spans at most two limbs."""
     lb = fp.LIMB_BITS
-    mask = (1 << w) - 1
+    mask = (1 << _WINDOW) - 1
     rows = []
-    for k in range(_jac_rounds(w)):
-        j, off = divmod(w * k, lb)
+    for k in range(_DIGITS):
+        j, off = divmod(_WINDOW * k, lb)
         v = limbs[j] >> off
-        if off + w > lb and j + 1 < fp.NUM_LIMBS:
+        if off + _WINDOW > lb:
             v = v | (limbs[j + 1] << (lb - off))
         rows.append(v & mask)
     return jnp.stack(rows[::-1], axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("w",))
-def _scalar_prep(z, r, s, qx, qy, range_ok, rn_ok, w: int = _WINDOW):
+@jax.jit
+def _scalar_prep(z, r, s, qx, qy, range_ok, rn_ok):
     """Packed 256-bit scalars -> ladder inputs, all on device.
 
     z/r/s/qx/qy: (8, N) uint32 little-endian words of the digest int,
@@ -351,30 +274,39 @@ def _scalar_prep(z, r, s, qx, qy, range_ok, rn_ok, w: int = _WINDOW):
     n_lanes = z.shape[1]
     raw = 1 << 256  # bound of any 256-bit input
 
+    # Independent products of one field ride one fp.mont_mul, side by
+    # side on the lane axis: the same integers a lane, and a program of
+    # twelve products where it held forty-five (see _mod_n_inv_mont)
+    def side_by_side(*arrs):
+        return jnp.concatenate(arrs, axis=1)
+
+    def apart(arr, k):
+        return jnp.split(arr, k, axis=1)
+
     # mod-n: w = s^-1, u1 = z·w, u2 = r·w  (Montgomery domain throughout)
-    r2n = fp.const(ns.r2_mod_p, n_lanes, ns.p)
-    s_m = fp.mont_mul(fp.wrap(s, raw), r2n, ns)
-    w_m = _mod_n_inv_mont(fp.wrap(s_m.arr, _SCALAR_BOUND))
-    z_m = fp.mont_mul(fp.wrap(z, raw), r2n, ns)
-    r_mn = fp.mont_mul(fp.wrap(r, raw), r2n, ns)
-    one = fp.const(1, n_lanes, 2)
-    u1 = fp.canon(fp.mont_mul(fp.mont_mul(z_m, w_m, ns), one, ns), ns)
-    u2 = fp.canon(fp.mont_mul(fp.mont_mul(r_mn, w_m, ns), one, ns), ns)
-    d1 = _digits_from_limbs(u1, w)
-    d2 = _digits_from_limbs(u2, w)
+    s_m, z_m, r_mn = apart(fp.mont_mul(
+        fp.wrap(side_by_side(s, z, r), raw),
+        fp.const(ns.r2_mod_p, 3 * n_lanes, ns.p), ns).arr, 3)
+    w_m = _mod_n_inv_mont(fp.wrap(s_m, _SCALAR_BOUND)).arr
+    u_m = fp.mont_mul(fp.wrap(side_by_side(z_m, r_mn), _SCALAR_BOUND),
+                      fp.wrap(side_by_side(w_m, w_m), _SCALAR_BOUND), ns)
+    u = fp.canon(fp.mont_mul(u_m, fp.const(1, 2 * n_lanes, 2), ns), ns)
+    d1, d2 = apart(_digits_from_limbs(u), 2)
 
     # mod-p: Montgomery forms of qx, qy, r, (r+n) mod p + on-curve check
-    r2p = fp.const(fs.r2_mod_p, n_lanes, fs.p)
-    qx_m = fp.mont_mul(fp.wrap(qx, raw), r2p, fs)
-    qy_m = fp.mont_mul(fp.wrap(qy, raw), r2p, fs)
-    r_mp = fp.canon(fp.mont_mul(fp.wrap(r, raw), r2p, fs), fs)
     rn = fp.add(fp.wrap(r, raw), fp.const(CURVE_N, n_lanes, CURVE_N + 1))
-    rn_mp = fp.canon(fp.mont_mul(rn, r2p, fs), fs)
+    to_mont = fp.mont_mul(
+        fp.wrap(side_by_side(qx, qy, r, rn.arr), rn.bound),
+        fp.const(fs.r2_mod_p, 4 * n_lanes, fs.p), fs)
+    qx_m, qy_m = (fp.wrap(x, to_mont.bound)
+                  for x in apart(to_mont.arr, 4)[:2])
+    qx_c, qy_c, r_mp, rn_mp = apart(fp.canon(to_mont, fs), 4)
 
     # y² == x³ - 3x + b  (all Montgomery domain)
     b_m = fp.const(_B_M, n_lanes, fs.p)
-    y2 = fp.mont_mul(qy_m, qy_m, fs)
-    x2 = fp.mont_mul(qx_m, qx_m, fs)
+    yx = fp.wrap(side_by_side(qy_m.arr, qx_m.arr), to_mont.bound)
+    squares = fp.mont_mul(yx, yx, fs)
+    y2, x2 = (fp.wrap(x, squares.bound) for x in apart(squares.arr, 2))
     x3 = fp.mont_mul(x2, qx_m, fs)
     three_x = fp.add(fp.add(qx_m, qx_m), qx_m)
     rhs = fp.add(fp.sub(x3, three_x, fs), b_m)
@@ -382,8 +314,7 @@ def _scalar_prep(z, r, s, qx, qy, range_ok, rn_ok, w: int = _WINDOW):
 
     valid = range_ok & on_curve
     flags = jnp.stack([rn_ok.astype(jnp.int32), valid.astype(jnp.int32)])
-    return (d1, d2, fp.canon(qx_m, fs), fp.canon(qy_m, fs), r_mp, rn_mp,
-            flags)
+    return d1, d2, qx_c, qy_c, r_mp, rn_mp, flags
 
 
 @jax.jit
@@ -462,268 +393,6 @@ def _verify_device(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid):
         rn_ok & fp.is_zero_mod_p(fp.sub(X, rnz, fs), fs)
     )
     return ok & (~at_infinity) & valid
-
-
-def _ladder_kernel(d1_ref, d2_ref, qx_ref, qy_ref, rm_ref, rnm_ref,
-                   flags_ref, gtab_ref, out_ref, qtab_ref):
-    """Pallas TPU kernel: the whole double-scalar ladder for one batch
-    tile, with every intermediate in VMEM/registers.
-
-    The jnp program (:func:`_verify_device`) is HBM-bound: each of its
-    ~5.4k Montgomery muls round-trips a (42, N) working buffer through
-    HBM (measured ~75 µs/mul at N=8192 — ~100x below VPU arithmetic
-    peak).  Here the working set (ladder state, Q window table, mul
-    temporaries) lives in VMEM for the kernel's lifetime, so the ladder
-    runs at VPU speed.  Same math, same two-complete-adds structure.
-    """
-    fs = _FS
-    tile = qx_ref.shape[1]
-    p = fs.p
-    b_m = fp.const(_B_M, tile, p)
-
-    def stack_point(P):
-        return jnp.stack([c.arr for c in P], axis=0)  # (3, 21, tile)
-
-    def unstack_point(a, bound: int):
-        return tuple(fp.wrap(a[i], bound) for i in range(3))
-
-    Q = (fp.wrap(qx_ref[...], p), fp.wrap(qy_ref[...], p),
-         fp.const(_ONE_M, tile, p))
-    identity = (fp.const(0, tile, p), fp.const(_ONE_M, tile, p),
-                fp.const(0, tile, p))
-
-    # Q window table in VMEM scratch: [k]Q for k=0..15
-    qtab_ref[0] = stack_point(_clamp_point(identity))
-    qtab_ref[1] = stack_point(_clamp_point(Q))
-    def qstep(k, prev):
-        nxt = stack_point(_clamp_point(_point_add_complete(
-            unstack_point(prev, _COORD_BOUND), Q, b_m)))
-        qtab_ref[k] = nxt
-        return nxt
-    _ = jax.lax.fori_loop(1, 15, lambda k, prev: qstep(k + 1, prev),
-                          qtab_ref[1])
-
-    def pick(table_read, digit, entries: int = 16):
-        """Masked-sum table pick: acc += (digit == k) * table[k]."""
-        acc = jnp.zeros((3, fp.NUM_LIMBS, tile), dtype=jnp.int32)
-        for k in range(entries):
-            mask = (digit == k).astype(jnp.int32)[None, None, :]
-            acc = acc + table_read(k) * mask
-        return acc
-
-    def round_body(k, carry):
-        dg1 = d1_ref[k]  # (tile,) int32
-        dg2 = d2_ref[k]
-
-        def dbl(_, a):
-            R = unstack_point(a, _COORD_BOUND)
-            return stack_point(_clamp_point(_point_add_complete(R, R, b_m)))
-
-        a = jax.lax.fori_loop(0, _WINDOW, dbl, carry)
-        g_pick = pick(lambda i: gtab_ref[i][:, :, None], dg1)
-        a = stack_point(_clamp_point(_point_add_complete(
-            unstack_point(a, _COORD_BOUND),
-            unstack_point(g_pick, p), b_m)))
-        q_pick = pick(lambda i: qtab_ref[i], dg2)
-        return stack_point(_clamp_point(_point_add_complete(
-            unstack_point(a, _COORD_BOUND),
-            unstack_point(q_pick, _COORD_BOUND), b_m)))
-
-    carry0 = stack_point(_clamp_point(identity))
-    final = jax.lax.fori_loop(0, _DIGITS, round_body, carry0)
-    X = fp.wrap(final[0], _COORD_BOUND)
-    Z = fp.wrap(final[2], _COORD_BOUND)
-
-    rz = fp.mont_mul(fp.wrap(rm_ref[...], p), Z, fs)
-    rnz = fp.mont_mul(fp.wrap(rnm_ref[...], p), Z, fs)
-    at_infinity = fp.is_zero_mod_p(Z, fs)
-    rn_ok = flags_ref[0] != 0
-    valid = flags_ref[1] != 0
-    ok = fp.is_zero_mod_p(fp.sub(X, rz, fs), fs) | (
-        rn_ok & fp.is_zero_mod_p(fp.sub(X, rnz, fs), fs))
-    out_ref[0] = (ok & (~at_infinity) & valid).astype(jnp.int32)
-
-
-def _ladder_kernel_list(d1_ref, d2_ref, qx_ref, qy_ref, rm_ref, rnm_ref,
-                        flags_ref, out_ref, qtab_ref):
-    """Limb-list ladder kernel: every limb of every element is one full
-    (S, 128) VMEM tile, and limb shifts inside the Montgomery multiply
-    are Python indexing instead of the stacked layout's concatenates.
-
-    Measured against :func:`_ladder_kernel` (stacked (L, N) layout): the
-    stacked kernel spends ~2/3 of its time materializing shift
-    concatenates; this layout removes them entirely, so every VPU op is
-    a productive MAC on a full tile."""
-    fs = _FS
-    S = qx_ref.shape[1]  # sublane rows per tile (lanes = S * 128)
-    shape = (S, 128)
-    p = fs.p
-    b_m = fp.l_const(_B_M, shape, p)
-
-    def read_fl(ref, bound):
-        return fp.l_wrap([ref[i] for i in range(fp.NUM_LIMBS)], bound)
-
-    Q = (read_fl(qx_ref, p), read_fl(qy_ref, p),
-         fp.l_const(_ONE_M, shape, p))
-    identity = (fp.l_const(0, shape, p), fp.l_const(_ONE_M, shape, p),
-                fp.l_const(0, shape, p))
-
-    def clamp(P):
-        for c in P:
-            assert c.bound <= _COORD_BOUND, c.bound
-        return tuple(fp.l_wrap(c.limbs, _COORD_BOUND) for c in P)
-
-    def flatten(P):  # point -> nested tuple of arrays (fori_loop carry)
-        return tuple(tuple(c.limbs) for c in P)
-
-    def unflatten(t, bound=_COORD_BOUND):
-        return tuple(fp.l_wrap(limbs, bound) for limbs in t)
-
-    # --- Q window table in VMEM scratch: [k]Q for k = 0..15 --------------
-    def store_entry(k, t):
-        for c in range(3):
-            for l in range(fp.NUM_LIMBS):
-                qtab_ref[k, c, l] = t[c][l]
-
-    store_entry(0, flatten(clamp(identity)))
-    q1 = flatten(clamp(Q))
-    store_entry(1, q1)
-
-    def qstep(k, prev):
-        nxt = flatten(clamp(_point_add_complete_l(unflatten(prev), Q, b_m)))
-        store_entry(k + 1, nxt)
-        return nxt
-
-    _ = jax.lax.fori_loop(1, 15, qstep, q1)
-
-    # --- 64 digit rounds x (4 dbl + G add + Q add) -----------------------
-    def round_body(k, carry):
-        dg1 = d1_ref[k]  # (S, 128) int32
-        dg2 = d2_ref[k]
-
-        def dbl(_, t):
-            R = unflatten(t)
-            return flatten(clamp(_point_dbl_complete_l(R, b_m)))
-
-        a = jax.lax.fori_loop(0, _WINDOW, dbl, carry)
-
-        masks1 = [(dg1 == kk).astype(jnp.int32) for kk in range(16)]
-        masks2 = [(dg2 == kk).astype(jnp.int32) for kk in range(16)]
-
-        # G pick: the table entries are compile-time scalars, so the pick
-        # is a masked sum of constants with zero terms skipped
-        g_pick = []
-        for c in range(3):
-            limbs = []
-            for l in range(fp.NUM_LIMBS):
-                acc = None
-                for kk in range(16):
-                    g = int(_G_TABLE[c, kk, l])
-                    if g == 0:
-                        continue
-                    term = masks1[kk] * g
-                    acc = term if acc is None else acc + term
-                limbs.append(jnp.zeros(shape, jnp.int32) if acc is None
-                             else acc)
-            g_pick.append(fp.l_wrap(limbs, p))
-        a = flatten(clamp(_point_add_complete_l(
-            unflatten(a), tuple(g_pick), b_m)))
-
-        # Q pick: masked sum over the VMEM table (static entry reads)
-        q_pick = []
-        for c in range(3):
-            limbs = []
-            for l in range(fp.NUM_LIMBS):
-                acc = masks2[0] * qtab_ref[0, c, l]
-                for kk in range(1, 16):
-                    acc = acc + masks2[kk] * qtab_ref[kk, c, l]
-                limbs.append(acc)
-            q_pick.append(fp.l_wrap(limbs, _COORD_BOUND))
-        return flatten(clamp(_point_add_complete_l(
-            unflatten(a), tuple(q_pick), b_m)))
-
-    carry0 = flatten(clamp(identity))
-    final = jax.lax.fori_loop(0, _DIGITS, round_body, carry0)
-    X, _, Z = unflatten(final)
-
-    rz = fp.l_mont_mul(read_fl(rm_ref, p), Z, fs)
-    rnz = fp.l_mont_mul(read_fl(rnm_ref, p), Z, fs)
-    at_infinity = fp.l_is_zero_mod_p(Z, fs)
-    rn_ok = flags_ref[0] != 0
-    valid = flags_ref[1] != 0
-    ok = fp.l_is_zero_mod_p(fp.l_sub(X, rz, fs), fs) | (
-        rn_ok & fp.l_is_zero_mod_p(fp.l_sub(X, rnz, fs), fs))
-    out_ref[...] = (ok & (~at_infinity) & valid).astype(jnp.int32)
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _verify_device_pallas(d1, d2, qx, qy, r_m, rn_m, flags,
-                          tile: int = 1024, interpret: bool = False):
-    """Run the limb-list ladder kernel over a (…, N) batch.
-
-    ``tile`` = lanes per grid step, a multiple of 128 (the batch axis is
-    reshaped to (rows, 128) so each limb is a full VPU tile)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = qx.shape[1]
-    assert n % 128 == 0 and tile % 128 == 0 and n % tile == 0, (n, tile)
-    rows, sub = n // 128, tile // 128
-    grid = rows // sub
-
-    def rs(x):  # (rows-major lane split)
-        return x.reshape(x.shape[0], rows, 128)
-
-    spec = lambda r: pl.BlockSpec(
-        (r, sub, 128), lambda i: (0, i, 0), memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        _ladder_kernel_list,
-        grid=(grid,),
-        in_specs=[
-            spec(_DIGITS), spec(_DIGITS),
-            spec(fp.NUM_LIMBS), spec(fp.NUM_LIMBS),
-            spec(fp.NUM_LIMBS), spec(fp.NUM_LIMBS),
-            spec(2),
-        ],
-        out_specs=pl.BlockSpec((sub, 128), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((16, 3, fp.NUM_LIMBS, sub, 128), jnp.int32)],
-        interpret=interpret,
-    )(rs(d1), rs(d2), rs(qx), rs(qy), rs(r_m), rs(rn_m), rs(flags))
-    return out.reshape(n) != 0
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
-def _verify_device_pallas_stacked(d1, d2, qx, qy, r_m, rn_m, flags,
-                                  tile: int = 256, interpret: bool = False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n = qx.shape[1]
-    assert n % tile == 0, (n, tile)
-    grid = n // tile
-    lane = lambda rows: pl.BlockSpec(
-        (rows, tile), lambda i: (0, i), memory_space=pltpu.VMEM)
-    out = pl.pallas_call(
-        _ladder_kernel,
-        grid=(grid,),
-        in_specs=[
-            lane(_DIGITS), lane(_DIGITS),
-            lane(fp.NUM_LIMBS), lane(fp.NUM_LIMBS),
-            lane(fp.NUM_LIMBS), lane(fp.NUM_LIMBS),
-            lane(2),
-            pl.BlockSpec(memory_space=pltpu.VMEM),  # g_table, shared
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((16, 3, fp.NUM_LIMBS, tile), jnp.int32)],
-        interpret=interpret,
-    )(d1, d2, qx, qy, r_m, rn_m, flags,
-      jnp.asarray(_G_TABLE.transpose(1, 0, 2)))
-    return out[0] != 0
 
 
 # --- Jacobian ladder (the fast production kernel) --------------------------
@@ -842,29 +511,12 @@ def _jac_add(P1, P2, fs=_FS):
     return (X3, Y3, Z3), H
 
 
-def _jac_rounds(w: int) -> int:
-    """Ladder rounds for a w-bit window (ceil; the top digit of an
-    uneven split reads zero bits past 256 — limbs carry 273)."""
-    return -(-256 // w)
+_TABLE = 1 << _WINDOW  # entries of a window table, the identity's included
 
-
-@functools.lru_cache(maxsize=None)
-def _g_affine_table(w: int = _WINDOW) -> np.ndarray:
-    """(2, 2^w, 21) int32 — affine Montgomery (x, y) of [k]G, k >= 1.
-
-    Entry 0 is a placeholder: zero digits select the accumulator before
-    the pick is ever used."""
-    from ..core import curve as host_curve
-
-    size = 1 << w
-    rows = np.zeros((2, size, fp.NUM_LIMBS), dtype=np.int32)
-    for k in range(1, size):
-        x, y = host_curve.point_mul(k, (CURVE_GX, CURVE_GY))
-        rows[0, k] = fp.int_to_limbs(fp.to_mont(x, _FS))
-        rows[1, k] = fp.int_to_limbs(fp.to_mont(y, _FS))
-    return rows
-
-
+# (2, 16, 21) int32 — affine Montgomery (x, y) of [k]G, k >= 1: the
+# rows of _G_TABLE without their Z.  Entry 0 is a placeholder: zero
+# digits select the accumulator before the pick is ever used.
+_G_AFFINE = _G_TABLE[:2]
 
 
 def _jac_identity(like):
@@ -879,33 +531,58 @@ def _jac_lift_affine(x2, y2):
             fp.l_full(_ONE_M, x2.limbs[0], _JB))
 
 
-def _jac_qtable(qx, qy, fs=_FS, size: int = 16):
-    """Entries [1..size-1] = [k]Q as Jacobian FL points (bound <= _JB).
+def _jac_qtable(qx, qy, fs=_FS):
+    """Entries [1..15] = [k]Q as Jacobian FL points (bound <= _JB), as a
+    list: the eager twin's table.  The kernel builds the same entries
+    into VMEM scratch with the same two formulas
+    (:func:`_ladder_kernel_jac`).
 
     Exception-free for on-curve Q: [k]Q = ±Q would need (k∓1)Q = identity
-    with k−1 < size ≪ n (prime group order).  Off-curve garbage (already
+    with k−1 < 16 ≪ n (prime group order).  Off-curve garbage (already
     doomed by the `valid` flag) may produce garbage entries — harmless,
     the verdict is masked and any spurious exception flag just routes the
     lane to the host oracle, which rejects it."""
-    e1 = _jac_clamp((fp.l_wrap(qx.limbs, CURVE_P),
-                     fp.l_wrap(qy.limbs, CURVE_P),
-                     fp.l_full(_ONE_M, qx.limbs[0], CURVE_P)))
+    e1 = _jac_clamp(_jac_lift_affine(qx, qy))
     entries = [e1, _jac_clamp(_jac_dbl(e1, fs))]
-    for _ in range(3, size):
+    for _ in range(3, _TABLE):
         nxt, _h = _jac_madd(entries[-1], qx, qy, fs)
         entries.append(_jac_clamp(nxt))
     return entries
 
 
-def _jac_round(acc, started, exc, dg1, dg2, g_pick_fn, q_pick_fn, fs=_FS,
-               w: int = _WINDOW):
-    """One w-bit digit round: w doublings, G mixed add, Q general add —
+def _jac_flatten(P):
+    """Point -> nested tuple of limb arrays (a ``fori_loop`` carry)."""
+    return tuple(tuple(c.limbs) for c in P)
+
+
+def _jac_unflatten(t):
+    return tuple(fp.l_wrap(limbs, _JB) for limbs in t)
+
+
+def _jac_dbl_window(acc, fs=_FS):
+    """The round's ``_WINDOW`` doublings.  Traced limbs: one loop over
+    one :func:`_jac_dbl`, so that the compiler is handed the doubling
+    once and not four times; numpy limbs (the eager twin) run the same
+    doublings one after another."""
+    acc = _jac_clamp(acc)
+    if fp._xp(*acc[0].limbs) is np:
+        for _ in range(_WINDOW):
+            acc = _jac_clamp(_jac_dbl(acc, fs))
+        return acc
+    return _jac_unflatten(jax.lax.fori_loop(
+        0, _WINDOW,
+        lambda _, t: _jac_flatten(_jac_clamp(_jac_dbl(_jac_unflatten(t),
+                                                      fs))),
+        _jac_flatten(acc)))
+
+
+def _jac_round(acc, started, exc, dg1, dg2, g_pick_fn, q_pick_fn, fs=_FS):
+    """One 4-bit digit round: 4 doublings, G mixed add, Q general add —
     with the structural identity selects and exception flagging described
     in the section comment.  ``started``/``exc`` are int32 masks of the
     limb shape; ``g_pick_fn(dg) -> (x2, y2)`` affine FLs, ``q_pick_fn(dg)
     -> Jacobian FL point``.  Returns (acc, started, exc)."""
-    for _ in range(w):
-        acc = _jac_clamp(_jac_dbl(acc, fs))
+    acc = _jac_dbl_window(acc, fs)
 
     gx, gy = g_pick_fn(dg1)
     res, H = _jac_madd(acc, gx, gy, fs)
@@ -956,7 +633,7 @@ def _jac_final(acc, started, r_m, rn_m, rn_ok, valid, fs=_FS):
 
 
 def _jac_verify_eager(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid,
-                      n_rounds: Optional[int] = None, w: int = _WINDOW):
+                      n_rounds: int = _DIGITS):
     """Host twin of the Pallas Jacobian kernel, same round logic via the
     shared helpers — runs on plain numpy (no jit, no device) so tests can
     drive short crafted ladders cheaply.  d1/d2: (n_rounds, N) int32
@@ -966,13 +643,9 @@ def _jac_verify_eager(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid,
         return fp.l_wrap([np.asarray(a[i]) for i in range(fp.NUM_LIMBS)],
                          bound)
 
-    if n_rounds is None:
-        n_rounds = _jac_rounds(w)
-    size = 1 << w
-    g_tab = _g_affine_table(w)
     qx_f, qy_f = to_fl(qx, CURVE_P), to_fl(qy, CURVE_P)
     n = d1.shape[1]
-    qtab = _jac_qtable(qx_f, qy_f, size=size)
+    qtab = _jac_qtable(qx_f, qy_f)
 
     def g_pick_fn(dg):
         out = []
@@ -980,8 +653,8 @@ def _jac_verify_eager(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid,
             limbs = []
             for l in range(fp.NUM_LIMBS):
                 acc = np.zeros((n,), np.int32)
-                for k in range(1, size):
-                    g = int(g_tab[c, k, l])
+                for k in range(1, _TABLE):
+                    g = int(_G_AFFINE[c, k, l])
                     if g:
                         acc = acc + np.where(dg == k, g, 0)
                 limbs.append(acc)
@@ -994,7 +667,7 @@ def _jac_verify_eager(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid,
             limbs = []
             for l in range(fp.NUM_LIMBS):
                 acc = np.zeros((n,), np.int32)
-                for k in range(1, size):
+                for k in range(1, _TABLE):
                     acc = acc + np.where(dg == k, qtab[k - 1][c].limbs[l], 0)
                 limbs.append(acc)
             out.append(fp.l_wrap(limbs, _JB))
@@ -1006,44 +679,65 @@ def _jac_verify_eager(d1, d2, qx, qy, r_m, rn_m, rn_ok, valid,
     exc = np.zeros((n,), np.int32)
     for k in range(n_rounds):
         acc, started, exc = _jac_round(acc, started, exc, d1[k], d2[k],
-                                       g_pick_fn, q_pick_fn, w=w)
+                                       g_pick_fn, q_pick_fn)
     ok = _jac_final(acc, started, to_fl(r_m, CURVE_P), to_fl(rn_m, CURVE_P),
                     rn_ok, valid)
     return np.asarray(ok), np.asarray(exc != 0)
 
 
 def _ladder_kernel_jac(d1_ref, d2_ref, qx_ref, qy_ref, rm_ref, rnm_ref,
-                       flags_ref, out_ref, qtab_ref, *, w: int = _WINDOW):
-    """Pallas limb-list Jacobian ladder.  Same structure as
-    :func:`_ladder_kernel_list` but ~1.5x fewer Montgomery products per
-    round; emits bit0 = verdict, bit1 = exception flag per lane."""
+                       flags_ref, out_ref, qtab_ref):
+    """Pallas limb-list Jacobian ladder: every limb of every element is
+    one full (S, 128) VMEM tile, and limb shifts inside the Montgomery
+    multiply are Python indexing.  Emits bit0 = verdict, bit1 = exception
+    flag per lane.
+
+    What the compiler is handed inline is what it takes its time over
+    (PERF.md section 6, PR 46), so each formula stands in the body once
+    where a loop can carry it: one mixed add for the Q table's thirteen,
+    one doubling for a round's four."""
     fs = _FS
     S = qx_ref.shape[1]
     shape = (S, 128)
-    size = 1 << w
-    g_tab = _g_affine_table(w)
 
     def read_fl(ref, bound):
         return fp.l_wrap([ref[i] for i in range(fp.NUM_LIMBS)], bound)
 
-    qx_f, qy_f = read_fl(qx_ref, CURVE_P), read_fl(qy_ref, CURVE_P)
+    def read_entry(k):
+        return tuple(
+            fp.l_wrap([qtab_ref[k, c, l] for l in range(fp.NUM_LIMBS)], _JB)
+            for c in range(3))
 
-    # --- Q table (entries 1..size-1) into VMEM scratch -------------------
-    entries = _jac_qtable(qx_f, qy_f, fs, size=size)
-    for k, e in enumerate(entries):
+    def write_entry(k, e):
         for c in range(3):
             for l in range(fp.NUM_LIMBS):
                 qtab_ref[k, c, l] = e[c].limbs[l]
 
+    # --- Q table into VMEM scratch: slot k holds [k + 1]Q ----------------
+    # the same entries as _jac_qtable's, each from the one before
+    def read_q():
+        return read_fl(qx_ref, CURVE_P), read_fl(qy_ref, CURVE_P)
+
+    e1 = _jac_clamp(_jac_lift_affine(*read_q()))
+    write_entry(0, e1)
+    write_entry(1, _jac_clamp(_jac_dbl(e1, fs)))
+
+    def qstep(k, carry):
+        nxt, _h = _jac_madd(read_entry(k - 1), *read_q(), fs)
+        write_entry(k, _jac_clamp(nxt))
+        return carry
+
+    jax.lax.fori_loop(2, _TABLE - 1, qstep, 0)
+
     def g_pick_fn(dg):
-        masks = [(dg == k).astype(jnp.int32) for k in range(size)]
+        masks = [(dg == k).astype(jnp.int32) for k in range(_TABLE)]
         out = []
         for c in range(2):
             limbs = []
             for l in range(fp.NUM_LIMBS):
                 acc = None
-                for k in range(1, size):
-                    g = int(g_tab[c, k, l])
+                for k in range(1, _TABLE):
+                    g = int(_G_AFFINE[c, k, l])
                     if g == 0:
                         continue
                     term = masks[k] * g
@@ -1054,48 +748,44 @@ def _ladder_kernel_jac(d1_ref, d2_ref, qx_ref, qy_ref, rm_ref, rnm_ref,
         return tuple(out)
 
     def q_pick_fn(dg):
-        masks = [(dg == k).astype(jnp.int32) for k in range(size)]
+        masks = [(dg == k).astype(jnp.int32) for k in range(_TABLE)]
         out = []
         for c in range(3):
             limbs = []
             for l in range(fp.NUM_LIMBS):
                 acc = masks[1] * qtab_ref[0, c, l]
-                for k in range(2, size):
+                for k in range(2, _TABLE):
                     acc = acc + masks[k] * qtab_ref[k - 1, c, l]
                 limbs.append(acc)
             out.append(fp.l_wrap(limbs, _JB))
         return tuple(out)
 
-    def flatten(acc, started, exc):
-        return tuple(tuple(c.limbs) for c in acc) + (started, exc)
-
     def round_body(k, carry):
-        acc = tuple(fp.l_wrap(limbs, _JB) for limbs in carry[:3])
-        started, exc = carry[3], carry[4]
-        acc, started, exc = _jac_round(acc, started, exc,
-                                       d1_ref[k], d2_ref[k],
-                                       g_pick_fn, q_pick_fn, fs, w=w)
-        return flatten(acc, started, exc)
+        acc, started, exc = _jac_round(
+            _jac_unflatten(carry[:3]), carry[3], carry[4],
+            d1_ref[k], d2_ref[k], g_pick_fn, q_pick_fn, fs)
+        return _jac_flatten(acc) + (started, exc)
 
-    acc0 = _jac_identity(qx_f.limbs[0])
     z = jnp.zeros(shape, jnp.int32)
-    carry = jax.lax.fori_loop(0, _jac_rounds(w), round_body,
-                              flatten(acc0, z, z))
-    acc = tuple(fp.l_wrap(limbs, _JB) for limbs in carry[:3])
-    started, exc = carry[3], carry[4]
+    carry = jax.lax.fori_loop(
+        0, _DIGITS, round_body,
+        _jac_flatten(_jac_identity(z)) + (z, z))
 
     rn_ok = flags_ref[0] != 0
     valid = flags_ref[1] != 0
-    ok = _jac_final(acc, started, read_fl(rm_ref, CURVE_P),
-                    read_fl(rnm_ref, CURVE_P), rn_ok, valid, fs)
-    out_ref[...] = ok.astype(jnp.int32) + 2 * exc
+    ok = _jac_final(_jac_unflatten(carry[:3]), carry[3],
+                    read_fl(rm_ref, CURVE_P), read_fl(rnm_ref, CURVE_P),
+                    rn_ok, valid, fs)
+    out_ref[...] = ok.astype(jnp.int32) + 2 * carry[4]
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret", "w"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def _verify_device_pallas_jac(d1, d2, qx, qy, r_m, rn_m, flags,
-                              tile: int = 1024, interpret: bool = False,
-                              w: int = _WINDOW):
-    """Run the Jacobian ladder kernel; returns (ok, exc) bool (N,) arrays."""
+                              tile: int = 1024, interpret: bool = False):
+    """Run the Jacobian ladder kernel; returns (ok, exc) bool (N,) arrays.
+
+    ``tile`` = lanes per grid step, a multiple of 128 (the batch axis is
+    reshaped to (rows, 128) so each limb is a full VPU tile)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -1110,10 +800,10 @@ def _verify_device_pallas_jac(d1, d2, qx, qy, r_m, rn_m, flags,
     spec = lambda r: pl.BlockSpec(
         (r, sub, 128), lambda i: (0, i, 0), memory_space=pltpu.VMEM)
     out = pl.pallas_call(
-        functools.partial(_ladder_kernel_jac, w=w),
+        _ladder_kernel_jac,
         grid=(grid,),
         in_specs=[
-            spec(_jac_rounds(w)), spec(_jac_rounds(w)),
+            spec(_DIGITS), spec(_DIGITS),
             spec(fp.NUM_LIMBS), spec(fp.NUM_LIMBS),
             spec(fp.NUM_LIMBS), spec(fp.NUM_LIMBS),
             spec(2),
@@ -1122,8 +812,7 @@ def _verify_device_pallas_jac(d1, d2, qx, qy, r_m, rn_m, flags,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows, 128), jnp.int32),
         scratch_shapes=[
-            pltpu.VMEM(((1 << w) - 1, 3, fp.NUM_LIMBS, sub, 128),
-                       jnp.int32)],
+            pltpu.VMEM((_TABLE - 1, 3, fp.NUM_LIMBS, sub, 128), jnp.int32)],
         interpret=interpret,
     )(rs(d1), rs(d2), rs(qx), rs(qy), rs(r_m), rs(rn_m), rs(flags))
     out = out.reshape(n)
@@ -1151,42 +840,24 @@ def _host_verify_prehashed(z: int, r: int, s: int, qx: int, qy: int) -> bool:
 
 
 PALLAS_STRICT = False  # True: never fall back (tests assert kernel health)
-# "jac" (fast, default) | "complete" (RCB16, for A/B).  Only consulted on
-# the production path (backend="pallas" + scalar_prep="device"); the
-# host-prep pallas branch always runs the RCB16 kernels (it exists for
-# the interpret-mode kernel test, which targets them explicitly).
-PALLAS_KERNEL = "jac"
-# Jacobian ladder window bits.  w=4: 64 rounds, 16-entry tables.  w=5:
-# 52 rounds (fewer adds/tests per bit) but 32-entry tables (pricier
-# picks/setup) — measured A/B on the chip decides; both are covered by
-# the eager-twin differentials.  UPOW_JAC_WINDOW overrides, so an A/B
-# run can flip it per subprocess without editing source.
 
 
-def _env_choice(name: str, default: int, allowed) -> int:
-    """Env-knob parse that can't take down an importer: only the
-    differential-covered values are accepted; anything else (typo,
-    stray export, untested window) logs and falls back to the default —
-    a consensus node must not boot into an unvetted kernel config."""
-    import logging
-    import os
-
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    try:
-        val = int(raw.strip())
-    except ValueError:
-        val = None
-    if val not in allowed:
-        logging.getLogger("upow_tpu.crypto").warning(
-            "%s=%r invalid (allowed %s); using %d", name, raw,
-            sorted(allowed), default)
-        return default
-    return val
+def _one_stack_chunk(thunk):
+    """Call ``thunk`` with every Python frame it pushes in one chunk."""
+    return thunk()
 
 
-PALLAS_JAC_WINDOW = _env_choice("UPOW_JAC_WINDOW", 4, {4, 5})
+# CPython (3.11 on) keeps a thread's frames in 16 KiB chunks and hands a
+# chunk back to the OS the moment its first frame pops, so a call site
+# that happens to stand at a chunk's end maps and unmaps memory at every
+# call.  Lowering this program is ~10^7 calls at a few dozen depths, and
+# where it starts decides how many of them stand there: the same
+# lowering took the chip's host 17 s from a bare process's main thread
+# and 134 s from a node's worker thread (PERF.md section 6, PR 46).  A
+# frame this wide gets a chunk of its own, 2 MiB, whose second half
+# holds whatever jax pushes below it.
+_one_stack_chunk.__code__ = _one_stack_chunk.__code__.replace(
+    co_stacksize=1 << 17)
 
 
 def _pallas_or_jnp(pallas_thunk, jnp_thunk) -> np.ndarray:
@@ -1199,7 +870,7 @@ def _pallas_or_jnp(pallas_thunk, jnp_thunk) -> np.ndarray:
     (and under ``PALLAS_STRICT``) the failure raises instead: the slow
     path would pass for the device."""
     try:
-        return np.asarray(pallas_thunk())
+        return np.asarray(_one_stack_chunk(pallas_thunk))
     except Exception:
         from ..device.runtime import tpu_required
 
@@ -1216,17 +887,14 @@ def _pallas_or_jnp(pallas_thunk, jnp_thunk) -> np.ndarray:
         return np.asarray(jnp_thunk())
 
 
-# tile caps: 128-multiples that divide the 8192-lane bench/production
-# pad shapes; the sweep only needs these three
-_TILE_CAP = _env_choice("UPOW_TILE_CAP", 1024, {128, 256, 512, 1024})
+_TILE_CAP = 1024  # lanes a grid step: one vreg a limb, eight sublane rows
 
 
-def _pick_tile(padded: int, cap: int = _TILE_CAP) -> int:
-    """Largest 128-multiple divisor of ``padded`` that is <= ``cap``
-    (``padded`` is always a multiple of 128 on the pallas path;
-    UPOW_TILE_CAP overrides the default 1024 for the chip tile sweep)."""
+def _pick_tile(padded: int) -> int:
+    """Largest 128-multiple divisor of ``padded`` that is <= 1024
+    (``padded`` is always a multiple of 128 on the pallas path)."""
     rows = padded // 128
-    for k in range(min(cap // 128, rows), 0, -1):
+    for k in range(min(_TILE_CAP // 128, rows), 0, -1):
         if rows % k == 0:
             return 128 * k
     return 128
@@ -1270,34 +938,25 @@ def _unpack_fused(packed):
     return z, r, s, qx, qy, packed[40] != 0, packed[41] != 0
 
 
-@functools.partial(jax.jit, static_argnames=("tile",))
-def _prep_and_verify_pallas(packed, tile: int):
-    """One dispatch: device scalar prep -> Pallas ladder kernel (RCB16)."""
-    with jax.named_scope("upow.p256_verify"):
-        args = _scalar_prep(*_unpack_fused(packed))
-        return _verify_device_pallas(*args, tile=tile)
-
-
-def _jac_body(packed, tile: int, w: int):
+def _jac_body(packed, tile: int):
     """Shared trace body: fused input -> device scalar prep -> Jacobian
     ladder kernel -> stacked (2, N) bool (row 0 accept verdicts, row 1
     exception flags; those lanes need the host oracle).  One input and
     one output array = one transfer each way."""
     with jax.named_scope("upow.p256_verify"):
-        args = _scalar_prep(*_unpack_fused(packed), w=w)
-        ok, exc = _verify_device_pallas_jac(*args, tile=tile, w=w)
+        args = _scalar_prep(*_unpack_fused(packed))
+        ok, exc = _verify_device_pallas_jac(*args, tile=tile)
         return jnp.stack([ok, exc])
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "w"))
-def _prep_and_verify_pallas_jac(packed, tile: int, w: int = _WINDOW):
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _prep_and_verify_pallas_jac(packed, tile: int):
     """One dispatch: device scalar prep -> Jacobian ladder kernel."""
-    return _jac_body(packed, tile, w)
+    return _jac_body(packed, tile)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "mesh", "w"))
-def _prep_and_verify_pallas_jac_sharded(packed, tile: int, mesh,
-                                        w: int = _WINDOW):
+@functools.partial(jax.jit, static_argnames=("tile", "mesh"))
+def _prep_and_verify_pallas_jac_sharded(packed, tile: int, mesh):
     """Mesh-DP variant: every device runs scalar prep + the Pallas ladder
     on its own batch shard (the program is elementwise over lanes, so the
     only communication is the output gather).  ``shard_map`` is required
@@ -1310,7 +969,7 @@ def _prep_and_verify_pallas_jac_sharded(packed, tile: int, mesh,
     shard_map, check_kw = shard_map_compat()
 
     def per_device(packed_):
-        return _jac_body(packed_, tile, w)
+        return _jac_body(packed_, tile)
 
     lanes = P(None, "dp")
     return shard_map(
@@ -1385,23 +1044,18 @@ def verify_batch_prehashed(
     its lane axis sharded over the mesh ("dp"), so the elementwise
     verify program runs SPMD with zero collectives (SURVEY §2.3 DP
     verify).  Without it, inputs live on one device.  The jnp backend
-    shards via plain jit; the pallas backend (jac kernel + device prep)
-    wraps the kernel in shard_map — pallas_call has no partitioning
-    rule, so each device runs the grid on its own shard.
+    shards via plain jit; the pallas backend wraps the kernel in
+    shard_map — pallas_call has no partitioning rule, so each device
+    runs the grid on its own shard.
 
     ``scalar_prep``: "device" moves s⁻¹ mod n, u₁/u₂, Montgomery
     conversions, the on-curve check and digit extraction into the jitted
     program (default on TPU — the host bigint loop costs 5x the ladder
     kernel); "host" keeps them in Python (default on CPU, where compile
-    time matters more than per-batch host microseconds)."""
+    time matters more than per-batch host microseconds).  The pallas
+    backend is one fused dispatch and takes the device prep only."""
     n = len(digests)
     assert len(signatures) == n and len(pubkeys) == n
-    if mesh is not None:
-        import math
-
-        n_dev = mesh.devices.size
-        # padded length must split evenly across the mesh
-        pad_block = pad_block * n_dev // math.gcd(pad_block, n_dev)
     if n == 0:
         return np.zeros(0, dtype=bool)
     if backend is None or scalar_prep is None:
@@ -1412,20 +1066,19 @@ def verify_batch_prehashed(
             backend = "pallas" if platform == "tpu" else "jnp"
         if scalar_prep is None:
             scalar_prep = "device" if platform == "tpu" else "host"
-    if mesh is not None and backend == "pallas":
-        if PALLAS_KERNEL != "jac" or scalar_prep != "device":
+    n_dev = mesh.devices.size if mesh is not None else 1
+    # padded length must split evenly across the mesh ...
+    unit = n_dev
+    if backend == "pallas":
+        if scalar_prep != "device":
             raise ValueError(
-                "mesh + pallas is wired for the jac kernel with device "
-                "scalar prep; pass backend='jnp' otherwise")
-        import math
-
-        # the one real invariant: padded must be a multiple of
-        # 128 * n_dev, so every device's shard fills whole kernel tiles
-        unit = 128 * mesh.devices.size
-        pad_block = pad_block * unit // math.gcd(pad_block, unit)
-    elif backend == "pallas":
-        # the limb-list kernel reshapes the batch axis to (rows, 128)
-        pad_block = max(pad_block, 128)
+                "the pallas ladder takes its operands from the device "
+                "scalar prep; pass backend='jnp' for host prep")
+        # ... and the kernel reshapes the batch axis to (rows, 128), so
+        # every device's shard fills whole kernel tiles
+        unit = 128 * n_dev
+    pad_block = math.lcm(pad_block, unit)
+    padded = _pad_to_block(n, pad_block)
 
     # occupancy + in-process jit hit/miss telemetry: real lanes vs the
     # padded batch actually dispatched; the compile key mirrors what
@@ -1433,58 +1086,43 @@ def verify_batch_prehashed(
     from ..telemetry import device as _ktel
 
     _ktel.record_batch(
-        "p256_verify", real=n, padded=_pad_to_block(n, pad_block),
-        compile_key=(backend, scalar_prep, _pad_to_block(n, pad_block),
-                     PALLAS_KERNEL,
-                     mesh.devices.size if mesh is not None else 0))
+        "p256_verify", real=n, padded=padded,
+        compile_key=(backend, scalar_prep, padded,
+                     n_dev if mesh is not None else 0))
+
+    def over_mesh(*arrays):
+        if mesh is None:
+            return arrays
+        from ..parallel.mesh import shard_batch_arrays
+
+        return shard_batch_arrays(mesh, *arrays)
 
     if scalar_prep == "device":
-        padded = _pad_to_block(n, pad_block)
         inputs, zs, rs, ss, qxs, qys = _pack_device_inputs(
             digests, signatures, pubkeys, padded)
-        if backend == "pallas" and PALLAS_KERNEL == "jac":
+        inputs, = over_mesh(inputs)
+        if backend != "pallas":
+            return np.asarray(_prep_and_verify_jnp(inputs))[:n]
+
+        def pallas_thunk():
             if mesh is not None:
-                from ..parallel.mesh import shard_batch_arrays
+                return _prep_and_verify_pallas_jac_sharded(
+                    inputs, tile=_pick_tile(padded // n_dev), mesh=mesh)
+            return _prep_and_verify_pallas_jac(inputs,
+                                               tile=_pick_tile(padded))
 
-                inputs, = shard_batch_arrays(mesh, inputs)
+        def jnp_thunk():
+            # the jnp fallback's complete formulas have no exceptions
+            # (sharded inputs partition the plain-jit program too)
+            ok = np.asarray(_prep_and_verify_jnp(inputs))
+            return np.stack([ok, np.zeros_like(ok)])
 
-            def pallas_thunk():
-                if mesh is not None:
-                    res = _prep_and_verify_pallas_jac_sharded(
-                        inputs,
-                        tile=_pick_tile(padded // mesh.devices.size),
-                        mesh=mesh, w=PALLAS_JAC_WINDOW)
-                else:
-                    res = _prep_and_verify_pallas_jac(
-                        inputs, tile=_pick_tile(padded),
-                        w=PALLAS_JAC_WINDOW)
-                return np.asarray(res)
-
-            def jnp_thunk():
-                # the jnp fallback's complete formulas have no exceptions
-                # (sharded inputs partition the plain-jit program too)
-                ok = np.asarray(_prep_and_verify_jnp(inputs))
-                return np.stack([ok, np.zeros_like(ok)])
-
-            res = _pallas_or_jnp(pallas_thunk, jnp_thunk)
-            out, exc = res[0], res[1]
-            if exc[:n].any():
-                out = out.copy()
-                for i in np.nonzero(exc[:n])[0]:
-                    out[i] = _host_verify_prehashed(
-                        zs[i], rs[i], ss[i], qxs[i], qys[i])
-            return out[:n]
-        if backend == "pallas":
-            out = _pallas_or_jnp(
-                lambda: _prep_and_verify_pallas(inputs,
-                                                tile=_pick_tile(padded)),
-                lambda: _prep_and_verify_jnp(inputs))
-        else:
-            if mesh is not None:
-                from ..parallel.mesh import shard_batch_arrays
-
-                inputs, = shard_batch_arrays(mesh, inputs)
-            out = np.asarray(_prep_and_verify_jnp(inputs))
+        out, exc = _pallas_or_jnp(pallas_thunk, jnp_thunk)
+        if exc[:n].any():
+            out = out.copy()
+            for i in np.nonzero(exc[:n])[0]:
+                out[i] = _host_verify_prehashed(
+                    zs[i], rs[i], ss[i], qxs[i], qys[i])
         return out[:n]
 
     u1s, u2s, qxs, qys, rms, rnms, rnoks, valids = [], [], [], [], [], [], [], []
@@ -1507,7 +1145,6 @@ def verify_batch_prehashed(
         rnoks.append(rn < CURVE_P)
         valids.append(ok)
 
-    padded = _pad_to_block(n, pad_block)
     pad = padded - n
 
     def arr(xs):
@@ -1520,30 +1157,9 @@ def verify_batch_prehashed(
             np.pad(_scalar_digits(xs), ((0, 0), (0, pad)), constant_values=0)
         )
 
-    if backend == "pallas":
-        flags = jnp.asarray(np.stack([
-            np.pad(np.array(rnoks, dtype=np.int32), (0, pad)),
-            np.pad(np.array(valids, dtype=np.int32), (0, pad)),
-        ]))
-        out = _pallas_or_jnp(
-            lambda: _verify_device_pallas(
-                digits(u1s), digits(u2s), arr(qxs), arr(qys), arr(rms),
-                arr(rnms), flags, tile=_pick_tile(padded)),
-            lambda: _verify_device(
-                digits(u1s), digits(u2s), arr(qxs), arr(qys), arr(rms),
-                arr(rnms),
-                jnp.asarray(np.pad(np.array(rnoks, dtype=bool), (0, pad))),
-                jnp.asarray(np.pad(np.array(valids, dtype=bool), (0, pad)))))
-        return out[:n]
-    else:
-        inputs = (
-            digits(u1s), digits(u2s), arr(qxs), arr(qys), arr(rms), arr(rnms),
-            jnp.asarray(np.pad(np.array(rnoks, dtype=bool), (0, pad))),
-            jnp.asarray(np.pad(np.array(valids, dtype=bool), (0, pad))),
-        )
-        if mesh is not None:
-            from ..parallel.mesh import shard_batch_arrays
-
-            inputs = shard_batch_arrays(mesh, *inputs)
-        out = _verify_device(*inputs)
-    return np.asarray(out)[:n]
+    inputs = (
+        digits(u1s), digits(u2s), arr(qxs), arr(qys), arr(rms), arr(rnms),
+        jnp.asarray(np.pad(np.array(rnoks, dtype=bool), (0, pad))),
+        jnp.asarray(np.pad(np.array(valids, dtype=bool), (0, pad))),
+    )
+    return np.asarray(_verify_device(*over_mesh(*inputs)))[:n]

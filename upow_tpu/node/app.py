@@ -115,7 +115,6 @@ class Node:
         if self.config.telemetry.instance_scope:
             self.telemetry_scope = telemetry.TelemetryScope.from_config(
                 self.config.telemetry)
-        self.config.device.apply_kernel_overrides()
         if state is not None:
             # injected backend (tests: the pg backend over the mock
             # driver; a live server would come through config instead)

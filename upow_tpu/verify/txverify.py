@@ -161,15 +161,8 @@ def _verify_mesh(mesh_devices: int):
     """DP mesh for the device verify dispatch (SURVEY §2.3): 0 = all
     visible devices, 1 = single device (no mesh), N = first N.  On a
     one-chip host this is always None — the batch stays resident on the
-    single device with no partitioning overhead.  The 'complete' kernel
-    variant has no mesh wiring (p256 partitions the jac ladder only);
-    it keeps the unsharded dispatch rather than poisoning the device
-    path."""
+    single device with no partitioning overhead."""
     if mesh_devices == 1:
-        return None
-    from ..crypto import p256
-
-    if p256.PALLAS_KERNEL == "complete":
         return None
     with _VERIFY_MESH_LOCK:
         if mesh_devices not in _VERIFY_MESH:
