@@ -1,7 +1,8 @@
-"""What the tests of the miner's seams share (ISSUE 44; no test lives
-here): a device that answers at once and logs every issue and every
-answer read, a feed without its thread, and ``miner.run`` over a scripted
-node for a given number of jobs."""
+"""What the tests of the miner's seams share (ISSUES 44 and 47; no test
+lives here): a device that answers at once, logs every issue and every
+answer read and says which rounds it has finished, the engine's depth
+pinned, a feed without its thread, and ``miner.run`` over a scripted node
+for a given number of jobs."""
 
 import importlib.util
 import os
@@ -16,6 +17,7 @@ SEAMS = ("mine.jobs",) + miner.SEAM_COUNTERS
 SHARD = (0, 1 << 18)      # shard 0 of 2^18: a range of RANGE nonces
 RANGE = 1 << 14
 ROUNDS = ("mine.rounds", "mine.nonces")
+QUEUE = engine.QUEUE_COUNTERS
 
 
 def minerlog():
@@ -44,14 +46,23 @@ class FakeDevice:
     round's start) in the order the loop made them; ``hits`` maps (job
     number, start) to the nonce that round answers, which every job's
     ``check`` is made to pass; a round takes ``round_s`` to answer, and
-    ``on_wait(job number, start)`` runs before each answer is read."""
+    ``on_wait(job number, start)`` runs before each answer is read.  The
+    engine keeps ``depth`` rounds in flight whatever their period (None:
+    by its own rule, from two).  A handle says it is ready where the
+    device has finished its round: the rounds answered so far and the
+    ``ahead`` next (in the order they were issued)."""
 
-    def __init__(self, monkeypatch, hits=(), round_s=0.0):
+    def __init__(self, monkeypatch, hits=(), round_s=0.0, depth=2):
         self.log, self.jobs = [], []
         self.hits, self.round_s = dict(hits), round_s
         self.on_wait = lambda number, start: None
+        self.issued = self.answered = self.ahead = 0
         monkeypatch.setattr(engine, "_make_dispatcher", self.make)
         monkeypatch.setattr(engine.MiningJob, "check", lambda job, n: True)
+        monkeypatch.setattr(engine, "_depth",
+                            depth or engine.MIN_ROUNDS_IN_FLIGHT)
+        if depth:
+            monkeypatch.setattr(engine, "depth_for", lambda round_s: depth)
 
     def make(self, job, backend, mesh_devices=0, batch=None):
         number, device = len(self.jobs), self
@@ -60,11 +71,16 @@ class FakeDevice:
         class Handle:
             def __init__(self, start):
                 self.start = start
+                self.nth, device.issued = device.issued, device.issued + 1
+
+            def ready(self):
+                return self.nth < device.answered + device.ahead
 
             def __int__(self):
                 time.sleep(device.round_s)
                 device.on_wait(number, self.start)
                 device.log.append(("wait", number, self.start))
+                device.answered = self.nth + 1
                 return device.hits.get((number, self.start), int(SENTINEL))
 
         def dispatch(start, count):
@@ -76,6 +92,15 @@ class FakeDevice:
     def rounds(self, what: str, number: int) -> list:
         return [start for w, n, start in self.log
                 if w == what and n == number]
+
+    def most_in_flight(self, upto=None) -> int:
+        """The most rounds that were out at once, whichever job's (rounds
+        dropped after a hit stay out: ``upto`` ends the count there)."""
+        out = most = 0
+        for what, _number, _start in self.log[:upto]:
+            out += 1 if what == "issue" else -1
+            most = max(most, out)
+        return most
 
 
 class InlineFeed(miner.TemplateFeed):

@@ -660,6 +660,42 @@ def test_engine_stats_exported_for_node_gauges():
     assert st["job_layouts"] == 0 and st["jit_entries"] >= 1
 
 
+def test_a_rounds_answer_says_when_it_is_ready_and_the_mesh_line_the_depth(
+        monkeypatch, capsys):
+    """The handle of a round asks its array's host side (no transfer of
+    its own, and nothing of the program touched: the jit cache stays as
+    it was), and its words are on their way to the host from the issue
+    on; the miner's ``mesh:`` line carries the round loop's depth in
+    force."""
+    import json
+
+    from upow_tpu.mine import engine, miner
+
+    eng = _armed_engine(batch_per_device=64)
+    eng.set_job(_seeded_job(5))
+    entries = _jit_entries()
+    asked = []
+    to_host = type(jax.numpy.zeros(1)).copy_to_host_async
+    monkeypatch.setattr(type(jax.numpy.zeros(1)), "copy_to_host_async",
+                        lambda words: asked.append(words) or to_host(words))
+    handle = eng.dispatch(0, eng.capacity)
+    # the answer's host copy is asked for at the issue, once a round
+    assert asked == [handle._words]
+    assert handle.ready() in (True, False)
+    hit = int(handle)
+    assert handle.ready() and int(handle) == hit
+    assert engine._unfinished([handle, handle]) == 0
+    assert _jit_entries() == entries
+    for depth in (2, 4):
+        monkeypatch.setattr(engine, "_depth", depth)
+        miner._print_mesh_accounting(0)
+        line = capsys.readouterr().out.splitlines()[-1]
+        assert line.startswith("mesh: ")
+        said = json.loads(line[len("mesh: "):])
+        assert said["rounds_in_flight"] == depth and said["jit_entries"] \
+            == eng.stats()["jit_entries"]
+
+
 # ------------------------- --device tpu: the engine on a one-device mesh ----
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -258,25 +258,38 @@ def test_supervisor_respawns_hung_child(tmp_path):
     assert __import__("time").monotonic() - t0 < 30
 
 
-# ---- the seam: the round pipeline runs across jobs (ISSUE 44) ----
+# ---- the seam: the round pipeline runs across jobs (ISSUE 44), as deep
+# as the queue's lead in seconds asks (ISSUE 47) ----
 
 def _seam_jobs(n=2):
     return [_job("9") for _ in range(n)]
 
 
+def _issues(number, *starts):
+    return [("issue", number, start) for start in starts]
+
+
+def _waits(number, *starts):
+    return [("wait", number, start) for start in starts]
+
+
+@pytest.mark.parametrize("depth", [2, 4])
 @pytest.mark.parametrize("not_yet", [0, 1, 2])
 def test_the_next_jobs_first_rounds_go_behind_the_last_of_the_job_in_hand(
-        monkeypatch, not_yet):
-    """Four rounds a job.  Once the job in hand has issued its last and
-    while rounds are in flight, ``next_job`` is asked before each wait;
-    where it answers, the next job's first two rounds are issued before
-    the answers still out are read (``not_yet``: how often it says None
-    first; the third ask would come with nothing in flight and is never
-    made).  The sweep handed back as ``ahead`` goes on where it was."""
+        monkeypatch, not_yet, depth):
+    """Four rounds a job, ``depth`` in flight.  Once the job in hand has
+    issued its last and while rounds are in flight, ``next_job`` is asked
+    before each wait (``not_yet``: how often it says None first; an ask
+    with nothing in flight is never made); from where it answers, every
+    answer of the job in hand read makes room for one round of the next
+    job, issued before the answers still out are read.  The bound is one
+    over both jobs.  The sweep handed back as ``ahead`` goes on where it
+    was."""
     import miner_seams
-    from upow_tpu.mine.engine import ROUNDS_IN_FLIGHT, Sweep
+    from upow_tpu.mine import engine
+    from upow_tpu.mine.engine import Sweep
 
-    device = miner_seams.FakeDevice(monkeypatch)
+    device = miner_seams.FakeDevice(monkeypatch, depth=depth)
     jobs, asked, made = _seam_jobs(), [], []
     before = miner_seams.counters(*miner_seams.ROUNDS)
 
@@ -289,55 +302,265 @@ def test_the_next_jobs_first_rounds_go_behind_the_last_of_the_job_in_hand(
 
     first = mine(jobs[0], "jnp", batch=64, stride_end=256, next_job=next_job)
     assert first.nonce is None and first.hashes_tried == 256
-    in_hand = [("issue", 0, 0), ("issue", 0, 64), ("wait", 0, 0),
-               ("issue", 0, 128), ("wait", 0, 64), ("issue", 0, 192)]
-    ahead = [("issue", 1, 0), ("issue", 1, 64)]
-    last = [("wait", 0, 128), ("wait", 0, 192)]
-    assert ROUNDS_IN_FLIGHT == 2
-    assert device.log == in_hand + last[:not_yet] \
-        + (ahead if not_yet < 2 else []) + last[not_yet:]
-    assert len(asked) == min(not_yet + 1, 2) and len(made) == (not_yet < 2)
-    # never more than the two jobs' two each are out (the benchmark's
-    # traced runs allow the lines and the device four rounds of edge)
-    out = 0
-    for what, _number, _start in device.log:
-        out += 1 if what == "issue" else -1
-        assert out <= 2 * ROUNDS_IN_FLIGHT
+    assert engine.rounds_in_flight() == depth >= engine.MIN_ROUNDS_IN_FLIGHT
+    in_hand, last = {
+        2: (_issues(0, 0, 64) + _waits(0, 0) + _issues(0, 128)
+            + _waits(0, 64) + _issues(0, 192), _waits(0, 128, 192)),
+        4: (_issues(0, 0, 64, 128, 192), _waits(0, 0, 64, 128, 192)),
+    }[depth]
+    # the k-th of the last answers read leaves room for k rounds of the
+    # next job in all, once that job is there (after ``not_yet`` of them)
+    expected, ahead = list(in_hand), 0
+    for k, wait in enumerate(last, 1):
+        expected.append(wait)
+        if depth > not_yet <= k:
+            expected += _issues(1, *range(64 * ahead, 64 * k, 64))
+            ahead = k
+    assert device.log == expected
+    assert device.log[len(in_hand):] == {
+        (2, 0): _waits(0, 128) + _issues(1, 0) + _waits(0, 192)
+        + _issues(1, 64),
+        (2, 2): _waits(0, 128, 192),
+        (4, 2): _waits(0, 0, 64) + _issues(1, 0, 64) + _waits(0, 128)
+        + _issues(1, 128) + _waits(0, 192) + _issues(1, 192),
+    }.get((depth, not_yet), expected[len(in_hand):])
+    assert len(asked) == min(not_yet + 1, depth)
+    assert len(made) == (not_yet < depth)
+    # issues - waits <= depth at every step, across the seam too (the
+    # benchmark's traced runs allow the lines and the device four rounds
+    # of edge)
+    assert device.most_in_flight() == depth
     # only what was answered is counted: the rounds ahead are in flight
     assert miner_seams.grew(before) == {"mine.rounds": 4, "mine.nonces": 256}
     if made:
-        assert [c for _h, c in made[0].inflight] == [64, 64]
+        assert [c for _h, c in made[0].inflight] == [64] * depth
         second = mine(jobs[1], "jnp", ahead=made[0])
         assert second.nonce is None and second.hashes_tried == 256
         assert device.rounds("issue", 1) == device.rounds("wait", 1) \
             == [0, 64, 128, 192]
         assert len(device.jobs) == 2      # prepared once, by its Sweep
+        assert device.most_in_flight() == depth
 
 
-def test_the_queue_is_filled_before_a_round_is_said(monkeypatch):
+@pytest.mark.parametrize("depth", [2, 4])
+def test_the_queue_is_filled_before_a_round_is_said(monkeypatch, depth):
     """An answer read, the next round is issued before ``progress``
     hears of it: what the loop says about a round lies behind the
     device's queue, not before it.  The sweep a ``ttl`` cuts issues no
     round after the cut."""
     import miner_seams
 
-    device = miner_seams.FakeDevice(monkeypatch)
+    device = miner_seams.FakeDevice(monkeypatch, depth=depth)
 
     def progress(tried, _elapsed):
         device.log.append(("said", 0, tried))
 
-    mine(_job("9"), "jnp", batch=64, stride_end=256, progress=progress)
-    assert device.log == [
-        ("issue", 0, 0), ("issue", 0, 64),
-        ("wait", 0, 0), ("issue", 0, 128), ("said", 0, 64),
-        ("wait", 0, 64), ("issue", 0, 192), ("said", 0, 128),
-        ("wait", 0, 128), ("said", 0, 192),
-        ("wait", 0, 192), ("said", 0, 256)]
+    mine(_job("9"), "jnp", batch=64, stride_end=384, progress=progress)
+    assert device.log == {
+        2: [("issue", 0, 0), ("issue", 0, 64),
+            ("wait", 0, 0), ("issue", 0, 128), ("said", 0, 64),
+            ("wait", 0, 64), ("issue", 0, 192), ("said", 0, 128),
+            ("wait", 0, 128), ("issue", 0, 256), ("said", 0, 192),
+            ("wait", 0, 192), ("issue", 0, 320), ("said", 0, 256),
+            ("wait", 0, 256), ("said", 0, 320),
+            ("wait", 0, 320), ("said", 0, 384)],
+        4: [("issue", 0, 0), ("issue", 0, 64), ("issue", 0, 128),
+            ("issue", 0, 192),
+            ("wait", 0, 0), ("issue", 0, 256), ("said", 0, 64),
+            ("wait", 0, 64), ("issue", 0, 320), ("said", 0, 128),
+            ("wait", 0, 128), ("said", 0, 192),
+            ("wait", 0, 192), ("said", 0, 256),
+            ("wait", 0, 256), ("said", 0, 320),
+            ("wait", 0, 320), ("said", 0, 384)]}[depth]
     del device.log[:]
-    mine(_job("9"), "jnp", batch=64, stride_end=256, ttl=0.0,
+    mine(_job("9"), "jnp", batch=64, stride_end=384, ttl=0.0,
          progress=progress)
-    assert device.log == [("issue", 1, 0), ("issue", 1, 64),
-                          ("wait", 1, 0), ("said", 0, 64)]
+    assert device.log == _issues(1, *range(0, 64 * depth, 64)) \
+        + [("wait", 1, 0), ("said", 0, 64)]
+
+
+@pytest.mark.parametrize("round_ms, depth", [
+    (1.86, 4),      # the pod: 5.6 ms queued behind the round that runs
+    (7.2, 2),       # one chip: a round outlasts the lead by itself
+    (0.9, 4),       # the cap: nothing deeper was measured
+    (3.0, 3), (2.5, 3), (2.49, 4), (5.0, 2), (4.99, 3)])
+def test_the_depth_is_the_fewest_rounds_that_queue_the_lead(round_ms, depth):
+    from upow_tpu.mine import engine
+
+    round_s = round_ms / 1e3
+    assert engine.depth_for(round_s) == depth
+    assert engine.MIN_ROUNDS_IN_FLIGHT <= depth <= engine.MAX_ROUNDS_IN_FLIGHT
+    if depth > engine.MIN_ROUNDS_IN_FLIGHT:
+        assert (depth - 2) * round_s < engine.QUEUE_LEAD_S
+    if depth < engine.MAX_ROUNDS_IN_FLIGHT:
+        assert (depth - 1) * round_s >= engine.QUEUE_LEAD_S
+
+
+def test_nothing_timed_yet_the_engine_keeps_the_fewest_rounds_in_flight():
+    """A fresh process (the module as imported): no answer has been
+    timed, so the first job begins at the range's floor."""
+    import importlib.util
+
+    from upow_tpu.mine import engine
+
+    spec = importlib.util.find_spec("upow_tpu.mine.engine")
+    fresh = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(fresh)
+    assert fresh.rounds_in_flight() == fresh.MIN_ROUNDS_IN_FLIGHT == 2
+    assert engine.MIN_ROUNDS_IN_FLIGHT == 2 <= engine.MAX_ROUNDS_IN_FLIGHT
+
+
+@pytest.mark.parametrize("periods_ms, depths", [
+    # the pod: the second answer gives the first period
+    ([1.86] * 8, [2] + [4] * 8),
+    # one chip
+    ([7.2] * 4, [2] * 5),
+    # a stall of 50 ms is in the job's mean for as long as it outweighs
+    # the lead (the shallower queue for as long), then falls out of it
+    ([1.86, 1.86, 50.0] + [1.86] * 80,
+     [2, 4, 4] + [2] * 13 + [3] * 60 + [4] * 8),
+])
+def test_the_depth_follows_the_mean_period_of_the_jobs_own_answers(
+        monkeypatch, periods_ms, depths):
+    """One job's answers at scripted times: the depth after each is
+    :func:`depth_for` of the mean period since the job's first answer
+    (one answer is no period: the depth stays where it was)."""
+    import miner_seams
+    from upow_tpu.mine import engine
+
+    device = miner_seams.FakeDevice(monkeypatch, depth=None)
+    clock = [100.0]
+    monkeypatch.setattr(engine.time, "monotonic", lambda: clock[0])
+    steps = iter([0.0] + [ms / 1e3 for ms in periods_ms])
+    seen = []
+
+    def on_wait(_number, _start):
+        clock[0] += next(steps)
+
+    device.on_wait = on_wait
+    mine(_job("9"), "jnp", batch=64, stride_end=64 * len(depths),
+         progress=lambda *_a: seen.append(engine.rounds_in_flight()))
+    assert seen == depths
+    assert device.most_in_flight() == max(depths)
+
+
+@pytest.mark.parametrize("round_s, depth", [(0.0, 4), (0.008, 2)])
+def test_a_jobs_first_rounds_go_out_at_the_depth_the_last_job_ended_on(
+        monkeypatch, round_s, depth):
+    """The engine's own rule on a device whose rounds take ``round_s``:
+    the process's first job begins two deep; short rounds deepen the
+    queue inside it, and the next job begins where it ended."""
+    import miner_seams
+    from upow_tpu.mine import engine
+
+    device = miner_seams.FakeDevice(monkeypatch, round_s=round_s, depth=None)
+    jobs = _seam_jobs()
+    mine(jobs[0], "jnp", batch=64, stride_end=512)
+    assert device.log[:3] == _issues(0, 0, 64) + _waits(0, 0)
+    assert engine.rounds_in_flight() == depth == device.most_in_flight()
+    del device.log[:]
+    mine(jobs[1], "jnp", batch=64, stride_end=512)
+    assert device.log[:depth + 1] == \
+        _issues(1, *range(0, 64 * depth, 64)) + _waits(1, 0)
+    assert device.most_in_flight() == depth
+
+
+def test_a_hit_in_the_oldest_of_four_rounds_returns_at_once(monkeypatch,
+                                                            capsys):
+    """Four in flight, the oldest two the job in hand's last and the
+    newest two the next job's first (the feed's template came a round
+    into the job, so the seam began at its second answer): the hit
+    returns without another answer read or round issued, only what was
+    answered is counted, the job issued behind it is dropped, and the job
+    after the push starts with nothing in flight."""
+    import miner_seams
+
+    batch = miner_seams.RANGE // 4
+    device = miner_seams.FakeDevice(monkeypatch, depth=4,
+                                    hits={(1, 2 * batch): 12345})
+    pushes = []
+
+    def push(_node, content, txs, block_no):
+        pushes.append((content, list(device.log)))
+        return {"ok": True}
+
+    names = miner_seams.SEAMS + miner_seams.ROUNDS + ("mine.jobs_found",)
+    before = miner_seams.counters(*names)
+    miner_seams.run_jobs(monkeypatch, capsys, 4, push=push)
+    at = device.log.index(("wait", 1, 2 * batch))
+    assert device.log[at - 3:at + 2] == _waits(1, batch) \
+        + _issues(2, 0, batch) + _waits(1, 2 * batch) + _issues(3, 0)
+    assert device.rounds("wait", 1) == [0, batch, 2 * batch]
+    assert device.rounds("issue", 1) == [0, batch, 2 * batch, 3 * batch]
+    assert device.rounds("issue", 2) == [0, batch]
+    assert device.rounds("wait", 2) == []
+    assert device.rounds("wait", 3) == [0, batch, 2 * batch, 3 * batch]
+    assert len(pushes) == 1
+    assert pushes[0][0] == device.jobs[1].block_content(12345)
+    assert pushes[0][1] == device.log[:at + 1]      # pushed at once
+    assert miner_seams.grew(before) == {
+        "mine.jobs": 3, "mine.jobs_overlapped": 1, "mine.jobs_drained": 1,
+        "mine.jobs_dropped": 1, "mine.jobs_found": 1,
+        "mine.rounds": 4 + 3 + 4, "mine.nonces": 11 * batch}
+    assert device.most_in_flight(upto=at + 1) == 4
+
+
+def test_the_queues_counters_say_how_often_it_ran_low(monkeypatch):
+    """``ahead``: the rounds the device has finished beyond the one whose
+    answer was read, by the end of the fill.  Four in flight: three were
+    out at the fill; with one of them unfinished at most the queue was
+    low, with none the device had gone idle.  With one round out the
+    queue is low by construction, and only ``mine.queue_empty`` counts."""
+    import miner_seams
+
+    device = miner_seams.FakeDevice(monkeypatch, depth=4)
+    finished_beyond = {0: 0, 64: 1, 128: 2, 192: 3, 256: 4, 320: 1}
+
+    def on_wait(_number, start):
+        device.ahead = finished_beyond.get(start, 0)
+
+    device.on_wait = on_wait
+    names = miner_seams.QUEUE + miner_seams.ROUNDS
+    before = miner_seams.counters(*names)
+    mine(_job("9"), "jnp", batch=64, stride_end=64 * 9)
+    # out at each fill: 3, 3, 3, 3, 3, 3 (the range's last issue), then
+    # 2, 1 and 0 as the job drains with no job behind it
+    assert miner_seams.grew(before) == {
+        "mine.queue_low": 3, "mine.queue_empty": 2 + 1,
+        "mine.rounds": 9, "mine.nonces": 64 * 9}
+    # two in flight: one round out at every fill, the brink by
+    # construction: only the idle device is counted
+    device = miner_seams.FakeDevice(monkeypatch, depth=2)
+    device.on_wait = on_wait
+    before = miner_seams.counters(*names)
+    mine(_job("9"), "jnp", batch=64, stride_end=64 * 9)
+    assert miner_seams.grew(before) == {
+        "mine.queue_low": 0, "mine.queue_empty": 5 + 1,
+        "mine.rounds": 9, "mine.nonces": 64 * 9}
+
+
+def test_the_queues_counters_are_exported_at_zero_from_the_first_scrape(
+        monkeypatch, capsys):
+    import json
+
+    from upow_tpu import telemetry
+    from upow_tpu.mine import engine, miner
+    from upow_tpu.telemetry import scope
+
+    def fetch(_node):
+        raise KeyboardInterrupt     # before any job is built
+
+    monkeypatch.setattr(miner, "fetch_mining_info", fetch)
+    with scope.activate(scope.TelemetryScope("queue")):
+        assert not set(engine.QUEUE_COUNTERS) & set(telemetry.counters())
+        with pytest.raises(KeyboardInterrupt):
+            miner.run("addr", "http://x/", "python", 64, 1.0, once=True)
+        have = telemetry.counters()
+        assert [have[name] for name in engine.QUEUE_COUNTERS] == [0, 0]
+        miner._print_exit_lines()
+        said = json.loads(capsys.readouterr().out.splitlines()[-1]
+                          [len("telemetry: "):])["counters"]
+        assert [said[name] for name in engine.QUEUE_COUNTERS] == [0, 0]
 
 
 def test_a_job_the_ttl_cuts_and_a_host_backend_never_ask_for_the_next(
@@ -377,7 +600,7 @@ def test_a_hit_in_the_last_round_drops_the_job_issued_ahead(monkeypatch,
     out, _ = miner_seams.run_jobs(monkeypatch, capsys, 4, push=push)
     # jobs 0 and 1 are the miner's first two; 2 was issued ahead and
     # dropped; 3 is the job after the push
-    assert device.rounds("issue", 2) == [0, 4096]
+    assert device.rounds("issue", 2) == [0]     # two in flight, in all
     assert device.rounds("wait", 2) == []
     assert device.rounds("wait", 3) == [0, 4096, 8192, 12288]
     assert device.log.index(("issue", 2, 0)) \

@@ -265,6 +265,42 @@ def test_the_asks_lead_covers_a_fetch_longer_than_the_rounds_in_flight(
     assert grew[miner.OVERLAPPED] + grew[miner.DRAINED] == 3
 
 
+@pytest.mark.parametrize("depth", [2, 4])
+def test_the_ask_leads_by_the_depth_in_force(monkeypatch, capsys, depth):
+    """Sixteen rounds of 30 ms a job, a node that answers at once: the
+    seam begins at the job's last issue, ``depth`` rounds before its end,
+    and the ask comes a round before that (the look comes once a round),
+    so every seam finds its template at the first look.  A slow seam's
+    build may make it one round earlier, never later."""
+    import miner_seams
+
+    device = miner_seams.FakeDevice(monkeypatch, round_s=0.03, depth=depth)
+    left = []
+
+    class Feed(miner_seams.InlineFeed):
+        def ask(self):
+            if self._asked_for <= self.jobs:
+                answered = sum(what == "wait" for what, *_ in device.log)
+                left.append(16 - answered % 16)
+            super().ask()
+
+    names = ("mine.jobs", "mine.jobs_held") + miner.SEAM_COUNTERS
+    before = miner_seams.counters(*names)
+    miner_seams.run_jobs(monkeypatch, capsys, 3, rounds=16, feed=Feed)
+    assert len(left) == 3 and set(left) <= {depth + 1, depth + 2}, left
+    assert miner_seams.grew(before) == {
+        "mine.jobs": 3, "mine.jobs_held": 0, miner.OVERLAPPED: 2,
+        miner.DRAINED: 0, miner.DROPPED: 0}
+    assert device.most_in_flight() == depth
+    # the next job's first round goes out behind the answer that made
+    # room for it: the first after the job in hand's last issue
+    for number in (0, 1):
+        last_issue = device.log.index(
+            ("issue", number, miner_seams.RANGE - miner_seams.RANGE // 16))
+        assert device.log[last_issue + 1][0] == "wait"
+        assert device.log[last_issue + 2] == ("issue", number + 1, 0)
+
+
 def test_a_template_that_comes_after_the_seams_last_look_makes_no_held_job(
         monkeypatch, capsys):
     """The node answers only once the last round of the job in hand is

@@ -698,7 +698,8 @@ def test_main_leaves_an_installed_handler_alone_and_says_goodbye(
     import json
 
     flat = json.loads(out[-1][len("telemetry: "):])
-    assert set(flat) == {"spans", "counters"}
+    assert set(flat) == {"spans", "counters", "rounds_in_flight"}
+    assert flat["rounds_in_flight"] == engine.rounds_in_flight()
 
 
 def test_main_prints_and_installs_nothing_with_the_switch_off(

@@ -751,6 +751,11 @@ class SearchAnswer:
     def __init__(self, words, kernel: str):
         self._words, self._kernel, self._hit = words, kernel, None
 
+    def ready(self) -> bool:
+        """Whether ``int()`` would return without waiting: asked of the
+        array's host side, no transfer."""
+        return self._hit is not None or self._words.is_ready()
+
     def __int__(self) -> int:
         if self._hit is None:
             from ..telemetry import device as _ktel

@@ -223,19 +223,65 @@ class MineResult:
         return self.hashes_tried / self.elapsed if self.elapsed > 0 else 0.0
 
 
-#: rounds a job keeps in flight on a device backend, so that the chip
-#: never idles while the host blocks on a result.  A hit wastes at most
-#: the rounds in flight (already dispatched): negligible against the
-#: throughput the overlap buys
-ROUNDS_IN_FLIGHT = 2
+#: seconds of queued search the device needs behind the round it runs to
+#: outlast the host's routine absences: a job's lay over the pod (2.4
+#: ms), the round in which the feed's thread holds the interpreter
+#: (2.7), a stall of 2.8 (PERF.md section 6, PR 44)
+QUEUE_LEAD_S = 0.005
+#: the depth's range.  Two: the device never idles while the host blocks
+#: on a result, and where a round outlasts the lead more buys no hash
+#: and stands in front of every other dispatch the device owner is
+#: handed (one chip: 9-10 ms behind two rounds, 23-24 behind four;
+#: PERF.md section 6, PR 47).  Four: nothing deeper was measured, a hit
+#: throws away what is queued behind it, and a traced run's lines and
+#: device events may lie four rounds apart at either edge (benchmarks/
+#: drivers/mine_sweep.py ``TRACE_EDGE_ROUNDS``)
+MIN_ROUNDS_IN_FLIGHT, MAX_ROUNDS_IN_FLIGHT = 2, 4
+#: how often the queue ran low, a count a fill (:func:`mine`); exported
+#: at zero from the miner's first scrape
+QUEUE_COUNTERS = QUEUE_LOW, QUEUE_EMPTY = (
+    "mine.queue_low", "mine.queue_empty")
+#: the depth in force, whichever job's the rounds: the process's, as the
+#: device's queue is, so a job's first rounds go out at the depth the
+#: last job ended on (:func:`mine` sets it from each job's own answers)
+_depth = MIN_ROUNDS_IN_FLIGHT
+
+
+def depth_for(round_s: float) -> int:
+    """Rounds to keep in flight where one takes ``round_s``: the fewest,
+    within the range, whose queued part (all but the one that runs)
+    lasts ``QUEUE_LEAD_S``."""
+    depth = MIN_ROUNDS_IN_FLIGHT
+    while (depth < MAX_ROUNDS_IN_FLIGHT
+           and (depth - 1) * round_s < QUEUE_LEAD_S):
+        depth += 1
+    return depth
+
+
+def rounds_in_flight() -> int:
+    """The depth in force."""
+    return _depth
+
+
+def _unfinished(handles) -> int:
+    """How many of ``handles`` (oldest first; rounds finish in order) the
+    device has not answered yet.  Asked of the host's side of the array
+    (``SearchAnswer.ready``, a bare array's ``is_ready``): no transfer; a
+    handle that cannot say is an answer already."""
+    for done, handle in enumerate(handles):
+        ready = getattr(handle, "ready", None) \
+            or getattr(handle, "is_ready", None)
+        if ready is not None and not ready():
+            return len(handles) - done
+    return 0
 
 
 class Sweep:
     """One job's rounds on a device backend: made ready (``mine.prepare``:
-    on the mesh the job's arrays laid), issued ``ROUNDS_IN_FLIGHT`` deep,
-    answered in order.  :func:`mine` drives it, and may be handed one
-    whose first rounds an earlier call issued behind the last rounds of
-    its own job (``ahead``): the device then goes from one job's last
+    on the mesh the job's arrays laid), issued as deep as :func:`mine`
+    says, answered in order.  :func:`mine` drives it, and may be handed
+    one whose first rounds an earlier call issued behind the last rounds
+    of its own job (``ahead``): the device then goes from one job's last
     round to the next job's first with no host between them.  Its spans
     lie in the tree of the span that was ambient when it was made."""
 
@@ -246,7 +292,7 @@ class Sweep:
         self.mesh_devices = mesh_devices
         self.cursor, self.end = start, min(stride_end, MAX_SEARCH_END)
         self.root = telemetry.current_span()
-        self.t0 = time.time()
+        self.t0 = time.monotonic()
         self.tried = 0
         self.first: Optional[float] = None
         self.inflight: deque = deque()  # (handle, count), oldest first
@@ -254,17 +300,17 @@ class Sweep:
             self.dispatch = _make_dispatcher(
                 job, backend, mesh_devices=mesh_devices, batch=batch)
 
-    def issue(self) -> None:
-        """Rounds issued until ``ROUNDS_IN_FLIGHT`` are in flight or the
-        range is spent."""
-        while len(self.inflight) < ROUNDS_IN_FLIGHT and self.cursor < self.end:
+    def issue(self, depth: int) -> None:
+        """Rounds issued until ``depth`` of this job's are in flight or
+        the range is spent."""
+        while len(self.inflight) < depth and self.cursor < self.end:
             count = min(self.batch, self.end - self.cursor)
             if self.first is None:
                 # on the static-target engine a new tip's trace and
                 # compile live in this dispatch
                 with telemetry.span("mine.first_issue"):
                     handle = self.dispatch(self.cursor, count)
-                self.first = time.time() - self.t0
+                self.first = time.monotonic() - self.t0
             else:
                 with telemetry.span("mine.round.issue", light=True):
                     handle = self.dispatch(self.cursor, count)
@@ -291,7 +337,7 @@ class Sweep:
         return hit
 
     def result(self, nonce: Optional[int]) -> MineResult:
-        return MineResult(nonce, self.tried, time.time() - self.t0,
+        return MineResult(nonce, self.tried, time.monotonic() - self.t0,
                           self.first or 0.0)
 
 
@@ -314,51 +360,78 @@ def mine(job: MiningJob, backend: str = "jnp", *, start: int = 0,
     surplus lanes masked (:func:`_make_dispatcher`); ``hashes_tried``,
     ``mine.nonces`` and the progress callback count live lanes only.
 
-    The pipeline does not drain at a job's end where the caller has the
-    next job ready: once the last round of ``job`` is issued and while
-    rounds are in flight, ``next_job()`` is asked, before each wait, for
-    the next job's :class:`Sweep` (None: not yet), whose first rounds are
-    then issued behind the ones in flight, under its own root span (so
-    twice ``ROUNDS_IN_FLIGHT`` are out for the length of a round); the
-    caller hands it back as ``ahead`` with that job (``start``,
-    ``stride_end`` and ``batch`` are then the sweep's own).  A hit in
-    ``job``'s last rounds leaves such a sweep to be dropped by the caller:
-    its rounds are never waited for and counted nowhere.  Host backends
-    keep nothing in flight and never ask.
+    A device backend keeps :func:`rounds_in_flight` rounds on the device
+    (:func:`depth_for` of the mean period of the job's own answers: two
+    where a round outlasts ``QUEUE_LEAD_S``, up to four where rounds are
+    short; a job's first rounds go out at the depth the last job ended
+    on), and the pipeline does not drain at a job's end where the caller
+    has the next job ready: once the last round of ``job`` is issued and
+    while rounds are in flight, ``next_job()`` is asked, before each
+    wait, for the next job's :class:`Sweep` (None: not yet), whose first
+    rounds are then issued, under its own root span, into the room
+    ``job``'s answers leave: the one bound holds over both jobs.  The
+    caller hands that sweep back as ``ahead`` with its job (``start``,
+    ``stride_end`` and ``batch`` are then the sweep's own).  A hit
+    returns at once: what is queued behind it is never waited for and
+    counted nowhere, and a sweep issued ahead is the caller's to drop.
+    Host backends keep nothing in flight and never ask.
 
     An answer read, the queue is filled again (the next round issued, at
-    a seam the next job's) before ``progress`` hears of the round: with
-    ``ROUNDS_IN_FLIGHT`` out, the device is one round from idling when
-    an answer comes, and what the loop says lies behind that, not before
-    it.  A sweep the ``ttl`` cuts issues nothing after the cut.
+    a seam the next job's) before anything else: before ``progress``
+    hears of the round, before the queue's own accounting.  That is the
+    answer's time into the period, and, of the rounds that were out when
+    the answer was read, how many the device had not finished by the end
+    of the fill: ``mine.queue_empty`` where none (the device went idle
+    before the host had refilled its queue, or within the fill),
+    ``mine.queue_low`` where at most one of two or more (two in flight
+    would have been at the brink; not counted with one round out, where
+    it would be every round).  A sweep the ``ttl`` cuts issues nothing
+    after the cut.
     """
+    global _depth
     sweep = ahead if ahead is not None else Sweep(
         job, backend, start=start, stride_end=stride_end, batch=batch,
         mesh_devices=mesh_devices)
     if sweep.dispatch is None:
         return _mine_on_host(sweep, ttl, progress)
-    issued_ahead = False
+    following: Optional[Sweep] = None
+    first_answer, periods = None, 0
 
     def fill() -> None:
-        nonlocal issued_ahead
-        sweep.issue()
-        if (next_job is not None and not issued_ahead
-                and sweep.cursor >= sweep.end and sweep.inflight):
+        nonlocal following
+        sweep.issue(_depth)
+        if sweep.cursor < sweep.end or next_job is None:
+            return
+        if following is None and sweep.inflight:
             following = next_job()
-            if following is not None:
-                issued_ahead = True
-                with telemetry.attached(following.root):
-                    following.issue()
+        if following is not None:
+            with telemetry.attached(following.root):
+                following.issue(_depth - len(sweep.inflight))
 
     fill()
     while sweep.inflight:
         hit = sweep.wait()
         if hit is not None:
             return sweep.result(hit)
-        elapsed = time.time() - sweep.t0
+        now = time.monotonic()
+        elapsed = now - sweep.t0
         cut = elapsed > ttl
         if not cut:
-            fill()   # before anything is said: the device is waiting
+            # before anything is said or counted: the device is waiting
+            out = [handle for handle, _count in sweep.inflight]
+            if following is not None:
+                out += [handle for handle, _count in following.inflight]
+            fill()
+            if first_answer is None:
+                first_answer = now
+            else:
+                periods += 1
+                _depth = depth_for((now - first_answer) / periods)
+            unfinished = _unfinished(out)
+            if not unfinished:
+                telemetry.inc(QUEUE_EMPTY)
+            if unfinished <= 1 < len(out):
+                telemetry.inc(QUEUE_LOW)
         if progress is not None:
             with telemetry.span("mine.progress", light=True):
                 progress(sweep.tried, elapsed)
@@ -381,13 +454,14 @@ def _mine_on_host(sweep: Sweep, ttl: float,
         if hit is not None:
             # device says hit; host double-checks before shipping (cheap)
             if job.check(hit):
-                return MineResult(hit, sweep.tried, time.time() - sweep.t0)
+                return MineResult(hit, sweep.tried,
+                                  time.monotonic() - sweep.t0)
             raise AssertionError(
                 f"backend {backend} returned nonce {hit} failing host check")
-        elapsed = time.time() - sweep.t0
+        elapsed = time.monotonic() - sweep.t0
         if progress is not None:
             progress(sweep.tried, elapsed)
         if elapsed > ttl:
             break
         sweep.cursor += count
-    return MineResult(None, sweep.tried, time.time() - sweep.t0)
+    return MineResult(None, sweep.tried, time.monotonic() - sweep.t0)

@@ -25,7 +25,8 @@ tpu`` on a mesh of one device, ``--device mesh`` over
   ``mine.mesh.job_layouts`` counts the placements, and
   ``stats()["jit_entries"]`` stays 1.  Two jobs stay laid
   (``JOBS_LAID``): the miner lays the next job and issues its first
-  rounds while the last rounds of the job in hand are in flight, so a
+  rounds while the last rounds of the job in hand are in flight (never
+  more than the round loop's one depth over both jobs), so a
   job's rounds (:meth:`dispatcher`) are bound to the arrays of their own
   job, whichever was set last.
 * **Disjoint shard ranges** — each round's [start, start+count) window
@@ -313,7 +314,8 @@ class MeshEngine:
         """Scan [start, start+count) of the job set last across the
         mesh; returns the async device handle (a
         ``sha256.SearchAnswer``: ``int()`` blocks and yields min hit or
-        SENTINEL).
+        SENTINEL; its words are copied to the host as soon as the round
+        has run, asked for here).
 
         ``count`` must fit one round (<= :attr:`capacity`); the caller's
         loop (engine.mine) sizes rounds accordingly."""
@@ -364,6 +366,11 @@ class MeshEngine:
                     mid, tail, ranges, target,
                     batch, nonce_spec, mesh, interpret),
                 kernel="sha256_search_mesh", source="mine").result()
+        # the answer's way to the host (0.45 ms on the pod) starts when
+        # the round ends, not when the loop asks for it: with it inside
+        # the loop's cycle the host is the pod's pace, and a deeper queue
+        # holds answers nobody has read (PERF.md section 6, PR 47)
+        words.copy_to_host_async()
         return sha_kernel.SearchAnswer(words, "mine_mesh")
 
     def dispatcher(self, job) -> Callable:

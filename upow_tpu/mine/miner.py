@@ -49,7 +49,10 @@ The rounds do not drain at a job's end (a *seam*) where the next
 template is there in time: once the job in hand has issued its last
 round, and while rounds are in flight, the loop builds the next job,
 prepares it (on the mesh: lays its arrays) and issues its first rounds
-behind them, and only then reads the last answers of the job in hand
+behind them, one for each answer of the job in hand it reads (the rounds
+in flight, whichever job's, stay at the engine's depth:
+``engine.rounds_in_flight``), so the last answers of the job in hand are read
+with the next job's rounds queued behind them
 (``mine.jobs_overlapped``).  The lines keep their order all the same: the
 next job's ``difficulty:`` and ``header:`` lines are said after the
 ``template expired`` line of the job in hand, though its timestamp was
@@ -79,8 +82,8 @@ from typing import NamedTuple, Optional
 from .. import telemetry
 from ..core.clock import timestamp
 from ..core.merkle import miner_merkle_root
-from .engine import (MAX_SEARCH_END, ROUNDS_IN_FLIGHT, MiningJob, Sweep,
-                     mine)
+from .engine import (MAX_SEARCH_END, QUEUE_COUNTERS, MiningJob, Sweep, mine,
+                     rounds_in_flight)
 
 GENESIS_PREV_HASH = (18_884_643).to_bytes(32, "little").hex()  # miner.py:37-40
 
@@ -568,7 +571,8 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
     if backend in ("pallas", "jnp", "mesh") and not once:
         _start_hang_watchdog(heartbeat, ttl + hang_grace)
     roll = HeaderRoll()
-    for name in ROLL_COUNTERS + FEED_COUNTERS + SEAM_COUNTERS:
+    for name in (ROLL_COUNTERS + FEED_COUNTERS + SEAM_COUNTERS
+                 + QUEUE_COUNTERS):
         telemetry.ensure_counter(name)   # at zero from scrape one
     total = min(hi, MAX_SEARCH_END) - lo
     feed = TemplateFeed(node)
@@ -587,15 +591,16 @@ def run(address: str, node: str, device: str, batch: int, ttl: float,
         _say(f"{tried / elapsed / 1e6:.2f} MH/s ({tried} hashes)")
         # the next template is asked for so that it is in hand when the
         # seam begins, at this job's last issue: the rounds then still in
-        # flight, one more because this look comes once a round, the
-        # fetch's seconds, and the last seam's build and prepare for
-        # room (a seam that finds no template looks again a round
-        # later); a sweep the ttl will cut ends there.  Once the seam has
-        # its template the next ask is the next job's
+        # flight (the engine's depth in force), one more because this
+        # look comes once a round, the fetch's seconds, and the last
+        # seam's build and prepare for room (a seam that finds no
+        # template looks again a round later); a sweep the ttl will cut
+        # ends there.  Once the seam has its template the next ask is the
+        # next job's
         round_s = elapsed * batch / tried
         left_s = min((total - tried) / batch * round_s, ttl - elapsed)
         if not once and ahead is None and left_s <= (
-                (ROUNDS_IN_FLIGHT + 1) * round_s + feed.took + seam_s):
+                (rounds_in_flight() + 1) * round_s + feed.took + seam_s):
             feed.ask()
 
     def open_root():
@@ -740,6 +745,7 @@ def _print_mesh_accounting(mesh_devices: int) -> None:
         "dispatches": stats["dispatches"],
         "job_layouts": stats["job_layouts"],
         "jit_entries": stats["jit_entries"],
+        "rounds_in_flight": rounds_in_flight(),
         "exact_steps": telemetry.counters().get(
             "kernel.mine_mesh.exact_steps", 0),
         "last_round_shards": last.get("shards", [])}), flush=True)
@@ -795,8 +801,9 @@ def _install_profile_hooks(profile) -> None:
 
 def _print_exit_lines() -> None:
     """What the searching process leaves on stdout under
-    ``ProfilingConfig.enabled``: the device's peak memory and the flat
-    span and counter aggregates.  An open capture is closed first."""
+    ``ProfilingConfig.enabled``: the device's peak memory, the flat
+    span and counter aggregates and the round loop's depth in force.  An
+    open capture is closed first."""
     from .. import profiling
 
     if profiling.status().get("active"):
@@ -806,8 +813,8 @@ def _print_exit_lines() -> None:
              if "peak_bytes_in_use" in mem]
     _say(f"memory: peak_bytes={max(peaks) if peaks else 'null'}")
     _say("telemetry: " + json.dumps(
-        {"spans": telemetry.stats(), "counters": telemetry.counters()},
-        sort_keys=True))
+        {"spans": telemetry.stats(), "counters": telemetry.counters(),
+         "rounds_in_flight": rounds_in_flight()}, sort_keys=True))
 
 
 def _reap(procs, timeout: float = 5.0) -> None:
