@@ -923,3 +923,64 @@ def test_pg_concurrent_churn(driver_kind, monkeypatch):
             import fake_asyncpg
 
             fake_asyncpg.reset()
+
+
+def test_pg_index_hears_a_transaction_once_after_its_commit(monkeypatch):
+    """The pg backend takes the sqlite backend's path to the resident
+    index (StateViews._index_batch): what a transaction adds and removes
+    is held back and applied after the COMMIT as one delta a table; a
+    rollback applies nothing and rebuilds nothing."""
+    from upow_tpu.state.device_index import DeviceUtxoIndex
+
+    deltas = []
+    real = DeviceUtxoIndex.apply_delta
+
+    def counted(self, created, spent, created_values=None):
+        deltas.append((len(created), len(spent)))
+        return real(self, created, spent, created_values)
+
+    monkeypatch.setattr(DeviceUtxoIndex, "apply_delta", counted)
+
+    async def scenario():
+        state = PgChainState(driver=MockPgDriver())
+        state.enable_device_index()
+        manager = BlockManager(state, sig_backend="host")
+        builder = WalletBuilder(state)
+        actors = make_actors()
+        d_g, a_g = actors["genesis"]
+        _, a_o = actors["outsider"]
+        for _ in range(3):
+            await mine_block(manager, state, a_g)
+        tx = await builder.create_transaction(d_g, a_o, "2")
+        await push(state, tx)
+        deltas.clear()
+        await mine_block(manager, state, a_g, include_pending=True)
+        accepted = list(deltas)
+        created, spent = (tx.hash(), 0), tx.inputs[0].outpoint
+
+        rebuilt = []
+
+        async def no_rebuild():
+            rebuilt.append(1)
+
+        monkeypatch.setattr(state, "_aindex_rebuild", no_rebuild)
+        deltas.clear()
+        try:
+            async with state.atomic():
+                await state.remove_outputs([tx])     # spends `spent` again
+                state._index_add("unspent_outputs", [("ab" * 32, 0)])
+                raise ValueError("a rule failed")
+        except ValueError:
+            pass
+        verdicts = await state.outpoints_exist(
+            [created, spent, ("ab" * 32, 0)])
+        stage = state._index_stage
+        state.close()
+        return accepted, list(deltas), rebuilt, verdicts, stage
+
+    clock.reset()
+    accepted, after_rollback, rebuilt, verdicts, stage = run(scenario())
+    # the block's spent input and its created outputs: ONE delta
+    assert len(accepted) == 1 and accepted[0][1] == 1 and accepted[0][0] >= 2
+    assert after_rollback == [] and rebuilt == [] and stage is None
+    assert verdicts == [True, False, False]

@@ -166,6 +166,28 @@ def test_utxo_probe_kernel_compiles(one_chip, no_compile_cache):
     assert compiled.memory_analysis() is not None
 
 
+def test_utxo_apply_kernel_compiles_at_a_deployments_size(
+        one_chip, no_compile_cache):
+    """One block's delta applied to the resident lanes (ISSUE 50), at
+    the size the cell ``utxo-at-scale`` runs: capacity 2^22 over 4 M
+    rows, a slab of 8,161 created rows and 8,160 spent (each padded to
+    8,192); the six lanes are donated, so the program needs one
+    more copy of them and not two."""
+    from upow_tpu.state import device_index as di
+
+    cap, new, gone = 1 << 22, di._pad_len(8161), di._pad_len(8160)
+    lane = _shape((cap,), jnp.int32, one_chip)
+    slab = _shape((new,), jnp.int32, one_chip)
+    spent = _shape((gone,), jnp.int32, one_chip)
+    count = _shape((), jnp.int32, one_chip)
+    compiled = di._apply_kernel.lower(
+        *([lane] * 6), count, *([slab] * 6), count, *([spent] * 5), count,
+        window=di.PROBE_WINDOW).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 6 * 4 * cap
+    assert memory.temp_size_in_bytes < 16 * 4 * cap
+
+
 # ------------------------------------------------------- mesh search ----
 
 @pytest.mark.parametrize("n", [4, 1])

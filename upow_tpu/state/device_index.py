@@ -1,10 +1,5 @@
-"""HBM-resident UTXO index: device membership + value store (ISSUE 11).
-
-Earlier rounds kept 64-bit XOR-fold fingerprints on device as a
-*prefilter* and resolved every hit through a host-side exact map — one
-Python dict walk per probed outpoint, which is exactly the per-tx host
-round-trip the accept path must shed to reach the PAPER.md target.
-This round promotes the structure to a true resident index:
+"""HBM-resident UTXO index: device membership + value store (ISSUE 11),
+kept resident at a deployment's size (ISSUE 50).
 
 * **128-bit effective fingerprints.**  The sorted key is the historical
   64-bit XOR-fold (``fingerprint_batch`` — bit-identical to previous
@@ -13,33 +8,41 @@ This round promotes the structure to a true resident index:
   txid lane).  A probe matches only when both agree, so a false
   "present" needs a 128-bit collision (~2^64 birthday work even for an
   adversary minting both outputs) — the device verdict is trusted
-  without consulting the host map.
-* **Packed value store.**  Aligned with the keys: amount (two int32
-  lanes), a 32-bit script hash (crc32 of the owning address), and the
-  creation height.  Probes gather the amount lanes in the same
-  dispatch, so the differential can cross-check resident amounts
-  against SQL without extra traffic.
-* **Windowed sorted probe.**  One ``searchsorted`` on the
-  order-preserving high key lane, then an 8-slot window scan over the
-  equal-run (key + check lanes compared elementwise).  int32 lanes
-  throughout: without jax_enable_x64 JAX silently downcasts 64-bit
-  arrays, which would truncate AFTER the host sort and hand
-  searchsorted an unsorted array.  Sign-flip (``x ^ 0x8000_0000``)
-  keeps uint32 order under int32 compare.
-* **Shadow map, demoted.**  The exact multiset map ``fp64 ->
-  [outpoints]`` is still maintained (it is the rollback/differential
-  oracle and the twin resolver) but it is consulted ONLY when the
-  device declares ambiguity: an equal-key run longer than the probe
-  window, or a hit on a fingerprint that has ever had 64-bit twins.
-  ``index.shadow_consults`` counts every consult; a collision-free
-  block keeps it at zero (acceptance criterion).
+  without consulting the host.
+* **Device layout.**  Six int32 lanes (key hi/lo, check a/b, amount
+  lo/hi) sorted by the 64-bit key, padded to a power-of-two capacity.
+  int32 lanes throughout: without jax_enable_x64 JAX silently downcasts
+  64-bit arrays.  Sign-flip (``x ^ 0x8000_0000``) keeps uint32 order
+  under int32 compare.  The capacity only grows, and only when a
+  block's rows no longer fit (a *re-layout*, ``index.relayouts``).
+* **Windowed sorted probe** (``upow.utxo_probe``).  A binary search on
+  the two key lanes, then an 8-slot window scan over the equal-key run
+  (key + check lanes compared elementwise); the amount lanes are
+  gathered in the same dispatch.
+* **A block's update is one program** (``upow.utxo_apply``).  The
+  resident lanes are donated; the operands are the block's delta: the
+  sorted slab of created rows and the spent rows' identities, padded to
+  powers of two.  The program finds the spent rows itself (the probe's
+  search), places the slab, and rewrites the lanes in one gather a
+  lane: host -> device bytes follow the delta, never the set.
+* **Host mirror, two levels.**  The 128-bit identities and the value
+  columns (amount, 32-bit script hash, creation height) of every live
+  row, as sorted numpy arrays: a *base* from the build with tombstones,
+  and a small sorted *delta* of the rows added since, folded into the
+  base when it outgrows a sixteenth of it.  A block costs
+  O(delta log N) there; no Python object a row exists anywhere.  It is
+  the rollback / differential oracle and the twin resolver: it is
+  consulted ONLY when the device declares ambiguity — a hit on a
+  fingerprint that has ever had 64-bit twins, or an equal-key run
+  longer than the probe window.  ``index.shadow_consults`` counts every
+  consult; a collision-free block keeps it at zero.  Only fingerprints
+  that have twins are remembered (``twin_fingerprints``): a
+  collision-free set remembers none.
 * **O(delta) reorg.**  ``apply_block`` appends an undo record
   (created, spent, spent values) to a bounded log; ``rollback_block``
-  replays the inverse as two sorted-slab splices — no full rebuild.
-  Storage backends mirror this with per-outpoint delta add/remove in
-  ``remove_blocks``.
+  replays the inverse as one more delta — no full rebuild.
 
-All device work — probes, batched apply, the fused accept-path
+All device work — probes, a block's apply, the fused accept-path
 dispatch (:func:`fused_probe`) — is issued through
 ``device/runtime.py``'s ``submit_call`` so the weighted fair scheduler
 and degrade choke point govern it like every other kernel.
@@ -48,6 +51,7 @@ and degrade choke point govern it like every other kernel.
 from __future__ import annotations
 
 import functools
+import logging
 import time
 import zlib
 from collections import deque
@@ -56,6 +60,12 @@ from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..telemetry import device as ktel
+from ..telemetry import metrics
+from ..telemetry.tracing import span
+
+log = logging.getLogger("upow_tpu.state")
 
 Outpoint = Tuple[str, int]
 
@@ -98,21 +108,30 @@ def fingerprint(outpoint: Outpoint) -> int:
     return (base ^ ((index + 1) * _MIX)) & _U64
 
 
-def fingerprint_batch(outpoints: Sequence[Outpoint]) -> np.ndarray:
-    """(N,) uint64 fingerprints in one ``np.frombuffer`` pass.
-
-    One joined-hex decode + one frombuffer + vectorized fold/mix — no
-    per-outpoint hashlib/int.from_bytes loop.
-    """
+def _txid_lanes(outpoints: Sequence[Outpoint]) -> Tuple[np.ndarray, np.ndarray]:
+    """((N, 4) little-endian u64 lanes of the txids, (N,) u64 output
+    indices): one joined-hex decode + one frombuffer, no per-outpoint
+    hashlib/int.from_bytes loop."""
     n = len(outpoints)
-    if not n:
-        return np.zeros(0, dtype=np.uint64)
     blob = bytes.fromhex("".join(o[0] for o in outpoints))
     lanes = np.frombuffer(blob, dtype="<u8").reshape(n, 4)
-    base = np.bitwise_xor.reduce(lanes, axis=1)
     idx = np.fromiter((o[1] for o in outpoints), dtype=np.uint64, count=n)
+    return lanes, idx
+
+
+def fingerprint_lanes(lanes: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """(N,) uint64 fingerprints from the txids' u64 lanes and the output
+    indices as columns (the build streams a table through this)."""
+    base = np.bitwise_xor.reduce(lanes, axis=1)
     with np.errstate(over="ignore"):
         return base ^ ((idx + np.uint64(1)) * np.uint64(_MIX))
+
+
+def fingerprint_batch(outpoints: Sequence[Outpoint]) -> np.ndarray:
+    """(N,) uint64 fingerprints of a list of outpoints."""
+    if not len(outpoints):
+        return np.zeros(0, dtype=np.uint64)
+    return fingerprint_lanes(*_txid_lanes(outpoints))
 
 
 def check_fp(outpoint: Outpoint) -> int:
@@ -126,21 +145,31 @@ def check_fp(outpoint: Outpoint) -> int:
     return (acc ^ (((index + 1) * _MIX2) & _U64)) & _U64
 
 
-def check_batch(outpoints: Sequence[Outpoint]) -> np.ndarray:
-    """(N,) uint64 *check* fingerprints — independent of
-    :func:`fingerprint_batch`; together they form the 128-bit effective
-    identity a resident probe trusts without host confirmation."""
-    n = len(outpoints)
-    if not n:
-        return np.zeros(0, dtype=np.uint64)
-    blob = bytes.fromhex("".join(o[0] for o in outpoints))
-    lanes = np.frombuffer(blob, dtype="<u8").reshape(n, 4)
-    idx = np.fromiter((o[1] for o in outpoints), dtype=np.uint64, count=n)
+def check_lanes(lanes: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """Columnar twin of :func:`check_batch`."""
     with np.errstate(over="ignore"):
         acc = lanes[:, 0] * np.uint64(_CHECK_MULTS[0])
         for k in range(1, 4):
             acc = acc ^ (lanes[:, k] * np.uint64(_CHECK_MULTS[k]))
         return acc ^ ((idx + np.uint64(1)) * np.uint64(_MIX2))
+
+
+def check_batch(outpoints: Sequence[Outpoint]) -> np.ndarray:
+    """(N,) uint64 *check* fingerprints — independent of
+    :func:`fingerprint_batch`; together they form the 128-bit effective
+    identity a resident probe trusts without host confirmation."""
+    if not len(outpoints):
+        return np.zeros(0, dtype=np.uint64)
+    return check_lanes(*_txid_lanes(outpoints))
+
+
+def script_hash_batch(addresses: Sequence) -> np.ndarray:
+    """(N,) uint32 crc32 of each address (``str`` or ``bytes``): one C
+    call a row through ``map``, no interpreter statement a row."""
+    n = len(addresses)
+    if n and isinstance(addresses[0], str):
+        addresses = map(str.encode, addresses)
+    return np.fromiter(map(zlib.crc32, addresses), dtype=np.uint32, count=n)
 
 
 def _lane_hi(fps: np.ndarray) -> np.ndarray:
@@ -163,39 +192,364 @@ def _eq_lanes(fps: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
             u32[:, 1].view(np.int32).copy())
 
 
+
+
 def _pow2(n: int) -> int:
     return 1 << max(0, (n - 1).bit_length()) if n else 1
+
+
+def _pad_len(n: int) -> int:
+    """Padded length of a block's operand: a power of two, at least the
+    probe window, so a handful of small deltas share one program."""
+    return max(PROBE_WINDOW, _pow2(n))
+
+
+def _key_search(keys_hi, keys_lo, n_live, q_hi, q_lo):
+    """First row in [0, n_live) whose 64-bit key (hi, lo) is not below
+    the query's: a binary search over both lanes, one gather a lane a
+    step.  (``searchsorted`` on the high lane alone leaves a run of
+    equal high words to the window, and 4 M keys share high words by
+    the thousand.)"""
+    cap = keys_hi.shape[0]
+    lo = jnp.zeros(q_hi.shape, jnp.int32)
+    hi = jnp.zeros(q_hi.shape, jnp.int32) + n_live
+
+    def step(_, bounds):
+        lo, hi = bounds
+        mid = jnp.minimum((lo + hi) >> 1, cap - 1)
+        k_hi, k_lo = keys_hi[mid], keys_lo[mid]
+        below = (k_hi < q_hi) | ((k_hi == q_hi) & (k_lo < q_lo))
+        open_ = lo < hi
+        return (jnp.where(open_ & below, mid + 1, lo),
+                jnp.where(open_ & ~below, mid, hi))
+
+    lo, _hi = jax.lax.fori_loop(0, cap.bit_length() + 1, step, (lo, hi))
+    return lo
+
+
+def _window_match(lanes, n_live, pos, q_hi, q_lo, q_ca, q_cb, window):
+    """(rows (Q, W), key_eq, full_eq) of the ``window`` slots from
+    ``pos``: which hold the query's 64-bit key, which its whole 128-bit
+    identity."""
+    keys_hi, keys_lo, chk_a, chk_b = lanes[:4]
+    cap = keys_hi.shape[0]
+    idx = pos[:, None] + jnp.arange(window, dtype=jnp.int32)[None, :]
+    valid = idx < n_live
+    idx_c = jnp.clip(idx, 0, cap - 1)
+    key_eq = (keys_hi[idx_c] == q_hi[:, None]) \
+        & (keys_lo[idx_c] == q_lo[:, None]) & valid
+    full_eq = key_eq & (chk_a[idx_c] == q_ca[:, None]) \
+        & (chk_b[idx_c] == q_cb[:, None])
+    return idx_c, key_eq, full_eq
 
 
 @functools.partial(jax.jit, static_argnames=("window",))
 def _probe_kernel(keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi,
                   n_live, q_hi, q_lo, q_ca, q_cb, window):
-    """Windowed sorted probe: searchsorted on the high key lane, then a
-    ``window``-slot scan of the equal run comparing all four identity
-    lanes.  Returns per query: full 128-bit hit, 64-bit key hit (the
-    prefilter contract), run overflow (ambiguity), and the amount lanes
-    gathered at the matched row."""
+    """Windowed sorted probe: a search on the key lanes, then a
+    ``window``-slot scan of the equal-key run comparing all four
+    identity lanes.  Returns per query: full 128-bit hit, 64-bit key hit
+    (the prefilter contract), run overflow (ambiguity), and the amount
+    lanes gathered at the matched row."""
     with jax.named_scope("upow.utxo_probe"):
         cap = keys_hi.shape[0]
-        pos = jnp.searchsorted(keys_hi, q_hi, side="left")
-        idx = pos[:, None] + jnp.arange(window)[None, :]
-        valid = idx < n_live
-        idx_c = jnp.clip(idx, 0, cap - 1)
-        hi_eq = (keys_hi[idx_c] == q_hi[:, None]) & valid
-        key_eq = hi_eq & (keys_lo[idx_c] == q_lo[:, None])
-        full_eq = key_eq & (chk_a[idx_c] == q_ca[:, None]) \
-            & (chk_b[idx_c] == q_cb[:, None])
+        pos = _key_search(keys_hi, keys_lo, n_live, q_hi, q_lo)
+        _rows, key_eq, full_eq = _window_match(
+            (keys_hi, keys_lo, chk_a, chk_b), n_live, pos,
+            q_hi, q_lo, q_ca, q_cb, window)
         hit = full_eq.any(axis=1)
         key_hit = key_eq.any(axis=1)
-        overflow = hi_eq[:, window - 1]
+        overflow = key_eq[:, window - 1]
         row = jnp.clip(pos + jnp.argmax(full_eq, axis=1), 0, cap - 1)
         return hit, key_hit, overflow, amt_lo[row], amt_hi[row]
+
+
+@functools.partial(jax.jit, static_argnames=("window",),
+                   donate_argnums=(0, 1, 2, 3, 4, 5))
+def _apply_kernel(keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi, n_live,
+                  s_hi, s_lo, s_ca, s_cb, s_al, s_ah, n_new,
+                  d_hi, d_lo, d_ca, d_cb, d_occ, n_spent, window):
+    """One block's delta applied to the resident lanes (donated).
+
+    ``d_*`` are the spent rows' identities (``d_occ``: which of several
+    rows of one identity, 0 but for duplicates), ``s_*`` the created
+    rows sorted by key; both padded, ``n_spent`` / ``n_new`` real.  The
+    spent rows are found as a probe finds them; every surviving row and
+    every slab row is given its place in the new order by three
+    prefix sums, and a new lane is one gather from the old lane with the
+    slab scattered into the places kept free for it.  Returns the six
+    lanes and the new live count.
+    """
+    with jax.named_scope("upow.utxo_apply"):
+        old = (keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi)
+        slab = (s_hi, s_lo, s_ca, s_cb, s_al, s_ah)
+        cap = keys_hi.shape[0]
+        i32 = jnp.int32
+        # the spent rows: where each lies, and whether it is there
+        pos = _key_search(keys_hi, keys_lo, n_live, d_hi, d_lo)
+        rows, _key_eq, full_eq = _window_match(
+            old, n_live, pos, d_hi, d_lo, d_ca, d_cb, window)
+        nth = jnp.cumsum(full_eq.astype(i32), axis=1)
+        pick = full_eq & (nth == d_occ[:, None] + 1)
+        found = pick.any(axis=1) \
+            & (jnp.arange(d_hi.shape[0], dtype=i32) < n_spent)
+        gone = jnp.where(found, jnp.take_along_axis(
+            rows, jnp.argmax(pick, axis=1)[:, None], axis=1)[:, 0], cap)
+        dead = jnp.zeros(cap, i32).at[gone].set(1, mode="drop")
+        dead_upto = jnp.cumsum(dead)              # inclusive
+        n_dead = dead_upto[cap - 1]
+        # rank among the survivors before which each slab row goes, and
+        # from it the slab row's place in the new order
+        at = _key_search(keys_hi, keys_lo, n_live, s_hi, s_lo)
+        dead_before = jnp.where(
+            at > 0, dead_upto[jnp.clip(at - 1, 0, cap - 1)], 0)
+        rank = at - dead_before
+        j = jnp.arange(s_hi.shape[0], dtype=i32)
+        is_new = j < n_new
+        place = jnp.where(is_new, rank + j, cap)
+        fresh = jnp.zeros(cap, i32).at[place].set(1, mode="drop")
+        fresh_before = jnp.cumsum(fresh) - fresh  # exclusive
+        # a surviving row of rank r comes from old row r + (spent rows
+        # before it).  In the new order that row stands at r + (slab
+        # rows before it), so each spent row opens its gap there.
+        r_gone = gone - (dead_upto[jnp.clip(gone, 0, cap - 1)] - 1)
+        rank_new = jnp.where(is_new, rank, jnp.iinfo(i32).max)
+        gap_at = jnp.where(
+            found, r_gone + jnp.searchsorted(
+                rank_new, r_gone, side="right").astype(i32), cap)
+        gaps = jnp.cumsum(
+            jnp.zeros(cap, i32).at[gap_at].add(1, mode="drop"))
+        p = jnp.arange(cap, dtype=i32)
+        src = jnp.clip(p - fresh_before + gaps, 0, cap - 1)
+        n_after = n_live - n_dead + n_new
+        live = p < n_after
+
+        def rewritten(lane, s_lane, fill):
+            kept = lane.at[src].get(mode="clip", indices_are_sorted=True)
+            return jnp.where(live, kept, i32(fill)).at[place].set(
+                s_lane, mode="drop", indices_are_sorted=True)
+
+        return tuple(map(rewritten, old, slab,
+                         (_I32_MAX, _I32_MAX, 0, 0, 0, 0))) + (n_after,)
+
+
+_COLUMNS = (("keys", np.uint64), ("chk", np.uint64), ("amount", np.int64),
+            ("script", np.uint32), ("height", np.uint32))
+
+#: the mirror's delta level is folded into the base when it holds more
+#: rows than this and than a sixteenth of the base
+_DELTA_MIN = 4096
+
+
+class _Level:
+    """Rows of one level of the host mirror, sorted by key; ``dead``
+    marks the rows spent since the level was written."""
+
+    __slots__ = ("keys", "chk", "amount", "script", "height", "dead",
+                 "n_dead")
+
+    def __init__(self, keys, chk, amount, script, height):
+        self.keys, self.chk, self.amount = keys, chk, amount
+        self.script, self.height = script, height
+        self.dead = np.zeros(len(keys), dtype=bool)
+        self.n_dead = 0
+
+    @classmethod
+    def empty(cls) -> "_Level":
+        return cls(*(np.zeros(0, dtype=t) for _n, t in _COLUMNS))
+
+    def live(self) -> int:
+        return len(self.keys) - self.n_dead
+
+    def columns(self) -> tuple:
+        """The live rows' five columns, in order."""
+        cols = tuple(getattr(self, name) for name, _t in _COLUMNS)
+        if not self.n_dead:
+            return cols
+        keep = ~self.dead
+        return tuple(c[keep] for c in cols)
+
+    def find(self, fps: np.ndarray, chks: np.ndarray,
+             claim: bool = False) -> np.ndarray:
+        """(N,) row of the first live row with each 128-bit identity, -1
+        where there is none.  With ``claim`` no row answers twice: the
+        same identity asked k times takes k rows, as a spend does."""
+        row = np.full(len(fps), -1, dtype=np.int64)
+        if not len(self.keys) or not len(fps):
+            return row
+        lo = np.searchsorted(self.keys, fps, side="left")
+        run = np.searchsorted(self.keys, fps, side="right") - lo
+        one = np.nonzero(run == 1)[0]
+        cand = lo[one]
+        ok = (self.chk[cand] == chks[one]) & ~self.dead[cand]
+        row[one[ok]] = cand[ok]
+        taken: set = set()
+        if claim and len(one):
+            # two asks of one single row: the first takes it
+            _rows, first = np.unique(row[one], return_index=True)
+            again = np.ones(len(one), dtype=bool)
+            again[first] = False
+            row[one[again]] = -1
+        for i in np.nonzero(run > 1)[0]:     # twins: a handful, or tests
+            for r in range(int(lo[i]), int(lo[i] + run[i])):
+                if self.chk[r] == chks[i] and not self.dead[r] \
+                        and r not in taken:
+                    row[i] = r
+                    if claim:
+                        taken.add(r)
+                    break
+        return row
+
+    def has_key(self, fps: np.ndarray) -> np.ndarray:
+        """(N,) bool: some live row carries this 64-bit key."""
+        out = np.zeros(len(fps), dtype=bool)
+        if not len(self.keys) or not len(fps):
+            return out
+        lo = np.searchsorted(self.keys, fps, side="left")
+        hi = np.searchsorted(self.keys, fps, side="right")
+        if not self.n_dead:
+            return hi > lo
+        one = (hi - lo) == 1
+        out[one] = ~self.dead[lo[one]]
+        for i in np.nonzero((hi - lo) > 1)[0]:
+            out[i] = not self.dead[lo[i]:hi[i]].all()
+        return out
+
+    def kill(self, rows: np.ndarray) -> None:
+        self.dead[rows] = True
+        self.n_dead += len(rows)
+
+    def merged(self, other_columns: tuple) -> "_Level":
+        """This level's live rows with ``other_columns`` (sorted by key)
+        spliced in: one ``np.insert`` a column."""
+        mine = self.columns()
+        at = np.searchsorted(mine[0], other_columns[0], side="left")
+        return _Level(*(np.insert(col, at, new)
+                        for col, new in zip(mine, other_columns)))
+
+
+class _Mirror:
+    """The host's copy of every live row's identity and values, in two
+    sorted levels: what a block adds goes into the small ``delta``, what
+    it spends is marked dead where it lies, and the delta is folded into
+    the ``base`` only when it has outgrown a sixteenth of it, so a block
+    costs O(delta log N) and the fold, O(N), comes once in N / 16 rows.
+    """
+
+    def __init__(self, columns: tuple):
+        self.base = _Level(*columns)
+        self.delta = _Level.empty()
+
+    def __len__(self) -> int:
+        return self.base.live() + self.delta.live()
+
+    def find(self, fps, chks, claim=False) -> Tuple[np.ndarray, np.ndarray]:
+        """(rows in the base, rows in the delta), -1 where absent; a
+        row found in the base is not looked for in the delta."""
+        in_base = self.base.find(fps, chks, claim)
+        in_delta = np.full(len(fps), -1, dtype=np.int64)
+        rest = np.nonzero(in_base < 0)[0]
+        if len(rest) and len(self.delta.keys):
+            in_delta[rest] = self.delta.find(fps[rest], chks[rest], claim)
+        return in_base, in_delta
+
+    def contains(self, fps, chks) -> np.ndarray:
+        in_base, in_delta = self.find(fps, chks)
+        return (in_base >= 0) | (in_delta >= 0)
+
+    def has_key(self, fps) -> np.ndarray:
+        return self.base.has_key(fps) | self.delta.has_key(fps)
+
+    def _values_at(self, in_base, in_delta) -> tuple:
+        out = []
+        for name, dtype in _COLUMNS[2:]:
+            col = np.zeros(len(in_base), dtype=dtype)
+            for level, rows in ((self.base, in_base), (self.delta, in_delta)):
+                got = rows >= 0
+                col[got] = getattr(level, name)[rows[got]]
+            out.append(col)
+        return tuple(out)
+
+    def values(self, fps, chks) -> tuple:
+        """(amount, script, height) of each identity's row; zeros where
+        it is absent."""
+        return self._values_at(*self.find(fps, chks))
+
+    def take(self, fps, chks) -> Tuple[np.ndarray, tuple]:
+        """Spend each identity's row; (found, (amount, script, height) of
+        the rows found).  An absent identity is a no-op, as the SQL
+        DELETE is; one asked k times takes k rows."""
+        in_base, in_delta = self.find(fps, chks, claim=True)
+        found = (in_base >= 0) | (in_delta >= 0)
+        values = self._values_at(in_base, in_delta)
+        self.base.kill(in_base[in_base >= 0])
+        self.delta.kill(in_delta[in_delta >= 0])
+        return found, tuple(col[found] for col in values)
+
+    def insert(self, columns: tuple) -> None:
+        """``columns`` sorted by key into the delta; fold it when due."""
+        self.delta = self.delta.merged(columns)
+        if len(self.delta.keys) > max(_DELTA_MIN, len(self.base.keys) // 16):
+            self.fold()
+
+    def fold(self) -> None:
+        """The mirror's one O(N) step: five ``np.insert`` over the
+        base's columns (and its dead rows dropped)."""
+        if len(self.delta.keys) or self.base.n_dead:
+            with span("index.fold", base=len(self.base.keys),
+                      dead=self.base.n_dead, delta=len(self.delta.keys)):
+                self.base = self.base.merged(self.delta.columns())
+                self.delta = _Level.empty()
+            metrics.inc("index.folds")
+
+    def flat(self) -> tuple:
+        """Every live row's five columns in key order (folds first)."""
+        self.fold()
+        return self.base.columns()
+
+
+def _occurrence(fps: np.ndarray, chks: np.ndarray) -> np.ndarray:
+    """(N,) how many earlier entries carry the same 128-bit identity."""
+    n = len(fps)
+    order = np.lexsort((chks, fps))
+    f, c = fps[order], chks[order]
+    start = np.ones(n, dtype=bool)
+    start[1:] = (f[1:] != f[:-1]) | (c[1:] != c[:-1])
+    first = np.maximum.accumulate(np.where(start, np.arange(n), 0))
+    occ = np.empty(n, dtype=np.int32)
+    occ[order] = np.arange(n) - first
+    return occ
+
+
+def _padded(lane: np.ndarray, size: int, fill) -> np.ndarray:
+    out = np.full(size, fill, dtype=np.int32)
+    out[:len(lane)] = lane
+    return out
+
+
+def _amount_lanes(amount: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    u32 = np.ascontiguousarray(amount).view(np.uint32).reshape(-1, 2)
+    return (u32[:, 0].view(np.int32).copy(), u32[:, 1].view(np.int32).copy())
+
+
+def _row_lanes(keys, chk, amount, size: int) -> tuple:
+    """The six int32 device lanes of rows (sorted by key), padded to
+    ``size``: the key lanes with the largest key, the rest with 0."""
+    chk_a, chk_b = _eq_lanes(chk)
+    amt_lo, amt_hi = _amount_lanes(amount)
+    return tuple(_padded(lane, size, fill) for lane, fill in (
+        (_lane_hi(keys), _I32_MAX), (_lane_lo(keys), _I32_MAX),
+        (chk_a, 0), (chk_b, 0), (amt_lo, 0), (amt_hi, 0)))
+
+
+def _bytes_of(arrays) -> int:
+    return int(sum(a.nbytes for a in arrays))
 
 
 class DeviceUtxoIndex:
     """HBM-resident sorted-fingerprint UTXO index, one per UTXO-class
     table: 128-bit effective identity, packed value store, bounded undo
-    log, shadow map consulted only on declared ambiguity."""
+    log, host mirror consulted only on declared ambiguity."""
 
     #: undo records retained for O(delta) reorg rollback; a reorg deeper
     #: than this falls back to the storage layer's rebuild
@@ -203,43 +557,63 @@ class DeviceUtxoIndex:
 
     def __init__(self, outpoints: Iterable[Outpoint] = (),
                  values: Optional[Sequence[tuple]] = None):
-        # shadow map: fp64 -> live outpoints with that fingerprint.  A
-        # list, not a set: duplicates mirror the old multiset semantics
-        # (add twice -> remove twice), and twins (distinct outpoints,
-        # one fp64) stay individually tracked so spending one never
-        # makes the survivor report absent — the one error class the
-        # index must never produce.
-        self._shadow: Dict[int, List[Outpoint]] = {}
+        ops = [tuple(o) for o in outpoints]
+        self._init_columns(fingerprint_batch(ops), check_batch(ops),
+                           *self._norm_values(len(ops), values))
+
+    @classmethod
+    def from_columns(cls, fps: np.ndarray, chks: np.ndarray,
+                     amount: np.ndarray, script: np.ndarray,
+                     height: Optional[np.ndarray] = None
+                     ) -> "DeviceUtxoIndex":
+        """An index over rows given as columns, in any order: the bulk
+        build (one sort; no Python object a row)."""
+        self = cls.__new__(cls)
+        if height is None:
+            height = np.zeros(len(fps), dtype=np.uint32)
+        self._init_columns(fps, chks, amount, script, height)
+        return self
+
+    def _init_columns(self, fps, chks, amount, script, height) -> None:
+        order = np.argsort(fps, kind="stable")
+        keys = fps[order]
+        self._mirror = _Mirror((keys, chks[order], amount[order],
+                                script[order], height[order]))
         # fingerprints that EVER held >=2 live outpoints: any hit on one
-        # routes to the shadow map (sticky — a surviving twin's row may
-        # carry its spent sibling's check lanes after a k-th-duplicate
-        # removal, so the ambiguity outlives the second entry)
-        self._twin_fps: set = set()
+        # routes to the host mirror (sticky).  Found on the sorted keys;
+        # a collision-free set has none.
+        self._twin_fps: set = set(
+            np.unique(keys[1:][keys[1:] == keys[:-1]]).tolist())
         self._twins_arr: Optional[np.ndarray] = None
-        self._host_keys = np.zeros(0, dtype=np.uint64)   # sorted fp64
-        self._host_chk = np.zeros(0, dtype=np.uint64)    # aligned check
-        self._host_amount = np.zeros(0, dtype=np.int64)  # aligned values
-        self._host_script = np.zeros(0, dtype=np.uint32)
-        self._host_height = np.zeros(0, dtype=np.uint32)
-        self._dirty = True
-        self._dev: Optional[tuple] = None                # device arrays
+        self._lanes: Optional[tuple] = None      # device arrays
+        self._n_live = np.int32(0)               # rows live in them
+        self._capacity = _pow2(len(keys))
         self._undo: deque = deque(maxlen=self.UNDO_DEPTH)
         self._probes = 0
         self._shadow_consults = 0
-        ops = [tuple(o) for o in outpoints]
-        if ops:
-            self.add(ops, values)
+        self._relayouts = 0
 
     def __len__(self):
-        return int(self._host_keys.shape[0])
+        return len(self._mirror)
+
+    @property
+    def _host_keys(self) -> np.ndarray:
+        """Every live key, sorted (tests; folds the mirror)."""
+        return self._mirror.flat()[0]
 
     # ------------------------------------------------------------ values --
 
     @staticmethod
-    def _norm_values(n: int, values: Optional[Sequence[tuple]]):
+    def _norm_values(n: int, values) -> tuple:
         """(amount int64, script uint32, height uint32) arrays from the
         optional per-outpoint (amount, address|script_hash, height)
-        tuples; zeros where unknown (membership never depends on them)."""
+        tuples, or from three columns given as a tuple of arrays; zeros
+        where unknown (membership never depends on them)."""
+        if isinstance(values, tuple) and len(values) == 3 \
+                and all(isinstance(v, np.ndarray) for v in values):
+            return (values[0].astype(np.int64, copy=False),
+                    values[1].astype(np.uint32, copy=False),
+                    values[2].astype(np.uint32, copy=False))
         amt = np.zeros(n, dtype=np.int64)
         script = np.zeros(n, dtype=np.uint32)
         height = np.zeros(n, dtype=np.uint32)
@@ -257,139 +631,167 @@ class DeviceUtxoIndex:
         return amt, script, height
 
     def _capture_values(self, outpoints: Sequence[Outpoint]) -> List[tuple]:
-        """Value rows for live outpoints (zeros when absent) — snapshot
-        taken before a spend so the undo log can restore them."""
-        out: List[tuple] = []
+        """Value rows for live outpoints (zeros when absent), from the
+        mirror."""
         if not outpoints:
-            return out
-        fps = fingerprint_batch(outpoints)
-        chks = check_batch(outpoints)
-        lo = np.searchsorted(self._host_keys, fps, side="left")
-        hi = np.searchsorted(self._host_keys, fps, side="right")
-        for i in range(len(outpoints)):
-            row = None
-            for j in range(int(lo[i]), int(hi[i])):
-                if self._host_chk[j] == chks[i]:
-                    row = j
-                    break
-            if row is None:
-                out.append((0, 0, 0))
-            else:
-                out.append((int(self._host_amount[row]),
-                            int(self._host_script[row]),
-                            int(self._host_height[row])))
-        return out
+            return []
+        amt, script, height = self._mirror.values(
+            fingerprint_batch(outpoints), check_batch(outpoints))
+        return list(zip(amt.tolist(), script.tolist(), height.tolist()))
 
     # ------------------------------------------------------------ updates --
 
     def add(self, outpoints: Iterable[Outpoint],
             values: Optional[Sequence[tuple]] = None) -> None:
-        ops = [tuple(o) for o in outpoints]
-        if not ops:
-            return
-        fps = fingerprint_batch(ops)
-        chks = check_batch(ops)
-        for o, fp in zip(ops, fps.tolist()):
-            bucket = self._shadow.setdefault(fp, [])
-            bucket.append(o)
-            if len(bucket) >= 2 and fp not in self._twin_fps:
-                self._twin_fps.add(fp)
-                self._twins_arr = None
-        amt, script, height = self._norm_values(len(ops), values)
-        # incremental sorted insert: sort only the (small) slab, then
-        # splice it into place — no full re-sort of the whole key set
-        order = np.argsort(fps, kind="stable")
-        slab = fps[order]
-        pos = np.searchsorted(self._host_keys, slab)
-        self._host_keys = np.insert(self._host_keys, pos, slab)
-        self._host_chk = np.insert(self._host_chk, pos, chks[order])
-        self._host_amount = np.insert(self._host_amount, pos, amt[order])
-        self._host_script = np.insert(self._host_script, pos, script[order])
-        self._host_height = np.insert(self._host_height, pos, height[order])
-        self._dirty = True
+        self.apply_delta([tuple(o) for o in outpoints], [], values)
 
     def remove(self, outpoints: Iterable[Outpoint]) -> None:
-        ops = [tuple(o) for o in outpoints]
-        if not ops:
+        self.apply_delta([], [tuple(o) for o in outpoints])
+
+    def apply_delta(self, created: List[Outpoint], spent: List[Outpoint],
+                    created_values=None) -> tuple:
+        """Spend ``spent``, then create ``created``: the mirror first,
+        then ONE ``upow.utxo_apply`` program over the resident lanes
+        (when there are any: an index never probed has nothing on the
+        device yet).  Returns what an undo needs: the spent outpoints
+        that were there, and their values."""
+        if not created and not spent:
+            return [], self._norm_values(0, None)
+        with span("index.apply", created=len(created), spent=len(spent)):
+            mirror = self._mirror
+            d_fps, d_chks = fingerprint_batch(spent), check_batch(spent)
+            found, gone_values = mirror.take(d_fps, d_chks)
+            d_fps, d_chks = d_fps[found], d_chks[found]
+            s_fps, s_chks = fingerprint_batch(created), check_batch(created)
+            order = np.argsort(s_fps, kind="stable")
+            s_fps, s_chks = s_fps[order], s_chks[order]
+            amt, script, height = (
+                col[order] for col in self._norm_values(
+                    len(created), created_values))
+            # a key that is live already, or twice in this slab: twins
+            twins = mirror.has_key(s_fps)
+            twins[1:] |= s_fps[1:] == s_fps[:-1]
+            fresh = set(s_fps[twins].tolist()) - self._twin_fps
+            if fresh:
+                self._twin_fps |= fresh
+                self._twins_arr = None
+            if len(s_fps):
+                mirror.insert((s_fps, s_chks, amt, script, height))
+            metrics.inc("index.apply_rows", len(s_fps) + len(d_fps))
+            if self._lanes is not None:
+                try:
+                    self._apply_on_device(
+                        (s_fps, s_chks, amt), (d_fps, d_chks))
+                # the mirror holds the block, so the delta is not lost:
+                # the lanes go, and the next probe lays them out from it
+                except Exception:  # upowlint: disable=BE001
+                    self._lanes = None
+                    log.exception("utxo_apply failed on the device; the"
+                                  " lanes are laid out again at the next"
+                                  " probe")
+        return [o for o, f in zip(spent, found.tolist()) if f], gone_values
+
+    def _apply_on_device(self, slab: tuple, gone: tuple) -> None:
+        """The block's delta to the device, through the runtime."""
+        from ..device.runtime import get_runtime
+        s_fps, s_chks, s_amt = slab
+        d_fps, d_chks = gone
+        n_new, n_gone = len(s_fps), len(d_fps)
+        if len(self) > self._capacity:
+            # the block's rows no longer fit: the next power of two
+            self._relayout()
             return
-        doomed: List[Tuple[int, int]] = []  # (fp, chk) of live removals
-        fps = fingerprint_batch(ops)
-        chks = check_batch(ops)
-        for o, fp, chk in zip(ops, fps.tolist(), chks.tolist()):
-            bucket = self._shadow.get(fp)
-            if bucket is None or o not in bucket:
-                # absent entries are a no-op, matching the SQL DELETE
-                # (e.g. replaying a log whose spend references a
-                # never-created output must report a MISMATCH, not crash)
-                continue
-            bucket.remove(o)
-            if not bucket:
-                del self._shadow[fp]
-            doomed.append((fp, chk))
-        if not doomed:
-            return
-        rem_fps = np.array([d[0] for d in doomed], dtype=np.uint64)
-        lo = np.searchsorted(self._host_keys, rem_fps, side="left")
-        hi = np.searchsorted(self._host_keys, rem_fps, side="right")
-        marked: set = set()
-        for (fp, chk), l, h in zip(doomed, lo.tolist(), hi.tolist()):
-            # within the equal-fp run, delete the row whose check lanes
-            # match (keeps twins' value rows individually correct); the
-            # k-th-duplicate fallback covers true 128-bit twins, whose
-            # rows are indistinguishable anyway
-            pick = None
-            for j in range(l, h):
-                if j not in marked and self._host_chk[j] == chk:
-                    pick = j
-                    break
-            if pick is None:
-                for j in range(l, h):
-                    if j not in marked:
-                        pick = j
-                        break
-            if pick is not None:
-                marked.add(pick)
-        if not marked:
-            return
-        gone = np.fromiter(marked, dtype=np.int64, count=len(marked))
-        self._host_keys = np.delete(self._host_keys, gone)
-        self._host_chk = np.delete(self._host_chk, gone)
-        self._host_amount = np.delete(self._host_amount, gone)
-        self._host_script = np.delete(self._host_script, gone)
-        self._host_height = np.delete(self._host_height, gone)
-        self._dirty = True
+        new_pad, gone_pad = _pad_len(n_new), _pad_len(n_gone)
+        operands = _row_lanes(s_fps, s_chks, s_amt, new_pad) \
+            + (np.int32(n_new),) \
+            + tuple(_padded(lane, gone_pad, fill) for lane, fill in (
+                (_lane_hi(d_fps), _I32_MIN), (_lane_lo(d_fps), _I32_MIN),
+                *((lane, 0) for lane in _eq_lanes(d_chks)),
+                (_occurrence(d_fps, d_chks), 0))) \
+            + (np.int32(n_gone),)
+        want = len(self)
+
+        def _run():
+            t0 = time.perf_counter()
+            lanes, n_live = self._lanes, self._n_live
+            self._lanes = None           # donated: gone whatever happens
+            out = _apply_kernel(*lanes, n_live,
+                                *(jnp.asarray(a) for a in operands),
+                                window=PROBE_WINDOW)
+            n_after = int(out[6])
+            ktel.record_batch("utxo_apply", real=n_new + n_gone,
+                              padded=new_pad + gone_pad,
+                              seconds=time.perf_counter() - t0,
+                              compile_key=(self._capacity, new_pad,
+                                           gone_pad))
+            if n_after != want:
+                # a spent row the window did not reach (nine rows of one
+                # key): the mirror is right, lay the lanes out from it
+                return False
+            self._lanes, self._n_live = out[:6], np.int32(n_after)
+            return True
+
+        metrics.inc("index.upload_bytes", _bytes_of(operands))
+        if not get_runtime().submit_call(_run, kernel="utxo_apply",
+                                         source="index").result():
+            self._relayout()
+
+    def _relayout(self) -> None:
+        """The lanes laid out anew from the mirror, at the capacity its
+        rows need."""
+        self._relayouts += 1
+        metrics.inc("index.relayouts")
+        self._lanes = None
+        self._capacity = self.capacity()
+        self.materialize()
+
+    def apply_steps(self, steps: Sequence[tuple]) -> None:
+        """Held-back ``("add", outpoints, values)`` and ``("remove",
+        outpoints, None)`` steps, in order, as the fewest deltas.  A
+        delta spends before it creates; so a remove that follows the add
+        of the same outpoint closes the delta in hand first."""
+        created: List[Outpoint] = []
+        columns: List[tuple] = []
+        spent: List[Outpoint] = []
+
+        def close():
+            if created or spent:
+                values = tuple(np.concatenate(c) for c in zip(*columns)) \
+                    if columns else None
+                self.apply_delta(list(created), list(spent), values)
+            created.clear(), columns.clear(), spent.clear()
+
+        for kind, outpoints, values in steps:
+            outpoints = [tuple(o) for o in outpoints]
+            if kind == "add":
+                created.extend(outpoints)
+                columns.append(self._norm_values(len(outpoints), values))
+            else:
+                if created and not set(created).isdisjoint(outpoints):
+                    close()
+                spent.extend(outpoints)
+        close()
 
     def apply_block(self, created: Sequence[Outpoint],
                     spent: Sequence[Outpoint],
-                    created_values: Optional[Sequence[tuple]] = None,
-                    materialize: bool = False) -> None:
-        """Batched spend/create application for one accepted block,
-        recorded in the undo log for :meth:`rollback_block`.
-        ``materialize=True`` re-uploads the device arrays through the
-        runtime now (one ``utxo_apply`` dispatch) instead of lazily on
-        the next probe."""
-        spent = [tuple(o) for o in spent]
+                    created_values: Optional[Sequence[tuple]] = None
+                    ) -> None:
+        """Batched spend/create application for one accepted block (one
+        device program), recorded in the undo log for
+        :meth:`rollback_block`."""
         created = [tuple(o) for o in created]
-        spent_values = self._capture_values(spent) if spent else []
-        if spent:
-            self.remove(spent)
-        if created:
-            self.add(created, created_values)
-        self._undo.append((created, spent, spent_values))
-        if materialize and (created or spent):
-            self.materialize()
+        gone, gone_values = self.apply_delta(
+            created, [tuple(o) for o in spent], created_values)
+        self._undo.append((created, gone, gone_values))
 
     def rollback_block(self) -> bool:
-        """O(delta) inverse of the most recent :meth:`apply_block`:
-        two sorted-slab splices, no rebuild.  False when the undo log
-        is exhausted (caller falls back to a rebuild)."""
+        """O(delta) inverse of the most recent :meth:`apply_block`: one
+        more delta, no rebuild.  False when the undo log is exhausted
+        (caller falls back to a rebuild)."""
         if not self._undo:
             return False
-        created, spent, spent_values = self._undo.pop()
-        if created:
-            self.remove(created)
-        if spent:
-            self.add(spent, spent_values)
+        created, gone, gone_values = self._undo.pop()
+        self.apply_delta(gone, created, gone_values)
         return True
 
     def undo_depth(self) -> int:
@@ -398,68 +800,52 @@ class DeviceUtxoIndex:
     # ------------------------------------------------------ device state --
 
     def _device_state(self) -> tuple:
-        """(keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi, n_live) jnp
-        arrays at power-of-two capacity.  Must only run on the runtime's
-        drainer thread (inside a submitted call)."""
-        if self._dirty or self._dev is None:
-            n = len(self._host_keys)
-            cap = _pow2(n)
-            pad = cap - n
-
-            def _padded(lane: np.ndarray, fill) -> np.ndarray:
-                return np.concatenate(
-                    [lane, np.full(pad, fill, dtype=np.int32)])
-
-            chk_a, chk_b = _eq_lanes(self._host_chk)
-            amt_u = self._host_amount.view(np.uint64)
-            amt_lo = (amt_u & np.uint64(0xFFFFFFFF)).astype(
-                np.uint32).view(np.int32)
-            amt_hi = (amt_u >> np.uint64(32)).astype(
-                np.uint32).view(np.int32)
-            self._dev = tuple(jnp.asarray(_padded(lane, fill)) for lane, fill in (
-                (_lane_hi(self._host_keys), _I32_MAX),
-                (_lane_lo(self._host_keys), _I32_MAX),
-                (chk_a, 0), (chk_b, 0),
-                (amt_lo, 0), (amt_hi, 0),
-            )) + (np.int32(n),)
-            self._dirty = False
-        return self._dev
+        """(keys_hi, keys_lo, chk_a, chk_b, amt_lo, amt_hi, n_live): the
+        resident lanes, laid out from the mirror if the device holds
+        none.  Must only run on the runtime's drainer thread (inside a
+        submitted call)."""
+        if self._lanes is None:
+            keys, chk, amount, _script, _height = self._mirror.flat()
+            self._capacity = self.capacity()
+            lanes = _row_lanes(keys, chk, amount, self._capacity)
+            metrics.inc("index.upload_bytes", _bytes_of(lanes))
+            self._lanes = tuple(jnp.asarray(lane) for lane in lanes)
+            self._n_live = np.int32(len(keys))
+        return self._lanes + (self._n_live,)
 
     def materialize(self) -> None:
-        """Upload the current host state to the device through the
-        runtime (kernel ``utxo_apply``) — the batched spend/create
-        transfer the accept path schedules after each block."""
+        """Lay the lanes out on the device now, through the runtime
+        (the build's one upload; a no-op once they are resident)."""
         from ..device.runtime import get_runtime
-        from ..telemetry import device as ktel
-
-        n = len(self._host_keys)
 
         def _upload():
-            t0 = time.perf_counter()
-            dev = self._device_state()
-            jax.block_until_ready(dev[0])
-            ktel.record_batch("utxo_apply", real=n,
-                              padded=int(dev[0].shape[0]),
-                              seconds=time.perf_counter() - t0,
-                              compile_key=int(dev[0].shape[0]))
+            jax.block_until_ready(self._device_state()[0])
             return True
 
         get_runtime().submit_call(_upload, kernel="utxo_apply",
                                   source="index").result()
 
+    def capacity(self) -> int:
+        """Slots a lane: what the device holds, or would on its first
+        use."""
+        if self._lanes is None:
+            return max(self._capacity, _pow2(len(self)))
+        return self._capacity
+
     def resident_bytes(self) -> int:
         """Device residency: six int32 lanes at padded capacity."""
-        return 6 * 4 * _pow2(len(self._host_keys))
+        return 6 * 4 * self.capacity()
 
     def stats(self) -> dict:
         return {
             "entries": len(self),
-            "capacity": _pow2(len(self._host_keys)),
+            "capacity": self.capacity(),
             "resident_bytes": self.resident_bytes(),
             "probes": self._probes,
             "shadow_consults": self._shadow_consults,
             "twin_fingerprints": len(self._twin_fps),
             "undo_depth": len(self._undo),
+            "relayouts": self._relayouts,
         }
 
     # ------------------------------------------------------------ queries --
@@ -476,24 +862,17 @@ class DeviceUtxoIndex:
         runtime drainer thread (inside a submitted call).  Returns
         (present bool[N], maybe bool[N], amounts int64[N],
         shadow_consults)."""
-        from ..telemetry import device as ktel
-
         n = len(ops)
         qn = _pow2(n)
         t0 = time.perf_counter()
         dev = self._device_state()
-
-        def _padq(lane: np.ndarray, fill) -> np.ndarray:
-            return np.concatenate(
-                [lane, np.full(qn - n, fill, dtype=np.int32)])
-
         q_ca, q_cb = _eq_lanes(chks)
+        queries = tuple(_padded(lane, qn, fill) for lane, fill in (
+            (_lane_hi(fps), _I32_MIN), (_lane_lo(fps), _I32_MIN),
+            (q_ca, 0), (q_cb, 0)))
+        metrics.inc("index.upload_bytes", _bytes_of(queries))
         hit, key_hit, overflow, amt_lo, amt_hi = _probe_kernel(
-            *dev[:6], dev[6],
-            jnp.asarray(_padq(_lane_hi(fps), _I32_MIN)),
-            jnp.asarray(_padq(_lane_lo(fps), _I32_MIN)),
-            jnp.asarray(_padq(q_ca, 0)), jnp.asarray(_padq(q_cb, 0)),
-            window=PROBE_WINDOW)
+            *dev, *(jnp.asarray(q) for q in queries), window=PROBE_WINDOW)
         hit = np.asarray(hit)[:n]
         key_hit = np.asarray(key_hit)[:n]
         overflow = np.asarray(overflow)[:n]
@@ -506,11 +885,10 @@ class DeviceUtxoIndex:
         if twins.size:
             ambiguous |= (key_hit & np.isin(fps, twins))
         present = hit & ~ambiguous
-        consults = 0
-        for i in np.nonzero(ambiguous)[0]:
-            bucket = self._shadow.get(int(fps[i]))
-            present[i] = bucket is not None and tuple(ops[i]) in bucket
-            consults += 1
+        consults = int(ambiguous.sum())
+        if consults:
+            present[ambiguous] = self._mirror.contains(
+                fps[ambiguous], chks[ambiguous])
         amounts = ((amt_hi.view(np.uint32).astype(np.uint64)
                     << np.uint64(32))
                    | amt_lo.view(np.uint32).astype(np.uint64)
@@ -521,7 +899,7 @@ class DeviceUtxoIndex:
         self._shadow_consults += consults
         ktel.record_batch("utxo_probe", real=n, padded=qn, seconds=dt,
                           compile_key=(int(dev[0].shape[0]), qn))
-        ktel.record_index_probe(n, consults, int(ambiguous.sum()))
+        ktel.record_index_probe(n, consults, consults)
         return present, maybe, amounts, consults
 
     def _probe(self, outpoints: Sequence[Outpoint]) -> tuple:
@@ -546,7 +924,7 @@ class DeviceUtxoIndex:
     def contains_batch(self, outpoints: Sequence[Outpoint]) -> np.ndarray:
         """(N,) bool EXACT membership in one device dispatch.
 
-        The 128-bit lane compare answers directly; the shadow map is
+        The 128-bit lane compare answers directly; the host mirror is
         consulted only for probes the kernel itself declares ambiguous
         (run overflow or a known-twin fingerprint)."""
         if not outpoints:
@@ -562,17 +940,13 @@ class DeviceUtxoIndex:
         return present, amounts
 
     def shadow_contains_batch(self, outpoints: Sequence[Outpoint]) -> np.ndarray:
-        """(N,) bool membership answered PURELY by the host shadow map —
+        """(N,) bool membership answered PURELY by the host mirror —
         the byte-identity differential's oracle; never dispatches."""
-        out = np.zeros(len(outpoints), dtype=bool)
         if not len(outpoints):
-            return out
+            return np.zeros(0, dtype=bool)
         ops = [tuple(o) for o in outpoints]
-        for i, (o, fp) in enumerate(
-                zip(ops, fingerprint_batch(ops).tolist())):
-            bucket = self._shadow.get(fp)
-            out[i] = bucket is not None and o in bucket
-        return out
+        return self._mirror.contains(fingerprint_batch(ops),
+                                     check_batch(ops))
 
     def missing(self, outpoints: Sequence[Outpoint]) -> List[Outpoint]:
         """Outpoints that are definitely absent (exact)."""

@@ -148,7 +148,6 @@ class PgChainState(StateViews):
         self.drv = driver if driver is not None else AsyncpgDriver(dsn)
         self.path = dsn
         self.emission_path = emission_path
-        self._dev_index: Optional[Dict[str, object]] = None
         self._in_atomic = False
         # transaction-scope exclusivity: every DB call is a yield point
         # now (awaitable driver), so without this a concurrent writer's
@@ -159,8 +158,6 @@ class PgChainState(StateViews):
         # it) from foreign tasks (which must wait on the lock).
         self._write_lock = None
         self._txn_owner = None
-        self._index_mutations = 0  # dirty counter: rollback only pays
-        # the full index resync if the transaction actually touched it
         self._pending_gen = 0  # bumped on every LOCAL mempool mutation
         self.reinject_reorg_txs = False  # Node flips this from config
         # reorg notification for the hot-state read cache — same hook
@@ -183,40 +180,37 @@ class PgChainState(StateViews):
         """The single home of writer-lock + transaction bookkeeping:
         acquire the lock, mark this task as owner (its nested writes
         join the transaction), BEGIN, then COMMIT — or ROLLBACK on
-        error or when ``commit=False`` (replay).  Any rollback resyncs
-        the device index (in-memory mutations from the discarded
-        transaction would otherwise turn into definitive false
-        negatives in the membership prefilter), still under the lock."""
+        error or when ``commit=False`` (replay).  What the transaction
+        adds to and removes from the device index is held back
+        (``StateViews._index_batch``) and applied after the COMMIT as
+        one delta a table, still under the lock; a rollback applies
+        nothing, so the index never holds a discarded transaction's
+        rows."""
         async with self._writer():
-            self._in_atomic = True
-            self._txn_owner = asyncio.current_task()
-            rolled_back = False
-            mutations_at_entry = self._index_mutations
-            try:
-                await self.drv.abegin()
-                yield
-                if commit:
-                    await self.drv.acommit()
-                else:
+            with self._index_batch():
+                self._in_atomic = True
+                self._txn_owner = asyncio.current_task()
+                rolled_back = False
+                try:
+                    await self.drv.abegin()
+                    yield
+                    if commit:
+                        await self.drv.acommit()
+                    else:
+                        rolled_back = True
+                        await self.drv.arollback()
+                        self._index_forget()
+                except BaseException:
                     rolled_back = True
                     await self.drv.arollback()
-            except BaseException:
-                rolled_back = True
-                await self.drv.arollback()
-                raise
-            finally:
-                # also covers a failed BEGIN: leaking the owner flags
-                # would let this task's later writes bypass the lock
-                self._in_atomic = False
-                self._txn_owner = None
-                if rolled_back:
-                    self._bump_fees_gen()  # memos may hold discarded rows
-                if rolled_back and \
-                        self._index_mutations != mutations_at_entry:
-                    # in-memory index mutations from the discarded
-                    # transaction would otherwise become definitive
-                    # false negatives in the membership prefilter
-                    await self._aindex_rebuild()
+                    raise
+                finally:
+                    # also covers a failed BEGIN: leaking the owner flags
+                    # would let this task's later writes bypass the lock
+                    self._in_atomic = False
+                    self._txn_owner = None
+                    if rolled_back:
+                        self._bump_fees_gen()  # memos may hold discarded rows
 
     @asynccontextmanager
     async def _txn(self):
@@ -287,6 +281,8 @@ class PgChainState(StateViews):
         """Same device-resident membership index as the sqlite backend
         (storage.py enable_device_index).  Sync (blocking) — called once
         at node boot; runtime resyncs go through :meth:`_aindex_rebuild`.
+        A block's update takes the sqlite backend's path
+        (``StateViews._index_batch``: one delta a table after the COMMIT).
         The reference pg schema carries no amount column on
         unspent_outputs, so bulk loads seed the resident value store
         with zeros; incremental adds (which decode the tx) thread real
@@ -312,34 +308,6 @@ class PgChainState(StateViews):
             self._dev_index = None
             return False
         return True
-
-    def _index_add(self, table, outpoints, values=None):
-        if self._dev_index is not None:
-            self._index_mutations += 1
-            self._dev_index[table].add(outpoints, values)
-
-    def _index_remove(self, table, outpoints):
-        if self._dev_index is not None:
-            self._index_mutations += 1
-            self._dev_index[table].remove(outpoints)
-
-    def resident_indexes(self):
-        """Per-table DeviceUtxoIndex map when enabled, else None — the
-        accept path's gate for the fused resident probe."""
-        return self._dev_index
-
-    def index_stats(self):
-        """Aggregate resident-index telemetry (same shape as the sqlite
-        backend's); None when the index is disabled."""
-        if not self._dev_index:
-            return None
-        agg = {"entries": 0, "resident_bytes": 0, "probes": 0,
-               "shadow_consults": 0, "twin_fingerprints": 0}
-        for index in self._dev_index.values():
-            s = index.stats()
-            for k in agg:
-                agg[k] += s[k]
-        return agg
 
     async def _aindex_rebuild(self):
         """Resync the device index from the live tables without blocking
@@ -509,7 +477,8 @@ class PgChainState(StateViews):
             # removed txs' outputs by class (absent = no-op), mirroring
             # the sqlite backend; restores delta-add below, so the
             # wholesale post-reorg resync is gone from the happy path.
-            # The _open_txn rollback rebuild still covers failures.
+            # Held back until the COMMIT (_open_txn): a failure rolls
+            # back and the index hears nothing.
             if self._dev_index is not None:
                 doomed_by_table: Dict[str, list] = {}
                 for tx in txs:
@@ -1385,6 +1354,7 @@ class PgChainState(StateViews):
     async def rebuild_utxos(self) -> None:
         """Full-chain replay of every output table from the transactions
         log (reference create_unspent_outputs.py + database.py:846-862)."""
+        nested = self._owns_txn()
         async with self._txn():
             for table in ("unspent_outputs",) + _GOV_TABLES:
                 await self.drv.aexecute(f"DELETE FROM {table}")
@@ -1396,11 +1366,15 @@ class PgChainState(StateViews):
             for tx in txs:
                 await self.add_transaction_outputs([tx])
                 await self.remove_outputs([tx])
-        if not self._owns_txn():
-            # inside a replay transaction the owning scope resyncs the
-            # index after its rollback; here, resync under the writer
-            # lock so a concurrent commit can't be clobbered by a stale
-            # snapshot swap
+            if not nested:
+                # the index is built again from the tables below: what
+                # it would hear tx by tx is dropped
+                self._index_forget()
+        if not nested:
+            # (inside a replay transaction the owning scope rolls back
+            # and the index hears nothing); here, resync under the
+            # writer lock so a concurrent commit can't be clobbered by a
+            # stale snapshot swap
             async with self._writer():
                 await self._aindex_rebuild()
 

@@ -135,6 +135,16 @@ _INPUT_TABLE = {
 from .views import StateViews
 
 
+def _host_peak_mb() -> float:
+    """This process's peak resident memory in MB (``ru_maxrss``, which
+    Linux counts in KB).  The index's build says it before and after
+    itself: a TPU runtime's own mappings are in both."""
+    import resource
+
+    return round(resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0,
+                 1)
+
+
 class ChainState(StateViews):
     """One chain's durable state.  ``path=None`` -> in-memory (tests).
 
@@ -167,7 +177,6 @@ class ChainState(StateViews):
         )
         # optional device-resident membership prefilter per UTXO table
         # (SURVEY.md §2.2; the block-accept hot path's spend check)
-        self._dev_index: Optional[Dict[str, object]] = None
         if device_index:
             self.enable_device_index()
         # decoded-mempool cache: several read paths walk every pending tx
@@ -281,48 +290,74 @@ class ChainState(StateViews):
                 "— sqlite membership checks only")
             self._dev_index = None
             return
+        from .. import trace
         from .device_index import DeviceUtxoIndex
 
         self._dev_index = {}
         for table in ("unspent_outputs",) + _GOV_TABLES:
-            rows = self.db.execute(
-                f"SELECT tx_hash, idx, amount, address FROM {table}"
-            ).fetchall()
-            self._dev_index[table] = DeviceUtxoIndex(
-                [(r["tx_hash"], r["idx"]) for r in rows],
-                values=[(r["amount"], r["address"] or "", 0) for r in rows])
+            t0, peak_before = _time.perf_counter(), _host_peak_mb()
+            index = DeviceUtxoIndex.from_columns(*self._index_columns(table))
+            index.materialize()
+            self._dev_index[table] = index
+            stats = index.stats()
+            trace.event("index_built", table=table,
+                        entries=stats["entries"],
+                        capacity=stats["capacity"],
+                        resident_bytes=stats["resident_bytes"],
+                        seconds=round(_time.perf_counter() - t0, 3),
+                        host_peak_before_mb=peak_before,
+                        host_peak_mb=_host_peak_mb())
 
-    def _index_add(self, table: str, outpoints, values=None) -> None:
-        if self._dev_index is not None:
-            self._dev_index[table].add(outpoints, values)
+    #: rows a fetch of the index's build: the table streams through in
+    #: chunks, so the build holds columns and never 4 M row objects
+    _INDEX_CHUNK = 1 << 18
 
-    def _index_remove(self, table: str, outpoints) -> None:
-        if self._dev_index is not None:
-            self._dev_index[table].remove(outpoints)
+    def _index_columns(self, table: str) -> tuple:
+        """(fingerprints, check fingerprints, amounts, script hashes) of
+        a table's rows as numpy columns, with no Python statement a row:
+        each chunk is transposed, its hashes decoded in one
+        ``fromhex`` and its addresses hashed through ``map``."""
+        import numpy as np
+
+        from .device_index import (check_lanes, fingerprint_lanes,
+                                   script_hash_batch)
+
+        cur = self.db.cursor()
+        cur.row_factory = None
+        cur.execute(f"SELECT tx_hash, idx, COALESCE(amount, 0),"
+                    f" CAST(COALESCE(address, '') AS BLOB) FROM {table}")
+        parts = [(np.zeros(0, np.uint64), np.zeros(0, np.uint64),
+                  np.zeros(0, np.int64), np.zeros(0, np.uint32))]
+        while True:
+            rows = cur.fetchmany(self._INDEX_CHUNK)
+            if not rows:
+                break
+            hashes, idxs, amounts, addresses = zip(*rows)
+            del rows
+            lanes = np.frombuffer(bytes.fromhex("".join(hashes)),
+                                  dtype="<u8").reshape(-1, 4)
+            idx = np.array(idxs, dtype=np.uint64)
+            parts.append((fingerprint_lanes(lanes, idx),
+                          check_lanes(lanes, idx),
+                          np.array(amounts, dtype=np.int64),
+                          script_hash_batch(addresses)))
+        return tuple(np.concatenate(col) for col in zip(*parts))
+
+    @staticmethod
+    def _index_values(outputs) -> tuple:
+        """The index's value columns (amount, script hash, height) of a
+        block's outputs."""
+        import numpy as np
+
+        from .device_index import script_hash_batch
+
+        return (np.array([o.amount for o in outputs], dtype=np.int64),
+                script_hash_batch([o.address or "" for o in outputs]),
+                np.zeros(len(outputs), dtype=np.uint32))
 
     def _index_rebuild(self) -> None:
         if self._dev_index is not None:
             self.enable_device_index()
-
-    def resident_indexes(self) -> Optional[Dict[str, object]]:
-        """The per-table :class:`DeviceUtxoIndex` map when the device
-        index is enabled and armed, else None — the accept path's gate
-        for the fused resident probe (verify/block.py)."""
-        return self._dev_index
-
-    def index_stats(self) -> Optional[dict]:
-        """Aggregate resident-index telemetry across every UTXO-class
-        table (residency bytes, probe/shadow-consult counters) for the
-        /metrics exporter; None when the index is disabled."""
-        if not self._dev_index:
-            return None
-        agg = {"entries": 0, "resident_bytes": 0, "probes": 0,
-               "shadow_consults": 0, "twin_fingerprints": 0}
-        for index in self._dev_index.values():
-            s = index.stats()
-            for k in agg:
-                agg[k] += s[k]
-        return agg
 
     def close(self):
         self.db.close()
@@ -335,19 +370,21 @@ class ChainState(StateViews):
         make atomic()'s rollback silently keep the committed half:
         accepted block + mempool removals with the spent UTXOs still
         unspent)."""
-        self._in_atomic = True
-        try:
-            self.db.execute("BEGIN")
-            yield
-            self.db.commit()
-        except BaseException:
-            self.db.rollback()
-            self._amount_cache.clear()  # may hold rolled-back rows
-            self._bump_fees_gen()
-            self._index_rebuild()  # undo any index updates the txn made
-            raise
-        finally:
-            self._in_atomic = False
+        with self._index_batch():
+            # (the index hears of the block below this ``try``, once it
+            # has committed: nothing after the commit may raise)
+            self._in_atomic = True
+            try:
+                self.db.execute("BEGIN")
+                yield
+                self.db.commit()
+            except BaseException:
+                self.db.rollback()
+                self._amount_cache.clear()  # may hold rolled-back rows
+                self._bump_fees_gen()
+                raise
+            finally:
+                self._in_atomic = False
 
     def _commit(self) -> None:
         if not getattr(self, "_in_atomic", False):
@@ -508,31 +545,34 @@ class ChainState(StateViews):
             self.db.executemany(
                 f"DELETE FROM {table} WHERE tx_hash = ?", [(h,) for h in created]
             )
-        # O(delta) index maintenance (ISSUE 11): enumerate the removed
-        # txs' outputs by class and delta-remove them — already-spent
-        # outputs are absent and no-op, matching the blanket SQL DELETE.
-        # The restored spends below delta-add through the same hooks, so
-        # the full rebuild a reorg used to pay is gone.
-        if self._dev_index is not None:
-            doomed_by_table: Dict[str, list] = {}
-            for tx in txs:
-                h = tx.hash()
-                for index, out in enumerate(tx.outputs):
-                    doomed_by_table.setdefault(
-                        _OUTPUT_TABLE[out.output_type], []).append((h, index))
-            for table, outpoints in doomed_by_table.items():
-                self._index_remove(table, outpoints)
-        # restore outputs their inputs had spent — but not outputs of txs
-        # that are themselves being removed (reference database.py
-        # remove_blocks filters `tx_input.tx_hash not in transactions_hashes`;
-        # restoring those would leave orphaned UTXO rows after a reorg of
-        # dependent txs and diverge the UTXO fingerprint)
-        created_set = set(created)
-        restore = [
-            tx_input for tx in txs if not tx.is_coinbase
-            for tx_input in tx.inputs if tx_input.tx_hash not in created_set
-        ]
-        await self._restore_spent_outputs(restore)
+        # the removals and the restored spends below reach the resident
+        # index as one delta a table
+        with self._index_batch():
+            # O(delta) index maintenance (ISSUE 11): enumerate the removed
+            # txs' outputs by class and delta-remove them — already-spent
+            # outputs are absent and no-op, matching the blanket SQL DELETE.
+            # The restored spends below delta-add through the same hooks, so
+            # the full rebuild a reorg used to pay is gone.
+            if self._dev_index is not None:
+                doomed_by_table: Dict[str, list] = {}
+                for tx in txs:
+                    h = tx.hash()
+                    for index, out in enumerate(tx.outputs):
+                        doomed_by_table.setdefault(
+                            _OUTPUT_TABLE[out.output_type], []).append((h, index))
+                for table, outpoints in doomed_by_table.items():
+                    self._index_remove(table, outpoints)
+            # restore outputs their inputs had spent — but not outputs of txs
+            # that are themselves being removed (reference database.py
+            # remove_blocks filters `tx_input.tx_hash not in transactions_hashes`;
+            # restoring those would leave orphaned UTXO rows after a reorg of
+            # dependent txs and diverge the UTXO fingerprint)
+            created_set = set(created)
+            restore = [
+                tx_input for tx in txs if not tx.is_coinbase
+                for tx_input in tx.inputs if tx_input.tx_hash not in created_set
+            ]
+            await self._restore_spent_outputs(restore)
         self.db.executemany(
             "DELETE FROM transactions WHERE tx_hash = ?", [(h,) for h in created]
         )
@@ -977,9 +1017,10 @@ class ChainState(StateViews):
                     " amount) VALUES (?,?,?,?)",
                     [(h, i, o.address, o.amount) for h, i, o in entries],
                 )
-            self._index_add(table, [(h, i) for h, i, _ in entries],
-                            values=[(o.amount, o.address or "", 0)
-                                    for _h, _i, o in entries])
+            if self._dev_index is not None:
+                self._index_add(table, [(h, i) for h, i, _ in entries],
+                                values=self._index_values(
+                                    [o for _h, _i, o in entries]))
 
     async def remove_outputs(self, txs: Sequence[AnyTx]) -> None:
         """Spend inputs from the table their tx type targets
@@ -1006,17 +1047,22 @@ class ChainState(StateViews):
 
     async def outpoints_exist(self, outpoints: List[Tuple[str, int]],
                               table: str = "unspent_outputs") -> List[bool]:
-        """Batched membership test: one row-value IN query per 400 outpoints
-        instead of a query per outpoint — an 8k-input block is ~20 queries.
+        """Batched membership test: one keyed IN query per 900 outpoints
+        instead of a query per outpoint — an 8k-input block is ~10 queries.
         (The reference does a set-diff against a full-column fetch,
         manager.py:531-615.)  With the device index enabled, the answer
         is EXACT and SQL-free: one ``searchsorted`` dispatch rejects
         definite misses, and the index's host-side exact map confirms
         the hits — including resolving 64-bit fingerprint twins down to
-        the precise outpoint (see device_index.py).  The index is
-        maintained in lockstep with every INSERT/DELETE on these tables
-        and rebuilt on rollback, so its view always matches what this
-        connection's SQL would report."""
+        the precise outpoint (see device_index.py).  The index follows
+        the tables a COMMITTED transaction at a time: what an open
+        ``atomic()`` body (or ``remove_blocks``) inserts and deletes is
+        held back and reaches the index as one delta once the
+        transaction has committed, never if it rolls back.  So while
+        such a body is open the index answers from the last committed
+        state where this connection's SQL would already show the open
+        transaction's rows; block accepts are serialised by the accept
+        lock, and a bulk rewrite of the tables rebuilds the index."""
         if not outpoints:
             return []
         if self._dev_index is not None and table in self._dev_index:
@@ -1027,31 +1073,43 @@ class ChainState(StateViews):
 
     async def _outpoints_exist_sql(self, outpoints: List[Tuple[str, int]],
                                    table: str) -> List[bool]:
+        """By the primary key's leading column: ``tx_hash IN (...)`` is
+        a SEARCH of the covering index a hash, and the few rows of each
+        hash are matched on ``idx`` here.  (A row-value ``(tx_hash, idx)
+        IN (VALUES ...)`` is planned by sqlite as a SCAN of the whole
+        index a query: 46 s a block over 4 M rows, PERF.md section 6,
+        PR 50.)"""
         if not outpoints:
             return []
         found: set = set()
-        CHUNK = 400
+        cur = self.db.cursor()
+        cur.row_factory = None
+        CHUNK = 900   # one variable an outpoint; sqlite's old limit is 999
         for off in range(0, len(outpoints), CHUNK):
-            chunk = outpoints[off:off + CHUNK]
-            placeholders = ",".join(["(?,?)"] * len(chunk))
-            params = [v for o in chunk for v in o]
-            rows = self.db.execute(
-                f"SELECT tx_hash, idx FROM {table} WHERE (tx_hash, idx)"
-                f" IN (VALUES {placeholders})", params,
-            ).fetchall()
-            found.update((r["tx_hash"], r["idx"]) for r in rows)
+            hashes = [o[0] for o in outpoints[off:off + CHUNK]]
+            found.update(cur.execute(
+                f"SELECT tx_hash, idx FROM {table} WHERE tx_hash IN"
+                f" ({','.join('?' * len(hashes))})", hashes))
         return [tuple(o) in found for o in outpoints]
 
     async def get_table_outpoints_hash(self, table: str) -> str:
+        """sha256 over ``tx_hash || idx`` of the table's rows in key
+        order (reference database.py:827-830), streamed: sqlite
+        concatenates, a chunk is one ``update``, no object a row is
+        kept."""
         import hashlib
 
-        rows = self.db.execute(
-            f"SELECT tx_hash, idx FROM {table} ORDER BY tx_hash, idx"
-        ).fetchall()
+        cur = self.db.cursor()
+        cur.row_factory = None
+        cur.execute(f"SELECT tx_hash || idx FROM {table}"
+                    " ORDER BY tx_hash, idx")
         h = hashlib.sha256()
-        for r in rows:
-            h.update(f"{r['tx_hash']}{r['idx']}".encode())
-        return h.hexdigest()
+        while True:
+            rows = cur.fetchmany(self._INDEX_CHUNK)
+            if not rows:
+                return h.hexdigest()
+            (texts,) = zip(*rows)
+            h.update("".join(texts).encode())
 
     # ------------------------------------------------------ address views --
 
@@ -1484,11 +1542,17 @@ class ChainState(StateViews):
             " b.hash = t.block_hash ORDER BY b.id"
         ).fetchall()
         txs = [tx_from_hex(r["tx_hex"], check_signatures=False) for r in rows]
-        for tx in txs:
-            await self.add_transaction_outputs([tx])
-            await self.remove_outputs([tx])
+        # the replay rewrites the tables wholesale and the index is built
+        # again from them below: what it would hear tx by tx is dropped
+        held, self._index_stage = self._index_stage, {}
+        try:
+            for tx in txs:
+                await self.add_transaction_outputs([tx])
+                await self.remove_outputs([tx])
+        finally:
+            self._index_stage = held
         self._commit()
-        self._index_rebuild()  # replay rewrote the tables wholesale
+        self._index_rebuild()
 
     # ---------------------------------------------------------- snapshots --
     # Canonical positional row shapes shared with the pg backend (the

@@ -30,9 +30,11 @@ seam was cut for the Postgres backend.
 from __future__ import annotations
 
 import json
+import logging
 import os
+from contextlib import contextmanager
 from decimal import Decimal
-from typing import Dict, Iterable, List, Tuple, Union
+from typing import Dict, Iterable, List, Optional, Tuple, Union
 
 from ..core.clock import timestamp as now_ts
 from ..core.codecs import OutputType, TransactionType
@@ -78,6 +80,98 @@ class StateViews:
     def _bump_fees_gen(self) -> None:
         """Invalidate every outstanding per-object fee memo (reorg)."""
         self._fees_gen = getattr(self, "_fees_gen", 0) + 1
+
+    # --------------------------------------------------- resident index ---
+    # (state/device_index.py).  A backend's INSERT/DELETE paths on the
+    # UTXO-class tables call _index_add / _index_remove; its transaction
+    # wraps itself in _index_batch, so the index hears of a transaction
+    # once, after the commit, as one delta a table, and never of one
+    # that rolled back.
+
+    #: per-table DeviceUtxoIndex map, None while the index is off
+    _dev_index: Optional[Dict[str, object]] = None
+    #: what an open _index_batch holds back, a list of steps a table
+    _index_stage: Optional[Dict[str, list]] = None
+
+    def _index_add(self, table: str, outpoints, values=None) -> None:
+        if self._dev_index is None:
+            return
+        if self._index_stage is None:
+            self._dev_index[table].add(outpoints, values)
+        else:
+            self._index_stage.setdefault(table, []).append(
+                ("add", list(outpoints), values))
+
+    def _index_remove(self, table: str, outpoints) -> None:
+        if self._dev_index is None:
+            return
+        if self._index_stage is None:
+            self._dev_index[table].remove(outpoints)
+        else:
+            self._index_stage.setdefault(table, []).append(
+                ("remove", list(outpoints), None))
+
+    @contextmanager
+    def _index_batch(self):
+        """Everything the body adds to and removes from the resident
+        index is held back and applied when the body has ended, a table
+        at a time, as ONE delta (one device program) where the order
+        allows (``DeviceUtxoIndex.apply_steps``).  A body that raises
+        applies nothing: the index never saw what the rolled-back
+        transaction did (``_index_forget`` says the same of a body that
+        rolled back without raising).
+
+        **Never raises once the body has ended**: by then the tables are
+        committed, and a caller that heard an exception would answer a
+        durable block as refused.  A device that fails an apply only
+        drops the lanes (``apply_delta``: the mirror holds the block and
+        the next probe lays them out again); anything else is a fault of
+        the host's copy, and the index is switched off: membership is
+        SQL's from there on, until the node starts again."""
+        if self._dev_index is None or self._index_stage is not None:
+            yield
+            return
+        self._index_stage = {}
+        try:
+            yield
+            stage = self._index_stage
+        finally:
+            self._index_stage = None
+        try:
+            for table, steps in stage.items():
+                self._dev_index[table].apply_steps(steps)
+        # the tables are committed and stand; the index does not
+        except Exception:  # upowlint: disable=BE001
+            logging.getLogger("upow_tpu.state").exception(
+                "resident index update failed after the commit; index"
+                " off, SQL membership checks from here on")
+            self._dev_index = None
+
+    def _index_forget(self) -> None:
+        """Drop what the open batch holds: its transaction was rolled
+        back on purpose (a replay), with no exception to say so."""
+        if self._index_stage is not None:
+            self._index_stage.clear()
+
+    def resident_indexes(self) -> Optional[Dict[str, object]]:
+        """The per-table :class:`DeviceUtxoIndex` map when the device
+        index is enabled and armed, else None — the accept path's gate
+        for the fused resident probe (verify/block.py)."""
+        return self._dev_index
+
+    def index_stats(self) -> Optional[dict]:
+        """Aggregate resident-index telemetry across every UTXO-class
+        table (residency bytes, probe/shadow-consult counters) for the
+        /metrics exporter; None when the index is disabled."""
+        if not self._dev_index:
+            return None
+        agg = {"entries": 0, "resident_bytes": 0, "probes": 0,
+               "shadow_consults": 0, "twin_fingerprints": 0}
+        for index in self._dev_index.values():
+            s = index.stats()
+            for k in agg:
+                agg[k] += s[k]
+        return agg
 
     # ----------------------------------------------------- transactions ---
 

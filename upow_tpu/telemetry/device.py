@@ -207,7 +207,12 @@ def preregister_index() -> None:
     for kernel in INDEX_KERNELS:
         preregister(kernel)
     for c in ("probes", "probe_outpoints", "shadow_consults",
-              "ambiguous_probes"):
+              "ambiguous_probes",
+              # a block's update (ISSUE 50): rows created + spent that
+              # were applied; host -> device bytes of builds, applies
+              # and probes' queries; capacity changes after the build;
+              # folds of the host mirror's delta into its base (O(N))
+              "apply_rows", "upload_bytes", "relayouts", "folds"):
         metrics.ensure_counter("index.%s" % c)
 
 

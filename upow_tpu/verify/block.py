@@ -329,14 +329,18 @@ class BlockManager:
             resident = None
             if self.fused_accept and hasattr(self.state, "resident_indexes"):
                 resident = self.state.resident_indexes()
-            if resident:
-                prep_cache, by_table, presence = \
-                    await self._fused_accept_scan(transactions, resident)
-                if not self._double_spend_verdict(
-                        by_table, presence, block_no, errors):
-                    return False
-            elif not await self._check_block_double_spends(
-                    transactions, block_no, errors):
+            with span("block.spend_scan",
+                      path="resident" if resident else "sql",
+                      txs=len(transactions)):
+                if resident:
+                    prep_cache, by_table, presence = \
+                        await self._fused_accept_scan(transactions, resident)
+                    unspent = self._double_spend_verdict(
+                        by_table, presence, block_no, errors)
+                else:
+                    unspent = await self._check_block_double_spends(
+                        transactions, block_no, errors)
+            if not unspent:
                 return False
 
         # pipelined verify (ISSUE 7 tentpole b/c): the block is split into
@@ -602,7 +606,8 @@ class BlockManager:
         event("block_seen", hash=block_hash, height=block_no)
 
         if block_no % 10 == 0:
-            fingerprint = await self.state.get_unspent_outputs_hash()
+            with span("block.utxo_fingerprint", block=block_no):
+                fingerprint = await self.state.get_unspent_outputs_hash()
             import logging
 
             logging.getLogger("upow_tpu").info(
