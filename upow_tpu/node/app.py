@@ -896,6 +896,11 @@ class Node:
         e.gauge("device_verify_health",
                 self.manager.device_health()["gauge"],
                 "Device verify path: 0=ok 1=degraded(CPU) 2=poisoned")
+        wal_pages = getattr(self.state, "wal_pages", None)
+        if wal_pages is not None:
+            e.gauge("state_wal_pages", wal_pages,
+                    "Frames in sqlite's write-ahead log at the last"
+                    " commit (the keeper folds them behind it)")
         index_stats = getattr(self.state, "index_stats", lambda: None)()
         if index_stats is not None:
             e.gauge("utxo_index_entries", index_stats["entries"],

@@ -30,6 +30,8 @@ from __future__ import annotations
 import json
 import os
 import sqlite3
+import struct
+import threading
 import time as _time
 from contextlib import asynccontextmanager
 from decimal import Decimal
@@ -40,6 +42,10 @@ from ..core.codecs import OutputType, TransactionType
 from ..core.constants import MAX_BLOCK_SIZE_HEX, SMALLEST
 from ..core.rewards import round_up_decimal
 from ..core.tx import CoinbaseTx, Tx, TxInput, tx_from_hex
+from ..logger import get_logger
+from .. import telemetry
+
+log = get_logger("state")
 
 AnyTx = Union[Tx, CoinbaseTx]
 
@@ -145,6 +151,105 @@ def _host_peak_mb() -> float:
                  1)
 
 
+#: frames of write-ahead log from which a commit wakes the keeper:
+#: sqlite's own ``wal_autocheckpoint`` default, so the cadence is the one
+#: the connection had (a full block's commit is thousands of frames: one
+#: checkpoint a block; mempool writes gather until they make a thousand)
+_CHECKPOINT_PAGES = 1000
+#: frames past which the committing thread checkpoints itself, as sqlite
+#: did before the keeper: three and a half full blocks over a 4 M-row
+#: table (18,2xx frames each).  The log begins again only when a
+#: transaction's first write finds every frame folded, so a keeper that
+#: is still copying then (commits that follow one another faster than a
+#: checkpoint takes, a long reader that pins the frames) lets it grow; a
+#: longer log slows every read that goes through it, and it must not
+#: grow without end
+_INLINE_CHECKPOINT_PAGES = 1 << 16
+
+
+def _checkpoint(db: sqlite3.Connection, sp) -> None:
+    """One passive checkpoint on ``db``: the log's committed frames
+    copied into the main file as far as no reader pins them, the file
+    synced.  Never waits for a lock of sqlite's: with another
+    connection's checkpoint in flight the pragma answers ``busy`` at
+    once, has done nothing and is not counted.  ``sp`` is the
+    ``state.wal_checkpoint`` span that times it, given the pragma's
+    row."""
+    busy, frames, backfilled = db.execute(
+        "PRAGMA wal_checkpoint(PASSIVE)").fetchone()
+    if sp is not None:
+        sp.fields.update(frames=frames, backfilled=backfilled, busy=busy)
+    if not busy:
+        telemetry.update((("state.checkpoints", 1),
+                          ("state.checkpoint_frames", backfilled)))
+
+
+class _WalKeeper:
+    """The thread that folds a sqlite file's write-ahead log back into
+    the file, on a connection of its own: what sqlite's automatic
+    checkpoint did inside the writer's ``commit()``, behind it.
+
+    A commit is durable once its frames are in the log and the log is
+    synced (``synchronous`` FULL, untouched); copying them into the main
+    file moves frames that are durable already, so nothing acknowledged
+    waits for it.  The writer wakes the keeper after a commit that
+    leaves the log at ``_CHECKPOINT_PAGES`` frames or more; the keeper
+    runs ``PRAGMA wal_checkpoint(PASSIVE)`` (one C call, the interpreter
+    lock released) and does nothing else.  It never touches the writer's
+    connection, nor the writer its own."""
+
+    def __init__(self, path: str):
+        self._path = path
+        # the wal-index: sqlite's ``-shm`` file, there for as long as a
+        # connection has the file open in WAL mode
+        self._shm = os.open(path + "-shm", os.O_RDONLY)
+        self._wake = threading.Event()
+        self._stopping = False
+        #: held around a checkpoint, the keeper's or the writer's own:
+        #: a writer that has to checkpoint waits for the one in flight
+        self.checkpointing = threading.Lock()
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="wal-keeper")
+        self._thread.start()
+
+    def log_frames(self) -> int:
+        """Frames in the log now: ``mxFrame`` of the wal-index header
+        (sqlite.org/walformat.html, the u32 at byte 16, in the host's
+        byte order).  Not the ``-wal`` file's size: a log that restarts
+        from its first frame keeps its length, so the file says the
+        longest the log has ever been."""
+        return struct.unpack("=I", os.pread(self._shm, 4, 16))[0]
+
+    def wake(self) -> None:
+        self._wake.set()
+
+    def stop(self) -> None:
+        """End the thread, a checkpoint in flight finished and its
+        connection closed."""
+        self._stopping = True
+        self._wake.set()
+        self._thread.join()
+        os.close(self._shm)
+
+    def _run(self) -> None:
+        db = sqlite3.connect(self._path)
+        try:
+            while True:
+                self._wake.wait()
+                self._wake.clear()
+                if self._stopping:
+                    return
+                try:
+                    with self.checkpointing, telemetry.request_trace(
+                            "state.wal_checkpoint", inline=False) as sp:
+                        _checkpoint(db, sp)
+                # the log stands and the next commit asks again
+                except sqlite3.Error:
+                    log.exception("write-ahead log checkpoint failed")
+        finally:
+            db.close()
+
+
 class ChainState(StateViews):
     """One chain's durable state.  ``path=None`` -> in-memory (tests).
 
@@ -152,7 +257,26 @@ class ChainState(StateViews):
     the active-inode cascade, fee math, fingerprints) live in
     :class:`StateViews`; this class implements the sqlite storage
     primitives under them.  :class:`upow_tpu.state.pg.PgChainState` is
-    the PostgreSQL implementation of the same seam."""
+    the PostgreSQL implementation of the same seam.
+
+    **The write-ahead log's checkpoint.**  A file-backed state that is
+    the file's sole writer (``path`` given, ``sole_writer`` true: the
+    node) sets ``wal_autocheckpoint=0`` on its connection and hands the
+    checkpoint to a :class:`_WalKeeper` thread: the cadence is sqlite's
+    own (a commit that leaves 1,000 frames or more in the log, so once a
+    full block), but the accept lock and the peer's acknowledgement no
+    longer wait for 18,000 pages to be copied into the main file.
+    Durability is as it was: ``synchronous`` stays FULL and
+    ``journal_mode`` WAL, every commit stays where it was and syncs the
+    log before it returns, and a process that dies between a commit and
+    its checkpoint loses nothing (the next open reads the log).  Should
+    a commit find the log past ``_INLINE_CHECKPOINT_PAGES`` frames (the
+    keeper cannot keep up: a long reader on another connection pins the
+    frames), the committing thread checkpoints itself, as sqlite did,
+    and counts ``state.checkpoint_inline``.  ``path=None`` has no log
+    and ``sole_writer=False`` (the wallet CLI beside a node) keeps
+    sqlite's automatic checkpoint: neither starts a thread.
+    ``close()`` joins the keeper and leaves the log folded."""
 
     def __init__(self, path: Optional[str] = None,
                  device_index: bool = False,
@@ -166,11 +290,18 @@ class ChainState(StateViews):
         self.sole_writer = sole_writer
         self.db = sqlite3.connect(self.path)
         self.db.row_factory = sqlite3.Row
-        if path:
-            self.db.execute("PRAGMA journal_mode=WAL")
+        wal = bool(path) and self.db.execute(
+            "PRAGMA journal_mode=WAL").fetchone()[0] == "wal"
         self.db.execute("PRAGMA foreign_keys=OFF")
         self.db.executescript(_SCHEMA)
         self.db.commit()
+        #: frames in the write-ahead log at the last commit (the gauge
+        #: ``upow_state_wal_pages``); None where no keeper runs
+        self.wal_pages: Optional[int] = None
+        self._keeper: Optional[_WalKeeper] = None
+        if wal and sole_writer:
+            self.db.execute("PRAGMA wal_autocheckpoint=0")
+            self._keeper = _WalKeeper(self.path)
         # emission audit sidecar (reference: emission_details.json pickledb)
         self.emission_path = (
             os.path.splitext(path)[0] + ".emission.json" if path else None
@@ -360,6 +491,11 @@ class ChainState(StateViews):
             self.enable_device_index()
 
     def close(self):
+        # the keeper's connection goes first, so that this one is the
+        # file's last and its close folds the log
+        if self._keeper is not None:
+            self._keeper.stop()
+            self._keeper = None
         self.db.close()
 
     @asynccontextmanager
@@ -378,6 +514,7 @@ class ChainState(StateViews):
                 self.db.execute("BEGIN")
                 yield
                 self.db.commit()
+                self._committed()
             except BaseException:
                 self.db.rollback()
                 self._amount_cache.clear()  # may hold rolled-back rows
@@ -389,6 +526,26 @@ class ChainState(StateViews):
     def _commit(self) -> None:
         if not getattr(self, "_in_atomic", False):
             self.db.commit()
+            self._committed()
+
+    def _committed(self) -> None:
+        """After a commit of this connection's: who folds the log.
+        Never raises (the commit stands whatever becomes of its
+        checkpoint)."""
+        keeper = self._keeper
+        if keeper is None:
+            return
+        self.wal_pages = pages = keeper.log_frames()
+        if pages > _INLINE_CHECKPOINT_PAGES:
+            telemetry.inc("state.checkpoint_inline")
+            try:
+                with keeper.checkpointing, telemetry.span(
+                        "state.wal_checkpoint", inline=True) as sp:
+                    _checkpoint(self.db, sp)
+            except sqlite3.Error:
+                log.exception("write-ahead log checkpoint failed")
+        elif pages >= _CHECKPOINT_PAGES:
+            keeper.wake()
 
     # ------------------------------------------------------------- blocks --
 

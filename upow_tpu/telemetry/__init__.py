@@ -72,6 +72,12 @@ def configure(cfg=None) -> None:
                  "verify.canary_pass", "verify.canary_fail",
                  "resilience.device_fallback"):
         metrics.ensure_counter(name)
+    # the write-ahead log's keeper (state/storage.py): checkpoints run,
+    # the frames they left folded, and those the committing thread had
+    # to run itself because the log had outgrown its bound
+    for name in ("state.checkpoints", "state.checkpoint_frames",
+                 "state.checkpoint_inline"):
+        metrics.ensure_counter(name)
     device.preregister("sha256_txid")
     device.preregister_runtime()
     device.preregister_index()
